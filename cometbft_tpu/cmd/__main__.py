@@ -217,12 +217,15 @@ def cmd_devnet(args) -> int:
     cs0 = nodes[0].consensus_state
     target = args.blocks
     t0 = time.time()
+    first_block_s = None
     try:
         last = 0
         while target <= 0 or cs0.rs.height <= target:
             time.sleep(0.2)
             if cs0.rs.height != last:
                 last = cs0.rs.height
+                if first_block_s is None and last > 1:
+                    first_block_s = round(time.time() - t0, 2)
                 print(f"height={last - 1} committed  ({(last - 1) / max(time.time() - t0, 1e-9):.2f} blocks/s)")
             if target > 0 and cs0.rs.height > target:
                 break
@@ -231,12 +234,39 @@ def cmd_devnet(args) -> int:
     for node in nodes:
         node.stop()
     print(f"devnet done at height {cs0.rs.height - 1}")
+    # The chain's own account of the run: at the highest height every node
+    # stored, all of them must hold the same block (its header carries the
+    # app hash of the height before), and a commit round above 0 marks a
+    # height that needed more than one round — e.g. a device compile or
+    # backend start-up landing inside a live round.
+    common = min(node.block_store.height() for node in nodes)
+    heads = {
+        (meta.block_id.hash, meta.header.app_hash)
+        for meta in (node.block_store.load_block_meta(common) for node in nodes)
+        if meta is not None
+    }
+    agree = common > 0 and len(heads) == 1
+    store0 = nodes[0].block_store
+    late_rounds = [
+        h
+        for h in range(1, store0.height())
+        if (store0.load_block_commit(h) or store0.load_seen_commit(h)).round > 0
+    ]
+    summary = {
+        "height": cs0.rs.height - 1,
+        "common_height": common,
+        "nodes_agree": agree,
+        "app_hash": next(iter(heads))[1].hex() if agree else None,
+        "first_block_s": first_block_s,
+        "heights_round_gt0": late_rounds,
+    }
+    print(f"devnet summary: {json.dumps(summary)}")
     from cometbft_tpu.sidecar import backend as _backend_mod
 
     live = _backend_mod._backend
     if live is not None and hasattr(live, "counters"):
-        print(f"backend counters: {live.counters()}")
-    return 0
+        print(f"backend counters: {json.dumps(live.counters(), default=str)}")
+    return 0 if agree else 1
 
 
 def cmd_light(args) -> int:

@@ -11,8 +11,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-
-from cometbft_tpu.libs import tomlcompat as tomllib
+import tomllib
 
 from cometbft_tpu.config.config import Config
 

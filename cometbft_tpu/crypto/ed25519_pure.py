@@ -187,6 +187,41 @@ def verify_zip215(pub: bytes, msg: bytes, sig: bytes) -> bool:
     return point_equal(eight_diff, IDENTITY)
 
 
+def zip215_edge_cases() -> list[tuple[str, bytes, bytes, bytes]]:
+    """(name, pub, msg, sig) edge vectors every verification tier is held
+    to against verify_zip215: non-canonical A/R encodings, small-order
+    components, s-range boundaries, malformed lengths, plain corruption.
+    Non-canonical encodings only exist for y < 19 (bit 255 is the sign
+    bit): y' = y + p is the ZIP-215 alias. The identity (y=1) has one —
+    rule 1 says it must DECODE, and with s=0 the cofactored equation
+    holds."""
+
+    def enc_int(y, sign=0):
+        return (y | (sign << 255)).to_bytes(32, "little")
+
+    seed = hashlib.sha512(b"zip215-edge").digest()[:32]
+    pub = public_key(seed)
+    msg = b"edge-message"
+    good = sign(seed, pub, msg)
+    small_order = (1).to_bytes(32, "little")  # y=1 -> identity point
+    noncanon_identity = enc_int(1 + P)
+    s0 = (0).to_bytes(32, "little")
+    return [
+        ("valid", pub, msg, good),
+        ("wrong-msg", pub, b"tampered", good),
+        ("corrupt-sig", pub, msg, good[:10] + bytes([good[10] ^ 1]) + good[11:]),
+        ("s=L", pub, msg, good[:32] + L.to_bytes(32, "little")),
+        ("s=L-1(garbage-R)", pub, msg, b"\x11" * 32 + (L - 1).to_bytes(32, "little")),
+        ("s=0 identity-A", small_order, msg, small_order + s0),
+        ("bad-pub-len", pub[:31], msg, good),
+        ("bad-sig-len", pub, msg, good[:63]),
+        ("undecodable-A", enc_int(P - 1, 0), msg, good),  # may or may not decode
+        ("noncanon-identity-A s=0", noncanon_identity, msg, small_order + s0),
+        ("y>=p-A", enc_int((1 << 255) - 1, 0), msg, good),  # reduces mod p
+        ("x0-sign1-A", enc_int(0, 1), msg, good),  # x=0 with sign bit: rejected
+    ]
+
+
 def batch_verify_zip215(
     pubs: list[bytes], msgs: list[bytes], sigs: list[bytes], rand_bytes=None
 ) -> tuple[bool, list[bool]]:

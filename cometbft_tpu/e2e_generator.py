@@ -429,7 +429,7 @@ def run_matrix(
     passed = sorted(s for s, r in results.items() if r["ok"])
     failed = sorted(s for s, r in results.items() if not r["ok"])
     stalled = sorted(s for s, r in results.items() if r.get("stalled"))
-    # One grep-able line per sweep for tpu_watch.log: per-seed verdicts.
+    # One grep-able line per sweep: per-seed verdicts.
     verdicts = " ".join(
         f"seed{s}:" + (
             "ok" if results[s]["ok"]

@@ -7,10 +7,14 @@ field multiplications than per-signature verification.  This package is
 that tier for the TPU framework's device-less hosts: `ed25519_msm.c`
 (radix-51 field arithmetic, ZIP-215 decompression, Pippenger MSM) and
 `sha256_merkle.c` (RFC-6962 tree with the whole level loop in C), built
-on first use with gcc into `_build/libcmtpu_native.so` and driven via
-ctypes.  Falls back cleanly (available() -> False) when no compiler is
-present; semantics are anchored by cometbft_tpu/crypto/ed25519_pure.py
-and the pure merkle tree, tested bit-exact in tests/test_native.py.
+on first use with gcc into `_build/libcmtpu_native-<hash>.so` — the hash
+covers the three sources and the compiler flags, so a binary copied along
+with a checkout can only load if it was built from exactly these bytes —
+and driven via ctypes.  Without a compiler available() is False and the
+callers keep their slower paths; require() raises what stopped the build
+for paths that must not run without it.  Semantics are anchored by
+cometbft_tpu/crypto/ed25519_pure.py and the pure merkle tree, tested
+bit-exact in tests/test_native.py.
 
 Soundness: the batch equation uses independent 128-bit random nonzero
 coefficients, so a batch that verifies without being valid has probability
@@ -30,7 +34,8 @@ import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ("ed25519_msm.c", "sha256_merkle.c", "fe_ifma.c")
-_SO_PATH = os.path.join(_HERE, "_build", "libcmtpu_native.so")
+_CFLAGS = ("-O3", "-fPIC", "-shared")
+_BUILD_DIR = os.path.join(_HERE, "_build")
 
 L = 2**252 + 27742317777372353535851937790883648493
 
@@ -39,39 +44,61 @@ _lock = threading.Lock()
 _msm_lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
+_error = ""  # why _lib is None once _tried
 
 
-def _build() -> str | None:
-    srcs = [os.path.join(_HERE, s) for s in _SOURCES]
+def _so_path() -> str:
+    """`_build/libcmtpu_native-<hash>.so`, the hash over the compiler
+    flags and every source byte."""
+    h = hashlib.sha256(" ".join(_CFLAGS).encode())
+    for name in _SOURCES:
+        with open(os.path.join(_HERE, name), "rb") as f:
+            h.update(b"\0" + name.encode() + b"\0" + f.read())
+    return os.path.join(_BUILD_DIR, f"libcmtpu_native-{h.hexdigest()[:16]}.so")
+
+
+def _build() -> str:
+    """Path of the library for the current sources, compiling it if no
+    file of that name exists yet. Raises what gcc or the filesystem did."""
+    path = _so_path()
+    if os.path.exists(path):
+        return path
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = path + f".tmp.{os.getpid()}"
     try:
-        src_mtime = max(os.path.getmtime(s) for s in srcs)
-        if os.path.exists(_SO_PATH) and os.path.getmtime(_SO_PATH) >= src_mtime:
-            return _SO_PATH
-        os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
-        tmp = _SO_PATH + f".tmp.{os.getpid()}"
         subprocess.run(
-            ["gcc", "-O3", "-fPIC", "-shared", "-o", tmp, *srcs],
+            ["gcc", *_CFLAGS, "-o", tmp]
+            + [os.path.join(_HERE, s) for s in _SOURCES],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        os.replace(tmp, _SO_PATH)
-        return _SO_PATH
-    except Exception:
-        return None
+    except subprocess.CalledProcessError as e:
+        raise OSError(
+            f"gcc exited {e.returncode}: {e.stderr.decode(errors='replace')[-2000:]}"
+        ) from e
+    os.replace(tmp, path)
+    return path
 
 
 def _load() -> ctypes.CDLL | None:
-    global _lib, _tried
+    global _lib, _tried, _error
     if _tried:
         return _lib
     with _lock:
         if _tried:
             return _lib
         if os.environ.get("CMTPU_NATIVE", "1") == "0":
+            _error = "disabled by CMTPU_NATIVE=0"
             _tried = True
             return None
-        path = _build()
+        try:
+            path = _build()
+        except (OSError, subprocess.TimeoutExpired) as e:
+            # No compiler / failed compile / read-only checkout: the host
+            # tier keeps its slower paths, and require() reports this.
+            path = None
+            _error = f"{type(e).__name__}: {e}"
         if path is not None:
             try:
                 lib = ctypes.CDLL(path)
@@ -124,8 +151,9 @@ def _load() -> ctypes.CDLL | None:
                     ctypes.c_long, ctypes.c_void_p, ctypes.c_void_p,
                 ]
                 _lib = lib
-            except OSError:
+            except OSError as e:
                 _lib = None
+                _error = f"{type(e).__name__}: {e}"
         _tried = True
         return _lib
 
@@ -134,6 +162,23 @@ def available() -> bool:
     """Blocking: builds the library on first call if needed (seconds of gcc).
     Latency-sensitive callers should use ready() + ensure_built_async()."""
     return _load() is not None
+
+
+def require() -> ctypes.CDLL:
+    """Blocking like available(), but a missing library is an error that
+    says why (gcc's own message, a load failure, CMTPU_NATIVE=0)."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError(f"native library unavailable: {_error}")
+    return lib
+
+
+def status() -> str:
+    """Non-blocking one-word state for counters and logs: `ready`,
+    `building` (not tried yet or in progress) or `failed: <why>`."""
+    if not _tried:
+        return "building"
+    return "ready" if _lib is not None else f"failed: {_error}"
 
 
 def ready():
@@ -159,9 +204,7 @@ def batch_verify(
     One MSM when everything is valid (the overwhelmingly common case);
     bisection recovers per-signature attribution on failure.
     """
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     n = len(pubs)
     bits = [False] * n
     if n == 0:
@@ -270,9 +313,7 @@ def _leaf_offsets(leaves: list[bytes]):
 
 def merkle_root(leaves: list[bytes]) -> bytes:
     """RFC-6962 root, identical to crypto/merkle hash_from_byte_slices."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     n = len(leaves)
     if n == 0:
         return hashlib.sha256(b"").digest()
@@ -291,9 +332,7 @@ def merkle_proof_parts(
     (root, leaf_hashes, packed_aunts, stride, counts) where leaf i's aunts
     are packed_aunts[i*stride : i*stride + 32*counts[i]] in 32-byte nodes,
     ordered sibling-first (crypto/merkle/proof.go:35-49 shape)."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     n = len(leaves)
     if n == 0:
         return hashlib.sha256(b"").digest(), [], b"", 0, []
@@ -323,9 +362,7 @@ def merkle_proof_parts(
 
 def sha256_batch(msgs: list[bytes]) -> list[bytes]:
     """Batch SHA-256 without per-call interpreter dispatch."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     n = len(msgs)
     if n == 0:
         return []

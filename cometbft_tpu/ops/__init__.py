@@ -12,4 +12,11 @@ Everything the reference dispatches through `crypto.BatchVerifier`
 
 Layouts put the batch dimension LAST ([limbs, N] / [words, N]) so the batch
 fills TPU vector lanes while limb/word indices stay static Python ints.
+
+Importing this package points JAX at the shared persistent compile cache
+(ops/xla_cache.py) before any of its programs can compile.
 """
+
+from cometbft_tpu.ops.xla_cache import enable_persistent_cache
+
+enable_persistent_cache()

@@ -128,8 +128,8 @@ def leaves_to_root_core(blocks, nblocks):
     """ONE jittable program: leaf-hash all padded messages AND reduce the
     full tree to the root. blocks uint32[B, 16, n] (n a power of two),
     nblocks int32[n] -> uint32[8, 1]. Fusing the leaf pass and the log2(n)
-    inner levels into a single dispatch matters on tunneled deployments
-    where each dispatch costs a host round-trip."""
+    inner levels into a single dispatch saves one host round-trip per
+    level."""
     cur = _leaf_core(blocks, nblocks)
     while cur.shape[1] > 1:
         cur = _inner_core(cur[:, 0::2], cur[:, 1::2])

@@ -18,8 +18,8 @@ broadcast into the lanes; the algorithms mirror ops/edwards.py exactly
 (same precomp form, same signed-window schedule) and are held to it by
 tests/test_pallas_ladder.py in interpret mode.
 
-Routed by CMTPU_LADDER=pallas (ed25519_kernel); A/B'd on device by
-tpu_ab.py.
+Routed by CMTPU_LADDER=pallas (ed25519_kernel); opt-in, off every
+shipped path.
 """
 
 from __future__ import annotations
@@ -168,8 +168,8 @@ def _ladder_math(s_dig, k_dig, ax, ay, az, at, n_windows=None):
     # per-lane [1..8]A table in precomp form. The chain is UNROLLED in
     # python: the rolled fori_loop form needed `tbl.at[i].set(...)` with a
     # traced index, which jnp lowers to `scatter` — a primitive Mosaic's TC
-    # kernel lowering does not implement (measured on device, tpu_ab.log
-    # round 5). Seven inlined point adds cost trace size, but inside ONE
+    # kernel lowering does not implement (measured on device, round
+    # 5). Seven inlined point adds cost trace size, but inside ONE
     # Mosaic kernel the XLA whole-graph compile ceiling that forced the
     # rolled form on the stacked path does not apply.
     pp = _to_precomp(a_point)

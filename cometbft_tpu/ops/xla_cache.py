@@ -1,14 +1,15 @@
-"""One definition of the repo's persistent XLA compile-cache setup.
+"""The repo's one persistent XLA compile-cache setting.
 
-The 8-device virtual-mesh programs (sharded verify, the two-process
-multihost commit step, the bn254 aggregate kernel) cost tens of seconds
-to compile on XLA:CPU; pointing every jax-using entry point — conftest,
-bench subprocess workers, the multihost/fanout shard workers — at the
-same `.jax_cache` directory under the repo root means each program
-compiles once per machine, not once per process. This used to be the
-same five lines copy-pasted into each of those files; a helper keeps the
-next worker script from drifting (e.g. forgetting the min-size knobs and
-silently caching nothing).
+Every program under `cometbft_tpu/ops` costs seconds to minutes to
+compile, and every product entry point (`python -m cometbft_tpu.sidecar`,
+`cmd start`, `cmd devnet`), the tests and `chip_smoke.py` reach them
+through this package — so `ops/__init__.py` calls the function below once
+at import and all of them share one cache.
+
+Where `JAX_COMPILATION_CACHE_DIR` is set, that directory is JAX's own
+default and nothing here overrides it (a machine that provides a cache
+keeps it); otherwise the cache lives at `<checkout>/.jax_cache`. The
+directory is part of the cache key, so it must not move between runs.
 """
 
 from __future__ import annotations
@@ -20,22 +21,16 @@ _REPO_ROOT = os.path.dirname(
 )
 
 
-def cache_dir(repo_root: str | None = None) -> str:
-    return os.path.join(repo_root or _REPO_ROOT, ".jax_cache")
-
-
-def enable_persistent_cache(repo_root: str | None = None) -> bool:
+def enable_persistent_cache() -> str:
     """Point this process's JAX at the shared on-disk compile cache, with
-    the size/time floors zeroed so even small programs persist. Imports
-    jax (and may initialize its config layer, NOT the backend); returns
-    False instead of raising when the running jaxlib lacks the knobs, so
-    callers can log-and-continue."""
+    the size/time floors zeroed so even small programs persist. Touches
+    jax's config layer only, never the backend. Returns the directory."""
     import jax
 
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir(repo_root))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        return True
-    except Exception:
-        return False
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir", os.path.join(_REPO_ROOT, ".jax_cache")
+        )
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return jax.config.jax_compilation_cache_dir

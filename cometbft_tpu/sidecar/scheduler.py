@@ -22,7 +22,7 @@ untagged (blocksync-class) traffic under a compat hold.
 
 The sidecar SERVER embeds the same shim over its device lock
 (sidecar/service.py, round 10): there the concurrent submitters are
-CONNECTIONS — many node processes sharing one tunnel — and streamed
+CONNECTIONS — many node processes sharing one chip — and streamed
 chunks, so cross-process requests merge into one columnar dispatch with
 the identical slicing/fallback discipline.
 """

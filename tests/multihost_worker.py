@@ -48,13 +48,6 @@ multihost.distributed_init(f"127.0.0.1:{port}", nproc, pid)
 
 import jax  # noqa: E402
 
-# Share the repo's persistent XLA compile cache (same as conftest/bench):
-# the 8-device two-process programs cost tens of seconds to compile on
-# XLA:CPU and would otherwise be re-paid by every tier-1 sweep.
-from cometbft_tpu.ops import xla_cache  # noqa: E402
-
-xla_cache.enable_persistent_cache()
-
 from cometbft_tpu.ops import sharded  # noqa: E402
 
 from cometbft_tpu.ops import ed25519_kernel as ek  # noqa: E402

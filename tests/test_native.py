@@ -54,33 +54,7 @@ def test_zip215_edge_vectors_match_pure():
     """The exact edge-vector set the device kernel is held to
     (tests/test_ops_kernel.py): non-canonical encodings, small-order
     points, s-range boundaries, malformed lengths."""
-    P, L = pure.P, pure.L
-
-    def enc_int(y, sign=0):
-        return (y | (sign << 255)).to_bytes(32, "little")
-
-    priv = ed25519.gen_priv_key_from_secret(b"edge")
-    pub = priv.pub_key().bytes()
-    msg = b"edge-message"
-    good = priv.sign(msg)
-    small_order = (1).to_bytes(32, "little")
-    noncanon_identity = enc_int(1 + P)
-
-    cases = [
-        ("valid", pub, msg, good),
-        ("wrong-msg", pub, b"tampered", good),
-        ("corrupt-sig", pub, msg, good[:10] + bytes([good[10] ^ 1]) + good[11:]),
-        ("s=L", pub, msg, good[:32] + L.to_bytes(32, "little")),
-        ("s=L-1(garbage-R)", pub, msg, b"\x11" * 32 + (L - 1).to_bytes(32, "little")),
-        ("s=0 identity-A", small_order, msg, small_order + (0).to_bytes(32, "little")),
-        ("bad-pub-len", pub[:31], msg, good),
-        ("bad-sig-len", pub, msg, good[:63]),
-        ("undecodable-A", enc_int(P - 1, 0), msg, good),
-        ("noncanon-identity-A s=0", noncanon_identity, msg,
-         small_order + (0).to_bytes(32, "little")),
-        ("y>=p-A", enc_int((1 << 255) - 1, 0), msg, good),
-        ("x0-sign1-A", enc_int(0, 1), msg, good),
-    ]
+    cases = pure.zip215_edge_cases()
     pubs = [c[1] for c in cases]
     msgs = [c[2] for c in cases]
     sigs = [c[3] for c in cases]

@@ -9,9 +9,9 @@ bit-for-bit, and the precomp-form point algebra matches ed25519_pure.
 What CANNOT: executing the full kernel on CPU.  The ~28k-op body is
 exactly the planar graph XLA:CPU compiles quadratically (the reason
 CMTPU_FE_MODE=compact exists), and Pallas interpret-mode emulation of a
-body this size is slower still.  On device the kernel is adopted only if
-tpu_ab.py's A/B wins AND the full bench re-run — whose commit-verify
-stages assert correct bitmaps — agrees (tpu_watch.sh).
+body this size is slower still.  On device the kernel stays opt-in until
+a chip benchmark's commit-verify cells, which assert correct bitmaps,
+show it winning (ROADMAP D2).
 """
 
 import jax
