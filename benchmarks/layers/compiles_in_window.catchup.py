@@ -1,0 +1,1 @@
+from layerlib import compiles_in_window as read  # noqa: F401

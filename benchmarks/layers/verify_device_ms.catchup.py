@@ -1,0 +1,1 @@
+from layerlib import verify_device_ms as read  # noqa: F401
