@@ -55,8 +55,10 @@ class BlockPool:
 
     # -- scheduling -----------------------------------------------------------
 
-    def make_requests(self) -> None:
-        """Spawn requesters for the window and (re)assign idle ones."""
+    def make_requests(self) -> int:
+        """Spawn requesters for the window and (re)assign idle ones.
+        Returns how many block requests it sent."""
+        sent = 0
         with self._mtx:
             for h in range(self.height, min(self.height + POOL_WINDOW, self.max_peer_height + 1)):
                 if h not in self._requesters:
@@ -75,6 +77,8 @@ class BlockPool:
                 req.peer_id = peer
                 req.requested_at = now
                 self._send_request(peer, req.height)
+                sent += 1
+        return sent
 
     def _pick_peer(self, height: int) -> str | None:
         for peer_id, peer_height in self._peers.items():

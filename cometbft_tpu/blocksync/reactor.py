@@ -17,6 +17,7 @@ import threading
 import time
 
 from cometbft_tpu.blocksync.pool import BlockPool
+from cometbft_tpu.libs import trace
 from cometbft_tpu.p2p.conn.connection import ChannelDescriptor
 from cometbft_tpu.p2p.reactor import BLOCKSYNC_CHANNEL, Reactor
 from cometbft_tpu.sidecar import engine
@@ -65,6 +66,10 @@ def decode_message(data: bytes):
     raise ValueError("unknown blocksync message")
 
 
+# First byte of an encoded block_response: field 3, length-delimited.
+_BLOCK_RESPONSE_KEY = bytes([3 << 3 | 2])
+
+
 class BlocksyncReactor(Reactor):
     """blocksync/reactor.go Reactor."""
 
@@ -98,6 +103,19 @@ class BlocksyncReactor(Reactor):
         )
         self._pf_job: tuple[threading.Event, list[float]] | None = None
         self.pipeline_overlap_ms = 0.0  # verify/apply overlap accumulated
+        # Always counted (counters(), on /metrics through the node's lazy
+        # gauges): where the sync thread's time goes when it is not applying
+        # a block. The two waits are timed round their spans, so a traced
+        # run's counter reads the span plus the span's own enter and exit.
+        self.heights_applied = 0
+        self.fetch_wait_ms = 0.0   # no pair of blocks to verify yet
+        self.verify_wait_ms = 0.0  # blocked on the prefetch worker
+        self.idle_sleeps = 0       # 10 ms sleeps of the pool routine
+        self.redo_requests = 0     # blocks refused and asked for again
+        # The fetch wait under way: (its span, perf_counter at its start,
+        # idle_sleeps at its start), from the first peek that found no pair
+        # to the next that finds one.
+        self._fetch_wait: tuple | None = None
 
     def get_channels(self):
         return [
@@ -141,7 +159,25 @@ class BlocksyncReactor(Reactor):
     def remove_peer(self, peer, reason) -> None:
         self.pool.remove_peer(peer.id)
 
+    def counters(self) -> dict:
+        return {
+            "heights_applied": self.heights_applied,
+            "fetch_wait_ms": round(self.fetch_wait_ms, 3),
+            "verify_wait_ms": round(self.verify_wait_ms, 3),
+            "idle_sleeps": self.idle_sleeps,
+            "redo_requests": self.redo_requests,
+            "pipeline_overlap_ms": round(self.pipeline_overlap_ms, 3),
+        }
+
     def receive(self, chan_id: int, peer, msg_bytes: bytes) -> None:
+        if msg_bytes[:1] == _BLOCK_RESPONSE_KEY:
+            # the one message with real work behind it: block decode + pool
+            with trace.span("blocksync.decode", bytes=len(msg_bytes)):
+                self._receive(peer, msg_bytes)
+        else:
+            self._receive(peer, msg_bytes)
+
+    def _receive(self, peer, msg_bytes: bytes) -> None:
         kind, payload = decode_message(msg_bytes)
         if kind == "block_request":
             block = self.block_store.load_block(payload)
@@ -170,9 +206,31 @@ class BlocksyncReactor(Reactor):
     # -- sync loop (reactor.go:280-410 poolRoutine) ---------------------------
 
     def _pool_routine(self) -> None:
+        try:
+            self._pool_loop()
+        finally:
+            self._fetch_wait_end()
+
+    def _fetch_wait_begin(self) -> None:
+        if self._fetch_wait is None:
+            t0 = time.perf_counter()
+            wait = trace.span("blocksync.fetch_wait")
+            wait.__enter__()
+            self._fetch_wait = (wait, t0, self.idle_sleeps)
+
+    def _fetch_wait_end(self) -> None:
+        if self._fetch_wait is not None:
+            wait, t0, sleeps0 = self._fetch_wait
+            self._fetch_wait = None
+            wait.set(sleeps=self.idle_sleeps - sleeps0)
+            wait.__exit__(None, None, None)
+            self.fetch_wait_ms += (time.perf_counter() - t0) * 1000.0
+
+    def _pool_loop(self) -> None:
         status_tick = 0.0
         while self._running and not self.synced:
-            self.pool.make_requests()
+            with trace.span("blocksync.make_requests") as req:
+                req.set(sent=self.pool.make_requests())
             now = self.clock.now()
             if now - status_tick > 10:
                 status_tick = now
@@ -190,6 +248,7 @@ class BlocksyncReactor(Reactor):
                 if self.on_caught_up:
                     self.on_caught_up(self.state)
                 return
+            self.idle_sleeps += 1
             self.clock.sleep(0.01)
 
     # Prefetch window: how many consecutive fetched blocks to batch-verify
@@ -243,45 +302,48 @@ class BlocksyncReactor(Reactor):
         # bv.add raise), and backend hiccups surface from bv.verify — the
         # per-block path re-verifies, attributes, and punishes as before.
         try:
-            bv = ed25519.BatchVerifier()
-            vh = vals.hash()
-            chain_id = self.state.chain_id
-            covered = 0
-            for j in range(len(window) - 1):
-                blk, nxt = window[j], window[j + 1]
-                commit = nxt.last_commit
-                if (
-                    blk.header.validators_hash != vh
-                    or commit is None
-                    or commit.height != blk.header.height
-                    or len(commit.signatures) != len(vals.validators)
-                ):
-                    break
-                sbs = commit.vote_sign_bytes_all(chain_id)
-                for idx, cs in enumerate(commit.signatures):
-                    if cs.is_absent():
-                        continue
-                    bv.add(vals.validators[idx].pub_key, sbs[idx], cs.signature)
-                covered += 1
-            self._prefetched_to = self.pool.height + max(covered, 1)
-            if covered >= 2 and len(bv):
-                # Blocksync-class engine admission (the untagged default,
-                # made explicit): window pre-verify yields to consensus
-                # votes but outranks ingress and light prewarm.
-                with engine.submission_class(engine.CLASS_BLOCKSYNC):
-                    bv.verify()  # populates the cache; bad sigs fall to per-block
+            with trace.span("blocksync.prefetch") as job:
+                bv = ed25519.BatchVerifier()
+                vh = vals.hash()
+                chain_id = self.state.chain_id
+                covered = 0
+                for j in range(len(window) - 1):
+                    blk, nxt = window[j], window[j + 1]
+                    commit = nxt.last_commit
+                    if (
+                        blk.header.validators_hash != vh
+                        or commit is None
+                        or commit.height != blk.header.height
+                        or len(commit.signatures) != len(vals.validators)
+                    ):
+                        break
+                    sbs = commit.vote_sign_bytes_all(chain_id)
+                    for idx, cs in enumerate(commit.signatures):
+                        if cs.is_absent():
+                            continue
+                        bv.add(vals.validators[idx].pub_key, sbs[idx], cs.signature)
+                    covered += 1
+                job.set(blocks=covered, lanes=len(bv))
+                self._prefetched_to = self.pool.height + max(covered, 1)
+                if covered >= 2 and len(bv):
+                    # Blocksync-class engine admission (the untagged default,
+                    # made explicit): window pre-verify yields to consensus
+                    # votes but outranks ingress and light prewarm.
+                    with engine.submission_class(engine.CLASS_BLOCKSYNC):
+                        bv.verify()  # populates the cache; bad sigs fall to per-block
         except Exception:
             self._prefetched_to = self.pool.height + 1
 
     # -- verify/apply pipeline ------------------------------------------------
 
-    def _pipeline_submit(self) -> None:
+    def _pipeline_submit(self) -> bool:
         """Kick the prefetch producer on a worker so it overlaps the
         apply_block that follows. One-deep: a still-running job means the
-        producer is already ahead — never stack a second one."""
+        producer is already ahead — never stack a second one. Returns
+        whether it started a worker."""
         job = self._pf_job
         if job is not None and not job[0].is_set():
-            return
+            return False
         done = threading.Event()
         times = [time.monotonic(), 0.0]
 
@@ -294,6 +356,7 @@ class BlocksyncReactor(Reactor):
 
         self._pf_job = (done, times)
         threading.Thread(target=run, daemon=True).start()
+        return True
 
     def _pipeline_wait(self) -> None:
         """Barrier before the serial verify: the producer must have finished
@@ -319,38 +382,59 @@ class BlocksyncReactor(Reactor):
         (VerifyCommitLight — batched on device), then apply."""
         first, second = self.pool.peek_two_blocks()
         if first is None or second is None:
+            self._fetch_wait_begin()
             return False
+        self._fetch_wait_end()
+        with trace.span("blocksync.sync_one", height=first.header.height) as one:
+            applied = self._sync_pair(first, second)
+            one.set(applied=applied)
+        return applied
+
+    def _sync_pair(self, first, second) -> bool:
         if self._pipeline_enabled:
-            self._pipeline_wait()
+            t0 = time.perf_counter()
+            with trace.span("blocksync.verify_wait"):
+                self._pipeline_wait()
+            self.verify_wait_ms += (time.perf_counter() - t0) * 1000.0
         else:
             self._prefetch_verify_window()
-        first_parts = first.make_part_set()
-        first_id = BlockID(first.hash(), first_parts.header())
+        with trace.span("blocksync.part_set"):
+            first_parts = first.make_part_set()
+            first_id = BlockID(first.hash(), first_parts.header())
         try:
             # ★ the TPU call (types/validation.go:59 via blocksync/reactor.go:360)
-            self.state.validators.verify_commit_light(
-                self.state.chain_id, first_id, first.header.height, second.last_commit
-            )
-            self.block_exec.validate_block(self.state, first)
+            with trace.span("blocksync.verify_light"):
+                self.state.validators.verify_commit_light(
+                    self.state.chain_id, first_id, first.header.height,
+                    second.last_commit,
+                )
+            with trace.span("blocksync.validate"):
+                self.block_exec.validate_block(self.state, first)
         except Exception:
+            self.redo_requests += 1
             bad_peer = self.pool.redo_request(first.header.height)
             if bad_peer and self.switch:
                 peer = self.switch.get_peer(bad_peer)
                 if peer:
                     self.switch.stop_peer_for_error(peer, "sent us an invalid block")
             return False
-        self.block_store.save_block(first, first_parts, second.last_commit)
+        with trace.span("blocksync.save"):
+            self.block_store.save_block(first, first_parts, second.last_commit)
         if self._pipeline_enabled:
             # Overlap the next window's verification (device) with this
             # block's application (app). The worker only POPULATES the
             # verified-triple cache — the accepting verify_commit_light
             # above still runs serially on this thread, so a validator-set
             # change simply misses the cache and verifies inline.
-            self._pipeline_submit()
+            with trace.span("blocksync.pipeline_submit") as kick:
+                kick.set(started=self._pipeline_submit())
             t0 = time.monotonic()
-            self.state, _ = self.block_exec.apply_block(self.state, first_id, first)
+            with trace.span("blocksync.apply"):
+                self.state, _ = self.block_exec.apply_block(self.state, first_id, first)
             self._pipeline_account(t0, time.monotonic())
         else:
-            self.state, _ = self.block_exec.apply_block(self.state, first_id, first)
+            with trace.span("blocksync.apply"):
+                self.state, _ = self.block_exec.apply_block(self.state, first_id, first)
         self.pool.pop_request()
+        self.heights_applied += 1
         return True

@@ -26,6 +26,7 @@ from cometbft_tpu.crypto.compat import (
     Ed25519PublicKey,
     InvalidSignature,
 )
+from cometbft_tpu.libs import trace
 
 KEY_TYPE = "ed25519"
 PUB_KEY_SIZE = 32
@@ -155,18 +156,33 @@ def _from_seed(seed: bytes) -> PrivKey:
 _VERIFIED_MAX = int(os.environ.get("CMTPU_VERIFY_CACHE_MAX", "") or 131072)
 _verified: dict[tuple, None] = {}
 _verified_lock = threading.Lock()
+# What the cache did, always counted (under _verified_lock): per verify()
+# call by arithmetic on lengths, never per triple. entries = hits + dups +
+# dispatched.
+_cache_counts = dict.fromkeys(
+    ("entries", "hits", "dups", "dispatched", "inserted", "evicted"), 0
+)
 
 
-def _verified_put_many(keys: list[tuple]) -> None:
+def verified_cache_counters() -> dict:
+    """Running counts of the verified-triple cache plus its present size."""
+    with _verified_lock:
+        return {**_cache_counts, "size": len(_verified)}
+
+
+def _verified_put_many(keys: list[tuple]) -> int:
     """Insert verified triples under one lock acquisition (10k inserts after
     a commit verify would otherwise take the lock 10k times).  Writers race
     from multiple threads (blocksync pool routine, consensus, light client);
     eviction shares the lock so list(dict) never races an insert.  The
     oldest-quarter eviction repeats until the bound holds, so even a batch
-    larger than a quarter of the cache cannot push it past _VERIFIED_MAX."""
+    larger than a quarter of the cache cannot push it past _VERIFIED_MAX.
+    Returns how many triples the sweeps evicted."""
     if not keys:
-        return
+        return 0
+    evicted = 0
     with _verified_lock:
+        size = len(_verified)
         for key in keys:
             if key in _verified:
                 # LRU refresh: a re-verified triple moves to the young end
@@ -174,9 +190,14 @@ def _verified_put_many(keys: list[tuple]) -> None:
                 # eviction sweeps.
                 del _verified[key]
             elif len(_verified) >= _VERIFIED_MAX:
+                before = len(_verified)
                 for k in list(_verified)[: max(1, _VERIFIED_MAX // 4)]:
                     _verified.pop(k, None)
+                evicted += before - len(_verified)
             _verified[key] = None
+        _cache_counts["inserted"] += len(_verified) - size + evicted
+        _cache_counts["evicted"] += evicted
+    return evicted
 
 
 def _verified_put(key: tuple) -> None:
@@ -237,38 +258,55 @@ class BatchVerifier(crypto.BatchVerifier):
         # from it after the dispatch. Membership is decided ONCE here —
         # concurrent writers may grow the cache mid-verify, and the merge
         # below must honor the filter's snapshot, not a fresher one.
-        keys = list(zip(self._pubs, self._sigs, self._msgs))
-        lane_of: dict[tuple, int] = {}
-        lanes: list[int] = []  # per-entry lane, -1 = cache hit
-        sub_pubs: list[bytes] = []
-        sub_msgs: list[bytes] = []
-        sub_sigs: list[bytes] = []
-        for key in keys:
-            if key in _verified:
-                lanes.append(-1)
-                continue
-            lane = lane_of.get(key)
-            if lane is None:
-                lane = len(sub_pubs)
-                lane_of[key] = lane
-                sub_pubs.append(key[0])
-                sub_msgs.append(key[2])
-                sub_sigs.append(key[1])
-            lanes.append(lane)
-        if not sub_pubs:
-            return True, [True] * len(keys)
-        try:
-            _, sub_bits = get_backend().batch_verify(sub_pubs, sub_msgs, sub_sigs)
-        except ChainExhausted:
-            # Every tier of the supervised chain failed (chaos runs can
-            # arrange this). Consensus liveness outranks batch speed:
-            # verify each signature through the scalar ZIP-215 path.
-            sub_bits = [
-                ed25519_pure.verify_zip215(p, m, s)
-                for p, m, s in zip(sub_pubs, sub_msgs, sub_sigs)
-            ]
-        bits = [True if lane < 0 else sub_bits[lane] for lane in lanes]
-        _verified_put_many(
-            [k for k, lane in zip(keys, lanes) if lane >= 0 and sub_bits[lane]]
-        )
-        return all(bits), bits
+        with trace.span("batch.verify", entries=len(self._pubs)) as call:
+            with trace.span("batch.cache_filter"):
+                keys = list(zip(self._pubs, self._sigs, self._msgs))
+                lane_of: dict[tuple, int] = {}
+                lanes: list[int] = []  # per-entry lane, -1 = cache hit
+                sub_pubs: list[bytes] = []
+                sub_msgs: list[bytes] = []
+                sub_sigs: list[bytes] = []
+                for key in keys:
+                    if key in _verified:
+                        lanes.append(-1)
+                        continue
+                    lane = lane_of.get(key)
+                    if lane is None:
+                        lane = len(sub_pubs)
+                        lane_of[key] = lane
+                        sub_pubs.append(key[0])
+                        sub_msgs.append(key[2])
+                        sub_sigs.append(key[1])
+                    lanes.append(lane)
+                hits = lanes.count(-1)
+                dispatched = len(sub_pubs)
+                dups = len(keys) - hits - dispatched
+                with _verified_lock:
+                    _cache_counts["entries"] += len(keys)
+                    _cache_counts["hits"] += hits
+                    _cache_counts["dups"] += dups
+                    _cache_counts["dispatched"] += dispatched
+            call.set(hits=hits, dups=dups, dispatched=dispatched, evicted=0)
+            if not sub_pubs:
+                return True, [True] * len(keys)
+            with trace.span("batch.dispatch"):
+                try:
+                    _, sub_bits = get_backend().batch_verify(
+                        sub_pubs, sub_msgs, sub_sigs
+                    )
+                except ChainExhausted:
+                    # Every tier of the supervised chain failed (chaos runs
+                    # can arrange this). Consensus liveness outranks batch
+                    # speed: verify each signature through the scalar
+                    # ZIP-215 path.
+                    sub_bits = [
+                        ed25519_pure.verify_zip215(p, m, s)
+                        for p, m, s in zip(sub_pubs, sub_msgs, sub_sigs)
+                    ]
+            with trace.span("batch.cache_insert"):
+                bits = [True if lane < 0 else sub_bits[lane] for lane in lanes]
+                evicted = _verified_put_many(
+                    [k for k, lane in zip(keys, lanes) if lane >= 0 and sub_bits[lane]]
+                )
+            call.set(evicted=evicted)
+            return all(bits), bits

@@ -15,11 +15,16 @@ Python-native equivalents of the Go profiles, plus the device tier's:
   /debug/jax/trace         capture a JAX profiler trace for ?seconds=N into
                            ?dir= (default <home>/jax-trace) — loadable in
                            TensorBoard/Perfetto; the XLA-level view of the
-                           verify/merkle kernels
+                           verify/merkle kernels. The session turns the
+                           program's spans on (libs/trace.py): they lie in
+                           the xplane as `seam:<name>` and are written as
+                           spans.json beside it
 """
 
 from __future__ import annotations
 
+import json
+import os
 import sys
 import threading
 import time
@@ -101,12 +106,27 @@ def jax_memory() -> str:
 
 
 def jax_trace(seconds: float, trace_dir: str) -> str:
+    """A profiler session of `seconds`: it is also what turns the program's
+    spans on (libs/trace.py), and the capture's spans are written as
+    `spans.json` beside the xplane, on `time.perf_counter()`."""
     import jax
 
-    jax.profiler.start_trace(trace_dir)
+    from cometbft_tpu.libs import trace
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0  # the Python tracer slows the host it observes
+    t0 = time.perf_counter()
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
     time.sleep(seconds)
     jax.profiler.stop_trace()
-    return f"trace written to {trace_dir} (open with TensorBoard/Perfetto)"
+    captured = [s for s in trace.spans() if s["t0"] >= t0]
+    with open(os.path.join(trace_dir, "spans.json"), "w") as f:
+        json.dump({"t0": t0, "t1": time.perf_counter(), "dropped": trace.dropped(),
+                   "spans": captured}, f)
+    return (
+        f"trace written to {trace_dir} (open with TensorBoard/Perfetto); "
+        f"{len(captured)} program spans in spans.json"
+    )
 
 
 class PprofServer:

@@ -17,6 +17,7 @@ immediately.
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 
@@ -474,6 +475,28 @@ class Node:
                        "95th-percentile coalescer queue wait, microseconds.",
                        sched_sample("queue_wait_p95_us"))
 
+        def hybrid_sample(key):
+            # The hybrid tier's running planner counters, wherever the chain
+            # holds it; zeros until the backend exists and has such a tier.
+            def fn():
+                b = backend_mod._backend
+                counters = getattr(b, "counters", None)
+                if counters is None:
+                    return 0
+                c = counters()
+                c = c.get("inner", c).get("tiers", {}).get("hybrid", {})
+                return int(c.get("backend", {}).get(key, 0))
+
+            return fn
+
+        for key, text in (
+            ("split_calls", "Calls the hybrid planner split between device and host."),
+            ("share_changes", "Split calls whose device share differs from the one before."),
+            ("plan_abs_err_ms", "Sum of |predicted - measured| call wall, ms."),
+            ("wall_ms", "Sum of measured call wall where a prediction was made, ms."),
+        ):
+            reg.gauge_func("hybrid", key, text, hybrid_sample(key))
+
         def sidecar_sample(key):
             # Lazy like the others: zeros until a grpc tier exists (bare
             # CMTPU_BACKEND=grpc client, or the auto chain's sidecar tier,
@@ -840,6 +863,33 @@ class Node:
                        lambda: int(getattr(
                            getattr(self, "blocksync_reactor", None),
                            "pipeline_overlap_ms", 0) or 0))
+
+        def bs(key):
+            def fn():
+                reactor = getattr(self, "blocksync_reactor", None)
+                return int(reactor.counters()[key]) if reactor else 0
+            return fn
+
+        for key, text in (
+            ("heights_applied", "Heights the blocksync reactor applied."),
+            ("fetch_wait_ms", "Sync thread with no pair of blocks to verify, ms."),
+            ("verify_wait_ms", "Sync thread blocked on the prefetch worker, ms."),
+            ("idle_sleeps", "10 ms sleeps of the blocksync pool routine."),
+            ("redo_requests", "Blocks refused and requested again."),
+        ):
+            reg.gauge_func("blocksync", key, text, bs(key))
+
+        def cache(key):
+            # sys.modules, not an import: a scrape constructs nothing.
+            def fn():
+                mod = sys.modules.get("cometbft_tpu.crypto.ed25519")
+                return mod.verified_cache_counters()[key] if mod else 0
+            return fn
+
+        for key in ("entries", "hits", "dups", "dispatched", "inserted",
+                    "evicted", "size"):
+            reg.gauge_func("verify_cache", key,
+                           f"Verified-triple cache: {key}.", cache(key))
 
     def _register_lightgw_metrics(self, reg) -> None:
         """Light-client gateway gauges. Strictly passive: they read the

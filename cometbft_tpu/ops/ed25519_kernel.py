@@ -38,6 +38,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from cometbft_tpu.libs import trace
 from cometbft_tpu.ops import edwards as ed
 from cometbft_tpu.ops import field25519 as fe
 from cometbft_tpu.ops import sha512_kernel as s5
@@ -58,6 +59,8 @@ BUCKETS = (8, 32, 128, 512, 1024, 2048, 4096, 6144, 8192, 10240, 16384, 32768)
 # vote challenge is 64 + ~120 bytes = 2 blocks; odd app messages fall into
 # the larger buckets.
 BLOCK_BUCKETS = (2, 4, 8, 32)
+# jax.named_scope of each stage of the verify program, in program order.
+KERNEL_SCOPES = ("sha512", "unpack", "decompress", "ladder", "finish")
 
 
 _probed_width = 0  # mesh_width()'s last answer; 0 = never probed
@@ -179,12 +182,15 @@ def verify_core(a_words, r_words, s_words, msg_words, msg_nblocks):
     no-op for the default stacked form)."""
     n, bwords = msg_words.shape
     bmax = bwords // 32
-    # [N, B*32] LE words -> [B, 2(hi/lo), 16, N] big-endian block words:
-    # layout shuffle + byte swap are the program's first (cheap, fused)
-    # ops instead of multi-MB host passes.
-    x = msg_words.astype(jnp.uint32).reshape(n, bmax, 16, 2)
-    blocks_be = s5.bswap32(jnp.transpose(x, (1, 3, 2, 0)))
-    k_words = s5.digest_to_le_words(s5.hash_blocks_core(blocks_be, msg_nblocks))
+    with jax.named_scope("sha512"):
+        # [N, B*32] LE words -> [B, 2(hi/lo), 16, N] big-endian block words:
+        # layout shuffle + byte swap are the program's first (cheap, fused)
+        # ops instead of multi-MB host passes.
+        x = msg_words.astype(jnp.uint32).reshape(n, bmax, 16, 2)
+        blocks_be = s5.bswap32(jnp.transpose(x, (1, 3, 2, 0)))
+        k_words = s5.digest_to_le_words(
+            s5.hash_blocks_core(blocks_be, msg_nblocks)
+        )
     return _verify_from_words(a_words, r_words, s_words, k_words)
 
 
@@ -195,30 +201,35 @@ def verify_core_hosthash(a_words, r_words, s_words, k_words):
 
 
 def _verify_from_words(a_words, r_words, s_words, k_words):
+    """The stages carry `jax.named_scope`s (KERNEL_SCOPES): HLO metadata
+    only, so the compiled programs and their cache keys stay as they were,
+    and a profile names each device operation's stage."""
     n = a_words.shape[1]
-    y_a, sign_a = unpack.words_to_limbs255(a_words)
-    y_r, sign_r = unpack.words_to_limbs255(r_words)
-    s_digits = unpack.scalar_words_to_digits(s_words)
-    k_digits = unpack.digest_words_to_digits(k_words)
-    with fe.compact_scope():
+    with jax.named_scope("unpack"):
+        y_a, sign_a = unpack.words_to_limbs255(a_words)
+        y_r, sign_r = unpack.words_to_limbs255(r_words)
+        s_digits = unpack.scalar_words_to_digits(s_words)
+        k_digits = unpack.digest_words_to_digits(k_words)
+    with jax.named_scope("decompress"), fe.compact_scope():
         y2 = jnp.concatenate([y_a, y_r], axis=1)
         sg2 = jnp.concatenate([sign_a, sign_r])
         pt, ok = ed.decompress(y2, sg2)
         a = tuple(c[:, :n] for c in pt)
         r = tuple(c[:, n:] for c in pt)
         neg_a = ed.point_neg(a)
-    if os.environ.get("CMTPU_LADDER", "xla") == "pallas":
-        # Opt-in A/B probe (ops/pallas_ladder.py): the whole ladder as one
-        # Mosaic kernel — attacks the XLA graph-size ceiling directly.
-        from cometbft_tpu.ops import pallas_ladder
+    with jax.named_scope("ladder"):
+        if os.environ.get("CMTPU_LADDER", "xla") == "pallas":
+            # Opt-in A/B probe (ops/pallas_ladder.py): the whole ladder as
+            # one Mosaic kernel — attacks the XLA graph-size ceiling directly.
+            from cometbft_tpu.ops import pallas_ladder
 
-        acc = pallas_ladder.windowed_double_base_mult(
-            s_digits, k_digits, neg_a,
-            interpret=jax.default_backend() == "cpu",
-        )
-    else:
-        acc = ed.windowed_double_base_mult(s_digits, k_digits, neg_a)
-    with fe.compact_scope():
+            acc = pallas_ladder.windowed_double_base_mult(
+                s_digits, k_digits, neg_a,
+                interpret=jax.default_backend() == "cpu",
+            )
+        else:
+            acc = ed.windowed_double_base_mult(s_digits, k_digits, neg_a)
+    with jax.named_scope("finish"), fe.compact_scope():
         acc = ed.point_add(acc, ed.point_neg(r))
         acc = ed.point_double(ed.point_double(ed.point_double(acc)))
         return ok[:n] & ok[n:] & ed.point_is_identity(acc)
@@ -481,17 +492,27 @@ def batch_verify_submit(pubs, msgs, sigs):
     host MSM share between submit and collect; callers that want the
     blocking behavior just collect immediately (batch_verify below)."""
     n = len(pubs)
-    operands, host_ok = pack_batch(pubs, msgs, sigs)
-    key = _bucket_key(operands)
+    with trace.span("device.pack", lanes=n) as pack:
+        operands, host_ok = pack_batch(pubs, msgs, sigs)
+        key = _bucket_key(operands)
+        pack.set(bucket=key[0])
     fn, sharded = _route_for(operands)
     if sharded:
         _mesh_count("sharded_dispatches")
         _mesh_count("padded_lanes", key[0] - n)
-    fut = _pool().submit(lambda: np.asarray(fn(*operands)))
+    caller = trace.current()
+
+    def run():  # on the device-owner thread, traced under the caller
+        with trace.span("device.run", parent=caller, bucket=key[0], sharded=sharded):
+            return np.asarray(fn(*operands))
+
+    fut = _pool().submit(run)
 
     def collect() -> tuple[bool, list]:
-        dev_ok = fut.result()
-        results = [bool(host_ok[i] and dev_ok[i]) for i in range(n)]
+        with trace.span("device.wait"):
+            dev_ok = fut.result()
+        with trace.span("device.unpack"):
+            results = [bool(host_ok[i] and dev_ok[i]) for i in range(n)]
         return all(results), results
 
     # (batch bucket, block bucket) — the compiled-program identity, so

@@ -130,10 +130,11 @@ def leaves_to_root_core(blocks, nblocks):
     nblocks int32[n] -> uint32[8, 1]. Fusing the leaf pass and the log2(n)
     inner levels into a single dispatch saves one host round-trip per
     level."""
-    cur = _leaf_core(blocks, nblocks)
-    while cur.shape[1] > 1:
-        cur = _inner_core(cur[:, 0::2], cur[:, 1::2])
-    return cur
+    with jax.named_scope("merkle"):  # HLO metadata only: a profile names the stage
+        cur = _leaf_core(blocks, nblocks)
+        while cur.shape[1] > 1:
+            cur = _inner_core(cur[:, 0::2], cur[:, 1::2])
+        return cur
 
 
 @functools.lru_cache(maxsize=None)

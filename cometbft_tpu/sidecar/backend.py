@@ -30,6 +30,8 @@ import sys
 import threading
 import time
 
+from cometbft_tpu.libs import trace
+
 _fallback_logged = False
 
 
@@ -157,6 +159,10 @@ class TpuBackend(VerifyBackend):
         return self._ed.mesh_width()
 
 
+def _route_name(share: int, n: int) -> str:
+    return "host" if share <= 0 else "device" if share >= n else "split"
+
+
 class HybridBackend(VerifyBackend):
     """Device + host tiers working the same batch concurrently.
 
@@ -229,10 +235,23 @@ class HybridBackend(VerifyBackend):
         self._device_lanes = 0
         self._host_lanes = 0
         self._routes: collections.deque = collections.deque(maxlen=64)
+        # Running aggregates over every call that reached the device, a
+        # program's first use left out (counters()): how often the planner
+        # splits and moves the split, and how far its predicted wall was
+        # from the measured one.
+        self._split_calls = 0
+        self._share_changes = 0
+        self._last_split_share = 0
+        self._plan_abs_err_ms = 0.0
+        self._wall_ms = 0.0
 
     def _plan(self, n: int) -> int:
         """Device share (a bucket size, possibly 0=all-host or >=n=all-device)
         minimizing predicted max(device time, host time)."""
+        return self._plan_cost(n)[0]
+
+    def _plan_cost(self, n: int) -> tuple[int, float]:
+        """_plan's share with the wall in ms the model predicts for it."""
         from cometbft_tpu.ops import ed25519_kernel as ek
 
         # Snapshot under the lock: _update_rates inserts first-observation
@@ -284,7 +303,19 @@ class HybridBackend(VerifyBackend):
         if best_b > 0 and self._bias:
             i = ladder.index(best_b) + self._bias
             best_b = ladder[max(0, min(i, len(ladder) - 1))]
-        return best_b
+            best_cost = max(dev_ms(best_b), host_ms(n - best_b))
+        return best_b, best_cost
+
+    def _planned_call(self, pubs, msgs, sigs, between=None):
+        """One call the planner routes, under its span: _plan_cost, then
+        _routed_call with the share and the wall predicted for it."""
+        n = len(pubs)
+        with trace.span("hybrid.call", n=n) as call:
+            with trace.span("hybrid.plan") as plan:
+                share, predicted_ms = self._plan_cost(n)
+                plan.set(share=share, predicted_ms=predicted_ms, bias=self._bias)
+            call.set(share=share, route=_route_name(share, n))
+            return self._routed_call(pubs, msgs, sigs, share, between, predicted_ms)
 
     def _note_route(self, n: int, share: int) -> None:
         """Count where n lanes went; calls big enough to be planned also
@@ -313,6 +344,10 @@ class HybridBackend(VerifyBackend):
                 "device_lanes": self._device_lanes,
                 "host_lanes": self._host_lanes,
                 "routes": list(self._routes),
+                "split_calls": self._split_calls,
+                "share_changes": self._share_changes,
+                "plan_abs_err_ms": round(self._plan_abs_err_ms, 2),
+                "wall_ms": round(self._wall_ms, 2),
             }
         return {
             **self._tpu.device_info(),
@@ -346,29 +381,32 @@ class HybridBackend(VerifyBackend):
         n = len(pubs)
         if n == 0:
             return False, []
-        if n < self._min_split:
-            # Small batches route host-side REGARDLESS of the native
-            # build's state: below the split threshold even per-signature
-            # OpenSSL (CpuBackend's own fallback) beats the device's fixed
-            # dispatch cost, and tiny batches carry no useful rate signal
-            # and must not decay the bias learned on commit-sized ones.
-            self._note_route(n, 0)
-            return self._cpu.batch_verify(pubs, msgs, sigs)
-        if self._native.ready() is None:
+        if n >= self._min_split and self._native.ready() is not None:
+            return self._planned_call(pubs, msgs, sigs)[0]
+        with trace.span("hybrid.call", n=n) as call:
+            if n < self._min_split:
+                # Small batches route host-side REGARDLESS of the native
+                # build's state: below the split threshold even per-signature
+                # OpenSSL (CpuBackend's own fallback) beats the device's fixed
+                # dispatch cost, and tiny batches carry no useful rate signal
+                # and must not decay the bias learned on commit-sized ones.
+                call.set(share=0, route="host")
+                self._note_route(n, 0)
+                with trace.span("hybrid.host_msm", lanes=n):
+                    return self._cpu.batch_verify(pubs, msgs, sigs)
             # Native tier still building (first seconds of a fresh host):
             # for commit-sized batches the device beats sequential OpenSSL.
+            call.set(share=n, route="device")
             self._note_route(n, n)
             return self._tpu.batch_verify(pubs, msgs, sigs)
-        share = self._plan(n)
-        res, _ = self._routed_call(pubs, msgs, sigs, share)
-        return res
 
-    def _routed_call(self, pubs, msgs, sigs, share, between=None):
+    def _routed_call(self, pubs, msgs, sigs, share, between=None, predicted_ms=None):
         """Execute one planned verification: all-host (share<=0), all-device
         (share>=n), or the concurrent split — the ONE copy of the
         plan->submit->host MSM->overlap->collect->rate-update protocol.
         `between` (optional) runs under the device wait (verify_and_root's
-        merkle); returns ((ok, bitmap), between_result)."""
+        merkle); `predicted_ms` is the wall the planner expected, booked
+        against the measured one. Returns ((ok, bitmap), between_result)."""
         from cometbft_tpu.ops import ed25519_kernel as ek
 
         n = len(pubs)
@@ -377,7 +415,8 @@ class HybridBackend(VerifyBackend):
         if share <= 0:
             self.last_share = 0
             t0 = time.perf_counter()
-            res = self._cpu.batch_verify(pubs, msgs, sigs)
+            with trace.span("hybrid.host_msm", lanes=n):
+                res = self._cpu.batch_verify(pubs, msgs, sigs)
             host_ms = (time.perf_counter() - t0) * 1000
             with self._rate_lock:
                 if host_ms > 1:
@@ -393,9 +432,10 @@ class HybridBackend(VerifyBackend):
         collect = ek.batch_verify_submit(pubs[:share], msgs[:share], sigs[:share])
         t_disp = time.perf_counter()
         if share < n:
-            ok_h, bits_h = self._native.batch_verify(
-                pubs[share:], msgs[share:], sigs[share:]
-            )
+            with trace.span("hybrid.host_msm", lanes=n - share):
+                ok_h, bits_h = self._native.batch_verify(
+                    pubs[share:], msgs[share:], sigs[share:]
+                )
         else:
             ok_h, bits_h = True, []
         t_host = time.perf_counter()
@@ -405,13 +445,16 @@ class HybridBackend(VerifyBackend):
         ok_d, bits_d = collect()
         t_dev = time.perf_counter()
         self._update_rates(
-            collect.program_key, share, n - share, t0, t_disp, t_host, t_wait, t_dev
+            collect.program_key, share, n - share, t0, t_disp, t_host, t_wait, t_dev,
+            predicted_ms,
         )
         if share < n:
             return (ok_d and ok_h, bits_d + bits_h), extra
         return (ok_d, bits_d), extra
 
-    def _update_rates(self, key, n_dev, n_host, t0, t_disp, t_host, t_wait, t_dev):
+    def _update_rates(
+        self, key, n_dev, n_host, t0, t_disp, t_host, t_wait, t_dev, predicted_ms=None
+    ):
         """EMA the rate model from what this call actually measured. The
         host share ran exclusively in [t_disp, t_host]. The device wall is
         only observable when the device was the straggler (collect(),
@@ -440,6 +483,14 @@ class HybridBackend(VerifyBackend):
             "bias": self._bias,
         }
         with self._rate_lock:
+            if n_host > 0:
+                self._split_calls += 1
+                if self._last_split_share not in (0, n_dev):
+                    self._share_changes += 1
+                self._last_split_share = n_dev
+            if not first_use and predicted_ms is not None:
+                self._plan_abs_err_ms += abs(predicted_ms - dev_ms)
+                self._wall_ms += dev_ms
             if host_ms > 1:
                 r = min(max(n_host / host_ms, 5.0), 5000.0)
                 self._host_rate += alpha * (r - self._host_rate)
@@ -494,9 +545,8 @@ class HybridBackend(VerifyBackend):
         if n < self._min_split or self._native.ready() is None:
             ok, bits = self.batch_verify(pubs, msgs, sigs)
             return (ok, bits), self.merkle_root(leaves)
-        share = self._plan(n)
-        return self._routed_call(
-            pubs, msgs, sigs, share, between=lambda: self.merkle_root(leaves)
+        return self._planned_call(
+            pubs, msgs, sigs, between=lambda: self.merkle_root(leaves)
         )
 
 

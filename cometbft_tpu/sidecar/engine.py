@@ -54,6 +54,7 @@ import sys
 import threading
 import time
 
+from cometbft_tpu.libs import trace
 from cometbft_tpu.sidecar.backend import VerifyBackend
 
 # Priority classes, drained strict-priority (lower value wins).
@@ -173,7 +174,9 @@ class VerifyFuture:
 
 
 class _Request:
-    __slots__ = ("pubs", "msgs", "sigs", "future", "klass", "deadline", "t_start")
+    __slots__ = (
+        "pubs", "msgs", "sigs", "future", "klass", "deadline", "t_start", "span",
+    )
 
     def __init__(self, pubs, msgs, sigs, future, klass, deadline):
         self.pubs = pubs
@@ -183,6 +186,9 @@ class _Request:
         self.klass = klass
         self.deadline = deadline  # absolute perf_counter deadline or None
         self.t_start = 0.0  # set when the dispatcher picks it up
+        # The submitter's open span: what the dispatcher thread does for
+        # this request is traced as its child (libs/trace.py).
+        self.span = trace.current()
 
 
 class VerificationEngine(VerifyBackend):
@@ -467,6 +473,10 @@ class VerificationEngine(VerifyBackend):
             for req in batch:
                 req.t_start = now
                 self._record_wait(req.klass, (now - req.future.t_submit) * 1000.0)
+                trace.record(
+                    "engine.queue_wait", req.future.t_submit, now,
+                    parent=req.span, klass=CLASS_NAMES[req.klass],
+                )
             try:
                 self._dispatch(batch)
             except BaseException as e:  # never kill the dispatcher
@@ -475,6 +485,14 @@ class VerificationEngine(VerifyBackend):
                         req.future._set_error(e)
 
     def _dispatch(self, batch: list[_Request]) -> None:
+        with trace.span(
+            "engine.dispatch", parent=batch[0].span, requests=len(batch),
+            lanes=sum(len(r.pubs) for r in batch),
+            klass=CLASS_NAMES[batch[0].klass],
+        ) as call:
+            self._dispatch_traced(batch, call)
+
+    def _dispatch_traced(self, batch: list[_Request], call) -> None:
         shared = len(batch) > 1
         with self._cond:
             self.counters_["dispatches"] += 1
@@ -512,28 +530,30 @@ class VerificationEngine(VerifyBackend):
         # Columnar pack with within-batch dedup: identical triples from
         # concurrent requests (N light clients walking the same descent)
         # share one lane.
-        lane_of: dict[tuple, int] = {}
-        pubs: list[bytes] = []
-        msgs: list[bytes] = []
-        sigs: list[bytes] = []
-        lanes: list[list[int]] = []
-        for req in batch:
-            req_lanes = []
-            for p, m, s in zip(req.pubs, req.msgs, req.sigs):
-                key = (p, s, m)
-                lane = lane_of.get(key)
-                if lane is None:
-                    lane = len(pubs)
-                    lane_of[key] = lane
-                    pubs.append(p)
-                    msgs.append(m)
-                    sigs.append(s)
-                req_lanes.append(lane)
-            lanes.append(req_lanes)
-        dedup = sum(len(r.pubs) for r in batch) - len(pubs)
-        if dedup:
-            with self._cond:
-                self.counters_["dedup_sigs"] += dedup
+        with trace.span("engine.merge", phase="pack"):
+            lane_of: dict[tuple, int] = {}
+            pubs: list[bytes] = []
+            msgs: list[bytes] = []
+            sigs: list[bytes] = []
+            lanes: list[list[int]] = []
+            for req in batch:
+                req_lanes = []
+                for p, m, s in zip(req.pubs, req.msgs, req.sigs):
+                    key = (p, s, m)
+                    lane = lane_of.get(key)
+                    if lane is None:
+                        lane = len(pubs)
+                        lane_of[key] = lane
+                        pubs.append(p)
+                        msgs.append(m)
+                        sigs.append(s)
+                    req_lanes.append(lane)
+                lanes.append(req_lanes)
+            dedup = sum(len(r.pubs) for r in batch) - len(pubs)
+            if dedup:
+                with self._cond:
+                    self.counters_["dedup_sigs"] += dedup
+        call.set(dedup=dedup)
         try:
             _, bits = self.inner.batch_verify(pubs, msgs, sigs)
         except BaseException:
@@ -544,9 +564,10 @@ class VerificationEngine(VerifyBackend):
             # dispatch, not something to mis-slice.
             self._fallback(batch)
             return
-        for req, req_lanes in zip(batch, lanes):
-            req_bits = [bits[lane] for lane in req_lanes]
-            req.future._set_result((all(req_bits), req_bits))
+        with trace.span("engine.merge", phase="slice"):
+            for req, req_lanes in zip(batch, lanes):
+                req_bits = [bits[lane] for lane in req_lanes]
+                req.future._set_result((all(req_bits), req_bits))
 
     def _fallback(self, batch: list[_Request]) -> None:
         """The merged dispatch failed: retry each request alone so one
@@ -618,42 +639,6 @@ class VerificationEngine(VerifyBackend):
         if inner_counters is not None:
             out["inner"] = inner_counters()
         return out
-
-    def register_metrics(self, registry) -> None:
-        """scheduler_* gauges (legacy names, dashboards keep reading) on a
-        libs.metrics Registry; the per-class engine_* gauges are registered
-        lazily by node/node.py so a scrape never constructs the backend."""
-        registry.gauge_func(
-            "scheduler", "requests", "Verification requests submitted.",
-            lambda: self.counters_["requests"],
-        )
-        registry.gauge_func(
-            "scheduler", "dispatches", "Backend dispatches issued.",
-            lambda: self.counters_["dispatches"],
-        )
-        registry.gauge_func(
-            "scheduler", "batched_requests",
-            "Requests that shared a coalesced dispatch.",
-            lambda: self.counters_["batched_requests"],
-        )
-        registry.gauge_func(
-            "scheduler", "fallback_splits",
-            "Coalesced dispatches split into per-request retries.",
-            lambda: self.counters_["fallback_splits"],
-        )
-        registry.gauge_func(
-            "scheduler", "coalesce_ratio_milli",
-            "Requests per dispatch x1000.",
-            lambda: int(
-                1000 * self.counters_["requests"]
-                / max(1, self.counters_["dispatches"])
-            ),
-        )
-        registry.gauge_func(
-            "scheduler", "queue_wait_p95_us",
-            "95th-percentile queue wait, microseconds.",
-            lambda: int(self._wait_percentile(0.95) * 1000),
-        )
 
     def close(self) -> None:
         with self._cond:

@@ -114,8 +114,5 @@ class CoalescingScheduler(VerifyBackend):
     def counters(self) -> dict:
         return self.engine.counters()
 
-    def register_metrics(self, registry) -> None:
-        self.engine.register_metrics(registry)
-
     def close(self) -> None:
         self.engine.close()

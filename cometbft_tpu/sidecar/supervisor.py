@@ -45,6 +45,7 @@ import random
 import threading
 import time
 
+from cometbft_tpu.libs import trace
 from cometbft_tpu.sidecar.backend import (
     CpuBackend,
     HybridBackend,
@@ -271,11 +272,20 @@ class ResilientBackend(VerifyBackend):
         and with nowhere left to degrade a timeout would only convert a
         slow correct answer into no answer."""
         attempt = 0
+        caller = trace.current()
+
+        def traced():  # on the tier's worker when a deadline is set
+            with trace.span(
+                "supervisor.tier_call", parent=caller, tier=tier.name,
+                attempt=attempt, anchored=anchored,
+            ):
+                return fn()
+
         while True:
             try:
                 if anchored or self.deadline_ms <= 0:
-                    return fn()
-                return tier.worker.run(fn, self.deadline_ms / 1000.0)
+                    return traced()
+                return tier.worker.run(traced, self.deadline_ms / 1000.0)
             except DeadlineExceeded:
                 with self._lock:
                     self.counters_["deadline_exceeded"] += 1

@@ -12,7 +12,7 @@ from __future__ import annotations
 import base64
 
 from cometbft_tpu.abci import types as abci
-from cometbft_tpu.libs import fail
+from cometbft_tpu.libs import fail, trace
 from cometbft_tpu.state.state import State
 from cometbft_tpu.state.validation import validate_block
 from cometbft_tpu.types import events as ev
@@ -94,33 +94,39 @@ class BlockExecutor:
 
     def validate_block(self, state: State, block: Block) -> None:
         """state/execution.go:180-192: header/commit checks + evidence check."""
-        validate_block(state, block)
-        if self.evpool:
-            self.evpool.check_evidence(block.evidence)
+        with trace.span("state.validate"):
+            validate_block(state, block)
+            if self.evpool:
+                self.evpool.check_evidence(block.evidence)
 
     def apply_block(
         self, state: State, block_id: BlockID, block: Block
     ) -> tuple[State, int]:
         """state/execution.go:194-280. Returns (new_state, retain_height)."""
         self.validate_block(state, block)
-        abci_responses = self._exec_block_on_proxy_app(state, block)
+        with trace.span("state.exec_abci"):
+            abci_responses = self._exec_block_on_proxy_app(state, block)
         fail.fail()  # kill-point: block executed, responses unsaved (execution.go:212)
         # Save ABCI responses for /block_results + reindexing.
-        self.state_store.save_abci_responses(
-            block.header.height, _encode_responses(abci_responses)
-        )
+        with trace.span("state.save_responses"):
+            self.state_store.save_abci_responses(
+                block.header.height, _encode_responses(abci_responses)
+            )
         fail.fail()  # kill-point: responses saved, state not updated (execution.go:219)
-        validator_updates = abci_responses["end_block"].validator_updates
-        _validate_validator_updates(validator_updates, state.consensus_params)
-        new_state = _update_state(
-            state, block_id, block, abci_responses, validator_updates
-        )
+        with trace.span("state.update"):
+            validator_updates = abci_responses["end_block"].validator_updates
+            _validate_validator_updates(validator_updates, state.consensus_params)
+            new_state = _update_state(
+                state, block_id, block, abci_responses, validator_updates
+            )
         # Lock mempool, commit app, update mempool (state/execution.go:288-330).
         fail.fail()  # kill-point: before app Commit (execution.go:255)
-        app_hash, retain_height = self._commit(new_state, block, abci_responses)
+        with trace.span("state.commit"):
+            app_hash, retain_height = self._commit(new_state, block, abci_responses)
         fail.fail()  # kill-point: app committed, state unsaved (execution.go:263)
         new_state.app_hash = app_hash
-        self.state_store.save(new_state)
+        with trace.span("state.save_state"):
+            self.state_store.save(new_state)
         # Evidence pool update (prune committed/expired evidence).
         if self.evpool:
             self.evpool.update(new_state, block.evidence)

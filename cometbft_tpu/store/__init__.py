@@ -11,6 +11,7 @@ from __future__ import annotations
 import json
 import threading
 
+from cometbft_tpu.libs import trace
 from cometbft_tpu.libs.db import DB
 from cometbft_tpu.types.block import Block, BlockMeta, Commit
 from cometbft_tpu.types.part_set import Part, PartSet
@@ -117,7 +118,7 @@ class BlockStore:
         if block is None:
             raise ValueError("BlockStore can only save a non-nil block")
         height = block.header.height
-        with self._mtx:
+        with trace.span("store.save_block", parts=part_set.total), self._mtx:
             expected = self._height + 1
             if self._height != 0 and height != expected:
                 raise ValueError(

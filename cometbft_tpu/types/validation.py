@@ -13,6 +13,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 from cometbft_tpu.crypto import batch as crypto_batch
+from cometbft_tpu.libs import trace
 from cometbft_tpu.types.block import BlockID, Commit, CommitSig
 
 BATCH_VERIFY_THRESHOLD = 2  # types/validation.go:12
@@ -77,47 +78,57 @@ def _should_batch_verify(vals, commit: Commit) -> bool:
     return _batch_key_type(vals, commit) is not None
 
 
+def _n_sigs(commit) -> int:
+    return len(commit.signatures) if commit is not None else 0
+
+
 def verify_commit(chain_id: str, vals, block_id: BlockID, height: int, commit: Commit) -> None:
     """+2/3 signed AND all signatures valid (types/validation.go:25-51).
     Checks every signature: apps may reward precommit inclusion."""
-    _verify_basic_vals_and_commit(vals, commit, height, block_id)
-    voting_power_needed = vals.total_voting_power() * 2 // 3
-    ignore = lambda c: c.is_absent()
-    count = lambda c: c.for_block_flag()
-    if commit.is_aggregate():
-        _verify_commit_aggregate(
-            chain_id, vals, commit, voting_power_needed, ignore, count, True
-        )
-    elif _should_batch_verify(vals, commit):
-        _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, ignore, count, True, True
-        )
-    else:
-        _verify_commit_single(
-            chain_id, vals, commit, voting_power_needed, ignore, count, True, True
-        )
+    with trace.span("validation.verify_commit", kind="full", sigs=_n_sigs(commit)):
+        with trace.span("validation.basic"):
+            _verify_basic_vals_and_commit(vals, commit, height, block_id)
+            voting_power_needed = vals.total_voting_power() * 2 // 3
+            batch = not commit.is_aggregate() and _should_batch_verify(vals, commit)
+        ignore = lambda c: c.is_absent()
+        count = lambda c: c.for_block_flag()
+        if commit.is_aggregate():
+            _verify_commit_aggregate(
+                chain_id, vals, commit, voting_power_needed, ignore, count, True
+            )
+        elif batch:
+            _verify_commit_batch(
+                chain_id, vals, commit, voting_power_needed, ignore, count, True, True
+            )
+        else:
+            _verify_commit_single(
+                chain_id, vals, commit, voting_power_needed, ignore, count, True, True
+            )
 
 
 def verify_commit_light(
     chain_id: str, vals, block_id: BlockID, height: int, commit: Commit
 ) -> None:
     """+2/3 signed; stops counting at quorum (types/validation.go:59-84)."""
-    _verify_basic_vals_and_commit(vals, commit, height, block_id)
-    voting_power_needed = vals.total_voting_power() * 2 // 3
-    ignore = lambda c: not c.for_block_flag()
-    count = lambda c: True
-    if commit.is_aggregate():
-        _verify_commit_aggregate(
-            chain_id, vals, commit, voting_power_needed, ignore, count, True
-        )
-    elif _should_batch_verify(vals, commit):
-        _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, ignore, count, False, True
-        )
-    else:
-        _verify_commit_single(
-            chain_id, vals, commit, voting_power_needed, ignore, count, False, True
-        )
+    with trace.span("validation.verify_commit", kind="light", sigs=_n_sigs(commit)):
+        with trace.span("validation.basic"):
+            _verify_basic_vals_and_commit(vals, commit, height, block_id)
+            voting_power_needed = vals.total_voting_power() * 2 // 3
+            batch = not commit.is_aggregate() and _should_batch_verify(vals, commit)
+        ignore = lambda c: not c.for_block_flag()
+        count = lambda c: True
+        if commit.is_aggregate():
+            _verify_commit_aggregate(
+                chain_id, vals, commit, voting_power_needed, ignore, count, True
+            )
+        elif batch:
+            _verify_commit_batch(
+                chain_id, vals, commit, voting_power_needed, ignore, count, False, True
+            )
+        else:
+            _verify_commit_single(
+                chain_id, vals, commit, voting_power_needed, ignore, count, False, True
+            )
 
 
 def verify_commit_light_trusting(
@@ -127,33 +138,38 @@ def verify_commit_light_trusting(
     (types/validation.go:94-135); lookups are by address."""
     from cometbft_tpu.types.validator_set import safe_mul
 
-    if vals is None:
-        raise ValueError("nil validator set")
-    if trust_level.denominator == 0:
-        raise ValueError("trustLevel has zero Denominator")
-    if commit is None:
-        raise ValueError("nil commit")
-    total_mul, overflow = safe_mul(vals.total_voting_power(), trust_level.numerator)
-    if overflow:
-        raise OverflowError(
-            "int64 overflow while calculating voting power needed. please provide "
-            "smaller trustLevel numerator"
-        )
-    voting_power_needed = total_mul // trust_level.denominator
-    ignore = lambda c: not c.for_block_flag()
-    count = lambda c: True
-    if commit.is_aggregate():
-        _verify_commit_aggregate(
-            chain_id, vals, commit, voting_power_needed, ignore, count, False
-        )
-    elif _should_batch_verify(vals, commit):
-        _verify_commit_batch(
-            chain_id, vals, commit, voting_power_needed, ignore, count, False, False
-        )
-    else:
-        _verify_commit_single(
-            chain_id, vals, commit, voting_power_needed, ignore, count, False, False
-        )
+    with trace.span("validation.verify_commit", kind="trusting", sigs=_n_sigs(commit)):
+        with trace.span("validation.basic"):
+            if vals is None:
+                raise ValueError("nil validator set")
+            if trust_level.denominator == 0:
+                raise ValueError("trustLevel has zero Denominator")
+            if commit is None:
+                raise ValueError("nil commit")
+            total_mul, overflow = safe_mul(
+                vals.total_voting_power(), trust_level.numerator
+            )
+            if overflow:
+                raise OverflowError(
+                    "int64 overflow while calculating voting power needed. please "
+                    "provide smaller trustLevel numerator"
+                )
+            voting_power_needed = total_mul // trust_level.denominator
+            batch = not commit.is_aggregate() and _should_batch_verify(vals, commit)
+        ignore = lambda c: not c.for_block_flag()
+        count = lambda c: True
+        if commit.is_aggregate():
+            _verify_commit_aggregate(
+                chain_id, vals, commit, voting_power_needed, ignore, count, False
+            )
+        elif batch:
+            _verify_commit_batch(
+                chain_id, vals, commit, voting_power_needed, ignore, count, False, False
+            )
+        else:
+            _verify_commit_single(
+                chain_id, vals, commit, voting_power_needed, ignore, count, False, False
+            )
 
 
 def _verify_commit_aggregate(
@@ -251,36 +267,40 @@ def _verify_commit_batch(
     look_up_by_index: bool,
 ) -> None:
     """types/validation.go:152-256 — the TPU call site."""
-    kt = _batch_key_type(vals, commit)
-    if kt is None:
-        raise ValueError(
-            "unsupported signature algorithm or insufficient signatures for batch verification"
-        )
-    bv = crypto_batch.create_batch_verifier(kt)
+    with trace.span("validation.key_type"):
+        kt = _batch_key_type(vals, commit)
+        if kt is None:
+            raise ValueError(
+                "unsupported signature algorithm or insufficient signatures for batch verification"
+            )
+        bv = crypto_batch.create_batch_verifier(kt)
     seen_vals: dict[int, int] = {}
     batch_sig_idxs: list[int] = []
     tallied = 0
-    all_sign_bytes = commit.vote_sign_bytes_all(chain_id)
-    for idx, commit_sig in enumerate(commit.signatures):
-        if ignore_sig(commit_sig):
-            continue
-        if look_up_by_index:
-            val = vals.validators[idx]
-        else:
-            val_idx, val = vals.get_by_address(commit_sig.validator_address)
-            if val is None:
+    with trace.span("validation.sign_bytes"):
+        all_sign_bytes = commit.vote_sign_bytes_all(chain_id)
+    with trace.span("validation.tally") as tally:
+        for idx, commit_sig in enumerate(commit.signatures):
+            if ignore_sig(commit_sig):
                 continue
-            if val_idx in seen_vals:
-                raise ValueError(
-                    f"double vote from {val} ({seen_vals[val_idx]} and {idx})"
-                )
-            seen_vals[val_idx] = idx
-        bv.add(val.pub_key, all_sign_bytes[idx], commit_sig.signature)
-        batch_sig_idxs.append(idx)
-        if count_sig(commit_sig):
-            tallied += val.voting_power
-        if not count_all_signatures and tallied > voting_power_needed:
-            break
+            if look_up_by_index:
+                val = vals.validators[idx]
+            else:
+                val_idx, val = vals.get_by_address(commit_sig.validator_address)
+                if val is None:
+                    continue
+                if val_idx in seen_vals:
+                    raise ValueError(
+                        f"double vote from {val} ({seen_vals[val_idx]} and {idx})"
+                    )
+                seen_vals[val_idx] = idx
+            bv.add(val.pub_key, all_sign_bytes[idx], commit_sig.signature)
+            batch_sig_idxs.append(idx)
+            if count_sig(commit_sig):
+                tallied += val.voting_power
+            if not count_all_signatures and tallied > voting_power_needed:
+                break
+        tally.set(added=len(batch_sig_idxs))
     if tallied <= voting_power_needed:
         raise ErrNotEnoughVotingPowerSigned(tallied, voting_power_needed)
     ok, valid_sigs = bv.verify()

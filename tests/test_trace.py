@@ -1,0 +1,524 @@
+"""libs/trace.py (ISSUE 24): the program's one tracer. Off without a profiler
+session (and without JAX); on, every layer boundary from `verify_commit`
+and the blocksync reactor down to the device-owner thread leaves a span in
+the ring and a `seam:` event in the profiler's xplane; the counters beside
+them are always on."""
+
+import glob
+import os
+import re
+import subprocess
+import sys
+import threading
+import time
+
+import jax
+import pytest
+
+import chip_smoke
+from cometbft_tpu import native
+from cometbft_tpu.crypto import ed25519
+from cometbft_tpu.libs import trace
+from cometbft_tpu.sidecar import backend as be
+from cometbft_tpu.sidecar import engine as engine_mod
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+N_VALS = 48
+
+needs_native = pytest.mark.skipif(
+    not native.available(), reason="native tier unavailable"
+)
+
+# One verify_commit through the auto chain with the hybrid splitting: every
+# span of the callers / batch / engine / supervisor / hybrid / host / device
+# rows of PERF.md's table (engine.merge needs two requests in one dispatch
+# and has its own test).
+COMMIT_SPANS = {
+    "validation.verify_commit", "validation.basic", "validation.key_type",
+    "validation.sign_bytes", "validation.tally",
+    "batch.verify", "batch.cache_filter", "batch.dispatch", "batch.cache_insert",
+    "engine.queue_wait", "engine.dispatch", "supervisor.tier_call",
+    "hybrid.call", "hybrid.plan", "hybrid.host_msm",
+    "device.pack", "device.run", "device.wait", "device.unpack",
+}
+SYNC_CHILDREN = {
+    "blocksync.verify_wait", "blocksync.part_set", "blocksync.verify_light",
+    "blocksync.validate", "blocksync.save", "blocksync.pipeline_submit",
+    "blocksync.apply",
+}
+
+
+@pytest.fixture(autouse=True)
+def clean():
+    trace.clear()
+    chip_smoke.clear_verified_cache()
+    yield
+    trace.clear()
+    chip_smoke.clear_verified_cache()
+
+
+@pytest.fixture
+def profiler(tmp_path):
+    """A profiler session as the harness starts one; yields the capture's directory."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        yield str(tmp_path)
+    finally:
+        if jax.profiler.TraceAnnotation.is_enabled():
+            jax.profiler.stop_trace()
+
+
+@pytest.fixture
+def auto_chain(monkeypatch):
+    """The node's chain (engine -> supervisor -> hybrid -> cpu) on XLA:CPU at
+    the rehearsal's settings, so a 48-signature commit still splits."""
+    for k, v in {"CMTPU_HYBRID_MIN": "8", "CMTPU_DEV_RATE": "1000",
+                 "CMTPU_HOST_RATE": "1000", "CMTPU_DEV_OVERHEAD_MS": "0"}.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.delenv("CMTPU_DEADLINE_MS", raising=False)
+    backend = chip_smoke.open_auto_chain("cpu")
+    native.available()  # the split needs the host tier built
+    # price the mesh as one chip (conftest gives 8 virtual devices), as
+    # tests/test_hybrid.py does: the planner then splits 48 lanes
+    backend.inner.tiers[0].backend._n_dev = 1
+    try:
+        yield backend
+    finally:
+        backend.close()
+        be.set_backend(None)
+        os.environ.pop("CMTPU_BACKEND", None)
+
+
+def _verify_one(height=1):
+    vals, commits = chip_smoke.make_commits(24, N_VALS, 2, "trace")
+    bid, commit = commits[height - 1]
+    vals.verify_commit(chip_smoke.CHAIN_ID, bid, commit.height, commit)
+
+
+def _by_id(spans):
+    return {s["id"]: s for s in spans}
+
+
+def _ancestors(span, by_id):
+    out = []
+    while span["parent"] is not None and span["parent"] in by_id:
+        span = by_id[span["parent"]]
+        out.append(span["name"])
+    return out
+
+
+# -- (a) off ---------------------------------------------------------------------
+
+
+def test_off_without_a_profiler_session(auto_chain):
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    _verify_one()
+    assert trace.spans() == [] and trace.dropped() == 0
+    assert trace.span("batch.verify") is trace.span("hybrid.call")  # the shared no-op
+    assert trace.current() is None
+
+
+def test_off_never_imports_jax():
+    code = (
+        "import sys, chip_smoke\n"
+        "from cometbft_tpu.libs import trace\n"
+        "vals, commits = chip_smoke.make_commits(3, 8, 1, 'nojax')\n"
+        "bid, commit = commits[0]\n"
+        "vals.verify_commit(chip_smoke.CHAIN_ID, bid, commit.height, commit)\n"
+        "assert trace.spans() == []\n"
+        "print('jax' in sys.modules)\n"
+    )
+    env = {k: v for k, v in os.environ.items() if not k.startswith("CMTPU_")}
+    env.update(CMTPU_BACKEND="cpu", JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == "False"
+
+
+# -- (b) on: one verify_commit ----------------------------------------------------
+
+
+@needs_native
+def test_one_verify_commit_gives_every_span_under_one_root(auto_chain, profiler):
+    _verify_one(1)  # first use of the share's program
+    trace.clear()
+    chip_smoke.clear_verified_cache()
+    _verify_one(2)
+    jax.profiler.stop_trace()
+    spans = trace.spans()
+    names = {s["name"] for s in spans}
+    assert COMMIT_SPANS <= names, sorted(COMMIT_SPANS - names)
+    assert names <= set(trace.NAMES)
+    by_id = _by_id(spans)
+    top = [s for s in spans if s["name"] == "validation.verify_commit"]
+    assert len(top) == 1 and top[0]["parent"] is None
+    assert top[0]["attrs"] == {"kind": "full", "sigs": N_VALS}
+    assert {s["root"] for s in spans} == {top[0]["id"]}, "one root for the whole request"
+    for s in spans:
+        assert s["t1"] >= s["t0"]
+        if s["parent"] is not None and s["name"] not in (
+            "engine.queue_wait", "engine.dispatch", "device.run",  # end on another thread
+        ):
+            p = by_id[s["parent"]]
+            assert p["t0"] <= s["t0"] and s["t1"] <= p["t1"], (s["name"], p["name"])
+    one = {s["name"]: s for s in spans}
+    assert by_id[one["engine.queue_wait"]["parent"]]["name"] == "batch.dispatch"
+    assert by_id[one["engine.dispatch"]["parent"]]["name"] == "batch.dispatch"
+    assert one["engine.dispatch"]["thread"] == "verify-engine"
+    assert one["device.run"]["thread"] == "cmtpu-dev"
+    assert "hybrid.call" in _ancestors(one["device.run"], by_id)
+    run = one["device.run"]["attrs"]  # the conftest's 8 virtual devices shard the call
+    assert set(run) == {"bucket", "sharded"} and run["bucket"] >= one["hybrid.call"]["attrs"]["share"]
+    plan = one["hybrid.plan"]["attrs"]
+    assert plan["share"] == one["hybrid.call"]["attrs"]["share"] and plan["predicted_ms"] > 0
+    call = one["hybrid.call"]["attrs"]
+    assert call["route"] == "split" and 0 < call["share"] < N_VALS and call["n"] == N_VALS
+    assert one["hybrid.host_msm"]["attrs"]["lanes"] == N_VALS - call["share"]
+    assert one["device.pack"]["attrs"]["lanes"] == call["share"]
+    assert one["batch.verify"]["attrs"] == {
+        "entries": N_VALS, "hits": 0, "dups": 0, "dispatched": N_VALS, "evicted": 0,
+    }
+    assert one["validation.tally"]["attrs"] == {"added": N_VALS}
+    assert one["supervisor.tier_call"]["attrs"] == {
+        "tier": "hybrid", "attempt": 0, "anchored": False,
+    }
+    # children of verify_commit cover it (the accounting PERF.md relies on)
+    kids = [s for s in spans if s["parent"] == top[0]["id"]]
+    assert {k["name"] for k in kids} == {
+        "validation.basic", "validation.key_type", "validation.sign_bytes",
+        "validation.tally", "batch.verify",
+    }
+    assert sum(k["t1"] - k["t0"] for k in kids) <= top[0]["t1"] - top[0]["t0"]
+
+
+# -- (c) the xplane ----------------------------------------------------------------
+
+
+@needs_native
+def test_the_xplane_holds_the_same_spans_as_seam_events(auto_chain, profiler):
+    _verify_one()
+    jax.profiler.stop_trace()
+    paths = glob.glob(os.path.join(profiler, "**", "*.xplane.pb"), recursive=True)
+    assert paths
+    data = jax.profiler.ProfileData.from_file(sorted(paths)[-1])
+    events = {
+        e.name for pl in data.planes if pl.name.startswith("/host:")
+        for ln in pl.lines for e in ln.events if e.name.startswith("seam:")
+    }
+    in_ring = {s["name"] for s in trace.spans()}
+    # engine.queue_wait is a record(): ring only
+    assert events == {"seam:" + n for n in in_ring - {"engine.queue_wait"}}
+    assert "seam:batch_verify" not in events, "that name is the benchmark's"
+    assert "seam:device.run" in events and "seam:validation.verify_commit" in events
+
+
+# -- (d) a short blocksync ----------------------------------------------------------
+
+
+def test_blocksync_heights_are_roots_with_their_children(profiler):
+    from test_blocksync import CHAIN_ID, _fresh_node, _populated_chain
+    from cometbft_tpu.blocksync.reactor import BlocksyncReactor
+    from cometbft_tpu.p2p.key import NodeKey
+    from cometbft_tpu.p2p.node_info import NodeInfo
+    from cometbft_tpu.p2p.switch import Switch
+    from cometbft_tpu.p2p.transport import MultiplexTransport
+    from cometbft_tpu.types import GenesisDoc, GenesisValidator, Time
+    from cometbft_tpu.types.priv_validator import MockPV
+
+    pvs = [MockPV() for _ in range(3)]
+    gen = GenesisDoc(
+        chain_id=CHAIN_ID, genesis_time=Time(1700000000, 0),
+        validators=[GenesisValidator(pv.address(), pv.get_pub_key(), 10, "") for pv in pvs],
+    )
+    gen.validate_and_complete()
+    _, server_store, _ = _populated_chain(pvs, gen, 8)
+
+    def switch(moniker):
+        nk = NodeKey()
+        ni = NodeInfo(node_id=nk.id, network=CHAIN_ID, moniker=moniker)
+        return nk, Switch(ni, MultiplexTransport(ni, nk))
+
+    nk_s, sw_s = switch("server")
+    sw_s.add_reactor("BLOCKSYNC", BlocksyncReactor(
+        state=_fresh_node(gen)[0], block_exec=None, block_store=server_store,
+        block_sync=False,
+    ))
+    addr_s = sw_s.start("127.0.0.1:0")
+    caught = threading.Event()
+    state, store, executor = _fresh_node(gen)
+    reactor = BlocksyncReactor(
+        state=state, block_exec=executor, block_store=store, block_sync=True,
+        on_caught_up=lambda st: caught.set(),
+    )
+    _, sw_c = switch("client")
+    sw_c.add_reactor("BLOCKSYNC", reactor)
+    sw_c.start("")
+    try:
+        sw_c.dial_peer(f"{nk_s.id}@{addr_s}")
+        assert caught.wait(45), "never reported caught up"
+    finally:
+        sw_c.stop()
+        sw_s.stop()
+    jax.profiler.stop_trace()
+    applied = store.height()
+    assert applied >= 7
+    spans = trace.spans()
+    assert {s["name"] for s in spans} <= set(trace.NAMES)
+    roots = [s for s in spans if s["name"] == "blocksync.sync_one" and s["attrs"]["applied"]]
+    assert [s["attrs"]["height"] for s in roots] == list(range(1, applied + 1))
+    for one in roots:
+        assert one["parent"] is None and one["root"] == one["id"]
+        kids = [s for s in spans if s["parent"] == one["id"]]
+        assert {k["name"] for k in kids} == SYNC_CHILDREN
+        assert sum(k["t1"] - k["t0"] for k in kids) <= one["t1"] - one["t0"]
+        mine = [s["name"] for s in spans if s["root"] == one["id"]]
+        assert mine.count("state.validate") == 2, "validate_block, then apply_block's own"
+        for name in ("state.exec_abci", "state.save_responses", "state.update",
+                     "state.commit", "state.save_state", "store.save_block"):
+            assert mine.count(name) == 1, name
+        assert mine.count("validation.verify_commit") >= 1
+    assert any(s["name"] == "blocksync.decode" and s["attrs"]["bytes"] > 0 for s in spans)
+    assert any(s["name"] == "blocksync.make_requests" for s in spans)
+    waits = [s for s in spans if s["name"] == "blocksync.fetch_wait"]
+    assert waits and all(s["parent"] is None for s in waits)
+    # (e) the reactor's counters
+    c = reactor.counters()
+    assert c["heights_applied"] == applied and c["redo_requests"] == 0
+    assert c["idle_sleeps"] >= sum(s["attrs"]["sleeps"] for s in waits) >= 1
+    assert c["fetch_wait_ms"] > 0 and c["verify_wait_ms"] >= 0
+    assert set(c) == {"heights_applied", "fetch_wait_ms", "verify_wait_ms",
+                      "idle_sleeps", "redo_requests", "pipeline_overlap_ms"}
+
+
+# -- (e) counters --------------------------------------------------------------------
+
+
+def test_share_changes_over_a_forced_sequence_of_shares(monkeypatch):
+    hb = be.HybridBackend()
+    ts = (0.0, 0.001, 0.010, 0.010, 0.020)  # t0, t_disp, t_host, t_wait, t_dev
+    hb._update_rates((32, 2), 32, 16, *ts)  # a program's first use
+    for share, predicted in ((32, 25.0), (32, 20.0), (8, 20.0), (32, None), (48, 20.0), (8, 20.0)):
+        hb._update_rates((share, 2), share, 48 - share, *ts, predicted)
+    c = hb.counters()
+    assert c["split_calls"] == 6, "the all-device call (48 of 48) is no split"
+    assert c["share_changes"] == 3  # 32 -> 8 -> 32 -> (48: not a split) -> 8
+    # first uses (32, 8 and 48 once each) and the call with no prediction are left out
+    assert c["wall_ms"] == 60.0 and c["plan_abs_err_ms"] == 5.0  # |25-20| + 0 + 0
+    for key in ("last_timing", "last_share", "routes", "device_lanes", "host_lanes"):
+        assert key in c
+
+
+def test_the_planner_hands_its_prediction_back_with_the_share(monkeypatch):
+    for key, value in (("DEV_RATE", "1000"), ("HOST_RATE", "1000"), ("DEV_OVERHEAD_MS", "0")):
+        monkeypatch.setenv("CMTPU_" + key, value)
+    hb = be.HybridBackend()
+    hb._n_dev = 1
+    # equal rates, no overhead: 48 lanes split at the 32 bucket, host 16
+    assert hb._plan_cost(48) == (32, 32 / 1000.0)
+    assert hb._plan(48) == 32
+    assert not hasattr(hb, "_predicted_ms"), "nothing of one call is left for the next"
+
+
+def test_cache_counters_add_up():
+    class Cpu:
+        def batch_verify(self, pubs, msgs, sigs):
+            return be.CpuBackend().batch_verify(pubs, msgs, sigs)
+
+    be.set_backend(Cpu())
+    try:
+        priv = ed25519.gen_priv_key_from_secret(b"trace-cache")
+        pub = priv.pub_key()
+        triples = [(pub, b"m%d" % i, priv.sign(b"m%d" % i)) for i in range(6)]
+        before = ed25519.verified_cache_counters()
+        for batch in (triples[:4], triples[2:] + triples[4:5], triples):
+            bv = ed25519.BatchVerifier()
+            for t in batch:
+                bv.add(*t)
+            assert bv.verify()[0]
+        after = ed25519.verified_cache_counters()
+    finally:
+        be.set_backend(None)
+    d = {k: after[k] - before[k] for k in before}
+    assert d["entries"] == 4 + 5 + 6
+    assert d["hits"] == 0 + 2 + 6 and d["dups"] == 1 and d["dispatched"] == 4 + 2
+    assert d["hits"] + d["dups"] + d["dispatched"] == d["entries"]
+    assert d["inserted"] == 6 and d["evicted"] == 0 and d["size"] == 6
+
+
+def test_engine_counters_keep_the_wait_percentile():
+    class Inner:
+        def batch_verify(self, pubs, msgs, sigs):
+            return True, [True] * len(pubs)
+
+    eng = engine_mod.VerificationEngine(Inner())
+    try:
+        assert eng.batch_verify([b"p"], [b"m"], [b"s"]) == (True, [True])
+        assert "queue_wait_p95_ms" in eng.counters()
+    finally:
+        eng.close()
+    assert not hasattr(eng, "register_metrics")
+
+
+def test_two_requests_in_one_dispatch_leave_engine_merge(profiler):
+    class Inner:
+        def batch_verify(self, pubs, msgs, sigs):
+            return True, [True] * len(pubs)
+
+    eng = engine_mod.VerificationEngine(Inner(), hold_ms=200.0, max_sigs=4)
+    try:
+        with trace.span("batch.dispatch") as mine:
+            futs = [eng.submit([b"p%d" % i] * 2, [b"m"] * 2, [b"s"] * 2) for i in range(2)]
+            assert all(f.result(10) == (True, [True, True]) for f in futs)
+    finally:
+        eng.close()
+    jax.profiler.stop_trace()
+    spans = trace.spans()
+    merges = [s for s in spans if s["name"] == "engine.merge"]
+    assert sorted(s["attrs"]["phase"] for s in merges) == ["pack", "slice"]
+    dispatch = next(s for s in spans if s["name"] == "engine.dispatch")
+    assert dispatch["attrs"] == {"requests": 2, "lanes": 4, "klass": "blocksync", "dedup": 2}
+    assert dispatch["parent"] == mine.id and all(s["parent"] == dispatch["id"] for s in merges)
+    waits = [s for s in spans if s["name"] == "engine.queue_wait"]
+    assert len(waits) == 2 and all(s["parent"] == mine.id for s in waits)
+
+
+# -- (f) the ring ---------------------------------------------------------------------
+
+
+def test_the_ring_is_bounded_and_counts_what_it_drops(profiler):
+    for i in range(trace.RING + 10):
+        trace.record("engine.queue_wait", float(i), float(i) + 0.5)
+    jax.profiler.stop_trace()
+    spans = trace.spans()
+    assert len(spans) == trace.RING and trace.dropped() == 10
+    assert spans[0]["t0"] == 10.0 and spans[-1]["t0"] == float(trace.RING + 9)
+    trace.record("engine.queue_wait", 0.0, 1.0)  # no session: not recorded
+    assert len(trace.spans()) == trace.RING
+
+
+def test_a_span_handed_to_another_thread_keeps_the_root(profiler):
+    got = {}
+
+    def worker(parent):
+        with trace.span("device.run", parent=parent, bucket=8) as run:
+            got["root"] = run.root
+
+    with trace.span("hybrid.call", n=8) as call:
+        t = threading.Thread(target=worker, args=(trace.current(),))
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    assert got["root"] == call.id
+    run = next(s for s in trace.spans() if s["name"] == "device.run")
+    assert run["parent"] == call.id and run["thread"] != threading.current_thread().name
+
+
+# -- (g) kernel scopes ----------------------------------------------------------------
+
+
+def test_the_lowered_verify_program_names_its_five_stages():
+    import numpy as np
+
+    from cometbft_tpu.ops import ed25519_kernel as ek
+    from cometbft_tpu.ops import merkle_kernel as mk
+
+    operands, _ = ek.pack_batch([b"\x00" * 32] * 8, [b"\x00" * 120] * 8, [b"\x00" * 64] * 8)
+    text = jax.jit(ek.verify_core).lower(*operands).as_text(debug_info=True)
+    for scope in ek.KERNEL_SCOPES:
+        assert re.search(rf"jit\(verify_core\)/(jit\(main\)/)?{scope}/", text), scope
+    assert ek.KERNEL_SCOPES == ("sha512", "unpack", "decompress", "ladder", "finish")
+    blocks, nblocks = np.zeros((1, 16, 8), np.uint32), np.ones(8, np.int32)
+    text = jax.jit(mk.leaves_to_root_core).lower(blocks, nblocks).as_text(debug_info=True)
+    assert "/merkle/" in text
+
+
+# -- (h) the names ---------------------------------------------------------------------
+
+
+def _names_in_code():
+    found = set()
+    for path in glob.glob(os.path.join(ROOT, "cometbft_tpu", "**", "*.py"), recursive=True):
+        with open(path) as f:
+            found.update(re.findall(r'trace\.(?:span|record)\(\s*"([a-z_.]+)"', f.read()))
+    return found
+
+
+def _names_in_perf_md():
+    with open(os.path.join(ROOT, "PERF.md")) as f:
+        table = f.read().split("<!-- spans -->")[1].split("<!-- /spans -->")[0]
+    return set(re.findall(r"`((?:validation|blocksync|state|store|batch|engine|supervisor|hybrid|device)\.[a-z_]+)`", table))
+
+
+@pytest.mark.parametrize("name", trace.NAMES)
+def test_every_name_is_one_the_code_emits_and_perf_md_lists(name):
+    assert name in _names_in_code(), "NAMES lists a span no site emits"
+    assert name in _names_in_perf_md(), "PERF.md's span table lacks it"
+
+
+def test_no_name_outside_the_list():
+    assert _names_in_code() == set(trace.NAMES)
+    assert _names_in_perf_md() == set(trace.NAMES)
+    assert len(set(trace.NAMES)) == len(trace.NAMES)
+    # one module defines spans: nothing else in the program touches the profiler's annotation
+    for path in glob.glob(os.path.join(ROOT, "cometbft_tpu", "**", "*.py"), recursive=True):
+        if not path.endswith(os.path.join("libs", "trace.py")):
+            with open(path) as f:
+                assert "TraceAnnotation" not in f.read(), path
+
+
+def test_pprof_jax_trace_writes_the_captures_spans(tmp_path, auto_chain):
+    import json
+
+    from cometbft_tpu.libs import pprof
+
+    _verify_one(1)  # the share's program is loaded before the capture starts
+    worker = threading.Thread(target=lambda: (time.sleep(0.3), _verify_one(2)))
+    worker.start()
+    msg = pprof.jax_trace(1.5, str(tmp_path))
+    worker.join(30)
+    assert not worker.is_alive()
+    with open(tmp_path / "spans.json") as f:
+        got = json.load(f)
+    assert "spans.json" in msg and got["dropped"] == 0
+    assert {s["name"] for s in got["spans"]} >= {"validation.verify_commit", "batch.verify"}
+    assert all(got["t0"] <= s["t0"] for s in got["spans"])
+
+
+def test_the_counters_reach_metrics_through_lazy_gauges():
+    import types
+
+    from cometbft_tpu.blocksync.reactor import BlocksyncReactor
+    from cometbft_tpu.libs.metrics import Registry
+    from cometbft_tpu.node.node import Node
+
+    class Chain:  # the shape counters() has under CMTPU_BACKEND=auto
+        name = "coalesce"
+
+        def counters(self):
+            hybrid = {"split_calls": 7, "share_changes": 3, "plan_abs_err_ms": 12.5,
+                      "wall_ms": 800.0}
+            return {"requests": 1, "dispatches": 1, "queue_wait_p95_ms": 0.0,
+                    "inner": {"tiers": {"hybrid": {"backend": hybrid}}}}
+
+    reg = Registry(namespace="cmt")
+    Node._register_backend_metrics(reg)
+    reactor = BlocksyncReactor.__new__(BlocksyncReactor)  # counters() reads these alone
+    vars(reactor).update(
+        pipeline_overlap_ms=0.0, heights_applied=9, fetch_wait_ms=4.2,
+        verify_wait_ms=0.0, idle_sleeps=2, redo_requests=1,
+    )
+    Node._register_hotpath_metrics(types.SimpleNamespace(blocksync_reactor=reactor), reg)
+    assert "cmt_hybrid_split_calls 0" in reg.render()  # no backend yet: nothing constructed
+    be.set_backend(Chain())
+    try:
+        out = reg.render()
+    finally:
+        be.set_backend(None)
+    assert "cmt_hybrid_split_calls 7" in out and "cmt_hybrid_share_changes 3" in out
+    assert "cmt_blocksync_heights_applied 9" in out and "cmt_blocksync_idle_sleeps 2" in out
+    assert "cmt_blocksync_redo_requests 1" in out
+    size = ed25519.verified_cache_counters()["size"]
+    assert f"cmt_verify_cache_size {size}" in out and "cmt_verify_cache_hits " in out
