@@ -31,6 +31,7 @@ import hashlib
 import os
 import queue
 import threading
+import time
 from concurrent.futures import Future
 
 import numpy as np
@@ -503,14 +504,16 @@ def batch_verify_submit(pubs, msgs, sigs):
     caller = trace.current()
 
     def run():  # on the device-owner thread, traced under the caller
+        started = time.perf_counter()
         with trace.span("device.run", parent=caller, bucket=key[0], sharded=sharded):
-            return np.asarray(fn(*operands))
+            dev_ok = np.asarray(fn(*operands))
+        return dev_ok, (started, time.perf_counter())
 
     fut = _pool().submit(run)
 
     def collect() -> tuple[bool, list]:
         with trace.span("device.wait"):
-            dev_ok = fut.result()
+            dev_ok, collect.run_times = fut.result()
         with trace.span("device.unpack"):
             results = [bool(host_ok[i] and dev_ok[i]) for i in range(n)]
         return all(results), results
@@ -518,6 +521,11 @@ def batch_verify_submit(pubs, msgs, sigs):
     # (batch bucket, block bucket) — the compiled-program identity, so
     # callers can tell a first dispatch (XLA compile) from a steady one.
     collect.program_key = key
+    # (start, return) of the program on the device-owner thread's clock,
+    # time.perf_counter(), once collect() has run: the device's wall is known
+    # to the caller even when it came to collect() long after the device
+    # finished. Always stamped; the device.run span exists only while traced.
+    collect.run_times = None
     return collect
 
 

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import os
+import statistics
 import sys
 import threading
 import time
@@ -168,14 +169,17 @@ class HybridBackend(VerifyBackend):
 
     The priors below (device ~100 sigs/ms plus a fixed per-dispatch cost,
     native host MSM ~70 sigs/ms with none) were taken on an earlier
-    installation and have not been re-measured on a directly attached chip;
-    the EMA corrects them from the first split call on. Neither dominates:
-    the device wins big batches, the host wins small ones, and for the
-    headline commit shape the OPTIMUM is both at once. batch_verify splits
-    each large batch at a bucket-aligned point chosen by a rate model
-    (EMA-updated from every measured call), dispatches the device share
-    asynchronously (ed25519_kernel.batch_verify_submit), runs the host MSM
-    share in the calling thread, and merges the bitmaps. Merkle roots go to
+    installation and only start the model: every call that reaches the
+    device books the wall of the bucket it ran, from the start of the pack
+    to the device-owner thread's own stamp of the program's return
+    (ed25519_kernel.batch_verify_submit), whichever tier was late, and the
+    host rate is an EMA over the host share's own interval. Neither tier
+    dominates: the device wins big batches, the host wins small ones, and
+    for the headline commit shape the OPTIMUM is both at once. batch_verify
+    splits each large batch at the bucket that minimizes the predicted
+    max(device wall, host time), dispatches the device share asynchronously,
+    runs the host MSM share in the calling thread, and merges the bitmaps.
+    Merkle roots go to
     the host SHA-NI tree (measured 10 ms vs 34 ms on device at 64k leaves,
     with no device round-trip).
 
@@ -201,7 +205,7 @@ class HybridBackend(VerifyBackend):
         self._n_dev = _ek.mesh_width()
         # sigs/ms PER CHIP; priors from the July 2026 stage splits (verify
         # 102 ms / 10,240 sigs device-side, 147 ms native), corrected by an
-        # EMA after every split call.
+        # EMA after every call that reaches the tier.
         self._dev_rate = float(os.environ.get("CMTPU_DEV_RATE", "100"))
         self._host_rate = float(os.environ.get("CMTPU_HOST_RATE", "70"))
         # Fixed per-dispatch device cost (pack + dispatch round trip), ms.
@@ -213,19 +217,20 @@ class HybridBackend(VerifyBackend):
         # a program can pay a multi-second XLA compile, which must not be
         # charged to the steady-state rate model.
         self._warmed: set[tuple] = set()
-        # Measured device wall per (batch bucket, mesh width) — EMA,
-        # straggler-observed only. The device cost is AFFINE — a fixed
-        # dispatch latency plus a per-lane slope — so a
-        # single sigs/ms rate learned at one bucket misprices every other;
-        # real walls win. Width in the key so a mesh-size change (or a test
-        # flipping the virtual mesh) can't reuse stale single-chip walls.
+        # Measured device wall per (batch bucket, mesh width): pack to the
+        # device-owner thread's return, the median of the last three calls
+        # that ran the bucket (_dev_recent). The device's own time repeats
+        # to a millisecond, and what disturbs a sample is one-sided and
+        # rare — a host stall inside the pack, 116 ms once in 815 calls on
+        # the chip's machine — but a bucket the planner has left is never
+        # measured again, so one such sample must not move its wall. The
+        # device cost is AFFINE — a fixed dispatch latency plus a per-lane
+        # slope — so a single sigs/ms rate learned at one bucket misprices
+        # every other; real walls win. Width in the key so a mesh-size
+        # change (or a test flipping the virtual mesh) can't reuse stale
+        # single-chip walls.
         self._dev_wall: dict[tuple[int, int], float] = {}
-        # Hill-climb bias on the bucket ladder: when the device finishes
-        # early its true wall is unobservable (collect() never blocks), so
-        # the rate model alone can NEVER learn to grow the device share —
-        # the controller shifts the split one bucket toward whichever tier
-        # sat idle, bounded so a broken model can't run away.
-        self._bias = 0
+        self._dev_recent: dict[tuple[int, int], collections.deque] = {}
         # Share + stage walls of the most recent split call (observability;
         # bench reports these so device runs explain themselves).
         self.last_share = 0
@@ -255,7 +260,7 @@ class HybridBackend(VerifyBackend):
         from cometbft_tpu.ops import ed25519_kernel as ek
 
         # Snapshot under the lock: _update_rates inserts first-observation
-        # bucket keys from straggler-collect threads, and iterating the live
+        # bucket keys from other callers' threads, and iterating the live
         # dict here would race that insert (RuntimeError: dictionary changed
         # size during iteration) escaping into consensus/blocksync callers.
         # Only walls observed at the CURRENT mesh width apply.
@@ -300,10 +305,6 @@ class HybridBackend(VerifyBackend):
             cost = max(dev_ms(b), host_ms(n - b))
             if cost < best_cost:
                 best_b, best_cost = b, cost
-        if best_b > 0 and self._bias:
-            i = ladder.index(best_b) + self._bias
-            best_b = ladder[max(0, min(i, len(ladder) - 1))]
-            best_cost = max(dev_ms(best_b), host_ms(n - best_b))
         return best_b, best_cost
 
     def _planned_call(self, pubs, msgs, sigs, between=None):
@@ -313,7 +314,7 @@ class HybridBackend(VerifyBackend):
         with trace.span("hybrid.call", n=n) as call:
             with trace.span("hybrid.plan") as plan:
                 share, predicted_ms = self._plan_cost(n)
-                plan.set(share=share, predicted_ms=predicted_ms, bias=self._bias)
+                plan.set(share=share, predicted_ms=predicted_ms)
             call.set(share=share, route=_route_name(share, n))
             return self._routed_call(pubs, msgs, sigs, share, between, predicted_ms)
 
@@ -389,7 +390,7 @@ class HybridBackend(VerifyBackend):
                 # build's state: below the split threshold even per-signature
                 # OpenSSL (CpuBackend's own fallback) beats the device's fixed
                 # dispatch cost, and tiny batches carry no useful rate signal
-                # and must not decay the bias learned on commit-sized ones.
+                # and must not move the model learned on commit-sized ones.
                 call.set(share=0, route="host")
                 self._note_route(n, 0)
                 with trace.span("hybrid.host_msm", lanes=n):
@@ -422,7 +423,6 @@ class HybridBackend(VerifyBackend):
                 if host_ms > 1:
                     r = min(max(n / host_ms, 5.0), 5000.0)
                     self._host_rate += 0.3 * (r - self._host_rate)
-                self._decay_bias()
             if between is not None:
                 extra = between()
             return res, extra
@@ -446,26 +446,29 @@ class HybridBackend(VerifyBackend):
         t_dev = time.perf_counter()
         self._update_rates(
             collect.program_key, share, n - share, t0, t_disp, t_host, t_wait, t_dev,
-            predicted_ms,
+            collect.run_times, predicted_ms,
         )
         if share < n:
             return (ok_d and ok_h, bits_d + bits_h), extra
         return (ok_d, bits_d), extra
 
     def _update_rates(
-        self, key, n_dev, n_host, t0, t_disp, t_host, t_wait, t_dev, predicted_ms=None
+        self, key, n_dev, n_host, t0, t_disp, t_host, t_wait, t_dev, t_run,
+        predicted_ms=None,
     ):
-        """EMA the rate model from what this call actually measured. The
-        host share ran exclusively in [t_disp, t_host]. The device wall is
-        only observable when the device was the straggler (collect(),
-        entered at t_wait, actually blocked); when the device finished
-        first, its wall time is unknowable from here — update NOTHING
-        rather than mis-learn a rate dominated by host work. A bucket's
-        first dispatch is also excluded: it can carry a multi-second XLA
-        compile that would poison the steady-state model in one step."""
+        """Update the model from what this call measured. The host share ran
+        exclusively in [t_disp, t_host]. The device's wall is t0 (the pack's
+        start) to t_run[1], the device-owner thread's own stamp of the
+        program's return (t_run is its (start, return) pair), so it is
+        known on every call, whichever tier was late: never t_dev, which
+        is the HOST's wall whenever the caller came to collect() after the
+        device had finished. A program's first dispatch is left out: it can
+        carry a multi-second XLA compile that would poison the steady-state
+        model in one step."""
         alpha = 0.3
         host_ms = (t_host - t_disp) * 1000
-        dev_ms = (t_dev - t0) * 1000
+        call_ms = (t_dev - t0) * 1000
+        dev_ms = (t_run[1] - t0) * 1000
         warm_key = (*key, self._n_dev)
         first_use = warm_key not in self._warmed
         self._warmed.add(warm_key)
@@ -477,10 +480,10 @@ class HybridBackend(VerifyBackend):
             "host_msm_ms": round(host_ms, 2),
             "overlap_extra_ms": round((t_wait - t_host) * 1000, 2),
             "dev_wait_ms": round((t_dev - t_wait) * 1000, 2),
+            "dev_run_ms": round((t_run[1] - t_run[0]) * 1000, 2),
             "dev_wall_ms": round(dev_ms, 2),
-            "total_ms": round((t_dev - t0) * 1000, 2),
+            "total_ms": round(call_ms, 2),
             "first_use": first_use,
-            "bias": self._bias,
         }
         with self._rate_lock:
             if n_host > 0:
@@ -489,43 +492,24 @@ class HybridBackend(VerifyBackend):
                     self._share_changes += 1
                 self._last_split_share = n_dev
             if not first_use and predicted_ms is not None:
-                self._plan_abs_err_ms += abs(predicted_ms - dev_ms)
-                self._wall_ms += dev_ms
+                self._plan_abs_err_ms += abs(predicted_ms - call_ms)
+                self._wall_ms += call_ms
             if host_ms > 1:
                 r = min(max(n_host / host_ms, 5.0), 5000.0)
                 self._host_rate += alpha * (r - self._host_rate)
-            straggler = t_dev - t_wait > 0.001
-            if straggler and not first_use and dev_ms > self._dev_overhead:
-                # Learned rate stays PER CHIP (observed mesh throughput /
-                # width) so it transfers if the mesh width changes.
-                r = n_dev / (dev_ms - self._dev_overhead) / self._n_dev
-                r = min(max(r, 5.0), 5000.0)
-                self._dev_rate += alpha * (r - self._dev_rate)
+            if not first_use:
+                if dev_ms > self._dev_overhead:
+                    # Learned rate stays PER CHIP (observed mesh throughput
+                    # / width) so it transfers if the mesh width changes.
+                    r = n_dev / (dev_ms - self._dev_overhead) / self._n_dev
+                    r = min(max(r, 5.0), 5000.0)
+                    self._dev_rate += alpha * (r - self._dev_rate)
                 wall_key = (key[0], self._n_dev)
-                prev = self._dev_wall.get(wall_key, dev_ms)
-                self._dev_wall[wall_key] = prev + alpha * (dev_ms - prev)
-            wait_ms = (t_dev - t_wait) * 1000
-            if n_host == 0:
-                # All-device/all-host calls carry no idle-tier signal;
-                # decay toward the model's choice so neither extreme is
-                # an absorbing state (the split paths stop updating the
-                # moment the backend stops splitting). Decay is not a
-                # timing measurement, so first-dispatch compiles don't
-                # gate it.
-                self._decay_bias()
-            elif not first_use:
-                if not straggler:
-                    # device idle at collect: give it one bucket more
-                    self._bias = min(self._bias + 1, 3)
-                elif wait_ms > 0.2 * max(dev_ms, 1.0):
-                    # device clearly the straggler: pull one bucket back
-                    self._bias = max(self._bias - 1, -3)
-
-    def _decay_bias(self):
-        if self._bias > 0:
-            self._bias -= 1
-        elif self._bias < 0:
-            self._bias += 1
+                recent = self._dev_recent.setdefault(
+                    wall_key, collections.deque(maxlen=3)
+                )
+                recent.append(dev_ms)
+                self._dev_wall[wall_key] = statistics.median_low(recent)
 
     def merkle_root(self, leaves):
         if self._native.ready() is not None:

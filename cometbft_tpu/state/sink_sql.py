@@ -184,7 +184,10 @@ class SqlEventSink:
             self._conn.commit()
 
     def stop(self) -> None:
-        self._conn.close()
+        # Under the write lock: closing the connection while the indexer's
+        # pump thread is inside a statement on it crashes the interpreter.
+        with self._mtx:
+            self._conn.close()
 
     # -- IndexerService adapters (tx_indexer / block_indexer duck types) ----
 

@@ -140,35 +140,211 @@ def test_backend_env_selects_hybrid(monkeypatch):
 
 
 @needs_native
-def test_all_device_path_feeds_model_and_decays_bias(monkeypatch):
-    """All-device calls must keep updating the model and decay the bias —
-    otherwise a bias-climbed all-device plan becomes an absorbing state
-    with no feedback path back to splitting."""
+def test_all_device_call_feeds_the_buckets_wall(monkeypatch):
+    """An all-device call keeps feeding the model: the bucket it ran gets a
+    wall from the second call on (the first is the program's warm-up), and
+    the wall is the device's own, not longer than the call."""
+    from cometbft_tpu.ops import ed25519_kernel as ek
+
     hb = _hybrid(monkeypatch, dev_rate=5000.0, host_rate=5.0)
-    hb._bias = 3
     pubs, msgs, sigs = _batch(48)
     assert hb._plan(48) >= 48  # model says all-device
     ok, bits = hb.batch_verify(pubs, msgs, sigs)
     assert ok and all(bits)
     assert hb.last_share == 48
-    assert hb._bias == 2  # decayed, not frozen
-    # Second call: the first was the program's warm-up (first_use), the
-    # second records a real device wall for the bucket.
+    assert hb.last_timing["first_use"] and hb._dev_wall == {}
     hb.batch_verify(pubs, msgs, sigs)
-    assert hb._bias == 1
-    from cometbft_tpu.ops import ed25519_kernel as ek
-
-    assert (ek.bucket_for(48), hb._n_dev) in hb._dev_wall
+    wall = hb._dev_wall[(ek.bucket_for(48), hb._n_dev)]
+    assert 0 < wall <= hb.last_timing["total_ms"]
+    assert wall == pytest.approx(hb.last_timing["dev_wall_ms"], abs=0.01)
 
 
 @needs_native
-def test_small_batches_do_not_touch_controller(monkeypatch):
+def test_small_batches_touch_no_wall(monkeypatch):
+    """A call under CMTPU_HYBRID_MIN goes to the host and leaves the model
+    learned on commit-sized calls alone: no wall, no rate, no split count."""
     hb = _hybrid(monkeypatch, min_split=64)
-    hb._bias = 2
+    with hb._rate_lock:
+        hb._dev_wall[(128, 1)] = 7.0
+    rates = (hb._dev_rate, hb._host_rate)
     pubs, msgs, sigs = _batch(16)
     ok, bits = hb.batch_verify(pubs, msgs, sigs)
     assert ok and all(bits)
-    assert hb._bias == 2 and hb._dev_wall == {}
+    assert hb._dev_wall == {(128, 1): 7.0}
+    assert (hb._dev_rate, hb._host_rate) == rates
+    assert hb.counters()["split_calls"] == 0 and hb.last_timing == {}
+
+
+# -- the planner on a simulated chip: no device, no sleeps ---------------------------
+
+# The chip's affine costs in ms (PERF.md section 5, chip runs of PR 24): the
+# device program, the pack before it, the unpack inside collect(), the host MSM.
+DEV_MS = (9.0, 6.35e-3)
+PACK_MS_PER_LANE = 0.8e-3
+UNPACK_MS = 1.3
+HOST_MS = (12.4, 20.7e-3)
+
+
+class _SimulatedTiers:
+    """Stands in for both tiers on a clock that only the tiers' own costs
+    move: `submit` packs and starts the device program, `batch_verify` is
+    the native MSM, collect() waits for the program if it still runs and
+    unpacks. Lanes are placeholders; every bitmap is all true."""
+
+    def __init__(self, monkeypatch, hb):
+        from cometbft_tpu.ops import ed25519_kernel as ek
+
+        self.now = 0.0
+        self.stall_ms = 0.0  # a host stall inside the next pack, once
+        self._ek = ek
+        monkeypatch.setattr(be, "time", self)
+        monkeypatch.setattr(ek, "batch_verify_submit", self.submit)
+        hb._native = self
+
+    def perf_counter(self):
+        return self.now
+
+    @staticmethod
+    def ready():
+        return object()
+
+    @staticmethod
+    def status():
+        return "simulated"
+
+    def submit(self, pubs, msgs, sigs):
+        n = len(pubs)
+        bucket = self._ek.bucket_for(n)
+        self.now += (PACK_MS_PER_LANE * n + self.stall_ms) / 1000
+        self.stall_ms = 0.0
+        started = self.now
+        returned = started + (DEV_MS[0] + DEV_MS[1] * bucket) / 1000
+
+        def collect():
+            self.now = max(self.now, returned) + UNPACK_MS / 1000
+            collect.run_times = (started, returned)
+            return True, [True] * n
+
+        collect.program_key = (bucket, 2)
+        collect.run_times = None
+        return collect
+
+    def batch_verify(self, pubs, msgs, sigs):
+        self.now += (HOST_MS[0] + HOST_MS[1] * len(pubs)) / 1000
+        return True, [True] * len(pubs)
+
+
+def _simulated(monkeypatch):
+    hb = _hybrid(monkeypatch, min_split=2048, dev_rate=100.0, host_rate=70.0)
+    hb._dev_overhead = 8.0  # with it, the shipped priors, all four
+    return hb, _SimulatedTiers(monkeypatch, hb)
+
+
+def _start_fresh(hb, lanes):
+    pass
+
+
+def _start_poisoned(hb, lanes):
+    # the stuck run of PR 24: the host's ~100 ms booked as the 6,144 wall
+    hb._warmed.add((6144, 2, 1))
+    hb._dev_wall[(6144, 1)] = 100.0
+    hb._host_rate = 42.0
+
+
+def _start_forced(hb, lanes):
+    hb._routed_call(*lanes, 6144)
+
+
+@pytest.mark.parametrize("start", [_start_fresh, _start_poisoned, _start_forced])
+def test_planner_reaches_the_fast_share_and_holds_it(monkeypatch, start):
+    """10,000 lanes on the chip's costs: 8,192 is the share (~70 ms a call
+    against ~84 all-device and ~98 at 6,144). From the priors, from the
+    mis-learned wall that held a whole run at 6,144, and after a first call
+    at 6,144, the planner is there within 8 calls and never leaves."""
+    hb, sim = _simulated(monkeypatch)
+    lanes = ([None] * 10000,) * 3
+    start(hb, lanes)
+    shares = []
+    for _ in range(8):
+        ok, bits = hb.batch_verify(*lanes)
+        assert ok and len(bits) == 10000
+        shares.append(hb.last_share)
+    assert shares[-1] == 8192, shares
+    assert 4096 not in shares and 0 not in shares, shares
+    changes = hb.counters()["share_changes"]
+    t0 = sim.now
+    for _ in range(50):
+        hb.batch_verify(*lanes)
+        assert hb.last_share == 8192
+    c = hb.counters()
+    assert c["share_changes"] == changes
+    assert (sim.now - t0) * 1000 / 50 == pytest.approx(69.6, abs=1.0)  # max(6.55+61.02, 6.55+49.83)+1.3
+    # the model behind the share is right: the last calls are predicted within 5%
+    assert hb.last_timing["dev_wall_ms"] == pytest.approx(67.57, abs=0.5)
+    assert abs(hb._plan_cost(10000)[1] - hb.last_timing["total_ms"]) < 0.05 * hb.last_timing["total_ms"]
+
+
+def test_one_stalled_call_does_not_strand_the_planner(monkeypatch):
+    """The chip's machine stalls the host now and then (116 ms inside one
+    pack in 815 calls, and a 20 s run left at 6,144 for good by a larger
+    one: chip runs of PR 25). The stall is booked as that call's device
+    wall, but a bucket the planner leaves is never measured again, so one
+    such call must not move the bucket's wall: the share holds."""
+    hb, sim = _simulated(monkeypatch)
+    lanes = ([None] * 10000,) * 3
+    for _ in range(12):
+        hb.batch_verify(*lanes)
+    assert hb.last_share == 8192
+    changes, wall = hb.counters()["share_changes"], hb._dev_wall[(8192, 1)]
+    sim.stall_ms = 150.0
+    hb.batch_verify(*lanes)
+    assert hb.last_timing["dev_wall_ms"] == pytest.approx(wall + 150.0, abs=0.1)
+    assert hb._dev_wall[(8192, 1)] == pytest.approx(wall, abs=0.1)
+    for _ in range(20):
+        hb.batch_verify(*lanes)
+        assert hb.last_share == 8192
+    assert hb.counters()["share_changes"] == changes
+
+
+def test_device_wall_learned_when_the_host_is_late(monkeypatch):
+    """A split call whose collect() returns at once (the device finished
+    long before the host) still books the bucket's wall, from the owner
+    thread's stamps, far below the call's wall: the 6,144 share must read
+    as ~53 ms of device beside ~92 ms of host, not as a ~100 ms device."""
+    hb, sim = _simulated(monkeypatch)
+    lanes = ([None] * 10000,) * 3
+    hb._routed_call(*lanes, 6144)  # the program's first use: nothing booked
+    assert hb._dev_wall == {}
+    hb._routed_call(*lanes, 6144)
+    t = hb.last_timing
+    assert t["dev_wait_ms"] == pytest.approx(UNPACK_MS, abs=0.01)  # collect() did not block
+    assert t["host_msm_ms"] == pytest.approx(92.22, abs=0.1) and t["total_ms"] > 98
+    assert hb._dev_wall == {(6144, 1): pytest.approx(52.93, abs=0.1)}  # 4.92 pack + 48.01 run
+    assert t["dev_wall_ms"] == pytest.approx(52.93, abs=0.1)
+    assert t["dev_run_ms"] == pytest.approx(48.01, abs=0.1)
+
+
+def test_run_stamps_taken_with_tracing_off():
+    """The device-owner thread stamps its start and return on every
+    dispatch, profiler session or none: the planner's reading cannot
+    depend on the device.run span, which exists only while traced."""
+    import time
+
+    from cometbft_tpu.libs import trace
+    from cometbft_tpu.ops import ed25519_kernel as ek
+
+    trace.clear()
+    assert not trace.spans()
+    pubs, msgs, sigs = _batch(12, tag=b"stamps")
+    t0 = time.perf_counter()
+    collect = ek.batch_verify_submit(pubs, msgs, sigs)
+    assert collect.run_times is None  # nothing to read before collect()
+    ok, bits = collect()
+    t1 = time.perf_counter()
+    assert ok and bits == [True] * 12
+    started, returned = collect.run_times
+    assert t0 <= started <= returned <= t1
+    assert not trace.spans(), "no session is open: no span was recorded"
 
 
 def test_multi_device_routing_shards_the_shipped_seam(monkeypatch):
@@ -267,7 +443,7 @@ def test_warm_keys_include_mesh_width(monkeypatch):
     sharded program compiles) even when the same (batch, block) program was
     already warm at another width."""
     hb = _hybrid(monkeypatch)
-    ts = (0.0, 0.001, 0.002, 0.002, 0.050)
+    ts = (0.0, 0.001, 0.002, 0.002, 0.050, (0.001, 0.049))
     hb._n_dev = 1
     hb._update_rates((128, 2), 128, 0, *ts)
     assert hb.last_timing["first_use"]

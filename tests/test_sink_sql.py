@@ -67,6 +67,25 @@ def test_sql_sink_unit_roundtrip(tmp_path):
     sink.stop()
 
 
+def test_stop_waits_for_a_write_in_flight(tmp_path):
+    """stop() closes the connection under the write lock: closing it while
+    the indexer's pump thread is inside a statement segfaults the process
+    (seen as a crashed tier-1 worker in the node test below)."""
+    import threading
+
+    sink = SqlEventSink(str(tmp_path / "sink.db"), "stop-chain")
+    stopped = threading.Event()
+    with sink._mtx:  # a write in flight
+        t = threading.Thread(target=lambda: (sink.stop(), stopped.set()), daemon=True)
+        t.start()
+        assert not stopped.wait(0.2), "stop() closed the connection under a writer"
+    assert stopped.wait(5)
+    t.join(5)
+    assert not t.is_alive()
+    with pytest.raises(sqlite3.ProgrammingError):
+        sink.index_block(1, {})
+
+
 def test_node_with_psql_indexer_writes_sqlite(tmp_path):
     """VERDICT r4 #6: indexer="psql" is real — a committing node lands its
     txs in the relational sink, queryable by plain SQL."""

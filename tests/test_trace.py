@@ -298,7 +298,8 @@ def test_blocksync_heights_are_roots_with_their_children(profiler):
 
 def test_share_changes_over_a_forced_sequence_of_shares(monkeypatch):
     hb = be.HybridBackend()
-    ts = (0.0, 0.001, 0.010, 0.010, 0.020)  # t0, t_disp, t_host, t_wait, t_dev
+    # t0, t_disp, t_host, t_wait, t_dev, t_run (the owner thread's start, return)
+    ts = (0.0, 0.001, 0.010, 0.010, 0.020, (0.001, 0.019))
     hb._update_rates((32, 2), 32, 16, *ts)  # a program's first use
     for share, predicted in ((32, 25.0), (32, 20.0), (8, 20.0), (32, None), (48, 20.0), (8, 20.0)):
         hb._update_rates((share, 2), share, 48 - share, *ts, predicted)
