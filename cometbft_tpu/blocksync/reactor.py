@@ -112,6 +112,7 @@ class BlocksyncReactor(Reactor):
         self.verify_wait_ms = 0.0  # blocked on the prefetch worker
         self.idle_sleeps = 0       # 10 ms sleeps of the pool routine
         self.redo_requests = 0     # blocks refused and asked for again
+        self.block_bytes_received = 0  # encoded block responses, as they left the wire
         # The fetch wait under way: (its span, perf_counter at its start,
         # idle_sleeps at its start), from the first peek that found no pair
         # to the next that finds one.
@@ -167,11 +168,14 @@ class BlocksyncReactor(Reactor):
             "idle_sleeps": self.idle_sleeps,
             "redo_requests": self.redo_requests,
             "pipeline_overlap_ms": round(self.pipeline_overlap_ms, 3),
+            "block_bytes_received": self.block_bytes_received,
+            **self.pool.counters(),
         }
 
     def receive(self, chan_id: int, peer, msg_bytes: bytes) -> None:
         if msg_bytes[:1] == _BLOCK_RESPONSE_KEY:
             # the one message with real work behind it: block decode + pool
+            self.block_bytes_received += len(msg_bytes)
             with trace.span("blocksync.decode", bytes=len(msg_bytes)):
                 self._receive(peer, msg_bytes)
         else:

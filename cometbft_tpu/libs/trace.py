@@ -58,11 +58,14 @@ NAMES = (
     "blocksync.apply",
     "blocksync.prefetch",
     "blocksync.decode",
+    "types.data_hash",
+    "types.part_set_proofs",
     # state and stores
     "state.validate",
     "state.exec_abci",
     "state.save_responses",
     "state.update",
+    "state.results_hash",
     "state.commit",
     "state.save_state",
     "store.save_block",

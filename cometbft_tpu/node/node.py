@@ -876,6 +876,10 @@ class Node:
             ("verify_wait_ms", "Sync thread blocked on the prefetch worker, ms."),
             ("idle_sleeps", "10 ms sleeps of the blocksync pool routine."),
             ("redo_requests", "Blocks refused and requested again."),
+            ("requests_sent", "Block requests the catch-up pool sent."),
+            ("requests_to_busiest_peer", "Block requests sent to the connected peer asked most."),
+            ("peers_asked", "Connected peers the catch-up pool has sent a block request."),
+            ("block_bytes_received", "Bytes of encoded block responses received."),
         ):
             reg.gauge_func("blocksync", key, text, bs(key))
 

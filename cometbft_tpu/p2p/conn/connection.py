@@ -59,9 +59,13 @@ class _Channel:
     def __init__(self, desc: ChannelDescriptor):
         self.desc = desc
         self.send_queue: queue.Queue[bytes] = queue.Queue(desc.send_queue_capacity)
-        self.sending: bytes | None = None
+        # The message being sent is a view that shrinks by a packet and the
+        # one being received a buffer that grows by one: a 300 KB block is
+        # 300 packets, and slicing or concatenating bytes copies the whole
+        # message for each of them.
+        self.sending: memoryview | None = None
         self.recently_sent = 0
-        self.recving = b""
+        self.recving = bytearray()
 
 
 class MConnection:
@@ -197,7 +201,7 @@ class MConnection:
         for ch in self.channels.values():
             if ch.sending is None:
                 try:
-                    ch.sending = ch.send_queue.get_nowait()
+                    ch.sending = memoryview(ch.send_queue.get_nowait())
                 except queue.Empty:
                     continue
             ratio = ch.recently_sent / max(ch.desc.priority, 1)
@@ -207,7 +211,7 @@ class MConnection:
 
     def _send_packet_for(self, ch: _Channel) -> None:
         data = ch.sending
-        chunk, rest = data[: self.max_payload], data[self.max_payload :]
+        chunk, rest = bytes(data[: self.max_payload]), data[self.max_payload :]
         eof = len(rest) == 0
         pkt = (
             wire.field_varint(1, ch.desc.id)
@@ -218,7 +222,7 @@ class MConnection:
         ch.recently_sent += len(chunk)
         # decay fairness counter
         ch.recently_sent = int(ch.recently_sent * 0.8)
-        ch.sending = rest if rest else None
+        ch.sending = None if eof else rest
 
     def _write_packet(self, packet_fields: bytes) -> None:
         framed = wire.length_delimited(packet_fields)
@@ -255,7 +259,7 @@ class MConnection:
                     if len(ch.recving) > ch.desc.recv_message_capacity:
                         raise ValueError("received message exceeds channel capacity")
                     if eof:
-                        msg, ch.recving = ch.recving, b""
+                        msg, ch.recving = bytes(ch.recving), bytearray()
                         if self._recvq is not None:
                             self._recvq.push(chan_id, msg)
                         else:

@@ -41,6 +41,7 @@ DATA_LEN_SIZE = 4
 DATA_MAX_SIZE = 1024
 TOTAL_FRAME_SIZE = DATA_MAX_SIZE + DATA_LEN_SIZE
 AEAD_SIZE_OVERHEAD = 16
+RAW_READ_SIZE = 65536  # bytes asked of the socket at a time
 KEY_AND_CHALLENGE_GEN = b"TENDERMINT_SECRET_CONNECTION_KEY_AND_CHALLENGE_GEN"
 
 
@@ -91,6 +92,7 @@ class SecretConnection:
         self.loc_pub_key = loc_priv_key.pub_key()
         self.rem_pub_key = None
         self._recv_buffer = b""
+        self._raw_buffer = bytearray()  # read off the socket, not yet asked for
         self._send_nonce = 0
         self._recv_nonce = 0
         self._handshake()
@@ -201,12 +203,18 @@ class SecretConnection:
         self._conn.sendall(data)
 
     def _read_raw(self, n: int) -> bytes:
-        out = b""
-        while len(out) < n:
-            chunk = self._conn.recv(n - len(out))
+        """The next n bytes of the stream. The socket is read in large
+        pieces: one call per 1 KiB frame is one release of the interpreter
+        lock per frame, and a receive thread that has to win the lock back
+        from a busy thread for every frame gets a few hundred KB/s."""
+        buf = self._raw_buffer
+        while len(buf) < n:
+            chunk = self._conn.recv(RAW_READ_SIZE)
             if not chunk:
                 raise SecretConnectionError("connection closed")
-            out += chunk
+            buf += chunk
+        out = bytes(buf[:n])
+        del buf[:n]
         return out
 
     def close(self) -> None:

@@ -296,6 +296,10 @@ def _update_state(
 
     from dataclasses import replace
 
+    deliver_txs = abci_responses["deliver_txs"]
+    with trace.span("state.results_hash", txs=len(deliver_txs)):
+        last_results_hash = results_hash(deliver_txs)
+
     version = state.version_consensus
     if params.version.app != version.app:
         from cometbft_tpu.types.block import Consensus
@@ -314,7 +318,7 @@ def _update_state(
         last_height_validators_changed=last_height_vals_changed,
         consensus_params=params,
         last_height_consensus_params_changed=last_height_params_changed,
-        last_results_hash=results_hash(abci_responses["deliver_txs"]),
+        last_results_hash=last_results_hash,
         app_hash=b"",
         version_consensus=version,
     )

@@ -13,6 +13,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field as dfield
 
 from cometbft_tpu.crypto import merkle, tmhash
+from cometbft_tpu.libs import trace
 from cometbft_tpu.types import cmttime
 from cometbft_tpu.types.cmttime import Time
 from cometbft_tpu.wire import proto as wire
@@ -674,7 +675,10 @@ class Data:
         from cometbft_tpu.types.tx import txs_hash
 
         if self._hash is None:
-            self._hash = txs_hash(self.txs)
+            with trace.span("types.data_hash", txs=len(self.txs)) as sp:
+                self._hash = txs_hash(self.txs)
+                if sp.id is not None:  # a walk over the txs, so only when traced
+                    sp.set(bytes=sum(map(len, self.txs)))
         return self._hash
 
     def encode(self) -> bytes:

@@ -13,6 +13,7 @@ from dataclasses import dataclass, field as dfield
 
 from cometbft_tpu.crypto import merkle, tmhash
 from cometbft_tpu.crypto.merkle.proof import Proof, proofs_from_byte_slices
+from cometbft_tpu.libs import trace
 from cometbft_tpu.libs.bit_array import BitArray
 from cometbft_tpu.types.block import BLOCK_PART_SIZE_BYTES, PartSetHeader
 from cometbft_tpu.wire import proto as wire
@@ -67,7 +68,8 @@ class PartSet:
         if total == 0:
             total = 1
         chunks = [data[i * part_size : (i + 1) * part_size] for i in range(total)]
-        root, proofs = proofs_from_byte_slices(chunks)
+        with trace.span("types.part_set_proofs", parts=total):
+            root, proofs = proofs_from_byte_slices(chunks)
         ps = cls(PartSetHeader(total=total, hash=root))
         for i, chunk in enumerate(chunks):
             part = Part(index=i, bytes=chunk, proof=proofs[i])

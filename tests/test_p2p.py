@@ -45,6 +45,47 @@ def test_secret_connection_roundtrip():
     assert got == big
 
 
+class _CountingSocket:
+    """A socket that counts the reads asked of it."""
+
+    def __init__(self, sock):
+        self._sock = sock
+        self.reads = 0
+
+    def __getattr__(self, name):
+        return getattr(self._sock, name)
+
+    def recv(self, n):
+        self.reads += 1
+        return self._sock.recv(n)
+
+
+@pytest.mark.parametrize("size", [1, 1024, 1025, 5000, 311_317])
+def test_secret_connection_reads_the_socket_in_large_pieces(size):
+    """A burst of 1 KiB frames is taken off the socket in a few reads, not
+    one a frame, and every byte comes out once and in order."""
+    a, b = socket.socketpair()
+    b = _CountingSocket(b)
+    k1, k2 = ed25519.gen_priv_key(), ed25519.gen_priv_key()
+    out = {}
+    t = threading.Thread(target=lambda: out.update(server=SecretConnection(b, k2)), daemon=True)
+    t.start()
+    sc1 = SecretConnection(a, k1)
+    t.join(timeout=5)
+    body = bytes(i * 7 % 253 for i in range(size))
+    writer = threading.Thread(target=lambda: (sc1.write(body), sc1.write(b"tail")), daemon=True)
+    writer.start()
+    time.sleep(0.2)  # the burst is in the socket's buffer before the first read
+    before = b.reads
+    got = out["server"].read_exact(size)
+    assert got == body and out["server"].read_exact(4) == b"tail"
+    writer.join(timeout=5)
+    frames = -(-size // 1024) + 1
+    assert b.reads - before <= max(2, frames // 8), (b.reads - before, frames)
+    a.close()
+    b.close()
+
+
 def test_secret_connection_rejects_tampered_ciphertext():
     """AEAD integrity: flipping any ciphertext bit on the wire must surface
     as a clean connection error on the reader — never plaintext corruption,
@@ -163,6 +204,33 @@ def test_switch_two_nodes():
         sw2.broadcast(0x77, b"reply-broadcast")
         assert r1.event.wait(5)
         assert r1.received[0][1] == b"reply-broadcast"
+    finally:
+        sw1.stop()
+        sw2.stop()
+
+
+@pytest.mark.parametrize("size", [0, 1, 1023, 1024, 1025, 65536, 311_317])
+def test_a_message_of_any_size_crosses_the_connection_whole(size):
+    """One packet, a packet boundary, and a loaded block's 300 packets: the
+    sender's shrinking view and the receiver's growing buffer lose nothing,
+    and the next message starts clean."""
+    sw1, _ = _make_switch("n1")
+    sw2, nk2 = _make_switch("n2")
+    r1, r2 = EchoReactor(0x77), EchoReactor(0x77)
+    r2.get_channels = lambda: [ChannelDescriptor(0x77, priority=5, recv_message_capacity=1 << 20)]
+    sw1.add_reactor("echo", r1)
+    sw2.add_reactor("echo", r2)
+    addr2 = sw2.start("127.0.0.1:0")
+    sw1.start("")
+    body = bytes(i * 31 % 251 for i in range(size))
+    try:
+        peer = sw1.dial_peer(f"{nk2.id}@{addr2}")
+        assert peer is not None
+        assert peer.send(0x77, body) and peer.send(0x77, b"after")
+        deadline = time.time() + 10
+        while time.time() < deadline and len(r2.received) < 2:
+            time.sleep(0.01)
+        assert [m for _, m in r2.received] == [body, b"after"]
     finally:
         sw1.stop()
         sw2.stop()

@@ -259,6 +259,7 @@ def test_blocksync_heights_are_roots_with_their_children(profiler):
     try:
         sw_c.dial_peer(f"{nk_s.id}@{addr_s}")
         assert caught.wait(45), "never reported caught up"
+        live = reactor.counters()  # two of the pool's counters count the peers still there
     finally:
         sw_c.stop()
         sw_s.stop()
@@ -277,10 +278,20 @@ def test_blocksync_heights_are_roots_with_their_children(profiler):
         mine = [s["name"] for s in spans if s["root"] == one["id"]]
         assert mine.count("state.validate") == 2, "validate_block, then apply_block's own"
         for name in ("state.exec_abci", "state.save_responses", "state.update",
-                     "state.commit", "state.save_state", "store.save_block"):
+                     "state.commit", "state.save_state", "store.save_block",
+                     "types.data_hash", "types.part_set_proofs", "state.results_hash"):
             assert mine.count(name) == 1, name
         assert mine.count("validation.verify_commit") >= 1
     assert any(s["name"] == "blocksync.decode" and s["attrs"]["bytes"] > 0 for s in spans)
+    for name, attrs in (("types.data_hash", {"txs", "bytes"}), ("types.part_set_proofs", {"parts"}),
+                        ("state.results_hash", {"txs"})):
+        assert all(set(s["attrs"]) == attrs for s in spans if s["name"] == name), name
+    by_id = {s["id"]: s for s in spans}
+    synced = {one["id"] for one in roots}  # the chain's builder hashed too, under no height
+    for name, parent in (("types.part_set_proofs", "blocksync.part_set"),
+                         ("state.results_hash", "state.update")):
+        assert all(by_id[s["parent"]]["name"] == parent
+                   for s in spans if s["name"] == name and s["root"] in synced), name
     assert any(s["name"] == "blocksync.make_requests" for s in spans)
     waits = [s for s in spans if s["name"] == "blocksync.fetch_wait"]
     assert waits and all(s["parent"] is None for s in waits)
@@ -290,7 +301,13 @@ def test_blocksync_heights_are_roots_with_their_children(profiler):
     assert c["idle_sleeps"] >= sum(s["attrs"]["sleeps"] for s in waits) >= 1
     assert c["fetch_wait_ms"] > 0 and c["verify_wait_ms"] >= 0
     assert set(c) == {"heights_applied", "fetch_wait_ms", "verify_wait_ms",
-                      "idle_sleeps", "redo_requests", "pipeline_overlap_ms"}
+                      "idle_sleeps", "redo_requests", "pipeline_overlap_ms",
+                      "block_bytes_received", "requests_sent",
+                      "requests_to_busiest_peer", "peers_asked"}
+    assert c["requests_sent"] >= applied and live["peers_asked"] == 1
+    assert live["requests_to_busiest_peer"] == live["requests_sent"] >= applied
+    assert c["block_bytes_received"] >= sum(
+        s["attrs"]["bytes"] for s in spans if s["name"] == "blocksync.decode")
 
 
 # -- (e) counters --------------------------------------------------------------------
@@ -450,7 +467,7 @@ def _names_in_code():
 def _names_in_perf_md():
     with open(os.path.join(ROOT, "PERF.md")) as f:
         table = f.read().split("<!-- spans -->")[1].split("<!-- /spans -->")[0]
-    return set(re.findall(r"`((?:validation|blocksync|state|store|batch|engine|supervisor|hybrid|device)\.[a-z_]+)`", table))
+    return set(re.findall(r"`((?:validation|blocksync|types|state|store|batch|engine|supervisor|hybrid|device)\.[a-z_]+)`", table))
 
 
 @pytest.mark.parametrize("name", trace.NAMES)
@@ -509,7 +526,9 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
     reactor = BlocksyncReactor.__new__(BlocksyncReactor)  # counters() reads these alone
     vars(reactor).update(
         pipeline_overlap_ms=0.0, heights_applied=9, fetch_wait_ms=4.2,
-        verify_wait_ms=0.0, idle_sleeps=2, redo_requests=1,
+        verify_wait_ms=0.0, idle_sleeps=2, redo_requests=1, block_bytes_received=311,
+        pool=types.SimpleNamespace(counters=lambda: {
+            "requests_sent": 8, "requests_to_busiest_peer": 2, "peers_asked": 4}),
     )
     Node._register_hotpath_metrics(types.SimpleNamespace(blocksync_reactor=reactor), reg)
     assert "cmt_hybrid_split_calls 0" in reg.render()  # no backend yet: nothing constructed
@@ -521,5 +540,8 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
     assert "cmt_hybrid_split_calls 7" in out and "cmt_hybrid_share_changes 3" in out
     assert "cmt_blocksync_heights_applied 9" in out and "cmt_blocksync_idle_sleeps 2" in out
     assert "cmt_blocksync_redo_requests 1" in out
+    assert "cmt_blocksync_requests_sent 8" in out and "cmt_blocksync_peers_asked 4" in out
+    assert "cmt_blocksync_requests_to_busiest_peer 2" in out
+    assert "cmt_blocksync_block_bytes_received 311" in out
     size = ed25519.verified_cache_counters()["size"]
     assert f"cmt_verify_cache_size {size}" in out and "cmt_verify_cache_hits " in out
