@@ -86,5 +86,11 @@ class BatchVerifier(abc.ABC):
     @abc.abstractmethod
     def add(self, key: PubKey, message: bytes, signature: bytes) -> None: ...
 
+    def add_many(self, keys, messages, signatures) -> None:
+        """add() for whole columns: the same entries in the same order, and
+        the error add() would raise at the first entry it refuses."""
+        for key, message, signature in zip(keys, messages, signatures, strict=True):
+            self.add(key, message, signature)
+
     @abc.abstractmethod
     def verify(self) -> tuple[bool, list[bool]]: ...

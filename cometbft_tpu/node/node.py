@@ -883,17 +883,25 @@ class Node:
         ):
             reg.gauge_func("blocksync", key, text, bs(key))
 
-        def cache(key):
+        def counter(module, counters, key):
             # sys.modules, not an import: a scrape constructs nothing.
             def fn():
-                mod = sys.modules.get("cometbft_tpu.crypto.ed25519")
-                return mod.verified_cache_counters()[key] if mod else 0
+                mod = sys.modules.get(module)
+                return getattr(mod, counters)()[key] if mod else 0
             return fn
 
         for key in ("entries", "hits", "dups", "dispatched", "inserted",
                     "evicted", "size"):
-            reg.gauge_func("verify_cache", key,
-                           f"Verified-triple cache: {key}.", cache(key))
+            reg.gauge_func("verify_cache", key, f"Verified-triple cache: {key}.",
+                           counter("cometbft_tpu.crypto.ed25519",
+                                   "verified_cache_counters", key))
+        for key, text in (
+            ("built", "Validator-set columns computed for commit verification."),
+            ("reused", "Commit verifications that found their set's columns computed."),
+        ):
+            reg.gauge_func("verify_columns", key, text,
+                           counter("cometbft_tpu.types.validator_set",
+                                   "columns_counters", key))
 
     def _register_lightgw_metrics(self, reg) -> None:
         """Light-client gateway gauges. Strictly passive: they read the

@@ -359,9 +359,28 @@ class Commit:
     _hash: bytes | None = dfield(default=None, compare=False, repr=False)
     _sb_cache: tuple | None = dfield(default=None, compare=False, repr=False)
     _sba_cache: tuple | None = dfield(default=None, compare=False, repr=False)
+    _columns: tuple | None = dfield(default=None, compare=False, repr=False)
 
     def size(self) -> int:
         return len(self.signatures)
+
+    def sig_columns(self) -> tuple:
+        """(flags, seconds, nanos, signatures): the signatures' attributes
+        as one list each, in commit order — what the sign bytes and the
+        batch engine's selection are computed from, read off the CommitSigs
+        once a commit (memoized under the contract of _hash: a commit does
+        not change after construction)."""
+        cols = self._columns
+        if cols is None:
+            sigs = self.signatures
+            times = [cs.timestamp for cs in sigs]
+            self._columns = cols = (
+                [cs.block_id_flag for cs in sigs],
+                [t.seconds for t in times],
+                [t.nanos for t in times],
+                [cs.signature for cs in sigs],
+            )
+        return cols
 
     def is_aggregate(self) -> bool:
         return bool(self.agg_signature)
@@ -441,21 +460,17 @@ class Commit:
 
         _, pre_commit, pre_nil, suffix = self._sign_bytes_cache(chain_id)
 
-        secs = np.fromiter(
-            (cs.timestamp.seconds for cs in self.signatures), np.int64, n
-        ).view(np.uint64)
-        nanos = np.fromiter(
-            (cs.timestamp.nanos for cs in self.signatures), np.int64, n
-        ).view(np.uint64)
-        flags = np.fromiter(
-            (cs.for_block_flag() for cs in self.signatures), bool, n
-        )
+        flag_col, sec_col, nano_col, _ = self.sig_columns()
+        secs = np.fromiter(sec_col, np.int64, n).view(np.uint64)
+        nanos = np.fromiter(nano_col, np.int64, n).view(np.uint64)
+        flags = np.fromiter(flag_col, np.int64, n) == BLOCK_ID_FLAG_COMMIT
 
         def varint_slots(v):
             slots = np.empty((n, 10), np.uint8)
             vv = v.copy()
             lens = np.ones(n, np.int64)
-            for s in range(10):
+            # no row reads a slot past the widest value's last byte
+            for s in range(max(1, -(-int(v.max()).bit_length() // 7))):
                 b = (vv & np.uint64(0x7F)).astype(np.uint8)
                 vv = vv >> np.uint64(7)
                 cont = vv != 0
@@ -470,7 +485,7 @@ class Commit:
         has_nano = nanos != 0
         ts_lens = has_sec * (1 + sec_lens) + has_nano * (1 + nano_lens)
 
-        out: list = [None] * n
+        out = np.empty(n, object)
         # Group rows with identical byte layout; realistic commits produce
         # one or two groups (same epoch -> same sec width; nano width 1..5).
         key = (
@@ -505,9 +520,9 @@ class Commit:
                 m[:, pos + 1 : pos + 1 + nl] = nano_slots[rows, :nl]
                 pos += 1 + nl
             m[:, pos : pos + len(suffix)] = np.frombuffer(suffix, np.uint8)
-            buf = m.tobytes()
-            for j, i in enumerate(rows):
-                out[i] = buf[j * total : (j + 1) * total]
+            # each row of m as one bytes object (a void scalar's tolist())
+            out[rows] = m.view(np.dtype((np.void, total))).ravel().tolist()
+        out = out.tolist()
         self._sba_cache = (chain_id, out)
         return out
 

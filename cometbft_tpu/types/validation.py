@@ -11,6 +11,8 @@ error is produced without re-verification).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from itertools import accumulate, compress
+from operator import mul, not_
 
 from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.libs import trace
@@ -50,32 +52,23 @@ class ErrInvalidCommitSignatures(Exception):
         )
 
 
-def _batch_key_type(vals, commit: Commit) -> str | None:
+def _batch_key_type(vals, commit: Commit, cols=None) -> str | None:
     """The single key type shared by EVERY validator in the set, if that
     type is batch-capable — else None. The reference keys this decision on
     the proposer alone (validation.go:145-150), which mis-batches a mixed
     set: a bn254 signature fed into the ed25519 batch engine is a type
     error, not a clean reject. Homogeneous sets batch; mixed sets fall back
-    to the per-signature scalar engine, which dispatches per key."""
+    to the per-signature scalar engine, which dispatches per key. The type
+    is a column of the set (`cols`, else vals.columns()): a chain's keys
+    change at validator updates, not at heights."""
     if len(commit.signatures) < BATCH_VERIFY_THRESHOLD:
         return None
-    kt = None
-    for val in vals.validators:
-        pk = val.pub_key
-        if pk is None:
-            return None
-        t = pk.type()
-        if kt is None:
-            kt = t
-        elif t != kt:
-            return None
+    if cols is None:
+        cols, _ = vals.columns()
+    kt = cols.key_type
     if kt is None or not crypto_batch.supports_batch_verifier(kt):
         return None
     return kt
-
-
-def _should_batch_verify(vals, commit: Commit) -> bool:
-    return _batch_key_type(vals, commit) is not None
 
 
 def _n_sigs(commit) -> int:
@@ -89,21 +82,11 @@ def verify_commit(chain_id: str, vals, block_id: BlockID, height: int, commit: C
         with trace.span("validation.basic"):
             _verify_basic_vals_and_commit(vals, commit, height, block_id)
             voting_power_needed = vals.total_voting_power() * 2 // 3
-            batch = not commit.is_aggregate() and _should_batch_verify(vals, commit)
         ignore = lambda c: c.is_absent()
         count = lambda c: c.for_block_flag()
-        if commit.is_aggregate():
-            _verify_commit_aggregate(
-                chain_id, vals, commit, voting_power_needed, ignore, count, True
-            )
-        elif batch:
-            _verify_commit_batch(
-                chain_id, vals, commit, voting_power_needed, ignore, count, True, True
-            )
-        else:
-            _verify_commit_single(
-                chain_id, vals, commit, voting_power_needed, ignore, count, True, True
-            )
+        _verify_commit_by_engine(
+            chain_id, vals, commit, voting_power_needed, ignore, count, True, True
+        )
 
 
 def verify_commit_light(
@@ -114,21 +97,11 @@ def verify_commit_light(
         with trace.span("validation.basic"):
             _verify_basic_vals_and_commit(vals, commit, height, block_id)
             voting_power_needed = vals.total_voting_power() * 2 // 3
-            batch = not commit.is_aggregate() and _should_batch_verify(vals, commit)
         ignore = lambda c: not c.for_block_flag()
         count = lambda c: True
-        if commit.is_aggregate():
-            _verify_commit_aggregate(
-                chain_id, vals, commit, voting_power_needed, ignore, count, True
-            )
-        elif batch:
-            _verify_commit_batch(
-                chain_id, vals, commit, voting_power_needed, ignore, count, False, True
-            )
-        else:
-            _verify_commit_single(
-                chain_id, vals, commit, voting_power_needed, ignore, count, False, True
-            )
+        _verify_commit_by_engine(
+            chain_id, vals, commit, voting_power_needed, ignore, count, False, True
+        )
 
 
 def verify_commit_light_trusting(
@@ -155,21 +128,47 @@ def verify_commit_light_trusting(
                     "provide smaller trustLevel numerator"
                 )
             voting_power_needed = total_mul // trust_level.denominator
-            batch = not commit.is_aggregate() and _should_batch_verify(vals, commit)
         ignore = lambda c: not c.for_block_flag()
         count = lambda c: True
-        if commit.is_aggregate():
-            _verify_commit_aggregate(
-                chain_id, vals, commit, voting_power_needed, ignore, count, False
-            )
-        elif batch:
-            _verify_commit_batch(
-                chain_id, vals, commit, voting_power_needed, ignore, count, False, False
-            )
-        else:
-            _verify_commit_single(
-                chain_id, vals, commit, voting_power_needed, ignore, count, False, False
-            )
+        _verify_commit_by_engine(
+            chain_id, vals, commit, voting_power_needed, ignore, count, False, False
+        )
+
+
+def _verify_commit_by_engine(
+    chain_id: str,
+    vals,
+    commit: Commit,
+    voting_power_needed: int,
+    ignore_sig,
+    count_sig,
+    count_all_signatures: bool,
+    look_up_by_index: bool,
+) -> None:
+    """The engine the commit's form and the set's keys allow: the pairing
+    product for an aggregate, the batch seam for a set of one batch-capable
+    key type, else signature by signature."""
+    if commit.is_aggregate():
+        _verify_commit_aggregate(
+            chain_id, vals, commit, voting_power_needed, ignore_sig, count_sig,
+            look_up_by_index,
+        )
+        return
+    with trace.span("validation.key_type") as span:
+        cols, reused = vals.columns()
+        span.set(cols="reused" if reused else "built")
+        kt = _batch_key_type(vals, commit, cols)
+        bv = crypto_batch.create_batch_verifier(kt) if kt is not None else None
+    if bv is None:
+        _verify_commit_single(
+            chain_id, vals, commit, voting_power_needed, ignore_sig, count_sig,
+            count_all_signatures, look_up_by_index,
+        )
+    else:
+        _verify_commit_batch(
+            chain_id, vals, commit, voting_power_needed, ignore_sig, count_sig,
+            count_all_signatures, look_up_by_index, cols, bv,
+        )
 
 
 def _verify_commit_aggregate(
@@ -265,55 +264,74 @@ def _verify_commit_batch(
     count_sig,
     count_all_signatures: bool,
     look_up_by_index: bool,
+    cols,
+    bv,
 ) -> None:
-    """types/validation.go:152-256 — the TPU call site."""
-    with trace.span("validation.key_type"):
-        kt = _batch_key_type(vals, commit)
-        if kt is None:
-            raise ValueError(
-                "unsupported signature algorithm or insufficient signatures for batch verification"
-            )
-        bv = crypto_batch.create_batch_verifier(kt)
-    seen_vals: dict[int, int] = {}
-    batch_sig_idxs: list[int] = []
-    tallied = 0
+    """types/validation.go:152-256 — the TPU call site. What
+    _verify_commit_single decides signature by signature is decided here on
+    columns: the set's (`cols`), the commit's (Commit.sig_columns) and the
+    positions selected from both; `bv` takes the selected triples whole.
+
+    ignore_sig and count_sig are asked once for each BlockIDFlag the commit
+    holds, not once a signature: every mode decides by the flag alone."""
     with trace.span("validation.sign_bytes"):
+        flags, _, _, signatures = commit.sig_columns()
         all_sign_bytes = commit.vote_sign_bytes_all(chain_id)
     with trace.span("validation.tally") as tally:
-        for idx, commit_sig in enumerate(commit.signatures):
-            if ignore_sig(commit_sig):
-                continue
-            if look_up_by_index:
-                val = vals.validators[idx]
-            else:
-                val_idx, val = vals.get_by_address(commit_sig.validator_address)
-                if val is None:
-                    continue
-                if val_idx in seen_vals:
-                    raise ValueError(
-                        f"double vote from {val} ({seen_vals[val_idx]} and {idx})"
-                    )
-                seen_vals[val_idx] = idx
-            bv.add(val.pub_key, all_sign_bytes[idx], commit_sig.signature)
-            batch_sig_idxs.append(idx)
-            if count_sig(commit_sig):
-                tallied += val.voting_power
-            if not count_all_signatures and tallied > voting_power_needed:
-                break
-        tally.set(added=len(batch_sig_idxs))
+        by_flag = {f: CommitSig(block_id_flag=f) for f in set(flags)}
+        kept = {f: not ignore_sig(cs) for f, cs in by_flag.items()}
+        counted = {f: bool(count_sig(cs)) for f, cs in by_flag.items()}
+        # Position p of the selection: commit entry sig_idxs[p], signed by
+        # validator val_idxs[p] — the entries the scalar loop does not skip.
+        sig_idxs = list(compress(range(len(flags)), [kept[f] for f in flags]))
+        double_at = None  # position of the first entry whose validator signed an earlier one
+        if look_up_by_index:
+            val_idxs = sig_idxs
+        else:
+            index, sigs = vals._index(), commit.signatures
+            val_idxs = [index.get(sigs[i].validator_address) for i in sig_idxs]
+            known = [v is not None for v in val_idxs]
+            sig_idxs = list(compress(sig_idxs, known))
+            val_idxs = list(compress(val_idxs, known))
+            if len(set(val_idxs)) < len(val_idxs):
+                first_at = dict(zip(reversed(val_idxs), reversed(range(len(val_idxs)))))
+                double_at = next(p for p, v in enumerate(val_idxs) if first_at[v] != p)
+        powers = [cols.powers[v] for v in val_idxs]
+        if not all(counted.values()):
+            powers = map(mul, powers, [counted[flags[i]] for i in sig_idxs])
+        running = list(accumulate(powers))
+        # The scalar loop looks at no entry past the one that carries a
+        # light mode's tally over the quorum, and at none past a double vote.
+        end = len(sig_idxs)
+        if not count_all_signatures:
+            over = [t > voting_power_needed for t in running]
+            end = next(compress(range(1, end + 1), over), end)
+        if double_at is not None and double_at < end:
+            end = double_at
+        else:
+            double_at = None
+        bv.add_many(
+            [cols.pub_keys[v] for v in val_idxs[:end]],
+            [all_sign_bytes[i] for i in sig_idxs[:end]],
+            [signatures[i] for i in sig_idxs[:end]],
+        )
+        if double_at is not None:
+            v = val_idxs[double_at]
+            raise ValueError(
+                f"double vote from {vals.validators[v]} "
+                f"({sig_idxs[first_at[v]]} and {sig_idxs[double_at]})"
+            )
+        tallied = running[end - 1] if end else 0
+        tally.set(added=end)
     if tallied <= voting_power_needed:
         raise ErrNotEnoughVotingPowerSigned(tallied, voting_power_needed)
     ok, valid_sigs = bv.verify()
     if ok:
         return
-    for i, sig_ok in enumerate(valid_sigs):
-        if not sig_ok:
-            idx = batch_sig_idxs[i]
-            sig = commit.signatures[idx]
-            raise ValueError(
-                f"wrong signature (#{idx}): {sig.signature.hex().upper()}"
-            )
-    raise RuntimeError("BUG: batch verification failed with no invalid signatures")
+    idx = next(compress(sig_idxs, map(not_, valid_sigs)), None)
+    if idx is None:
+        raise RuntimeError("BUG: batch verification failed with no invalid signatures")
+    raise ValueError(f"wrong signature (#{idx}): {signatures[idx].hex().upper()}")
 
 
 def _verify_commit_single(

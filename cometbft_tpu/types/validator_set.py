@@ -10,6 +10,9 @@ address ascending (ValidatorsByVotingPower, validator_set.go:755-764).
 
 from __future__ import annotations
 
+import threading
+from typing import NamedTuple
+
 from cometbft_tpu.crypto import merkle
 from cometbft_tpu.types.validator import Validator
 
@@ -48,6 +51,41 @@ def _by_voting_power_key(v: Validator):
     return (-v.voting_power, v.address)
 
 
+class SetColumns(NamedTuple):
+    """A validator set as commit verification reads it: one column per
+    attribute, in set order, and the one key type every validator shares
+    (None for a mixed set or a validator without a key)."""
+
+    pub_keys: tuple
+    powers: tuple
+    key_type: str | None
+
+
+class _ColumnsMemo:
+    """Holds a set's SetColumns once some verification has asked for them.
+    A set and its copies share ONE holder (what it holds is immutable), so
+    the first of them a commit is verified against fills it for all: a
+    node's state copies its sets every height and verifies against the
+    copies. A membership or power change gives the changed set a new one."""
+
+    __slots__ = ("columns",)
+
+    def __init__(self):
+        self.columns: SetColumns | None = None
+
+
+# How often ValidatorSet.columns() computed the columns and how often it
+# found them, always counted: a node whose `reused` stands still rebuilds
+# them every height.
+_columns_counts = {"built": 0, "reused": 0}
+_columns_lock = threading.Lock()
+
+
+def columns_counters() -> dict:
+    with _columns_lock:
+        return dict(_columns_counts)
+
+
 class ValidatorSet:
     """types/validator_set.go:51-97."""
 
@@ -63,6 +101,9 @@ class ValidatorSet:
         # order, so it shares _addr_index's invalidation points (membership/
         # power changes); proposer-priority rotation leaves it intact.
         self._hash_memo: bytes | None = None
+        # The columns commit verification reads (columns()): same
+        # invalidation points again, and copies share the holder.
+        self._columns_memo = _ColumnsMemo()
         if validators:
             err = self._update_with_change_set(
                 [v.copy() for v in validators], allow_deletes=False
@@ -106,7 +147,26 @@ class ValidatorSet:
         c.validators = [v.copy() for v in self.validators]
         c.proposer = self.proposer
         c._total_voting_power = self._total_voting_power
+        c._columns_memo = self._columns_memo
         return c
+
+    def columns(self) -> tuple[SetColumns, bool]:
+        """The set's columns, and whether they were there already (False:
+        this call computed them, one walk of the set)."""
+        memo = self._columns_memo
+        cols = memo.columns
+        reused = cols is not None
+        if not reused:
+            keys = tuple(v.pub_key for v in self.validators)
+            types = {None if k is None else k.type() for k in keys}
+            cols = memo.columns = SetColumns(
+                keys,
+                tuple(v.voting_power for v in self.validators),
+                types.pop() if len(types) == 1 else None,
+            )
+        with _columns_lock:
+            _columns_counts["reused" if reused else "built"] += 1
+        return cols, reused
 
     def total_voting_power(self) -> int:
         if self._total_voting_power == 0:
@@ -294,9 +354,13 @@ class ValidatorSet:
         self.rescale_priorities(PRIORITY_WINDOW_SIZE_FACTOR * self.total_voting_power())
         self._shift_by_avg_proposer_priority()
         self.validators.sort(key=_by_voting_power_key)
+        self._membership_changed()
+        return None
+
+    def _membership_changed(self) -> None:
         self._addr_index = None
         self._hash_memo = None
-        return None
+        self._columns_memo = _ColumnsMemo()
 
     def _apply_updates(self, updates: list[Validator]) -> None:
         existing = sorted(self.validators, key=lambda v: v.address)
@@ -314,16 +378,14 @@ class ValidatorSet:
         merged.extend(existing[i:])
         merged.extend(updates[j:])
         self.validators = merged
-        self._addr_index = None
-        self._hash_memo = None
+        self._membership_changed()
 
     def _apply_removals(self, deletes: list[Validator]) -> None:
         if not deletes:
             return
         dset = {d.address for d in deletes}
         self.validators = [v for v in self.validators if v.address not in dset]
-        self._addr_index = None
-        self._hash_memo = None
+        self._membership_changed()
 
     # -- verification wrappers (validator_set.go:662-680) --------------------
 

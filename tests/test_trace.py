@@ -182,6 +182,8 @@ def test_one_verify_commit_gives_every_span_under_one_root(auto_chain, profiler)
         "entries": N_VALS, "hits": 0, "dups": 0, "dispatched": N_VALS, "evicted": 0,
     }
     assert one["validation.tally"]["attrs"] == {"added": N_VALS}
+    # the second call on one set (make_commits memoizes it) finds its columns
+    assert one["validation.key_type"]["attrs"] == {"cols": "reused"}
     assert one["supervisor.tier_call"]["attrs"] == {
         "tier": "hybrid", "attempt": 0, "anchored": False,
     }
@@ -192,6 +194,26 @@ def test_one_verify_commit_gives_every_span_under_one_root(auto_chain, profiler)
         "validation.tally", "batch.verify",
     }
     assert sum(k["t1"] - k["t0"] for k in kids) <= top[0]["t1"] - top[0]["t0"]
+
+
+def test_key_type_says_whether_the_sets_columns_were_reused(profiler, monkeypatch):
+    from cometbft_tpu.types import validator_set
+
+    monkeypatch.setattr(ed25519.BatchVerifier, "verify",
+                        lambda self: (True, [True] * len(self)))  # the callers' layer alone
+    memoized, commits = chip_smoke.make_commits(24, N_VALS, 3, "cols")
+    vals = validator_set.ValidatorSet(memoized.validators)  # never verified against
+    before = validator_set.columns_counters()
+    for bid, commit in commits:
+        vals.verify_commit(chip_smoke.CHAIN_ID, bid, commit.height, commit)
+    bid, commit = commits[0]
+    vals.copy_increment_proposer_priority(1).verify_commit_light(
+        chip_smoke.CHAIN_ID, bid, commit.height, commit)
+    jax.profiler.stop_trace()
+    got = [s["attrs"] for s in trace.spans() if s["name"] == "validation.key_type"]
+    assert got == [{"cols": "built"}] + [{"cols": "reused"}] * 3
+    after = validator_set.columns_counters()
+    assert (after["built"] - before["built"], after["reused"] - before["reused"]) == (1, 3)
 
 
 # -- (c) the xplane ----------------------------------------------------------------
@@ -282,6 +304,10 @@ def test_blocksync_heights_are_roots_with_their_children(profiler):
                      "types.data_hash", "types.part_set_proofs", "state.results_hash"):
             assert mine.count(name) == 1, name
         assert mine.count("validation.verify_commit") >= 1
+    # the state copies its sets every height: the copies carry the columns
+    cols = [s["attrs"]["cols"] for s in sorted(spans, key=lambda s: s["t0"])
+            if s["name"] == "validation.key_type" and s["root"] in {r["id"] for r in roots[1:]}]
+    assert cols and set(cols) == {"reused"}, cols
     assert any(s["name"] == "blocksync.decode" and s["attrs"]["bytes"] > 0 for s in spans)
     for name, attrs in (("types.data_hash", {"txs", "bytes"}), ("types.part_set_proofs", {"parts"}),
                         ("state.results_hash", {"txs"})):
@@ -545,3 +571,6 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
     assert "cmt_blocksync_block_bytes_received 311" in out
     size = ed25519.verified_cache_counters()["size"]
     assert f"cmt_verify_cache_size {size}" in out and "cmt_verify_cache_hits " in out
+    from cometbft_tpu.types import validator_set
+    built = validator_set.columns_counters()["built"]
+    assert f"cmt_verify_columns_built {built}" in out and "cmt_verify_columns_reused " in out

@@ -223,3 +223,34 @@ class TestRoundTrips:
             raise AssertionError("should reject short address")
         except ValueError:
             pass
+
+
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("width", range(1, 10))
+def test_vote_sign_bytes_all_matches_scalar_at_each_varint_width(width):
+    """The vectorized path encodes no more varint bytes than the widest
+    timestamp of the commit needs (ISSUE 27): both sides of each 7-bit
+    boundary, under a chain id that ends in NUL bytes (the rows are cut
+    out of one matrix), byte-identical to the scalar splice and the same
+    whether or not the commit's columns were taken first."""
+    from cometbft_tpu.types.block import BlockID, Commit, CommitSig, PartSetHeader
+
+    top = 2 ** (7 * width)
+    values = [top - 1, top // 2, 1, 0] if width > 1 else [127, 64, 1, 0]
+    bid = BlockID(hash=b"\x01" * 32, part_set_header=PartSetHeader(total=3, hash=b"\x02" * 32))
+    sigs = [
+        CommitSig(2 + (i % 5 == 0), bytes([i]) * 20,
+                  Time(values[i % 4], min(values[(i + 1) % 4], 999_999_999)), bytes([i]) * 64)
+        for i in range(80)
+    ]
+    chain_id = "nul-tail\x00\x00"
+    c = Commit(height=9, round=0, block_id=bid, signatures=sigs)
+    got = c.vote_sign_bytes_all(chain_id)
+    assert all(type(b) is bytes for b in got)
+    assert got == [c.vote_sign_bytes(chain_id, i) for i in range(80)]
+    flags, secs, nanos, signatures = c.sig_columns()
+    assert (flags, secs, nanos, signatures) == (
+        [cs.block_id_flag for cs in sigs], [cs.timestamp.seconds for cs in sigs],
+        [cs.timestamp.nanos for cs in sigs], [cs.signature for cs in sigs])

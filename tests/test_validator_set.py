@@ -97,3 +97,94 @@ def test_hash_changes_with_membership(vset):
     h0 = vset.hash()
     vset.update_with_change_set([mkval(b"d", 1)])
     assert vset.hash() != h0
+
+
+# -- the columns commit verification reads (ISSUE 27) ---------------------------
+
+
+def _counts():
+    from cometbft_tpu.types import validator_set
+
+    return validator_set.columns_counters()
+
+
+def test_columns_are_the_sets_keys_powers_and_key_type_in_order(vset):
+    cols, reused = vset.columns()
+    assert not reused
+    assert cols.pub_keys == tuple(v.pub_key for v in vset.validators)
+    assert cols.powers == (30, 20, 10) == tuple(v.voting_power for v in vset.validators)
+    assert cols.key_type == ed25519.KEY_TYPE
+    again, reused = vset.columns()
+    assert reused and again is cols
+
+
+@pytest.mark.parametrize("change", ["add", "remove", "repower"])
+def test_columns_are_rebuilt_after_a_change_set(vset, change):
+    before, _ = vset.columns()
+    vset.update_with_change_set(
+        [{"add": mkval(b"d", 15), "remove": mkval(b"a", 0), "repower": mkval(b"b", 5)}[change]]
+    )
+    after, reused = vset.columns()
+    assert not reused and after is not before
+    assert after.pub_keys == tuple(v.pub_key for v in vset.validators)
+    assert after.powers == tuple(v.voting_power for v in vset.validators)
+    assert after.powers == {"add": (30, 20, 15, 10), "remove": (30, 20), "repower": (30, 10, 5)}[change]
+
+
+def test_columns_survive_proposer_rotation(vset):
+    cols, _ = vset.columns()
+    vset.increment_proposer_priority(3)
+    assert vset.columns() == (cols, True)
+
+
+@pytest.mark.parametrize("copy", ["copy", "copy_increment_proposer_priority"])
+def test_a_copy_carries_the_columns_both_ways(vset, copy):
+    def make(s):
+        return s.copy() if copy == "copy" else s.copy_increment_proposer_priority(1)
+
+    cols, _ = vset.columns()
+    c = make(vset)
+    assert c.columns() == (cols, True) and c.columns()[0] is cols
+    # a node's state copies next_validators every height and verifies against
+    # the copy only: what the copy builds, later copies of the original find
+    fresh = ValidatorSet([mkval(b"a", 10), mkval(b"b", 20)])
+    child = make(fresh)
+    built, reused = child.columns()
+    assert not reused
+    assert make(make(fresh)).columns() == (built, True)
+    # and a copy that changes leaves the original's columns alone
+    child.update_with_change_set([mkval(b"c", 5)])
+    assert child.columns()[0].powers == (20, 10, 5)
+    assert fresh.columns() == (built, True) and fresh.columns()[0].powers == (20, 10)
+
+
+def test_the_two_counters_count_builds_and_reuses(vset):
+    c0 = _counts()
+    vset.columns()
+    vset.copy().columns()
+    vset.columns()
+    c1 = _counts()
+    assert (c1["built"] - c0["built"], c1["reused"] - c0["reused"]) == (1, 2)
+    vset.update_with_change_set([mkval(b"d", 15)])
+    vset.columns()
+    c2 = _counts()
+    assert (c2["built"] - c1["built"], c2["reused"] - c1["reused"]) == (1, 0)
+
+
+def test_a_mixed_or_keyless_set_reads_no_key_type():
+    from cometbft_tpu.crypto import sr25519
+    from cometbft_tpu.types.block import Commit, CommitSig
+    from cometbft_tpu.types.validation import _batch_key_type
+
+    sr = sr25519.gen_priv_key().pub_key()
+    mixed = ValidatorSet([mkval(b"a", 10), Validator(sr.address(), sr, 10)])
+    commit = Commit(signatures=[CommitSig(), CommitSig()])
+    assert mixed.columns()[0].key_type is None
+    assert _batch_key_type(mixed, commit) is None
+    keyless = ValidatorSet()
+    keyless.validators = [Validator(b"\x01" * 20, None, 1), mkval(b"a", 10)]
+    assert keyless.columns()[0].key_type is None
+    assert _batch_key_type(keyless, commit) is None
+    whole = ValidatorSet([mkval(b"a", 10), mkval(b"b", 10)])
+    assert _batch_key_type(whole, commit) == ed25519.KEY_TYPE
+    assert _batch_key_type(whole, Commit(signatures=[CommitSig()])) is None  # under the threshold
