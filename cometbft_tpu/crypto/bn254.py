@@ -617,8 +617,8 @@ def gen_priv_key() -> PrivKey:
 # Fast host-tier pairing path (ISSUE 9).
 #
 # Everything above is the reference-faithful slow form and stays untouched —
-# `verify_signature_slow` below preserves it verbatim as the bench scalar
-# arm and the ground-truth the fast path is tested against. The fast path
+# `verify_signature_slow` below preserves it verbatim as the ground truth
+# the fast path is tested against. The fast path
 # changes the arithmetic, never the decision:
 #
 #  * `final_exponentiation_fast` — easy part by conjugation/Frobenius + one
@@ -760,8 +760,7 @@ def _hash_to_g2_cached(msg: bytes):
 def verify_signature_slow(pub: bytes, msg: bytes, sig: bytes) -> bool:
     """Today's scalar pairing, verbatim (pre-ISSUE-9 PubKey.verify_signature
     body): plain Miller loops, 2790-bit final-exponentiation ladder, uncached
-    hash-to-G2. The bench `agg` scalar arm and the fast-path equivalence
-    tests measure/check against THIS."""
+    hash-to-G2. The fast-path equivalence tests check against THIS."""
     if len(sig) != SIGNATURE_SIZE:
         return False
     try:
@@ -833,7 +832,7 @@ def verify_aggregate(pub_keys, msgs, agg_sig: bytes) -> bool:
 def verify_aggregate_slow(pub_keys, msgs, agg_sig: bytes) -> bool:
     """Decision-identical slow-arithmetic form of verify_aggregate (plain
     per-pair Miller loops + the 2790-bit final-exp ladder) — the anchor the
-    equivalence tests and the bench scalar arm compare against."""
+    equivalence tests compare against."""
     if len(pub_keys) != len(msgs) or not pub_keys:
         return False
     try:
@@ -1104,7 +1103,7 @@ def get_bn254_backend():
 
 
 def set_bn254_backend(b) -> None:
-    """Test/bench hook (None re-resolves lazily on next use)."""
+    """Test hook (None re-resolves lazily on next use)."""
     global _backend
     old = _backend
     _backend = b

@@ -163,12 +163,6 @@ class _LazyProofs(Sequence):
             yield self[i]
 
 
-# Which implementation the last proofs_from_byte_slices call used:
-# "device" | "host-native" | "pure-python" | "empty". Observability only
-# (bench stage note) — never branch on it.
-last_proofs_path = "none"
-
-
 def proofs_from_byte_slices(items: list[bytes]) -> tuple[bytes, Sequence[Proof]]:
     """Root + one inclusion proof per item (crypto/merkle/proof.go:35-49).
 
@@ -176,30 +170,15 @@ def proofs_from_byte_slices(items: list[bytes]) -> tuple[bytes, Sequence[Proof]]
     neighbor i^1; an odd trailing node is promoted with no aunt. Identical
     aunt lists to the reference's trailsFromByteSlices recursion.
 
-    Path selection: host by default — the device proof path moves every
-    tree level back to the host and measured ~12x slower than the host C
-    pass on an earlier installation (1364 ms vs 113.6 ms at 64k leaves,
-    July 2026; not measured on this one), so it is opt-in via
-    CMTPU_DEVICE_PROOFS=1 (A/B probes, device-rich hosts).
+    Built on the host: from 32 items up one pass of the native C library
+    (every level and every aunt; proofs materialize when indexed), else,
+    and until that library is built, the pure-Python construction below.
     """
-    global last_proofs_path
-    import os
-
     n = len(items)
     if n == 0:
         from cometbft_tpu.crypto.merkle.hash import empty_hash
 
-        last_proofs_path = "empty"
         return empty_hash(), []
-    if n >= 32 and os.environ.get("CMTPU_DEVICE_PROOFS", "") == "1":
-        try:
-            from cometbft_tpu.ops import merkle_kernel as mk
-
-            root, proofs = mk.proofs_from_byte_slices_device(items)
-            last_proofs_path = "device"
-            return root, proofs
-        except Exception:
-            pass  # fall through to the host paths below
     if n >= 32:
         from cometbft_tpu import native
 
@@ -207,10 +186,8 @@ def proofs_from_byte_slices(items: list[bytes]) -> tuple[bytes, Sequence[Proof]]
             root, leaf_hashes, packed, stride, counts = (
                 native.merkle_proof_parts(items)
             )
-            last_proofs_path = "host-native"
             return root, _LazyProofs(n, leaf_hashes, packed, stride, counts)
         native.ensure_built_async()
-    last_proofs_path = "pure-python"
     level = [leaf_hash(item) for item in items]
     leaf_hashes = list(level)
     aunts_per_leaf: list[list[bytes]] = [[] for _ in range(n)]

@@ -13,7 +13,7 @@ the verified-triple cache here and only VALID dispatched triples populate
 it afterward.
 
 When no engine is active (`CMTPU_COALESCE=0`, or a bare backend installed
-by tests/bench) the round-12 private window dispatcher runs instead:
+by tests) the round-12 private window dispatcher runs instead:
 callers block on a shared window (`CMTPU_VOTE_BATCH_WINDOW_MS`, default
 2 ms from the first waiter) and a dispatcher merges everything queued into
 ONE `ed25519.BatchVerifier` call.
@@ -74,7 +74,7 @@ class _Req:
 class SigBatcher:
     """Window-from-first-waiter batcher over `ed25519.BatchVerifier`.
 
-    `inline` (bench/test hook) dispatches each request through the batch
+    `inline` (test hook) dispatches each request through the batch
     verifier immediately with no window and no dispatcher thread — the
     "one device dispatch per vote" arm of an A/B comparison.
     """
@@ -338,7 +338,7 @@ def get_batcher() -> SigBatcher:
 
 
 def set_batcher(b: SigBatcher | None) -> SigBatcher | None:
-    """Install a batcher (tests/bench); returns the previous one."""
+    """Install a batcher (tests); returns the previous one."""
     global _batcher
     with _lock:
         old, _batcher = _batcher, b

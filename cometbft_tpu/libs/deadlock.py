@@ -7,7 +7,7 @@ Three tools:
                 graph (thread -> lock it waits on; lock -> owning thread).
                 `detect_cycles()` reports actual deadlock cycles with the
                 stacks of the involved threads. Zero overhead when unused;
-                tests and CMTPU_DEBUG_LOCKS=1 runs opt in.
+                tests opt in.
   Watchdog      progress monitor: samples a counter (e.g. consensus height)
                 and fires a callback with a full thread-stack dump when it
                 stops advancing for `stall_after` seconds — the "node is

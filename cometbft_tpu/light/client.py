@@ -95,7 +95,7 @@ class Client:
         # verified — handed onward unchanged via self.bundle().
         self._held_bundle: bytes | None = None
         self.logger = logger
-        # Speculative-bisection counters (bench/e2e observability).
+        # Speculative-bisection counters (e2e observability).
         self.speculation = {"descents": 0, "prewarmed_sigs": 0}
         # Gateway-assisted sync counters: which path served each forward
         # verification, and what a rejected/unavailable gateway cost.

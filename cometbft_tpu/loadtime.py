@@ -9,8 +9,7 @@ module is both halves against an in-process devnet: `run_load` drives a
 4-validator TCP devnet at a target tx rate until the window has passed,
 `build_report` recovers latencies from the committed payloads.
 
-Exercised by the gated bench stage (bench.py) and `python -m
-cometbft_tpu.cmd loadtime`.
+Run as `python -m cometbft_tpu.cmd loadtime`.
 """
 
 from __future__ import annotations

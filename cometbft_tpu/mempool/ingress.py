@@ -424,7 +424,7 @@ class IngressPipeline:
             )
 
     def flush_queue(self, timeout: float = 5.0) -> bool:
-        """Block until the lane queues are empty (tests/bench)."""
+        """Block until the lane queues are empty (tests)."""
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             if self.lanes.size() == 0:

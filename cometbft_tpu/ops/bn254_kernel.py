@@ -25,10 +25,9 @@ decision-identical to crypto.bn254.pairing_check by the agg tests.
 
 float64 is exact on XLA:CPU (and the virtual-mesh tests pin CPU); real TPU
 f64 is emulated and slow, which is why `device_available()` is opt-in via
-CMTPU_BN254_DEVICE=1 and the bench labels the arm honestly when absent.
-Keccak/SHA hash-to-field stays host-side (same convention as
-CMTPU_HOST_HASH); CMTPU_FE_MODE does not apply — this kernel has a single
-stacked-limb lowering (the fe modes are ed25519-field concerns).
+CMTPU_BN254_DEVICE=1.
+Keccak/SHA hash-to-field stays host-side; this kernel has a single
+stacked-limb lowering (field25519's two are ed25519-field concerns).
 """
 
 from __future__ import annotations

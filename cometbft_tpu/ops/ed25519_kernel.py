@@ -17,11 +17,9 @@ words plus the padded challenge blocks, and runs the WHOLE verification on
 device: SHA-512 (sha512_kernel), k = digest mod L + signed-window recode +
 point decoding (ops/unpack.py), the signed-4-bit-window double-scalar
 ladder (edwards.windowed_double_base_mult), and the identity test — one
-jit-compiled program per (batch, block-count) bucket pair.
-
-CMTPU_HOST_HASH=1 opts back into hashlib challenge hashing on the host
-(the device then receives 64-byte digests instead of message blocks) for
-A/B probes.
+jit-compiled program per (batch, block-count) bucket pair. A batch with a
+message past the largest block bucket is hashed on the host instead and the
+device receives 64-byte digests (verify_core_hosthash): see pack_batch.
 """
 
 from __future__ import annotations
@@ -41,13 +39,10 @@ import jax.numpy as jnp
 
 from cometbft_tpu.libs import trace
 from cometbft_tpu.ops import edwards as ed
-from cometbft_tpu.ops import field25519 as fe
 from cometbft_tpu.ops import sha512_kernel as s5
 from cometbft_tpu.ops import unpack
 
 L = 2**252 + 27742317777372353535851937790883648493
-
-HOST_HASH = os.environ.get("CMTPU_HOST_HASH") == "1"
 
 # Fixed batch buckets: one compiled program per size, reused forever
 # (SURVEY.md §7 "pre-compiled fixed-shape programs + bucketed batch sizes").
@@ -70,14 +65,13 @@ _probed_width = 0  # mesh_width()'s last answer; 0 = never probed
 @functools.lru_cache(maxsize=1)
 def mesh_width() -> int:
     """Process-local chips one verify dispatch can shard across (the 1-D
-    `sig` mesh of ops/sharded). 1 under CMTPU_HOST_HASH — the hosthash
-    program is never mesh-sharded. First call may initialize the JAX
+    `sig` mesh of ops/sharded). First call may initialize the JAX
     backend and raises whatever that raises: a device tier that cannot
     count its chips has not started. Callers that must never initialize
     it (node metric scrapes, the coalescer's default cap) read
     known_mesh_width() instead."""
     global _probed_width
-    n = 1 if HOST_HASH else max(1, jax.local_device_count())
+    n = max(1, jax.local_device_count())
     _probed_width = n
     return n
 
@@ -150,8 +144,7 @@ _mesh_counters = {
 
 def mesh_counters() -> dict:
     """Snapshot of the mesh routing counters plus the (passively read)
-    device count — the source for the node's lazy mesh_* gauges and the
-    bench JSON's attribution fields."""
+    device count — the source for the node's lazy mesh_* gauges."""
     with _mesh_lock:
         out = dict(_mesh_counters)
     out["devices"] = known_mesh_width()
@@ -178,9 +171,7 @@ def verify_core(a_words, r_words, s_words, msg_words, msg_nblocks):
     is on-device: block-layout transpose + byte swap, challenge hash,
     k = digest mod L, digit recodes, point decoding, window ladder, identity
     test. The A and R decompressions ride ONE width-2N pass (lane-stacked) —
-    same op count in half the program. Straight-line sections use
-    compact_scope (meaningful only under the opt-in planar lowering; a
-    no-op for the default stacked form)."""
+    same op count in half the program."""
     n, bwords = msg_words.shape
     bmax = bwords // 32
     with jax.named_scope("sha512"):
@@ -196,8 +187,9 @@ def verify_core(a_words, r_words, s_words, msg_words, msg_nblocks):
 
 
 def verify_core_hosthash(a_words, r_words, s_words, k_words):
-    """A/B variant (CMTPU_HOST_HASH=1): the 64-byte challenge digests come
-    pre-hashed from the host as int32[16, N] little-endian words."""
+    """The program for batches pack_batch hashed on the host (a message past
+    the largest block bucket): the 64-byte challenge digests come in as
+    int32[16, N] little-endian words."""
     return _verify_from_words(a_words, r_words, s_words, k_words)
 
 
@@ -211,7 +203,7 @@ def _verify_from_words(a_words, r_words, s_words, k_words):
         y_r, sign_r = unpack.words_to_limbs255(r_words)
         s_digits = unpack.scalar_words_to_digits(s_words)
         k_digits = unpack.digest_words_to_digits(k_words)
-    with jax.named_scope("decompress"), fe.compact_scope():
+    with jax.named_scope("decompress"):
         y2 = jnp.concatenate([y_a, y_r], axis=1)
         sg2 = jnp.concatenate([sign_a, sign_r])
         pt, ok = ed.decompress(y2, sg2)
@@ -219,18 +211,8 @@ def _verify_from_words(a_words, r_words, s_words, k_words):
         r = tuple(c[:, n:] for c in pt)
         neg_a = ed.point_neg(a)
     with jax.named_scope("ladder"):
-        if os.environ.get("CMTPU_LADDER", "xla") == "pallas":
-            # Opt-in A/B probe (ops/pallas_ladder.py): the whole ladder as
-            # one Mosaic kernel — attacks the XLA graph-size ceiling directly.
-            from cometbft_tpu.ops import pallas_ladder
-
-            acc = pallas_ladder.windowed_double_base_mult(
-                s_digits, k_digits, neg_a,
-                interpret=jax.default_backend() == "cpu",
-            )
-        else:
-            acc = ed.windowed_double_base_mult(s_digits, k_digits, neg_a)
-    with jax.named_scope("finish"), fe.compact_scope():
+        acc = ed.windowed_double_base_mult(s_digits, k_digits, neg_a)
+    with jax.named_scope("finish"):
         acc = ed.point_add(acc, ed.point_neg(r))
         acc = ed.point_double(ed.point_double(ed.point_double(acc)))
         return ok[:n] & ok[n:] & ed.point_is_identity(acc)
@@ -268,8 +250,7 @@ def warmup(buckets=(128, 1024, 6144, 10240), merkle_leaves=(1024, 65536)) -> Non
 
 def _bucket_key(operands) -> tuple[int, int]:
     """(batch, block) bucket pair; bmax 0 selects the host-hash program
-    (4 operands: either CMTPU_HOST_HASH=1, or the oversized-message
-    fallback in pack_batch)."""
+    (4 operands: the oversized-message fallback in pack_batch)."""
     n = operands[0].shape[1]
     bmax = operands[3].shape[1] // 32 if len(operands) == 5 else 0
     return n, bmax
@@ -334,7 +315,7 @@ def pack_batch(pubs, msgs, sigs):
     # length, so an adversary feeding growing messages cannot force a fresh
     # XLA compile per size.
     oversized = n > 0 and int(mlens.max()) + 64 > BLOCK_BUCKETS[-1] * 128 - 17
-    if HOST_HASH or oversized:
+    if oversized:
         k_le = np.zeros((nb, 64), np.uint8)
         digest_rows = bytearray(64 * n)
         sha512 = hashlib.sha512
@@ -478,13 +459,15 @@ def _verify_fn_for(operands):
 
 
 def clear_compiled_caches() -> None:
-    """Retrace seam for the fe-lowering tests: drops BOTH program caches
-    (the per-bucket single-device jits and the sharded-mesh jit) plus the
-    cached mesh width so a flipped CMTPU_FE_MODE actually re-lowers what
-    batch_verify runs."""
+    """Retrace seam for the fe-lowering tests: drops the program caches
+    (the per-bucket single-device jits and the sharded-mesh jit), the cached
+    mesh width and JAX's own trace cache, which is keyed by the function and
+    its operand shapes and would otherwise hand a flipped lowering
+    (field25519._ACCEL) the jaxpr of the one before."""
     _compiled.cache_clear()
     _sharded_verify.cache_clear()
     mesh_width.cache_clear()
+    jax.clear_caches()
 
 
 def batch_verify_submit(pubs, msgs, sigs):

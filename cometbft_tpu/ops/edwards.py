@@ -228,7 +228,7 @@ def build_table_pre(p) -> jnp.ndarray:
     """Per-lane window table [0..8]P in precomp form as ONE int32[9, 4, 17, N]
     array (axis 1 = ymx/ypx/2dT/Z). Built by a rolled chain of additions so
     the table costs a single compiled add_precomp body, not 7 inlined point
-    ops (compile-size control: every planar field mul is ~1.5k HLO ops)."""
+    ops (compile-size control)."""
     n = p[0].shape[1]
     pp = to_precomp(p)
     tbl = jnp.zeros((9, 4, fe.LIMBS, n), jnp.int32)

@@ -11,38 +11,27 @@ constants. Limbs carry a LOOSE invariant: every public op returns limbs in
   - column sums:      <= 17 * 2^15.2       < 2^19.3  (int32)
   - 19-fold:          < 2^23.7             (int32)
 
-The multiply has THREE lowerings, chosen per backend at trace time:
+The multiply has TWO lowerings, chosen from the platform at trace time:
 
-  - STACKED (TPU default): the schoolbook convolution as ~35 chunky HLO ops
+  - STACKED (accelerators): the schoolbook convolution as ~35 chunky HLO ops
     — pad x to 33 limbs, stack 17 rolls into a Toeplitz band [17, 33, N],
     broadcast-multiply by y, 15-bit-split, reduce over the j axis, 19-fold,
-    stacked carries. Same 289 limb products as the planar form but the
-    graph is ~45x smaller: the planar program for the full verify ladder
-    took XLA:TPU >8 MINUTES to compile (pass time superlinear in the
-    ~75k-op loop body), which timed out the round-3 bench driver; the
-    stacked program compiles in seconds and runs on the same VPU path.
-  - PLANAR (opt-in via CMTPU_FE_MODE=planar): all 289 limb products and
-    their column sums as individual [N]-wide VPU ops (one big XLA fusion).
-    Minimal arithmetic (no padded zeros, squaring symmetry) but compile
-    time makes it unshippable for the ladder; kept for A/B probes.
+    stacked carries. 289 limb products in a graph small enough that the
+    full verify ladder compiles in seconds (one [N]-wide op per limb
+    product, the form this replaced, took XLA:TPU >8 MINUTES: pass time is
+    superlinear in a ~75k-op loop body).
   - COMPACT (CPU): the [17,17,N] product tensor + one-hot f32 accumulation
     matmul (~15 HLO ops per multiply). XLA:CPU's compile time is quadratic
-    in elementwise-fusion size — a straight-line chain of 8 planar muls
-    takes minutes to compile on CPU — so the CPU backend (tests, the
+    in elementwise-fusion size, so the CPU backend (tests, the
     8-virtual-device dryrun, the host fallback) gets the small-graph form.
 
-Carries are one shift-mask pass per call: ~4 array ops on the stacked form
-(_carry_arr, used by the stacked and compact lowerings) or 17 planar
-shift-mask chains under CMTPU_FE_MODE=planar (_carry_rows). This is the
-TPU-native replacement for curve25519-voi's assembly field element
-(reference backend of crypto/ed25519/ed25519.go:27-29).
+Carries are one shift-mask pass per call, ~4 array ops on the stacked
+[17, N] form (_carry). This is the TPU-native replacement for
+curve25519-voi's assembly field element (reference backend of
+crypto/ed25519/ed25519.go:27-29).
 """
 
 from __future__ import annotations
-
-import os
-import threading
-from contextlib import contextmanager
 
 import numpy as np
 
@@ -78,10 +67,8 @@ _FOUR_P = np.array([4 * x for x in _P_LIMBS], np.int32).reshape(LIMBS, 1)
 
 
 def const_fe(v: int) -> np.ndarray:
-    """Field constant as int32[17, 1] (broadcasts over the batch).  Kept as
-    a NUMPY literal: jnp consumers convert on use, and the Pallas ladder
-    kernel (ops/pallas_ladder.py) can close over it — Pallas rejects
-    captured traced arrays but inlines host constants."""
+    """Field constant as int32[17, 1] (broadcasts over the batch). A numpy
+    literal: jnp consumers convert on use."""
     return int_to_limbs(v).reshape(LIMBS, 1)
 
 
@@ -105,82 +92,17 @@ def fe_to_bytes_le(x) -> np.ndarray:
     return np.packbits(bits, axis=1, bitorder="little")
 
 
-# -- planar internals --------------------------------------------------------
-#
-# Rows of a [17, N] field element are sliced into 17 independent [N] arrays,
-# operated on as plain SSA values, and re-stacked only at op boundaries; XLA's
-# slice-of-concat simplification makes chained ops planar end-to-end.
-
-
-def _rows(x) -> list:
-    return [x[i] for i in range(LIMBS)]
-
-
-def _carry_rows(c: list) -> list:
-    """One parallel carry pass over 17 planar columns: split each at 15 bits,
-    carry up one limb, top carry wraps to limb 0 with factor 19."""
-    hi = [v >> LIMB_BITS for v in c]
-    lo = [v & MASK for v in c]
-    out = [lo[0] + 19 * hi[LIMBS - 1]]
-    for k in range(1, LIMBS):
-        out.append(lo[k] + hi[k - 1])
-    return out
-
-
 def _carry(x: jnp.ndarray) -> jnp.ndarray:
-    if _mode() == "planar":
-        return jnp.stack(_carry_rows(_rows(x)))
-    return _carry_arr(x)
-
-
-def _mul_rows(xs: list, ys: list) -> list:
-    """289 limb products, 15-bit split per product, planar column sums,
-    19-fold, two carry passes. Returns 17 loose planar columns."""
-    cols = [None] * (2 * LIMBS)
-
-    def acc(k, v):
-        cols[k] = v if cols[k] is None else cols[k] + v
-
-    for i in range(LIMBS):
-        for j in range(LIMBS):
-            p = xs[i] * ys[j]
-            acc(i + j, p & MASK)
-            acc(i + j + 1, p >> LIMB_BITS)
-    folded = [cols[k] + 19 * cols[k + LIMBS] for k in range(LIMBS)]
-    return _carry_rows(_carry_rows(folded))
-
-
-def _sq_rows(xs: list) -> list:
-    """Squaring: 153 products (symmetry), cross terms doubled AFTER the
-    15-bit split (2*p would overflow int32 at loose-limb maxima)."""
-    cols = [None] * (2 * LIMBS)
-
-    def acc(k, v):
-        cols[k] = v if cols[k] is None else cols[k] + v
-
-    for i in range(LIMBS):
-        p = xs[i] * xs[i]
-        acc(2 * i, p & MASK)
-        acc(2 * i + 1, p >> LIMB_BITS)
-        for j in range(i + 1, LIMBS):
-            p = xs[i] * xs[j]
-            acc(i + j, (p & MASK) * 2)
-            acc(i + j + 1, (p >> LIMB_BITS) * 2)
-    folded = [cols[k] + 19 * cols[k + LIMBS] for k in range(LIMBS)]
-    return _carry_rows(_carry_rows(folded))
-
-
-# -- stacked (Toeplitz-band) multiply: the TPU-default lowering --------------
-
-
-def _carry_arr(x: jnp.ndarray) -> jnp.ndarray:
-    """One parallel carry pass as ~4 array ops on the stacked [17, N] form
-    (same math as _carry_rows: split at 15 bits, carry up one limb, top
-    carry wraps to limb 0 with factor 19)."""
+    """One parallel carry pass as ~4 array ops on the stacked [17, N] form:
+    split at 15 bits, carry up one limb, top carry wraps to limb 0 with
+    factor 19."""
     hi = x >> LIMB_BITS
     lo = x & MASK
     wrap = jnp.concatenate([19 * hi[LIMBS - 1 :], hi[: LIMBS - 1]], axis=0)
     return lo + wrap
+
+
+# -- stacked (Toeplitz-band) multiply: the accelerators' lowering ------------
 
 
 def _mul_stacked(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
@@ -188,9 +110,9 @@ def _mul_stacked(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     x[c-j] * y[j] via a rolled Toeplitz band. Products are split at 15 bits
     BEFORE the j-reduction (raw column sums of 2^30.2 products would
     overflow int32), the high halves land one column up, and columns 17..33
-    fold back with factor 19 (2^255 = 19 mod p). All bounds as the planar
-    form: split sums < 2^19.3, folded columns < 2^24.5, two carry passes
-    restore the loose invariant."""
+    fold back with factor 19 (2^255 = 19 mod p). Bounds: split sums
+    < 2^19.3, folded columns < 2^24.5, two carry passes restore the loose
+    invariant."""
     n = x.shape[1]
     xp = jnp.concatenate([x, jnp.zeros((LIMBS - 1, n), jnp.int32)], axis=0)
     band = jnp.stack([jnp.roll(xp, j, axis=0) for j in range(LIMBS)])
@@ -200,7 +122,7 @@ def _mul_stacked(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     zrow = jnp.zeros((1, n), jnp.int32)
     cols = jnp.concatenate([lo, zrow], axis=0) + jnp.concatenate([zrow, hi], axis=0)
     folded = cols[:LIMBS] + 19 * cols[LIMBS:]
-    return _carry_arr(_carry_arr(folded))
+    return _carry(_carry(folded))
 
 
 # -- compact (matmul-accumulation) multiply for the CPU backend --------------
@@ -235,15 +157,6 @@ def _mul_compact(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
 
 
 _ACCEL: bool | None = None
-_SCOPE = threading.local()
-# CMTPU_FE_MODE: auto (default; stacked on accelerators, compact on CPU),
-# or an explicit stacked / planar / compact override for A/B probes. A typo
-# must fail loudly, not silently measure the default lowering.
-_MODE_ENV = os.environ.get("CMTPU_FE_MODE", "auto")
-if _MODE_ENV not in ("auto", "stacked", "planar", "compact"):
-    raise ValueError(
-        f"CMTPU_FE_MODE={_MODE_ENV!r}: expected auto|stacked|planar|compact"
-    )
 
 
 def _is_accel() -> bool:
@@ -259,48 +172,18 @@ def _is_accel() -> bool:
 
 def _mode() -> str:
     """Lowering for the current trace (see module docstring)."""
-    if _MODE_ENV in ("stacked", "compact"):
-        return _MODE_ENV
-    if _MODE_ENV == "planar":
-        # Historical behavior for A/B probes: planar ladder, compact scopes.
-        if getattr(_SCOPE, "compact", False) or not _is_accel():
-            return "compact"
-        return "planar"
     return "stacked" if _is_accel() else "compact"
-
-
-@contextmanager
-def compact_scope():
-    """Mark a STRAIGHT-LINE trace region (decompression's inversion chain,
-    final adds). Only meaningful under CMTPU_FE_MODE=planar, where such
-    sections would dominate compile time for a marginal runtime share and
-    are forced compact; the default stacked lowering is small-graph
-    everywhere, so the scope is a no-op there."""
-    prev = getattr(_SCOPE, "compact", False)
-    _SCOPE.compact = True
-    try:
-        yield
-    finally:
-        _SCOPE.compact = prev
 
 
 def fe_mul(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     """z = x*y mod p under the loose invariant."""
-    m = _mode()
-    if m == "stacked":
+    if _mode() == "stacked":
         return _mul_stacked(x, y)
-    if m == "planar":
-        return jnp.stack(_mul_rows(_rows(x), _rows(y)))
     return _mul_compact(x, y)
 
 
 def fe_sq(x: jnp.ndarray) -> jnp.ndarray:
-    m = _mode()
-    if m == "stacked":
-        return _mul_stacked(x, x)
-    if m == "planar":
-        return jnp.stack(_sq_rows(_rows(x)))
-    return _mul_compact(x, x)
+    return fe_mul(x, x)
 
 
 def fe_add(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:

@@ -213,8 +213,7 @@ def _tree_root_jit(n: int):
 
 
 def merkle_root_pow2(leaf_digests: np.ndarray) -> bytes:
-    """Root from uint32[8, n] leaf digests, n a power of two — the bench/
-    sharded fast path."""
+    """Root from uint32[8, n] leaf digests, n a power of two."""
     n = leaf_digests.shape[1]
     if n & (n - 1):
         raise ValueError("merkle_root_pow2 requires a power-of-two leaf count")
@@ -222,118 +221,3 @@ def merkle_root_pow2(leaf_digests: np.ndarray) -> bytes:
         return sha.digest_words_to_bytes(leaf_digests)[0]
     out = _tree_root_jit(n)(jnp.asarray(leaf_digests))
     return sha.digest_words_to_bytes(np.asarray(out))[0]
-
-
-def merkle_levels_bytes(leaves: list[bytes]) -> list[list[bytes]]:
-    """All levels as byte digests (bottom-up) — the proof-building form used
-    by crypto/merkle.ProofsFromByteSlices (proof.go:35)."""
-    if len(leaves) == 0:
-        return [[]]
-    digests = hash_leaves_device(leaves)
-    return [sha.digest_words_to_bytes(lv) for lv in tree_levels(digests)]
-
-
-def _leaves_to_levels_core(blocks, nblocks):
-    """ONE jittable program: leaf-hash all padded messages and keep EVERY
-    tree level (power-of-two n). Returns a tuple of uint32[8, n/2^l]."""
-    cur = _leaf_core(blocks, nblocks)
-    levels = [cur]
-    while cur.shape[1] > 1:
-        cur = _inner_core(cur[:, 0::2], cur[:, 1::2])
-        levels.append(cur)
-    return tuple(levels)
-
-
-@functools.lru_cache(maxsize=None)
-def _leaves_to_levels_jit(bmax: int, n: int):
-    return jax.jit(_leaves_to_levels_core)
-
-
-_level_bytes_arr = sha.digest_words_to_arr
-
-
-def proof_levels_device(items: list[bytes]) -> list[np.ndarray]:
-    """All tree levels as uint8[m, 32] digest arrays, bottom-up. One fused
-    dispatch for power-of-two leaf counts; level-per-dispatch otherwise."""
-    n = len(items)
-    if n & (n - 1) == 0 and n > 0:
-        msgs = [b"\x00" + it for it in items]
-        blocks, nblocks = sha.pack_messages(msgs)
-        levels = _leaves_to_levels_jit(blocks.shape[0], n)(blocks, nblocks)
-        return [_level_bytes_arr(np.asarray(lv)) for lv in levels]
-    return [_level_bytes_arr(lv) for lv in tree_levels(hash_leaves_device(items))]
-
-
-def proofs_aunts_device(items: list[bytes]):
-    """Device-computed inclusion proofs for every item, in vectorized form:
-    (root bytes, leaf_hashes uint8[n, 32], aunts uint8[n, depth, 32],
-    aunt_counts int32[n]). The aunt of leaf i at level l is node
-    (i >> l) ^ 1 — absent (skipped, odd promotion) when past the level's
-    end; identical aunts to the host ProofsFromByteSlices recursion."""
-    n = len(items)
-    if n == 0:
-        raise ValueError(
-            "proofs_aunts_device: empty tree has no proofs "
-            "(use proofs_from_byte_slices_device for the empty-root case)"
-        )
-    levels = proof_levels_device(items)
-    root = bytes(levels[-1][0])
-    depth = len(levels) - 1
-    aunts = np.zeros((n, depth, 32), np.uint8)
-    counts = np.zeros(n, np.int32)
-    idx = np.arange(n)
-    for l in range(depth):
-        level = levels[l]
-        a = (idx >> l) ^ 1
-        have = a < level.shape[0]
-        rows = idx[have]
-        aunts[rows, counts[rows]] = level[a[have]]
-        counts[rows] += 1
-    return root, levels[0], aunts, counts
-
-
-class DeviceProofs:
-    """Lazy sequence of crypto/merkle Proof objects over the vectorized
-    device proof data — building 64k Python Proof objects eagerly costs more
-    than the hashing; callers usually need a handful."""
-
-    def __init__(self, root, leaf_hashes, aunts, counts):
-        self.root = root
-        self._leaf = leaf_hashes
-        self._aunts = aunts
-        self._counts = counts
-
-    def __len__(self):
-        return self._leaf.shape[0]
-
-    def __getitem__(self, i):
-        from cometbft_tpu.crypto.merkle.proof import Proof
-
-        if isinstance(i, slice):
-            return [self[j] for j in range(*i.indices(len(self)))]
-        if i < 0:
-            i += len(self)
-        if not 0 <= i < len(self):
-            raise IndexError(i)
-        return Proof(
-            total=len(self),
-            index=i,
-            leaf_hash=bytes(self._leaf[i]),
-            aunts=[bytes(a) for a in self._aunts[i, : self._counts[i]]],
-        )
-
-    def __iter__(self):
-        for i in range(len(self)):
-            yield self[i]
-
-
-def proofs_from_byte_slices_device(items: list[bytes]):
-    """Device analog of crypto/merkle.proofs_from_byte_slices: returns
-    (root bytes, DeviceProofs). Falls back to the host implementation for
-    the empty tree."""
-    if len(items) == 0:
-        from cometbft_tpu.crypto.merkle import proofs_from_byte_slices
-
-        return proofs_from_byte_slices(items)
-    root, leaf_hashes, aunts, counts = proofs_aunts_device(items)
-    return root, DeviceProofs(root, leaf_hashes, aunts, counts)

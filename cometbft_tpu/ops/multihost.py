@@ -175,7 +175,7 @@ def multihost_verify(mesh, pubs, msgs, sigs, axis="sig"):
     operands, host_ok = ek.pack_batch(pubs, msgs, sigs)
     if len(operands) != 5:
         raise NotImplementedError(
-            "host-hash packing (CMTPU_HOST_HASH / oversized messages) "
+            "host-hash packing (oversized messages) "
             "cannot serve the multi-host verify step"
         )
     specs = sharded._verify_specs(axis)

@@ -28,11 +28,6 @@ def make_mesh(devices=None, axis: str = "sig") -> Mesh:
 
 
 def _verify_specs(axis: str):
-    if ek.HOST_HASH:
-        raise NotImplementedError(
-            "CMTPU_HOST_HASH=1 is an A/B probe mode for the single-chip "
-            "kernel; the sharded path always hashes on device"
-        )
     return (
         P(None, axis),  # a_words [8, N]
         P(None, axis),  # r_words [8, N]
@@ -176,7 +171,7 @@ def sharded_commit_step_fn(mesh: Mesh, axis: str = "sig"):
 
 def make_example_batch(n: int):
     """Deterministic signed batch packed for verify_core (host crypto is
-    C-speed; used by bench + graft entry)."""
+    C-speed; used by the graft entry)."""
     from cometbft_tpu.crypto import ed25519 as host_ed
 
     pubs, msgs, sigs = [], [], []

@@ -232,7 +232,7 @@ class HybridBackend(VerifyBackend):
         self._dev_wall: dict[tuple[int, int], float] = {}
         self._dev_recent: dict[tuple[int, int], collections.deque] = {}
         # Share + stage walls of the most recent split call (observability;
-        # bench reports these so device runs explain themselves).
+        # chip_smoke.py and the benchmark's readers report them).
         self.last_share = 0
         self.last_timing: dict = {}
         # Where every lane this tier was sent actually ran, plus the last

@@ -273,9 +273,7 @@ class SidecarServer:
         return DEFAULT_STREAM_CHUNK
 
     def scheduler_counters(self) -> dict:
-        """The server-side coalescer's counters (empty when stripped) —
-        the bench `sidecar` stage reads the cross-connection merge ratio
-        from here."""
+        """The server-side coalescer's counters (empty when stripped)."""
         return self._sched.counters() if self._sched is not None else {}
 
     def _dispatch(self, method: str, payload: bytes, conn: dict | None = None) -> bytes:

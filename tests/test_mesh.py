@@ -88,8 +88,8 @@ def test_every_standard_bucket_routes_to_the_mesh(monkeypatch):
 
 
 def test_hosthash_program_never_shards():
-    """The 4-operand host-hash program (CMTPU_HOST_HASH / oversized-message
-    fallback) has no mesh variant; it must stay on the bucket program."""
+    """The 4-operand host-hash program (the oversized-message fallback) has
+    no mesh variant; it must stay on the bucket program."""
     hh = (
         np.zeros((8, 128), np.uint32),
         None,
@@ -193,22 +193,6 @@ def test_merkle_mesh_gate_requires_pow2_width(monkeypatch):
         assert mk._sharded_root() is None
     finally:
         mk._sharded_root.cache_clear()
-
-
-# -- bench scaling model -----------------------------------------------------
-
-
-def test_bench_mesh_model_curve():
-    """The bench stage's width model: ceil lane split + fixed dispatch
-    overhead, speedups keyed off the width-1 row regardless of input order."""
-    import bench
-
-    curve = bench._fit_and_model([8, 1, 2, 4], 65536, 0.007, 50.0)
-    assert [r["devices"] for r in curve] == [1, 2, 4, 8]
-    assert curve[0]["speedup"] == 1.0
-    assert curve[-1]["speedup"] >= 3.0  # the acceptance floor at width 8
-    # ceil lane split: 10 sigs over 3 chips = 4 lanes on the padded chip
-    assert bench._fit_and_model([3], 10, 1.0, 0.0)[0]["verify_ms"] == 4.0
 
 
 # -- observability + driver entry -------------------------------------------

@@ -154,40 +154,35 @@ def test_kernel_bitmap_matches_pure_on_zip215_edge_vectors():
     assert got[1] is False and got[3] is False
 
 
-def _force_mode_verify(mode: str, accel: bool):
-    """Run the full verify program on XLA:CPU under a forced fe lowering
-    mode; the bitmap must match the default (compact) path."""
+def test_stacked_lowering_full_verify_on_cpu():
+    """The accelerators' (stacked) lowering through the whole verify program,
+    forced on XLA:CPU through the platform test's own seam (fe._ACCEL) —
+    small graphs, so this runs in the normal suite. The multiply the trace
+    reaches is counted, so a trace cached under the other lowering cannot
+    pass for this one."""
     from cometbft_tpu.ops import field25519 as fe
 
-    prev_mode, prev_accel = fe._MODE_ENV, fe._ACCEL
-    fe._MODE_ENV, fe._ACCEL = mode, accel
+    prev_accel, mul_stacked = fe._ACCEL, fe._mul_stacked
+    traced = []
+
+    def counting(x, y):
+        traced.append(1)
+        return mul_stacked(x, y)
+
+    fe._ACCEL, fe._mul_stacked = True, counting
     try:
         ek.clear_compiled_caches()
         pubs, msgs, sigs = [], [], []
         for i in range(8):
-            priv = ed25519.gen_priv_key_from_secret(b"%s-%d" % (mode.encode(), i))
-            msg = b"%s-vote-%d" % (mode.encode(), i)
+            priv = ed25519.gen_priv_key_from_secret(b"stacked-%d" % i)
+            msg = b"stacked-vote-%d" % i
             pubs.append(priv.pub_key().bytes())
             msgs.append(msg)
             sigs.append(priv.sign(msg))
         sigs[3] = sigs[3][:8] + bytes([sigs[3][8] ^ 1]) + sigs[3][9:]
         ok, res = ek.batch_verify(pubs, msgs, sigs)
         assert res == [True, True, True, False, True, True, True, True]
+        assert traced, "the verify program was not traced under the stacked lowering"
     finally:
-        fe._MODE_ENV, fe._ACCEL = prev_mode, prev_accel
+        fe._ACCEL, fe._mul_stacked = prev_accel, mul_stacked
         ek.clear_compiled_caches()
-
-
-def test_stacked_lowering_full_verify_on_cpu():
-    """The TPU-default (stacked) lowering through the whole verify program,
-    forced on XLA:CPU — small graphs, so this runs in the normal suite."""
-    _force_mode_verify("stacked", accel=True)
-
-
-@pytest.mark.skipif(
-    not __import__("os").environ.get("CMTPU_SLOW_TESTS"),
-    reason="~2 min XLA:CPU compile; planar is the opt-in A/B lowering "
-    "(set CMTPU_SLOW_TESTS=1)",
-)
-def test_planar_lowering_full_verify_on_cpu():
-    _force_mode_verify("planar", accel=True)
