@@ -86,9 +86,20 @@ class BatchVerifier(abc.ABC):
     @abc.abstractmethod
     def add(self, key: PubKey, message: bytes, signature: bytes) -> None: ...
 
-    def add_many(self, keys, messages, signatures) -> None:
+    @staticmethod
+    def key_bytes(keys) -> tuple | None:
+        """The raw bytes of a column of keys, where this engine's add_many()
+        takes them and add() would refuse none of the keys; else None (the
+        default: an engine whose add_many() is the loop below). A validator
+        set asks once and keeps the answer as a column (SetColumns)."""
+        return None
+
+    def add_many(self, keys, messages, signatures, key_bytes=None) -> None:
         """add() for whole columns: the same entries in the same order, and
-        the error add() would raise at the first entry it refuses."""
+        the error add() would raise at the first entry it refuses.
+        `key_bytes`, where given, is what key_bytes() made of a column that
+        `keys` was selected from, selected alike: an engine that takes raw
+        bytes reads those and leaves the key objects alone."""
         for key, message, signature in zip(keys, messages, signatures, strict=True):
             self.add(key, message, signature)
 

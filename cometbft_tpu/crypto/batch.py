@@ -45,3 +45,11 @@ def supports_batch_verifier(key) -> bool:
     """batch.SupportsBatchVerifier (batch.go:25-32); PubKey or key-type
     string."""
     return _key_type_of(key) is not None
+
+
+def key_bytes(key_type: str | None, keys) -> tuple | None:
+    """What the key type's batch engine makes of a column of keys
+    (crypto.BatchVerifier.key_bytes); None where no engine batches the type."""
+    if key_type not in _REGISTRY:
+        return None
+    return _REGISTRY[key_type][1].key_bytes(keys)

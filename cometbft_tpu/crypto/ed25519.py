@@ -18,6 +18,7 @@ from __future__ import annotations
 import hashlib
 import os
 import threading
+from itertools import islice
 
 from cometbft_tpu import crypto
 from cometbft_tpu.crypto import ed25519_pure, tmhash
@@ -158,9 +159,11 @@ _verified: dict[tuple, None] = {}
 _verified_lock = threading.Lock()
 # What the cache did, always counted (under _verified_lock): per verify()
 # call by arithmetic on lengths, never per triple. entries = hits + dups +
-# dispatched.
+# dispatched; whole_miss + whole_hit + mixed = the verify() calls, by the
+# case each batch was (BatchVerifier.verify).
 _cache_counts = dict.fromkeys(
-    ("entries", "hits", "dups", "dispatched", "inserted", "evicted"), 0
+    ("entries", "hits", "dups", "dispatched", "inserted", "evicted",
+     "whole_miss", "whole_hit", "mixed"), 0
 )
 
 
@@ -200,6 +203,34 @@ def _verified_put_many(keys: list[tuple]) -> int:
     return evicted
 
 
+def _verified_put_missed(missed: dict[tuple, None], inserted_before: int) -> int:
+    """_verified_put_many(list(missed)) for distinct triples that were all
+    absent when `_cache_counts["inserted"]` read `inserted_before`: what the
+    per-triple loop leaves behind, by one eviction and one dict.update.
+
+    The loop sweeps the oldest quarter each time an insert finds the cache
+    full, so over the whole batch it takes as many whole quarters off the
+    old end of (the cache, then the batch) as the overflow needs: a batch
+    larger than the cache loses its own first triples too. Where a writer
+    has inserted since (`inserted` moved), a triple of the batch may be
+    there already and has to move to the young end: that batch goes through
+    the loop. Returns how many triples the sweeps evicted."""
+    with _verified_lock:
+        if _cache_counts["inserted"] == inserted_before:
+            size, quarter = len(_verified), max(1, _VERIFIED_MAX // 4)
+            over = size + len(missed) - _VERIFIED_MAX
+            evicted = -(-over // quarter) * quarter if over > 0 else 0
+            for key in list(islice(_verified, evicted)):
+                del _verified[key]
+            if evicted > size:
+                missed = dict.fromkeys(islice(missed, evicted - size, None))
+            _verified.update(missed)
+            _cache_counts["inserted"] += len(_verified) - size + evicted
+            _cache_counts["evicted"] += evicted
+            return evicted
+    return _verified_put_many(list(missed))
+
+
 def _verified_put(key: tuple) -> None:
     _verified_put_many([key])
 
@@ -221,9 +252,12 @@ def _as_bytes(column):
 class BatchVerifier(crypto.BatchVerifier):
     """Ed25519 batch verification (ed25519.go:196-228).
 
-    Entries accumulate host-side; `verify()` dispatches the whole batch to the
-    configured backend (TPU sidecar by default when a device is present,
-    pure-CPU otherwise) — the same seam as the reference's
+    Entries accumulate host-side, one at a time (`add`) or as whole columns
+    (`add_many`, which takes a validator set's checked key bytes as they
+    stand); `verify()` decides the batch against the verified-triple cache
+    as whole columns where it can and dispatches what the cache cannot
+    answer to the configured backend (TPU sidecar by default when a device
+    is present, pure-CPU otherwise) — the same seam as the reference's
     cachingVerifier.AddWithOptions + BatchVerifier.Verify.
     """
 
@@ -246,22 +280,30 @@ class BatchVerifier(crypto.BatchVerifier):
         self._msgs.append(bytes(message))
         self._sigs.append(bytes(signature))
 
-    def add_many(self, keys, messages, signatures) -> None:
-        """add()'s three checks on every entry, each as one pass over its
-        column. A batch with an entry that fails one goes through add()
-        entry by entry, so the first refused entry raises add()'s error."""
-        if not len(keys) == len(messages) == len(signatures):
-            raise ValueError("add_many: columns of unequal length")
+    @staticmethod
+    def key_bytes(keys) -> tuple | None:
+        """add()'s two checks on a key, each as one pass over the column."""
         if all(issubclass(t, PubKey) for t in set(map(type, keys))):
-            pubs = [key._bytes for key in keys]
-            if (
-                set(map(len, pubs)) <= {PUB_KEY_SIZE}
-                and set(map(len, signatures)) <= {SIGNATURE_SIZE}
-            ):
-                self._pubs.extend(pubs)
-                self._msgs.extend(_as_bytes(messages))
-                self._sigs.extend(_as_bytes(signatures))
-                return
+            pubs = tuple(key._bytes for key in keys)
+            if set(map(len, pubs)) <= {PUB_KEY_SIZE}:
+                return pubs
+        return None
+
+    def add_many(self, keys, messages, signatures, key_bytes=None) -> None:
+        """add()'s three checks on every entry, each as one pass over its
+        column; the two on the keys are not made again where `key_bytes`
+        brings their outcome, and no key object is then looked at. A batch
+        with an entry that fails one goes through add() entry by entry, so
+        the first refused entry raises add()'s error."""
+        pubs = self.key_bytes(keys) if key_bytes is None else key_bytes
+        columns = (keys, messages, signatures) + (() if pubs is None else (pubs,))
+        if len(set(map(len, columns))) != 1:
+            raise ValueError("add_many: columns of unequal length")
+        if pubs is not None and set(map(len, signatures)) <= {SIGNATURE_SIZE}:
+            self._pubs.extend(pubs)
+            self._msgs.extend(_as_bytes(messages))
+            self._sigs.extend(_as_bytes(signatures))
+            return
         super().add_many(keys, messages, signatures)
 
     def __len__(self) -> int:
@@ -271,47 +313,64 @@ class BatchVerifier(crypto.BatchVerifier):
         from cometbft_tpu.sidecar.backend import get_backend
         from cometbft_tpu.sidecar.supervisor import ChainExhausted
 
-        if not self._pubs:
+        n = len(self._pubs)
+        if not n:
             return False, []
         # Dispatch only the triples the cache cannot answer, deduplicating
         # repeats within the batch (the light client's trusting and light
         # checks of one hop share most of their triples; bisection descents
-        # revisit pivot commits). lane_of records each unique uncached
-        # triple's lane in the sub-batch; cached/duplicate entries resolve
-        # from it after the dispatch. Membership is decided ONCE here —
-        # concurrent writers may grow the cache mid-verify, and the merge
-        # below must honor the filter's snapshot, not a fresher one.
-        with trace.span("batch.verify", entries=len(self._pubs)) as call:
+        # revisit pivot commits). Which of three cases a batch is, the
+        # dict's own loops decide from the batch itself: all of it unseen
+        # and distinct (`whole_miss`: the columns are dispatched as they
+        # stand), all of it cached (`whole_hit`), or neither (`mixed`: the
+        # per-triple walk, where lane_of records each unique uncached
+        # triple's lane in the sub-batch and cached/duplicate entries
+        # resolve from it after the dispatch). Membership is decided ONCE
+        # here — concurrent writers may grow the cache mid-verify, and the
+        # merge below must honor the filter's snapshot, not a fresher one.
+        with trace.span("batch.verify", entries=n) as call:
             with trace.span("batch.cache_filter"):
+                # Read before any membership is decided: while it stands,
+                # no triple found missing here has been inserted since.
+                inserted_before = _cache_counts["inserted"]
                 keys = list(zip(self._pubs, self._sigs, self._msgs))
-                lane_of: dict[tuple, int] = {}
-                lanes: list[int] = []  # per-entry lane, -1 = cache hit
-                sub_pubs: list[bytes] = []
-                sub_msgs: list[bytes] = []
-                sub_sigs: list[bytes] = []
-                for key in keys:
-                    if key in _verified:
-                        lanes.append(-1)
-                        continue
-                    lane = lane_of.get(key)
-                    if lane is None:
-                        lane = len(sub_pubs)
-                        lane_of[key] = lane
-                        sub_pubs.append(key[0])
-                        sub_msgs.append(key[2])
-                        sub_sigs.append(key[1])
-                    lanes.append(lane)
-                hits = lanes.count(-1)
-                dispatched = len(sub_pubs)
-                dups = len(keys) - hits - dispatched
+                missed = dict.fromkeys(keys)  # what to dispatch, in lane order
+                lanes = range(n)  # per-entry lane; mixed: -1 = cache hit
+                sub_pubs, sub_msgs, sub_sigs = self._pubs, self._msgs, self._sigs
+                if len(missed) == n and _verified.keys().isdisjoint(missed):
+                    path, hits = "whole_miss", 0
+                elif all(map(_verified.__contains__, missed)):
+                    path, hits, missed = "whole_hit", n, {}
+                else:
+                    path = "mixed"
+                    lane_of: dict[tuple, int] = {}
+                    lanes = []
+                    sub_pubs, sub_msgs, sub_sigs = [], [], []
+                    for key in keys:
+                        if key in _verified:
+                            lanes.append(-1)
+                            continue
+                        lane = lane_of.get(key)
+                        if lane is None:
+                            lane = len(sub_pubs)
+                            lane_of[key] = lane
+                            sub_pubs.append(key[0])
+                            sub_msgs.append(key[2])
+                            sub_sigs.append(key[1])
+                        lanes.append(lane)
+                    hits = lanes.count(-1)
+                    missed = dict.fromkeys(lane_of)
+                dispatched = len(missed)
+                dups = n - hits - dispatched
                 with _verified_lock:
-                    _cache_counts["entries"] += len(keys)
+                    _cache_counts["entries"] += n
                     _cache_counts["hits"] += hits
                     _cache_counts["dups"] += dups
                     _cache_counts["dispatched"] += dispatched
-            call.set(hits=hits, dups=dups, dispatched=dispatched, evicted=0)
-            if not sub_pubs:
-                return True, [True] * len(keys)
+                    _cache_counts[path] += 1
+            call.set(hits=hits, dups=dups, dispatched=dispatched, evicted=0, path=path)
+            if not missed:
+                return True, [True] * n
             with trace.span("batch.dispatch"):
                 try:
                     _, sub_bits = get_backend().batch_verify(
@@ -327,9 +386,17 @@ class BatchVerifier(crypto.BatchVerifier):
                         for p, m, s in zip(sub_pubs, sub_msgs, sub_sigs)
                     ]
             with trace.span("batch.cache_insert"):
-                bits = [True if lane < 0 else sub_bits[lane] for lane in lanes]
-                evicted = _verified_put_many(
-                    [k for k, lane in zip(keys, lanes) if lane >= 0 and sub_bits[lane]]
-                )
+                if all(sub_bits) and not dups:
+                    # Every lane verified, so every entry did, and what the
+                    # filter found missing goes in whole. (A repeat is put
+                    # once an occurrence and ends where its last one stood:
+                    # the walk's.)
+                    bits = [True] * n
+                    evicted = _verified_put_missed(missed, inserted_before)
+                else:
+                    bits = [True if lane < 0 else sub_bits[lane] for lane in lanes]
+                    evicted = _verified_put_many(
+                        [k for k, lane in zip(keys, lanes) if lane >= 0 and sub_bits[lane]]
+                    )
             call.set(evicted=evicted)
             return all(bits), bits

@@ -891,7 +891,7 @@ class Node:
             return fn
 
         for key in ("entries", "hits", "dups", "dispatched", "inserted",
-                    "evicted", "size"):
+                    "evicted", "size", "whole_miss", "whole_hit", "mixed"):
             reg.gauge_func("verify_cache", key, f"Verified-triple cache: {key}.",
                            counter("cometbft_tpu.crypto.ed25519",
                                    "verified_cache_counters", key))

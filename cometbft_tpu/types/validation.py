@@ -270,7 +270,9 @@ def _verify_commit_batch(
     """types/validation.go:152-256 — the TPU call site. What
     _verify_commit_single decides signature by signature is decided here on
     columns: the set's (`cols`), the commit's (Commit.sig_columns) and the
-    positions selected from both; `bv` takes the selected triples whole.
+    positions selected from both. `bv` takes the selection as columns: the
+    keys as the raw bytes the set checked once (`cols.pub_bytes`), and,
+    where every entry of the commit is selected, the columns as they stand.
 
     ignore_sig and count_sig are asked once for each BlockIDFlag the commit
     holds, not once a signature: every mode decides by the flag alone."""
@@ -310,11 +312,17 @@ def _verify_commit_batch(
             end = double_at
         else:
             double_at = None
-        bv.add_many(
-            [cols.pub_keys[v] for v in val_idxs[:end]],
-            [all_sign_bytes[i] for i in sig_idxs[:end]],
-            [signatures[i] for i in sig_idxs[:end]],
-        )
+        pub_bytes = cols.pub_bytes
+        if look_up_by_index and end == len(flags) == len(cols.pub_keys):
+            # every flag kept and nothing cut: entry i is validator i's
+            bv.add_many(cols.pub_keys, all_sign_bytes, signatures, pub_bytes)
+        else:
+            bv.add_many(
+                [cols.pub_keys[v] for v in val_idxs[:end]],
+                [all_sign_bytes[i] for i in sig_idxs[:end]],
+                [signatures[i] for i in sig_idxs[:end]],
+                None if pub_bytes is None else [pub_bytes[v] for v in val_idxs[:end]],
+            )
         if double_at is not None:
             v = val_idxs[double_at]
             raise ValueError(

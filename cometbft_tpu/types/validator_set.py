@@ -13,6 +13,7 @@ from __future__ import annotations
 import threading
 from typing import NamedTuple
 
+from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.crypto import merkle
 from cometbft_tpu.types.validator import Validator
 
@@ -54,11 +55,16 @@ def _by_voting_power_key(v: Validator):
 class SetColumns(NamedTuple):
     """A validator set as commit verification reads it: one column per
     attribute, in set order, and the one key type every validator shares
-    (None for a mixed set or a validator without a key)."""
+    (None for a mixed set or a validator without a key). `pub_bytes` is the
+    keys' raw bytes as that type's batch engine takes them, its checks on a
+    key made here, once (crypto.BatchVerifier.key_bytes): None where the
+    engine takes key objects only or would refuse one of these keys, and
+    the engine then says which when it is handed the objects."""
 
     pub_keys: tuple
     powers: tuple
     key_type: str | None
+    pub_bytes: tuple | None
 
 
 class _ColumnsMemo:
@@ -159,10 +165,12 @@ class ValidatorSet:
         if not reused:
             keys = tuple(v.pub_key for v in self.validators)
             types = {None if k is None else k.type() for k in keys}
+            key_type = types.pop() if len(types) == 1 else None
             cols = memo.columns = SetColumns(
                 keys,
                 tuple(v.voting_power for v in self.validators),
-                types.pop() if len(types) == 1 else None,
+                key_type,
+                crypto_batch.key_bytes(key_type, keys),
             )
         with _columns_lock:
             _columns_counts["reused" if reused else "built"] += 1

@@ -180,6 +180,7 @@ def test_one_verify_commit_gives_every_span_under_one_root(auto_chain, profiler)
     assert one["device.pack"]["attrs"]["lanes"] == call["share"]
     assert one["batch.verify"]["attrs"] == {
         "entries": N_VALS, "hits": 0, "dups": 0, "dispatched": N_VALS, "evicted": 0,
+        "path": "whole_miss",
     }
     assert one["validation.tally"]["attrs"] == {"added": N_VALS}
     # the second call on one set (make_commits memoizes it) finds its columns

@@ -11,6 +11,7 @@ import random
 
 import pytest
 
+from cometbft_tpu import crypto
 from cometbft_tpu.crypto import ed25519
 from cometbft_tpu.types import validation
 from cometbft_tpu.types.block import (
@@ -45,8 +46,17 @@ BY_ADDR = {k.pub_key().address(): k for k in KEYS}
 
 
 class RecordingVerifier(ed25519.BatchVerifier):
-    """The seam with the backend taken out: keeps what was added, and
-    answers verify() lane by lane through the scalar verifier."""
+    """The seam with the backend taken out: keeps what was added and how it
+    was handed over, and answers verify() lane by lane through the scalar
+    verifier."""
+
+    def __init__(self):
+        super().__init__()
+        self.handed = []  # (keys, key_bytes) of each add_many
+
+    def add_many(self, keys, messages, signatures, key_bytes=None):
+        self.handed.append((keys, key_bytes))
+        super().add_many(keys, messages, signatures, key_bytes)
 
     def triples(self):
         return list(zip(self._pubs, self._msgs, self._sigs))
@@ -174,9 +184,17 @@ def _three_ways(mode, vals, commit, needed, monkeypatch):
     scalar engine, each on a commit object of its own."""
     ignore, count, count_all, by_index = MODES[mode]
     bv = RecordingVerifier()
+    cols = vals.columns()[0]
     columnar = _outcome(lambda: validation._verify_commit_batch(
         CHAIN, vals, _fresh(commit), needed, ignore, count, count_all, by_index,
-        vals.columns()[0], bv))
+        cols, bv))
+    # (ISSUE 29) the keys go over as the set's raw bytes, selected like the
+    # key objects; a selection that is the whole commit is no copy at all
+    assert cols.pub_bytes == tuple(k.bytes() for k in cols.pub_keys)
+    for keys, key_bytes in bv.handed:
+        assert list(key_bytes) == [k.bytes() for k in keys]
+        whole = by_index and len(keys) == len(commit.signatures)
+        assert (keys is cols.pub_keys and key_bytes is cols.pub_bytes) == whole
     ref_bv = RecordingVerifier()
     loop = _outcome(lambda: _loop_reference(
         CHAIN, vals, _fresh(commit), needed, ignore, count, count_all, by_index, ref_bv))
@@ -208,14 +226,18 @@ def _submitted_idxs(commit: Commit, triples) -> list[int]:
 
 
 @pytest.mark.parametrize("seed", range(8))
+@pytest.mark.parametrize("flags", ["mixed_flags", "all_for_the_block"])
 @pytest.mark.parametrize("mode", MODES)
-def test_sound_commits_decide_alike_and_submit_the_same_triples(mode, seed, monkeypatch):
-    _, vals, commit = _case(mode, seed)
+def test_sound_commits_decide_alike_and_submit_the_same_triples(mode, flags, seed, monkeypatch):
+    _, vals, commit = _case(
+        mode, seed, None if flags == "mixed_flags" else [BLOCK_ID_FLAG_COMMIT] * N)
     needed = _needed(mode, vals)
     columnar, loop, single = _three_ways(mode, vals, commit, needed, monkeypatch)
     assert columnar == loop == single
     outcome, triples = columnar
     assert outcome == "accepted" or outcome[0] == "ErrNotEnoughVotingPowerSigned"
+    if mode == "full" and flags == "all_for_the_block":
+        assert len(triples) == N  # the whole commit: the columns went as they stand
     if mode != "full" and outcome == "accepted":
         # the cut: the entry that carried the tally over the quorum is the last
         powers = {v.pub_key.bytes(): v.voting_power for v in vals.validators}
@@ -409,3 +431,118 @@ def test_the_default_add_many_loops_add_and_the_bulk_one_raises_what_add_raises(
     bv = sr25519.BatchVerifier()
     bv.add_many([sk.pub_key()] * 2, [b"a", b"b"], [sk.sign(b"a"), sk.sign(b"b")])
     assert bv.verify() == (True, [True, True])
+
+
+class _ForeignKey(crypto.PubKey):
+    """Says it is an Ed25519 key and is none of this program's."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def address(self):
+        return self._inner.address()
+
+    def bytes(self):
+        return self._inner.bytes()
+
+    def verify_signature(self, msg, sig):
+        return self._inner.verify_signature(msg, sig)
+
+    def type(self):
+        return ed25519.KEY_TYPE
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("fault", ["short_key", "foreign_key"])
+def test_a_key_the_seam_would_refuse_is_refused_with_adds_error(fault, mode, monkeypatch):
+    """The set checks its keys once, for the bytes column; a set with a key
+    add() refuses has no such column and the seam is handed the objects, so
+    the refusal is add()'s, word for word, as the old loop raised it."""
+    signers, vals, commit = _case(mode, 31, [BLOCK_ID_FLAG_COMMIT] * N)
+    at = 1
+    sound = vals.validators[at]
+    bad_key = (ed25519.PubKey(sound.pub_key.bytes()[:31]) if fault == "short_key"
+               else _ForeignKey(sound.pub_key))
+    members = [dataclasses.replace(v, pub_key=bad_key) if i == at else v
+               for i, v in enumerate(vals.validators)]
+    bad_vals = ValidatorSet(members)
+    assert [v.address for v in bad_vals.validators] == [v.address for v in vals.validators]
+    cols, _ = bad_vals.columns()
+    assert cols.key_type == ed25519.KEY_TYPE and cols.pub_bytes is None
+    ignore, count, count_all, by_index = MODES[mode]
+    needed = bad_vals.total_voting_power() - 1  # nothing is cut: the bad key is reached
+    bv, ref_bv = RecordingVerifier(), RecordingVerifier()
+    columnar = _outcome(lambda: validation._verify_commit_batch(
+        CHAIN, bad_vals, _fresh(commit), needed, ignore, count, count_all, by_index, cols, bv))
+    loop = _outcome(lambda: _loop_reference(
+        CHAIN, bad_vals, _fresh(commit), needed, ignore, count, count_all, by_index, ref_bv))
+    want = (("ValueError", "pubkey size is incorrect; expected: 32, got 31")
+            if fault == "short_key" else ("TypeError", "pubkey is not Ed25519"))
+    assert columnar == loop == want
+    assert bv.handed[0][1] is None
+
+
+def test_a_changed_set_gets_new_columns_and_new_key_bytes():
+    from cometbft_tpu.types import validator_set
+
+    vals = _signers(32)
+    cols, _ = vals.columns()
+    copy = vals.copy()
+    before = validator_set.columns_counters()
+    assert copy.columns() == (cols, True) and copy.columns()[0].pub_bytes is cols.pub_bytes
+    newcomer = KEYS[N].pub_key()
+    copy.update_with_change_set([Validator.new(newcomer, 1000)])
+    changed, reused = copy.columns()
+    after = validator_set.columns_counters()
+    assert not reused and after["built"] - before["built"] == 1
+    assert changed.pub_bytes is not cols.pub_bytes
+    assert changed.pub_bytes == tuple(v.pub_key.bytes() for v in copy.validators)
+    assert changed.pub_bytes[0] == newcomer.bytes() and newcomer.bytes() not in cols.pub_bytes
+    assert vals.columns() == (cols, True)  # the set it was copied from keeps its own
+
+
+def test_key_bytes_stand_for_the_key_objects_in_add_many():
+    from cometbft_tpu.crypto import batch as crypto_batch
+    from cometbft_tpu.crypto import sr25519
+
+    keys = [x.pub_key() for x in KEYS[:3]]
+    msgs = [b"m0", b"m1", b"m2"]
+    sigs = [x.sign(m) for x, m in zip(KEYS, msgs)]
+    raw = ed25519.BatchVerifier.key_bytes(keys)
+    assert raw == tuple(x.bytes() for x in keys) == crypto_batch.key_bytes("ed25519", keys)
+    assert ed25519.BatchVerifier.key_bytes([keys[0], ed25519.PubKey(b"\x01" * 31)]) is None
+    assert ed25519.BatchVerifier.key_bytes([keys[0], sr25519.gen_priv_key().pub_key()]) is None
+    assert crypto_batch.key_bytes(None, keys) is None
+    plain, handed = ed25519.BatchVerifier(), ed25519.BatchVerifier()
+    plain.add_many(keys, msgs, sigs)
+
+    class Untouchable:
+        def __getattribute__(self, name):
+            raise AssertionError("a key object was looked at")
+
+    handed.add_many([Untouchable()] * 3, msgs, sigs, key_bytes=raw)
+    assert (handed._pubs, handed._msgs, handed._sigs) == (plain._pubs, plain._msgs, plain._sigs)
+    # a short signature is still add()'s to refuse, at the first bad entry
+    with pytest.raises(ValueError) as e:
+        ed25519.BatchVerifier().add_many(keys, msgs, [sigs[0], sigs[1][:63], sigs[2][:1]], raw)
+    assert str(e.value) == "invalid signature"
+    for columns in ((keys[:2], msgs, sigs, raw), (keys, msgs, sigs, raw[:2])):
+        with pytest.raises(ValueError):
+            ed25519.BatchVerifier().add_many(*columns)
+    # an engine with no bulk entry of its own has no bytes column, and its
+    # add_many is the default: a loop of add() over the key objects
+    sk = sr25519.gen_priv_key()
+    assert sr25519.BatchVerifier.key_bytes([sk.pub_key()]) is None
+    assert crypto_batch.key_bytes(sr25519.KEY_TYPE, [sk.pub_key()]) is None
+    assert sr25519.BatchVerifier.add_many is crypto.BatchVerifier.add_many
+    added = []
+
+    class Looping(sr25519.BatchVerifier):
+        def add(self, key, message, signature):
+            added.append((key, message))
+            super().add(key, message, signature)
+
+    bv = Looping()
+    bv.add_many([sk.pub_key()] * 2, [b"a", b"b"], [sk.sign(b"a"), sk.sign(b"b")],
+                key_bytes=[b"ignored"] * 2)
+    assert [m for _, m in added] == [b"a", b"b"] and bv.verify() == (True, [True, True])

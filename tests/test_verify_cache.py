@@ -339,3 +339,265 @@ def test_partial_cache_hit_dispatches_only_uncached(counting_backend):
     bad = (priv.pub_key().bytes(), b"p-bad", b"\x05" * 64)
     ok, bits = _bv([entries[0], bad, entries[4]]).verify()
     assert not ok and bits == [True, False, True]
+
+
+# -- ISSUE 29: the seam decides a batch as whole columns ------------------------
+#
+# verify() takes a batch that is all unseen and distinct (`whole_miss`) or all
+# cached (`whole_hit`) by the dict's own loops and walks only a `mixed` one
+# triple by triple. `_walk_reference` is verify() as it stood before, the
+# walk for every batch, word for word but for the spans: each case below must
+# leave the same answer, the same columns at the backend and the same cache,
+# entry for entry in the same order.
+
+
+class RecordingBackend(CountingBackend):
+    """CountingBackend that keeps the columns of every dispatch, and can run
+    a writer of its own while the caller waits for the dispatch."""
+
+    def __init__(self):
+        super().__init__()
+        self.columns = []
+        self.meanwhile = None
+
+    def batch_verify(self, pubs, msgs, sigs):
+        self.columns.append((list(pubs), list(msgs), list(sigs)))
+        if self.meanwhile is not None:
+            self.meanwhile()
+        return super().batch_verify(pubs, msgs, sigs)
+
+
+@pytest.fixture
+def recording_backend(monkeypatch):
+    be = RecordingBackend()
+    import cometbft_tpu.sidecar.backend as backend_mod
+
+    monkeypatch.setattr(backend_mod, "get_backend", lambda: be)
+    return be
+
+
+def _walk_reference(entries, backend):
+    keys = [(pub, sig, msg) for pub, msg, sig in entries]
+    lane_of, lanes = {}, []
+    sub_pubs, sub_msgs, sub_sigs = [], [], []
+    for key in keys:
+        if key in ed25519._verified:
+            lanes.append(-1)
+            continue
+        lane = lane_of.get(key)
+        if lane is None:
+            lane = len(sub_pubs)
+            lane_of[key] = lane
+            sub_pubs.append(key[0])
+            sub_msgs.append(key[2])
+            sub_sigs.append(key[1])
+        lanes.append(lane)
+    if not sub_pubs:
+        return True, [True] * len(keys)
+    _, sub_bits = backend.batch_verify(sub_pubs, sub_msgs, sub_sigs)
+    bits = [True if lane < 0 else sub_bits[lane] for lane in lanes]
+    ed25519._verified_put_many(
+        [k for k, lane in zip(keys, lanes) if lane >= 0 and sub_bits[lane]]
+    )
+    return all(bits), bits
+
+
+_PRIV = ed25519.gen_priv_key_from_secret(b"issue-29")
+_PUB = _PRIV.pub_key().bytes()
+
+
+def _signed(tag: str, n: int):
+    msgs = [b"%s-%d" % (tag.encode(), i) for i in range(n)]
+    return [(_PUB, m, _PRIV.sign(m)) for m in msgs]
+
+
+def _forged(entry):
+    pub, msg, _ = entry
+    return pub, msg, b"\x07" * 64
+
+
+def _cases():
+    """name -> (cache cap, triples cached beforehand, the batch, the case it is)"""
+    old, new = _signed("old", 6), _signed("new", 11)
+    return {
+        "whole_miss": (64, old, new[:5], "whole_miss"),
+        "whole_hit": (64, old, old[1:5], "whole_hit"),
+        "whole_hit_with_repeats": (64, old, old[1:4] + old[2:3], "whole_hit"),
+        "part_hit": (64, old, old[:2] + new[:3] + old[4:5], "mixed"),
+        "repeats_of_a_miss": (64, old, new[:3] + new[1:2] + new[3:4], "mixed"),
+        "part_hit_with_repeats": (64, old, new[:2] + old[:1] + new[1:3], "mixed"),
+        "failed_lane_in_a_whole_miss": (
+            64, old, new[:2] + [_forged(new[2])] + new[3:5], "whole_miss"),
+        "failed_lane_in_a_mixed_batch": (
+            64, old, old[:1] + new[:2] + [_forged(new[2])], "mixed"),
+        "only_failed_lanes": (64, old, [_forged(new[0]), _forged(new[1])], "whole_miss"),
+        # cap 8, so a quarter is 2: six cached and a batch of 3, 5 and 11
+        "more_than_a_quarter": (8, old, new[:3], "whole_miss"),
+        "fills_the_cap_exactly": (8, old, new[:2], "whole_miss"),
+        "two_sweeps": (8, old, new[:5], "whole_miss"),
+        "more_than_the_whole_cache": (8, old, new, "whole_miss"),
+        "mixed_and_more_than_the_whole_cache": (8, old, old[4:] + new, "mixed"),
+        "more_than_an_empty_cache": (8, [], new, "whole_miss"),
+    }
+
+
+CASES = _cases()
+
+
+def _prepare(monkeypatch, cap, cached):
+    monkeypatch.setattr(ed25519, "_VERIFIED_MAX", cap)
+    ed25519._verified.clear()
+    ed25519._verified_put_many([(p, s, m) for p, m, s in cached])
+
+
+@pytest.mark.parametrize("name", CASES)
+def test_every_case_leaves_what_the_walk_leaves(name, recording_backend, monkeypatch):
+    cap, cached, batch, path = CASES[name]
+    _prepare(monkeypatch, cap, cached)
+    before = ed25519.verified_cache_counters()
+    got = _bv(batch).verify()
+    after = ed25519.verified_cache_counters()
+    got_columns, got_cache = recording_backend.columns, list(ed25519._verified)
+    recording_backend.columns = []
+    _prepare(monkeypatch, cap, cached)
+    want = _walk_reference(batch, recording_backend)
+    assert got == want
+    assert got_columns == recording_backend.columns
+    assert got_cache == list(ed25519._verified)
+    assert len(got_cache) <= cap
+    grew = {k: after[k] - before[k] for k in before}
+    assert [p for p in ("whole_miss", "whole_hit", "mixed") if grew[p]] == [path]
+    assert grew[path] == 1
+    assert grew["entries"] == len(batch) == grew["hits"] + grew["dups"] + grew["dispatched"]
+    assert grew["dispatched"] == sum(len(c[0]) for c in got_columns)
+    assert not {(p, s, m) for (p, m, s), ok in zip(batch, got[1]) if not ok} & set(got_cache)
+
+
+def test_a_whole_miss_is_dispatched_as_the_columns_stand(recording_backend):
+    batch = _signed("as-they-stand", 4)
+    bv = _bv(batch)
+    assert bv.verify() == (True, [True] * 4)
+    assert recording_backend.columns == [(bv._pubs, bv._msgs, bv._sigs)]
+
+
+@pytest.mark.parametrize("case", ["whole_miss", "mixed"])
+def test_a_writer_during_the_dispatch_changes_nothing_and_its_triples_end_young(
+    case, recording_backend
+):
+    """Membership is the filter's snapshot: triples another thread inserts
+    while the batch is at the backend were still dispatched and answered by
+    it, and the insert moves them to the young end like every other one."""
+    old, new = _signed("w-old", 3), _signed("w-new", 6)
+    ed25519._verified_put_many([(p, s, m) for p, m, s in old])
+    batch = (old[:1] if case == "mixed" else []) + new
+    other = _signed("w-other", 1)[0]
+    raced = [new[4], other, new[1]]
+    recording_backend.meanwhile = lambda: ed25519._verified_put_many(
+        [(p, s, m) for p, m, s in raced])
+    before = ed25519.verified_cache_counters()
+    assert _bv(batch).verify() == (True, [True] * len(batch))
+    grew = {k: v - before[k] for k, v in ed25519.verified_cache_counters().items()}
+    assert recording_backend.columns == [tuple(list(c) for c in zip(*new))]
+    assert grew[case] == 1 and grew["dispatched"] == 6 and grew["hits"] == len(batch) - 6
+    assert grew["inserted"] == 7, "the batch's six and the writer's other one, each once"
+    cache = list(ed25519._verified)
+    assert cache[-6:] == [(p, s, m) for p, m, s in new], "batch order, at the young end"
+    assert cache.index((other[0], other[2], other[1])) < len(cache) - 6
+    assert len(cache) == 3 + 6 + 1
+
+
+def test_a_racing_writer_of_other_triples_leaves_the_bound_and_the_order(
+    recording_backend, monkeypatch
+):
+    monkeypatch.setattr(ed25519, "_VERIFIED_MAX", 8)
+    old, new, others = _signed("r-old", 6), _signed("r-new", 3), _signed("r-other", 2)
+    ed25519._verified_put_many([(p, s, m) for p, m, s in old])
+    recording_backend.meanwhile = lambda: ed25519._verified_put_many(
+        [(p, s, m) for p, m, s in others])
+    assert _bv(new).verify() == (True, [True] * 3)
+    cache = list(ed25519._verified)
+    assert len(cache) <= 8 and cache[-3:] == [(p, s, m) for p, m, s in new]
+    assert (old[0][0], old[0][2], old[0][1]) not in cache, "oldest first"
+
+
+def test_the_span_names_the_case_that_ran(recording_backend, tmp_path):
+    import jax
+
+    from cometbft_tpu.libs import trace
+
+    old, new = _signed("span-old", 3), _signed("span-new", 3)
+    ed25519._verified_put_many([(p, s, m) for p, m, s in old])
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    trace.clear()
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for batch in (new, old, old[:1] + new[:1] + _signed("span-more", 1)):
+            assert _bv(batch).verify()[0]
+    finally:
+        jax.profiler.stop_trace()
+    got = [s["attrs"] for s in trace.spans() if s["name"] == "batch.verify"]
+    trace.clear()
+    assert got == [
+        {"entries": 3, "hits": 0, "dups": 0, "dispatched": 3, "evicted": 0, "path": "whole_miss"},
+        {"entries": 3, "hits": 3, "dups": 0, "dispatched": 0, "evicted": 0, "path": "whole_hit"},
+        {"entries": 3, "hits": 2, "dups": 0, "dispatched": 1, "evicted": 0, "path": "mixed"},
+    ]
+
+
+def test_racing_batches_keep_the_bound_and_the_counts(monkeypatch):
+    """More threads than cores put whole-miss, whole-hit and mixed batches
+    through a small cache at once: the bound holds whenever anyone looks, and
+    every insert and eviction is counted once (size = inserted - evicted)."""
+    import sys
+    import threading
+    import time
+
+    import cometbft_tpu.sidecar.backend as backend_mod
+
+    class AllValid:
+        def batch_verify(self, pubs, msgs, sigs):
+            time.sleep(0.0005)  # lets another thread write between filter and insert
+            return True, [True] * len(pubs)
+
+    monkeypatch.setattr(backend_mod, "get_backend", AllValid)
+    monkeypatch.setattr(ed25519, "_VERIFIED_MAX", 64)
+    before = ed25519.verified_cache_counters()
+    shared = [(_PUB, b"shared-%d" % i, b"\x01" * 64) for i in range(24)]
+    stop = time.monotonic() + 1.5
+    over, errors, calls = [], [], []
+
+    def worker(w: int):
+        try:
+            i = 0
+            while time.monotonic() < stop:
+                i += 1
+                own = [(_PUB, b"own-%d-%d-%d" % (w, i, j), b"\x02" * 64) for j in range(20)]
+                for batch in (own, shared[w % 4:][:12], own[:5] + shared[:6], own[:9]):
+                    ok, bits = _bv(batch).verify()
+                    calls.append(w)
+                    if not ok or bits != [True] * len(batch):
+                        errors.append((w, i, "answer"))
+                    size = len(ed25519._verified)
+                    if size > 64:
+                        over.append(size)
+        except Exception as e:  # reported below: a thread's exception is otherwise lost
+            errors.append((w, repr(e)))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=worker, args=(w,)) for w in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors and not over
+    grew = {k: v - before[k] for k, v in ed25519.verified_cache_counters().items()}
+    assert grew["size"] == grew["inserted"] - grew["evicted"]
+    assert grew["entries"] == grew["hits"] + grew["dups"] + grew["dispatched"]
+    assert grew["whole_miss"] and grew["whole_hit"] and grew["mixed"]
+    assert grew["whole_miss"] + grew["whole_hit"] + grew["mixed"] == len(calls)
