@@ -695,9 +695,10 @@ def run_sidecar_phase(args, phase: Phase) -> dict:
         addr = f"127.0.0.1:{s.getsockname()[1]}"
     extra = {"CMTPU_SIDECAR_ADDR": addr}
     if args.platform == "cpu":
-        # The default choice (auto) never puts a device tier on XLA:CPU, and
-        # warming the server's full-size default buckets there takes minutes.
-        extra["CMTPU_SIDECAR_DEVICE"] = "hybrid"
+        # The default choice (auto) never puts a device tier on XLA:CPU, so
+        # the CPU test's server is asked for the bare hybrid tier; and warming
+        # the server's full-size default buckets there takes minutes.
+        extra["CMTPU_BACKEND"] = "hybrid"
         extra["CMTPU_SIDECAR_WARM"] = "0"
     proc = subprocess.Popen(
         [sys.executable, "-m", "cometbft_tpu.sidecar"], env=child_env(extra), cwd=HERE,

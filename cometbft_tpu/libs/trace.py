@@ -22,6 +22,14 @@ builder, a cpu-only node) never does, and nothing constructs a
 benchmark's `--trace 1`, or an operator's `/debug/jax/trace`, which also
 writes `spans()` beside the xplane (libs/pprof.py).
 
+A process that never imports JAX (the node beside a sidecar) has no
+profiler to start. `capture()` is the session it can hold: while one is
+open every site writes the ring and constructs no `TraceAnnotation`;
+with neither a capture nor a profiler session every site is the no-op.
+`time.perf_counter()` is CLOCK_MONOTONIC on Linux, one clock for every
+process of a host, so the spans of a node and of the sidecar it calls
+merge by their times.
+
 A site sits at a layer boundary, once per call, dispatch, request or
 height — never inside a loop over lanes, signatures or messages. `NAMES`
 is every name the program emits (tests/test_trace.py holds the code and
@@ -31,6 +39,7 @@ PERF.md's table to it). This module imports nothing of JAX.
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import sys
 import threading
@@ -89,6 +98,14 @@ NAMES = (
     "device.run",
     "device.wait",
     "device.unpack",
+    # sidecar wire: the node's side, then the server's
+    "grpc.call",
+    "grpc.encode",
+    "grpc.wait",
+    "grpc.decode",
+    "sidecar.request",
+    "sidecar.decode",
+    "sidecar.encode",
 )
 
 _ring: collections.deque = collections.deque(maxlen=RING)
@@ -97,6 +114,7 @@ _ring_lock = threading.Lock()
 _ids = itertools.count(1)
 _tls = threading.local()
 _annotation = None  # jax.profiler.TraceAnnotation, once JAX is there to ask
+_captures = 0  # open capture()s: ring-only sessions
 
 
 def _session():
@@ -112,6 +130,22 @@ def _session():
             return None
         ann = _annotation = prof.TraceAnnotation
     return ann if ann.is_enabled() else None
+
+
+@contextlib.contextmanager
+def capture():
+    """A ring-only session, for a process with no profiler to start: while
+    it is open `span()` and `record()` write the ring. Nothing of JAX is
+    imported or asked; a profiler session that is on besides still gets
+    its annotations."""
+    global _captures
+    with _ring_lock:
+        _captures += 1
+    try:
+        yield
+    finally:
+        with _ring_lock:
+            _captures -= 1
 
 
 def _stack() -> list:
@@ -130,7 +164,7 @@ def _put(rec: dict) -> None:
 
 
 class _Off:
-    """What `span()` hands back with no profiler session: nothing happens."""
+    """What `span()` hands back with no session of either kind: nothing happens."""
 
     __slots__ = ()
     id = root = None
@@ -142,6 +176,9 @@ class _Off:
         return False
 
     def set(self, **attrs) -> None:
+        pass
+
+    def backdate(self, t0: float) -> None:
         pass
 
 
@@ -156,11 +193,17 @@ class _Span:
         self.name = name
         self.parent = parent
         self.attrs = attrs
-        self._ann = ann(PREFIX + name)
+        self._ann = None if ann is None else ann(PREFIX + name)  # None: ring only
 
     def set(self, **attrs) -> None:
         """Attributes known only once the work is done (hits, applied, ...)."""
         self.attrs.update(attrs)
+
+    def backdate(self, t0: float) -> None:
+        """Moves the start of an open span back to `t0`, for a span whose
+        need shows only in its first piece of work (a streamed request is
+        found by decoding its first chunk)."""
+        self.t0 = t0
 
     def __enter__(self):
         stack = _stack()
@@ -169,13 +212,15 @@ class _Span:
         parent = self.parent
         self.root = self.id if parent is None else parent.root
         stack.append(self)
-        self._ann.__enter__()
+        if self._ann is not None:
+            self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        self._ann.__exit__(*exc)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -196,12 +241,11 @@ def span(name: str, parent=None, **attrs):
     out, the parent is the innermost span open on this thread."""
     ann = _annotation  # _session(), inlined: this is every site's off path
     if ann is None:
-        if "jax" not in sys.modules:
-            return _OFF
-        ann = _session()
-        if ann is None:
-            return _OFF
+        if "jax" in sys.modules:
+            ann = _session()
     elif not ann.is_enabled():
+        ann = None
+    if ann is None and not _captures:
         return _OFF
     return _Span(name, parent, attrs, ann)
 
@@ -216,7 +260,7 @@ def current():
 def record(name: str, t0: float, t1: float, parent=None, **attrs) -> None:
     """Ring only, for an interval known after the fact or one that crosses
     threads (the engine's queue wait). Times are `time.perf_counter()`."""
-    if _session() is None:
+    if not _captures and _session() is None:
         return
     sid = next(_ids)
     _put({

@@ -542,6 +542,12 @@ class Node:
                        "Serving pod chip count from the Ping capability "
                        "reply.",
                        sidecar_sample("remote_mesh_width"))
+        for key, text in (
+            ("bytes_sent", "Bytes of request frames written to the sidecar."),
+            ("bytes_received", "Bytes of response frames read from the sidecar."),
+            ("lanes_sent", "Signatures sent to the sidecar for verification."),
+        ):
+            reg.gauge_func("sidecar", key, text, sidecar_sample(key))
 
     @staticmethod
     def _register_engine_metrics(reg) -> None:

@@ -31,23 +31,39 @@ Wire format: every frame is a 4-byte big-endian length + protobuf body.
 
 Streaming (round 10): a large BatchVerify splits into mesh-width-aligned
 chunks, each sent as an ordinary framed request (its own id, so the
-pipelined reader/pending-table/deadline machinery is unchanged). The
-server submits every chunk to its scheduler as it arrives and acks chunk
-k only after chunk k-1's dispatch resolved — a double buffer that
-overlaps wire receive + host pack of chunk k+1 with device dispatch of
-chunk k, one in-flight dispatch per connection. The FINAL chunk's
-response carries the whole stream's BatchVerifyResp; any chunk error
-fails the stream with an error response (never a partial bitmap).
-Capability-gated: servers advertise streaming in the Ping reply (field
-3) and clients fall back to unary against old servers; old unary clients
-see a protocol identical to round 9's.
+pipelined reader/pending-table/deadline machinery is unchanged). The chunk
+a server advertises is a bucket of the kernel's ladder
+(`ed25519_kernel.preferred_stream_chunk`): 1,024 lanes on one chip. The
+server decodes each chunk as it arrives and acks it at once, which
+overlaps its decode of chunk k with the client's encode of chunk k+1, and
+submits the stream ONCE, at the final chunk, as one call of all its lanes
+in the order sent. The planner behind it must see a commit whole: ten
+calls of 1,024 lanes sit under `CMTPU_HYBRID_MIN` and would all take the
+host route with the device idle. The FINAL chunk's response carries the
+whole stream's BatchVerifyResp; any chunk error fails the stream with an
+error response (never a partial bitmap). Capability-gated: servers
+advertise streaming in the Ping reply (field 3) and clients fall back to
+unary against old servers; old unary clients see a protocol identical to
+round 9's.
 
 Running the device behind one process also serializes TPU access — a chip
 belongs to one process at a time, so N node processes on one host can only
-share it through a server like this. Concurrent CONNECTIONS coalesce: the
-server routes verifications through a CoalescingScheduler over the device
-lock, so many node processes sharing one chip merge into single columnar
-dispatches with per-request bitmap slicing.
+share it through a server like this. What `python -m cometbft_tpu.sidecar`
+(and `SidecarServer(addr)` with no backend given) serves is the chain
+`get_backend()` assembles in that process, under `CMTPU_BACKEND=auto` the
+supervised one every node runs in process (engine -> `ResilientBackend`
+(`hybrid` -> `cpu`)): one assembly, with its deadline, breakers and cpu
+anchor, and ONE engine, in which concurrent CONNECTIONS coalesce into
+single columnar dispatches with per-request bitmap slicing. A server
+handed a bare backend (a test's, a fanout shard worker's) puts its own
+CoalescingScheduler over the device lock in front of it, as before.
+
+Both ends trace themselves into `libs/trace.py`'s ring: the client a
+`grpc.call` a call (children `grpc.encode`, `grpc.wait`, `grpc.decode`),
+the server a `sidecar.request` a request (children `sidecar.decode`,
+`sidecar.encode`, and the chain's own spans); `req` is the id of the frame
+that carried the answer on both. Bytes and lanes are counted always
+(`GrpcBackend.counters()`, `SidecarServer.counters()`).
 """
 
 from __future__ import annotations
@@ -62,11 +78,13 @@ import struct
 import threading
 import time
 
+from cometbft_tpu.libs import trace
 from cometbft_tpu.sidecar.backend import (
     LockedBackend,
     VerifyBackend,
-    device_backend,
+    get_backend,
 )
+from cometbft_tpu.sidecar.engine import engine_of
 from cometbft_tpu.sidecar.scheduler import CoalescingScheduler, VerifyFuture
 from cometbft_tpu.wire import proto
 
@@ -149,6 +167,11 @@ def _encode_request(req_id: int, method: str, payload: bytes) -> bytes:
     )
 
 
+def _encode_bitmap(ok: bool, bitmap) -> bytes:
+    """BatchVerifyResp."""
+    return proto.field_bool(1, ok) + proto.field_bytes(2, bytes(1 if b else 0 for b in bitmap))
+
+
 def _encode_response(req_id: int, ok: bool, error: str, payload: bytes) -> bytes:
     return (
         proto.field_varint(1, req_id, emit_default=True)
@@ -163,37 +186,79 @@ def _encode_response(req_id: int, ok: bool, error: str, payload: bytes) -> bytes
 
 class _ServerStream:
     """Per-connection state of one in-progress BatchVerifyChunk stream:
-    the futures of every submitted chunk (resolved in submission order by
-    the scheduler's single dispatcher) and the expected next sequence."""
+    the triples of every chunk so far in the order sent, the expected next
+    sequence, and the stream's `sidecar.request` span, open from its first
+    chunk to its answer, with the bytes of its frames."""
 
-    __slots__ = ("futures", "next_seq")
+    __slots__ = ("pubs", "msgs", "sigs", "next_seq", "span", "bytes_in", "bytes_out")
 
-    def __init__(self):
-        self.futures: list[tuple] = []  # (VerifyFuture, n_sigs)
+    def __init__(self, span):
+        self.pubs: list[bytes] = []
+        self.msgs: list[bytes] = []
+        self.sigs: list[bytes] = []
         self.next_seq = 0
+        self.span = span
+        self.bytes_in = 0
+        self.bytes_out = 0
+
+
+def _device_tier(backend):
+    """The tier under `backend` that holds the device, for the warm-up and
+    for the lines that say what is served: the backend itself when it is
+    bare, else the first tier of the chain under it that can `warmup`.
+    None for a host-only server."""
+    queue, seen = [backend], set()
+    while queue:
+        b = queue.pop(0)
+        if b is None or id(b) in seen:
+            continue
+        seen.add(id(b))
+        if hasattr(b, "warmup") and not isinstance(b, GrpcBackend):
+            return b
+        queue.append(getattr(b, "inner", None))
+        queue.extend(t.backend for t in getattr(b, "tiers", ()))
+    return None
 
 
 class SidecarServer:
-    """The long-lived device owner. Device calls are serialized with a lock
-    (one TPU, one XLA stream); socket handling is one thread per connection,
-    so hosts can pipeline requests like the reference's socket ABCI client.
-    Verifications route through a CoalescingScheduler over the device lock
-    (CMTPU_COALESCE=0 strips it): concurrent connections — many node
-    processes sharing one chip — merge into single columnar dispatches
-    with per-request bitmap slicing, the round-8 in-process move applied
-    across the wire."""
+    """The long-lived device owner. With no backend given it serves what
+    `get_backend()` assembles in this process — under `CMTPU_BACKEND=auto`
+    the supervised chain with its one engine, in which concurrent
+    connections (many node processes sharing one chip) merge into single
+    columnar dispatches with per-request bitmap slicing. A bare backend
+    handed in gets this server's own CoalescingScheduler over the device
+    lock in front of it (CMTPU_COALESCE=0 strips it). Socket handling is
+    one thread per connection, so hosts can pipeline requests like the
+    reference's socket ABCI client; MerkleRoot and Warmup are serialized
+    by the device lock either way."""
 
     def __init__(self, addr: str = DEFAULT_ADDR, backend: VerifyBackend | None = None):
         self.addr = addr
-        self.backend = backend if backend is not None else device_backend(
-            os.environ.get("CMTPU_SIDECAR_DEVICE", "auto").lower()
-        )
+        if backend is None:
+            backend = get_backend()
+        if isinstance(backend, GrpcBackend):
+            raise ValueError("a sidecar cannot serve the grpc backend: it would dial a sidecar")
+        self.backend = backend
         self._device_lock = threading.Lock()
+        # Where verifications are submitted: the engine the backend already
+        # carries, never a second one in front of it; else this server's own
+        # scheduler (None: stripped, calls go inline under the device lock).
         self._sched: CoalescingScheduler | None = None
-        if os.environ.get("CMTPU_COALESCE", "1") != "0":
-            self._sched = CoalescingScheduler(
+        self._front = engine_of(backend)
+        if self._front is None and os.environ.get("CMTPU_COALESCE", "1") != "0":
+            self._front = self._sched = CoalescingScheduler(
                 LockedBackend(self.backend, self._device_lock)
             )
+        self._count_lock = threading.Lock()  # the counters below
+        self.counters_ = {
+            "requests": 0,        # answered: a stream is one
+            "bytes_in": 0,        # frames read, their 4-byte lengths included
+            "bytes_out": 0,       # frames written, likewise
+            "lanes_in": 0,        # triples received for verification
+            "streams_failed": 0,  # streams torn down by an error
+        }
+        self._conns: set[socket.socket] = set()  # open connections (under _count_lock)
+        self._serving = threading.Event()
         host, port = addr.rsplit(":", 1)
         outer = self
 
@@ -201,6 +266,15 @@ class SidecarServer:
             def handle(self):
                 sock = self.request
                 conn = {"streams": {}}  # per-connection stream table
+                with outer._count_lock:
+                    outer._conns.add(sock)
+                try:
+                    self._serve(sock, conn)
+                finally:
+                    with outer._count_lock:
+                        outer._conns.discard(sock)
+
+            def _serve(self, sock, conn):
                 while True:
                     try:
                         body = read_frame(sock)
@@ -221,18 +295,8 @@ class SidecarServer:
                         return
                     if body is None:
                         return
-                    req_id = 0
-                    try:  # fault isolation per request, incl. malformed bodies
-                        fields = proto.decode_fields(body)
-                        req_id = proto.get_uvarint(fields, 1)
-                        method = proto.get_string(fields, 2)
-                        payload = proto.get_bytes(fields, 3)
-                        out = outer._dispatch(method, payload, conn)
-                        resp = _encode_response(req_id, True, "", out)
-                    except Exception as e:
-                        resp = _encode_response(req_id, False, f"{type(e).__name__}: {e}", b"")
                     try:
-                        write_frame(sock, resp)
+                        write_frame(sock, outer._answer(body, conn))
                     except OSError:
                         return
 
@@ -242,13 +306,49 @@ class SidecarServer:
 
         self._server = Server((host, int(port)), Handler)
 
+    def _count(self, **deltas) -> None:
+        with self._count_lock:
+            for key, d in deltas.items():
+                self.counters_[key] += d
+
+    def counters(self) -> dict:
+        """What crossed this server's wire since it started."""
+        with self._count_lock:
+            return dict(self.counters_)
+
+    def _answer(self, body: bytes, conn: dict) -> bytes:
+        """One request frame to the body of its response frame. Faults are
+        isolated per request, malformed bodies included. A unary request is
+        one `sidecar.request` span; a stream's span opens at its first
+        chunk and closes with its answer."""
+        n_in = len(body) + _LEN.size
+        req_id = 0
+        try:
+            fields = proto.decode_fields(body)
+            req_id = proto.get_uvarint(fields, 1)
+            method = proto.get_string(fields, 2)
+            payload = proto.get_bytes(fields, 3)
+        except Exception as e:
+            resp = _encode_response(req_id, False, f"{type(e).__name__}: {e}", b"")
+            self._count(requests=1, bytes_in=n_in, bytes_out=len(resp) + _LEN.size)
+            return resp
+        if method == "BatchVerifyChunk":
+            return self._answer_chunk(req_id, payload, conn["streams"], n_in)
+        with trace.span("sidecar.request", method=method, req=req_id, bytes_in=n_in) as sp:
+            try:
+                resp = _encode_response(req_id, True, "", self._dispatch(method, payload, conn, sp))
+            except Exception as e:
+                resp = _encode_response(req_id, False, f"{type(e).__name__}: {e}", b"")
+            sp.set(bytes_out=len(resp) + _LEN.size)
+        self._count(requests=1, bytes_in=n_in, bytes_out=len(resp) + _LEN.size)
+        return resp
+
     def _submit(self, pubs, msgs, sigs) -> VerifyFuture:
-        """One chunk/request into the verification path: async through the
-        scheduler (cross-connection coalescing + the device lock inside its
-        dispatcher) when wired, an immediately-resolved future otherwise —
-        the streaming handler's double buffer works against either."""
-        if self._sched is not None:
-            return self._sched.submit(pubs, msgs, sigs)
+        """One request into the verification path: async through the engine
+        the backend carries or this server's own scheduler (cross-connection
+        coalescing either way), an immediately-resolved future otherwise."""
+        if self._front is not None:
+            return self._front.submit(pubs, msgs, sigs)
         fut = VerifyFuture(len(pubs))
         try:
             with self._device_lock:
@@ -273,10 +373,13 @@ class SidecarServer:
         return DEFAULT_STREAM_CHUNK
 
     def scheduler_counters(self) -> dict:
-        """The server-side coalescer's counters (empty when stripped)."""
-        return self._sched.counters() if self._sched is not None else {}
+        """The counters of whatever coalesces this server's connections:
+        the backend's own engine, or the server-side scheduler (empty when
+        stripped)."""
+        return self._front.counters() if self._front is not None else {}
 
-    def _dispatch(self, method: str, payload: bytes, conn: dict | None = None) -> bytes:
+    def _dispatch(self, method: str, payload: bytes, conn: dict | None = None,
+                  sp=trace._OFF) -> bytes:
         if method == "Ping":
             # Capability reply: PingResp { 1: "pong", 2: mesh_width,
             # 3: streaming, 4: chunk }. The width is the REMOTE pod's chip
@@ -301,12 +404,15 @@ class SidecarServer:
                 + proto.field_varint(4, self._preferred_chunk())
             )
         if method == "BatchVerify":
-            fields = proto.decode_fields(payload)
-            pubs = proto.get_repeated_bytes(fields, 1)
-            msgs = proto.get_repeated_bytes(fields, 2)
-            sigs = proto.get_repeated_bytes(fields, 3)
+            with trace.span("sidecar.decode"):
+                fields = proto.decode_fields(payload)
+                pubs = proto.get_repeated_bytes(fields, 1)
+                msgs = proto.get_repeated_bytes(fields, 2)
+                sigs = proto.get_repeated_bytes(fields, 3)
             if not (len(pubs) == len(msgs) == len(sigs)):
                 raise ValueError("pubs/msgs/sigs length mismatch")
+            sp.set(lanes=len(pubs))
+            self._count(lanes_in=len(pubs))
             if not pubs:
                 # The scheduler short-circuits empty submissions with its
                 # own sentinel; keep the backend's empty-batch answer.
@@ -314,13 +420,8 @@ class SidecarServer:
                     ok, bitmap = self.backend.batch_verify(pubs, msgs, sigs)
             else:
                 ok, bitmap = self._submit(pubs, msgs, sigs).result()
-            return proto.field_bool(1, ok) + proto.field_bytes(
-                2, bytes(1 if b else 0 for b in bitmap)
-            )
-        if method == "BatchVerifyChunk":
-            if conn is None:
-                raise ValueError("BatchVerifyChunk requires a connection")
-            return self._dispatch_chunk(payload, conn["streams"])
+            with trace.span("sidecar.encode"):
+                return _encode_bitmap(ok, bitmap)
         if method == "MerkleRoot":
             fields = proto.decode_fields(payload)
             leaves = proto.get_repeated_bytes(fields, 1)
@@ -334,31 +435,40 @@ class SidecarServer:
             return b""
         raise ValueError(f"unknown method {method!r}")
 
-    def _dispatch_chunk(self, payload: bytes, streams: dict) -> bytes:
-        """One chunk of a streamed BatchVerify (module docstring: ChunkReq).
-        Non-final chunks are submitted to the scheduler and acked — after
-        the PREVIOUS chunk's dispatch resolved, the double buffer that
-        paces the client to one in-flight dispatch while it packs/sends
-        the next chunk. The final chunk's response is the whole stream's
-        BatchVerifyResp. Any failure tears the stream down and surfaces as
-        this chunk's error response — never a partial bitmap."""
-        fields = proto.decode_fields(payload)
-        sid = proto.get_uvarint(fields, 1)
-        seq = proto.get_uvarint(fields, 2)
-        final = proto.get_bool(fields, 3)
-        pubs = proto.get_repeated_bytes(fields, 4)
-        msgs = proto.get_repeated_bytes(fields, 5)
-        sigs = proto.get_repeated_bytes(fields, 6)
-        if seq == 0:
-            if sid in streams:
-                raise ValueError(f"stream {sid} already open")
-            if len(streams) >= 64:  # a leaking client must not hoard futures
-                raise ValueError("too many open streams on this connection")
-            streams[sid] = _ServerStream()
-        st = streams.get(sid)
-        if st is None:
-            raise ValueError(f"unknown stream {sid} (chunk seq {seq})")
+    def _answer_chunk(self, req_id: int, payload: bytes, streams: dict, n_in: int) -> bytes:
+        """One chunk of a streamed BatchVerify (module docstring: ChunkReq)
+        to the body of its response frame. A non-final chunk is decoded,
+        kept, and acked at once. The final chunk submits the whole stream as
+        ONE call, all its lanes in the order sent, and its response is the
+        stream's BatchVerifyResp. Any failure tears the stream down and
+        surfaces as this chunk's error response — never a partial bitmap."""
+        st = None
+        final = False
         try:
+            t0 = time.perf_counter()
+            fields = proto.decode_fields(payload)
+            sid = proto.get_uvarint(fields, 1)
+            seq = proto.get_uvarint(fields, 2)
+            final = proto.get_bool(fields, 3)
+            pubs = proto.get_repeated_bytes(fields, 4)
+            msgs = proto.get_repeated_bytes(fields, 5)
+            sigs = proto.get_repeated_bytes(fields, 6)
+            t1 = time.perf_counter()
+            if seq == 0:
+                if sid in streams:
+                    raise ValueError(f"stream {sid} already open")
+                if len(streams) >= 64:  # a leaking client must not hoard triples
+                    raise ValueError("too many open streams on this connection")
+                # Only the decode says that a stream starts here: its span
+                # opens now and takes the decode's start.
+                sp = trace.span("sidecar.request", method="BatchVerifyChunk").__enter__()
+                sp.backdate(t0)
+                streams[sid] = _ServerStream(sp)
+            st = streams.get(sid)
+            if st is None:
+                raise ValueError(f"unknown stream {sid} (chunk seq {seq})")
+            trace.record("sidecar.decode", t0, t1, parent=st.span, seq=seq)
+            st.bytes_in += n_in
             if seq != st.next_seq:
                 raise ValueError(
                     f"stream {sid}: chunk seq {seq}, expected {st.next_seq}"
@@ -366,42 +476,54 @@ class SidecarServer:
             st.next_seq += 1
             if not (len(pubs) == len(msgs) == len(sigs)):
                 raise ValueError("pubs/msgs/sigs length mismatch")
-            if pubs:
-                st.futures.append((self._submit(pubs, msgs, sigs), len(pubs)))
-            if not final:
-                if len(st.futures) >= 2:
-                    st.futures[-2][0].result()
-                return b""
-            all_ok = True
-            bits_out = bytearray()
-            for fut, n in st.futures:
-                ok, bits = fut.result()
+            self._count(lanes_in=len(pubs))
+            st.pubs += pubs
+            st.msgs += msgs
+            st.sigs += sigs
+            out = b""
+            if final:
+                n = len(st.pubs)
+                ok, bits = self._submit(st.pubs, st.msgs, st.sigs).result() if n else (True, [])
                 if len(bits) != n:
-                    raise ValueError(
-                        f"stream {sid}: chunk answered {len(bits)} of {n} lanes"
-                    )
-                all_ok = all_ok and ok
-                bits_out.extend(1 if b else 0 for b in bits)
-            del streams[sid]
-            return proto.field_bool(1, all_ok) + proto.field_bytes(
-                2, bytes(bits_out)
-            )
-        except Exception:
-            streams.pop(sid, None)
-            raise
+                    raise ValueError(f"stream {sid}: answered {len(bits)} of {n} lanes")
+                with trace.span("sidecar.encode"):
+                    out = _encode_bitmap(ok, bits)
+            resp = _encode_response(req_id, True, "", out)
+        except Exception as e:
+            final = True  # the stream ends here
+            resp = _encode_response(req_id, False, f"{type(e).__name__}: {e}", b"")
+            if st is not None:
+                self._count(streams_failed=1)
+        n_out = len(resp) + _LEN.size
+        self._count(bytes_in=n_in, bytes_out=n_out, requests=1 if final else 0)
+        if st is not None:
+            st.bytes_out += n_out
+            if final:
+                streams.pop(sid, None)
+                st.span.set(req=req_id, lanes=len(st.pubs), chunks=st.next_seq,
+                            bytes_in=st.bytes_in, bytes_out=st.bytes_out)
+                st.span.__exit__(None, None, None)
+        return resp
 
     def warmup(self, buckets=DEFAULT_BUCKETS) -> bool:
         """Precompile what the held device tier would dispatch for batches
         of these sizes, so the first real commit does not pay an XLA
         compile (SURVEY §7 hard part 3, <2 ms budget). Whichever tier that
-        is — bare device or hybrid — decides the programs; False when the
-        backend has no device tier to warm (a host-only server)."""
-        warm = getattr(self.backend, "warmup", None)
-        if warm is None:
+        is — bare device or hybrid, served bare or under the supervised
+        chain — decides the programs; False when the backend has no device
+        tier to warm (a host-only server)."""
+        tier = _device_tier(self.backend)
+        if tier is None:
             return False
         with self._device_lock:
-            warm(buckets)
+            tier.warmup(buckets)
         return True
+
+    def device_counters(self) -> dict:
+        """The counters of the tier that holds the device (what it is, the
+        lanes each side of it ran): {} for a host-only server."""
+        tier = _device_tier(self.backend)
+        return tier.counters() if hasattr(tier, "counters") else {}
 
     @property
     def bound_addr(self) -> str:
@@ -412,6 +534,7 @@ class SidecarServer:
         return f"{host}:{port}"
 
     def serve_forever(self):
+        self._serving.set()
         self._server.serve_forever()
 
     def start(self) -> "SidecarServer":
@@ -420,8 +543,19 @@ class SidecarServer:
         return self
 
     def shutdown(self):
-        self._server.shutdown()
+        """Stops listening and closes every open connection: a client then
+        sees the server gone, not a listener that went quiet. The backend is
+        the caller's to close; the server's own scheduler goes with it."""
+        if self._serving.is_set():
+            self._server.shutdown()
         self._server.server_close()
+        with self._count_lock:
+            conns = list(self._conns)
+        for sock in conns:
+            try:
+                sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
         if self._sched is not None:
             self._sched.close()
 
@@ -482,6 +616,9 @@ class GrpcBackend(VerifyBackend):
             "streamed_calls": 0,
             "streamed_chunks": 0,
             "stream_retries": 0,
+            "bytes_sent": 0,      # frames written, their 4-byte lengths included
+            "bytes_received": 0,  # response frames read by a waiter, likewise
+            "lanes_sent": 0,      # triples written for verification
         }
 
     def _connect_locked(self) -> None:
@@ -550,13 +687,14 @@ class GrpcBackend(VerifyBackend):
         for slot in dead.values():
             slot[0].set()
 
-    def _begin_call(self, method: str, payload: bytes, pin_sock=None):
+    def _begin_call(self, method: str, payload: bytes, pin_sock=None, lanes: int = 0):
         """Register a pending slot and write the request frame; returns
         (slot, req_id) for _await_slot. `pin_sock` (streaming) demands the
         frame ride a specific connection: a mid-stream reconnect would
         scatter one stream's chunks across sockets, and the server would
-        rightly reject the orphaned tail."""
-        slot = [threading.Event(), None, None]
+        rightly reject the orphaned tail. `lanes` is what the frame carries
+        for verification; the slot's last entry is the bytes written."""
+        slot = [threading.Event(), None, None, 0]
         with self._plock:
             if pin_sock is not None and self._sock is not pin_sock:
                 err = ConnectionError("sidecar connection lost mid-stream")
@@ -584,6 +722,10 @@ class GrpcBackend(VerifyBackend):
             err = ConnectionError(str(e))
             err.sock = sock  # which connection failed (see _call)
             raise err from e
+        slot[3] = len(req) + _LEN.size
+        with self._plock:
+            self.counters_["bytes_sent"] += slot[3]
+            self.counters_["lanes_sent"] += lanes
         return slot, req_id
 
     def _await_slot(self, slot, req_id: int, method: str) -> bytes:
@@ -595,37 +737,51 @@ class GrpcBackend(VerifyBackend):
             err = ConnectionError("sidecar connection lost mid-request")
             err.sock = slot[2]
             raise err
+        with self._plock:
+            self.counters_["bytes_received"] += len(slot[1]) + _LEN.size
         return slot[1]
 
-    def _call_once(self, method: str, payload: bytes) -> bytes:
-        slot, req_id = self._begin_call(method, payload)
-        return self._await_slot(slot, req_id, method)
+    def _drop_failed(self, e: ConnectionError) -> None:
+        """Tear down only the connection that actually failed: a thread
+        handling a stale failure must not close the replacement another
+        thread just established."""
+        failed = getattr(e, "sock", None)
+        with self._plock:
+            if self._sock is not None and (failed is None or self._sock is failed):
+                try:
+                    self._sock.close()
+                except OSError:
+                    pass
+                self._sock = None
 
-    def _call(self, method: str, payload: bytes) -> bytes:
-        for attempt in (0, 1):
-            try:
-                body = self._call_once(method, payload)
-                break
-            except ConnectionError as e:
-                # Tear down only the connection that actually failed: a
-                # thread handling a stale failure must not close the
-                # replacement another thread just established.
-                failed = getattr(e, "sock", None)
-                with self._plock:
-                    if self._sock is not None and (
-                        failed is None or self._sock is failed
-                    ):
-                        try:
-                            self._sock.close()
-                        except OSError:
-                            pass
-                        self._sock = None
-                if attempt:
-                    raise
-        fields = proto.decode_fields(body)
-        if not proto.get_bool(fields, 2):
-            raise RuntimeError(f"sidecar error: {proto.get_string(fields, 3)}")
-        return proto.get_bytes(fields, 4)
+    def _call(self, method: str, payload, lanes: int = 0, decode=None):
+        """One unary call under its `grpc.call` span, two attempts. `payload`
+        is the request's bytes, or a function that encodes them (timed as
+        `grpc.encode`); `decode` turns the response's payload into the
+        answer (timed as `grpc.decode` with the envelope's)."""
+        with trace.span("grpc.call", method=method, lanes=lanes, chunks=1) as call:
+            if callable(payload):
+                with trace.span("grpc.encode"):
+                    payload = payload()
+            sent = 0
+            for attempt in (0, 1):
+                try:
+                    slot, req_id = self._begin_call(method, payload, lanes=lanes)
+                    sent += slot[3]
+                    with trace.span("grpc.wait"):
+                        body = self._await_slot(slot, req_id, method)
+                    break
+                except ConnectionError as e:
+                    self._drop_failed(e)
+                    if attempt:
+                        raise
+            call.set(req=req_id, bytes_out=sent, bytes_in=len(body) + _LEN.size)
+            with trace.span("grpc.decode"):
+                fields = proto.decode_fields(body)
+                if not proto.get_bool(fields, 2):
+                    raise RuntimeError(f"sidecar error: {proto.get_string(fields, 3)}")
+                out = proto.get_bytes(fields, 4)
+                return out if decode is None else decode(out)
 
     def ping(self) -> bool:
         body = self._call("Ping", b"")
@@ -684,17 +840,22 @@ class GrpcBackend(VerifyBackend):
                 return self._batch_verify_streamed(pubs, msgs, sigs, chunk)
         with self._plock:
             self.counters_["unary_calls"] += 1
-        payload = b"".join(
-            proto.field_bytes(1, p, emit_default=True) for p in pubs
-        ) + b"".join(
-            proto.field_bytes(2, m, emit_default=True) for m in msgs
-        ) + b"".join(
-            proto.field_bytes(3, s, emit_default=True) for s in sigs
-        )
-        out = self._call("BatchVerify", payload)
-        fields = proto.decode_fields(out)
-        bitmap = proto.get_bytes(fields, 2)
-        return proto.get_bool(fields, 1), [bool(b) for b in bitmap[: len(pubs)]]
+
+        def encode() -> bytes:
+            return b"".join(
+                proto.field_bytes(1, p, emit_default=True) for p in pubs
+            ) + b"".join(
+                proto.field_bytes(2, m, emit_default=True) for m in msgs
+            ) + b"".join(
+                proto.field_bytes(3, s, emit_default=True) for s in sigs
+            )
+
+        def decode(out: bytes):
+            fields = proto.decode_fields(out)
+            bitmap = proto.get_bytes(fields, 2)
+            return proto.get_bool(fields, 1), [bool(b) for b in bitmap[:n]]
+
+        return self._call("BatchVerify", encode, lanes=n, decode=decode)
 
     def _batch_verify_streamed(self, pubs, msgs, sigs, chunk: int):
         """Chunked-streaming BatchVerify with the same two-attempt redial
@@ -702,23 +863,20 @@ class GrpcBackend(VerifyBackend):
         socket and the SECOND attempt re-streams from chunk 0 on a fresh
         connection (streams never resume mid-way — the server holds no
         cross-connection state, so a partial bitmap is impossible)."""
-        for attempt in (0, 1):
+        with trace.span("grpc.call", method="BatchVerifyChunk", lanes=len(pubs)) as call:
+            tally = {"bytes_out": 0, "bytes_in": 0}
             try:
-                return self._stream_once(pubs, msgs, sigs, chunk)
-            except ConnectionError as e:
-                failed = getattr(e, "sock", None)
-                with self._plock:
-                    if self._sock is not None and (
-                        failed is None or self._sock is failed
-                    ):
-                        try:
-                            self._sock.close()
-                        except OSError:
-                            pass
-                        self._sock = None
-                    self.counters_["stream_retries"] += 1
-                if attempt:
-                    raise
+                for attempt in (0, 1):
+                    try:
+                        return self._stream_once(pubs, msgs, sigs, chunk, tally)
+                    except ConnectionError as e:
+                        self._drop_failed(e)
+                        with self._plock:
+                            self.counters_["stream_retries"] += 1
+                        if attempt:
+                            raise
+            finally:
+                call.set(**tally)
 
     def _check_ack(self, body: bytes) -> None:
         fields = proto.decode_fields(body)
@@ -727,71 +885,88 @@ class GrpcBackend(VerifyBackend):
 
     @staticmethod
     def _stream_window() -> int:
-        """Unacked-chunk pipeline depth. The server still only ever has one
-        dispatch in flight per connection (its ack of chunk k gates on
-        chunk k-1's dispatch) — a deeper client window just keeps frames in
-        the socket on their way there, which is what hides a long wire RTT
-        behind device dispatch. Floor 2: below that the pipeline degenerates
-        into send/ack lockstep and the overlap disappears."""
+        """Unacked-chunk pipeline depth. The server acks a chunk as soon as
+        it has decoded it and dispatches once, at the final chunk — a
+        deeper client window just keeps frames in the socket on their way
+        there, which is what hides a long wire RTT and overlaps the
+        server's decode with this side's encode. Floor 2: below that the
+        pipeline degenerates into send/ack lockstep and the overlap
+        disappears."""
         try:
             return max(2, int(os.environ.get("CMTPU_SIDECAR_WINDOW", "6")))
         except ValueError:
             return 6
 
-    def _stream_once(self, pubs, msgs, sigs, chunk: int):
+    def _stream_once(self, pubs, msgs, sigs, chunk: int, tally: dict):
+        """One attempt at a stream, on one connection. `tally` takes what
+        the enclosing `grpc.call` span says of it: the bytes of its frames
+        both ways, its chunks, and `req`, the id of the final chunk's frame."""
         n = len(pubs)
         with self._plock:
             self._next_stream += 1
             sid = self._next_stream
         n_chunks = (n + chunk - 1) // chunk
+        tally["chunks"] = n_chunks
         window = self._stream_window()
         slots: list[tuple] = []
         pinned = None
+
+        def ack(i: int) -> bytes:
+            body = self._await_slot(*slots[i], "BatchVerifyChunk")
+            tally["bytes_in"] += len(body) + _LEN.size
+            return body
+
         for seq in range(n_chunks):
             lo, hi = seq * chunk, min((seq + 1) * chunk, n)
-            payload = (
-                proto.field_varint(1, sid, emit_default=True)
-                + proto.field_varint(2, seq, emit_default=True)
-                + proto.field_bool(3, seq == n_chunks - 1)
-                + b"".join(
-                    proto.field_bytes(4, p, emit_default=True) for p in pubs[lo:hi]
+            with trace.span("grpc.encode", seq=seq):
+                payload = (
+                    proto.field_varint(1, sid, emit_default=True)
+                    + proto.field_varint(2, seq, emit_default=True)
+                    + proto.field_bool(3, seq == n_chunks - 1)
+                    + b"".join(
+                        proto.field_bytes(4, p, emit_default=True) for p in pubs[lo:hi]
+                    )
+                    + b"".join(
+                        proto.field_bytes(5, m, emit_default=True) for m in msgs[lo:hi]
+                    )
+                    + b"".join(
+                        proto.field_bytes(6, s, emit_default=True) for s in sigs[lo:hi]
+                    )
                 )
-                + b"".join(
-                    proto.field_bytes(5, m, emit_default=True) for m in msgs[lo:hi]
-                )
-                + b"".join(
-                    proto.field_bytes(6, s, emit_default=True) for s in sigs[lo:hi]
-                )
-            )
             # Windowed pipelining: at most `window` unacked chunks in
             # flight — the server is packing/dispatching chunk k while this
             # thread packs and sends later chunks, and the k-th ack gates
             # chunk k+window so a slow server applies backpressure instead
             # of buffering the whole batch in socket memory.
             if seq >= window:
-                self._check_ack(
-                    self._await_slot(*slots[seq - window], "BatchVerifyChunk")
-                )
-            slots.append(self._begin_call("BatchVerifyChunk", payload, pin_sock=pinned))
+                self._check_ack(ack(seq - window))
+            slots.append(self._begin_call(
+                "BatchVerifyChunk", payload, pin_sock=pinned, lanes=hi - lo
+            ))
+            tally["bytes_out"] += slots[-1][0][3]
             if pinned is None:
                 pinned = slots[0][0][2]
+        tally["req"] = slots[-1][1]
         with self._plock:
             self.counters_["streamed_chunks"] += n_chunks
-        for i in range(max(0, n_chunks - window), n_chunks - 1):
-            self._check_ack(self._await_slot(*slots[i], "BatchVerifyChunk"))
-        final = self._await_slot(*slots[-1], "BatchVerifyChunk")
-        fields = proto.decode_fields(final)
-        if not proto.get_bool(fields, 2):
-            raise RuntimeError(f"sidecar error: {proto.get_string(fields, 3)}")
-        out = proto.decode_fields(proto.get_bytes(fields, 4))
-        bitmap = proto.get_bytes(out, 2)
-        if len(bitmap) != n:
-            raise RuntimeError(
-                f"sidecar stream answered {len(bitmap)} of {n} lanes"
-            )
+        with trace.span("grpc.wait"):
+            for i in range(max(0, n_chunks - window), n_chunks - 1):
+                self._check_ack(ack(i))
+            final = ack(n_chunks - 1)
+        with trace.span("grpc.decode"):
+            fields = proto.decode_fields(final)
+            if not proto.get_bool(fields, 2):
+                raise RuntimeError(f"sidecar error: {proto.get_string(fields, 3)}")
+            out = proto.decode_fields(proto.get_bytes(fields, 4))
+            bitmap = proto.get_bytes(out, 2)
+            if len(bitmap) != n:
+                raise RuntimeError(
+                    f"sidecar stream answered {len(bitmap)} of {n} lanes"
+                )
+            result = proto.get_bool(out, 1), [bool(b) for b in bitmap]
         with self._plock:
             self.counters_["streamed_calls"] += 1
-        return proto.get_bool(out, 1), [bool(b) for b in bitmap]
+        return result
 
     def counters(self) -> dict:
         with self._plock:
@@ -802,11 +977,13 @@ class GrpcBackend(VerifyBackend):
         return out
 
     def merkle_root(self, leaves):
-        payload = b"".join(
-            proto.field_bytes(1, leaf, emit_default=True) for leaf in leaves
+        return self._call(
+            "MerkleRoot",
+            lambda: b"".join(
+                proto.field_bytes(1, leaf, emit_default=True) for leaf in leaves
+            ),
+            decode=lambda out: proto.get_bytes(proto.decode_fields(out), 1),
         )
-        out = self._call("MerkleRoot", payload)
-        return proto.get_bytes(proto.decode_fields(out), 1)
 
     def warmup(self, buckets=DEFAULT_BUCKETS) -> None:
         self._call(
@@ -824,41 +1001,56 @@ class GrpcBackend(VerifyBackend):
                 self._sock = None
 
 
-def main() -> None:
-    """`python -m cometbft_tpu.sidecar`: serve until killed. Says at start
-    which tier and device it resolved, and on SIGTERM/SIGINT what that tier
-    did (lanes on the device and on the host, the planner's last routes),
-    so whoever runs it can tell a device server from a host one."""
+def open_sidecar(addr: str, backend: VerifyBackend | None = None, say=print) -> SidecarServer:
+    """What `python -m cometbft_tpu.sidecar` does once it has its address,
+    for every process that is to be a sidecar (the benchmark's is one):
+    a server over `backend`, or over what `get_backend()` assembles here,
+    bound and not yet serving, with the lines that say which tier and
+    device it resolved, so whoever runs it can tell a device server from a
+    host one."""
+    server = SidecarServer(addr, backend)
+    say(f"sidecar: serving on {server.bound_addr} (backend={server.backend.name})")
+    say(f"sidecar: backend {json.dumps(server.device_counters(), sort_keys=True)}")
+    return server
 
-    def backend_counters() -> str:
-        counters = getattr(server.backend, "counters", dict)
-        return json.dumps(counters(), sort_keys=True)
+
+def close_sidecar(server: SidecarServer, say=print) -> None:
+    """Says what the served tier did (lanes on the device and on the host,
+    the planner's last routes), what crossed the wire and, over a
+    supervised chain, what its supervisor counted; then stops the server."""
+    say(f"sidecar: stopping, backend {json.dumps(server.device_counters(), sort_keys=True)}")
+    say(f"sidecar: stopping, server {json.dumps(server.counters(), sort_keys=True)}")
+    chain = getattr(server.backend, "counters", dict)().get("inner", {})
+    if "chain" in chain:
+        events = {k: v for k, v in chain.items() if k != "tiers"}
+        say(f"sidecar: stopping, supervisor {json.dumps(events, sort_keys=True)}")
+    server.shutdown()
+
+
+def main() -> None:
+    """`python -m cometbft_tpu.sidecar`: serve until killed."""
 
     def on_term(signum, frame):
         raise SystemExit(0)
 
-    addr = os.environ.get("CMTPU_SIDECAR_ADDR", DEFAULT_ADDR)
-    server = SidecarServer(addr)
-    print(
-        f"sidecar: serving on {server.bound_addr} (backend={server.backend.name})",
-        flush=True,
-    )
-    print(f"sidecar: backend {backend_counters()}", flush=True)
+    def say(line: str) -> None:
+        print(line, flush=True)
+
+    # The address is this process's own listener: taken out of the
+    # environment, so that the chain assembled here gets no `grpc` tier
+    # that dials it.
+    addr = os.environ.pop("CMTPU_SIDECAR_ADDR", DEFAULT_ADDR)
+    server = open_sidecar(addr, say=say)
     signal.signal(signal.SIGTERM, on_term)
     try:
         if os.environ.get("CMTPU_SIDECAR_WARM", "1") == "1":
             warmed = server.warmup()
-            print(
-                "sidecar: warmup complete"
-                if warmed
-                else "sidecar: no device tier to warm",
-                flush=True,
-            )
+            say("sidecar: warmup complete" if warmed else "sidecar: no device tier to warm")
         server.serve_forever()
     except KeyboardInterrupt:
         pass
     finally:
-        print(f"sidecar: stopping, backend {backend_counters()}", flush=True)
+        close_sidecar(server, say=say)
 
 
 if __name__ == "__main__":

@@ -61,6 +61,6 @@ def test_the_metric_is_in_the_benchmark_once_and_names_its_cell():
     assert entry == {
         "name": NAME, "unit": "%", "better": "lower", "source": "program_counter",
         "layer": "hybrid planner", "moves": "commit_verify_p95_ms",
-        "workloads": ["commit10k-cold", "commit10k-cold-x4"],
+        "workloads": ["commit10k-cold", "commit10k-cold-x4", "commit10k-sidecar"],
     }
     assert [m["name"] for m in bench["per_layer"]].count(NAME) == 1

@@ -138,6 +138,58 @@ def test_off_never_imports_jax():
     assert out.stdout.strip().splitlines()[-1] == "False"
 
 
+def test_a_capture_is_a_session_a_process_without_jax_can_hold():
+    """Ring-only: under `trace.capture()` the sites record, nothing of JAX
+    is imported and no annotation is made; outside it they are off again."""
+    code = (
+        "import sys, chip_smoke\n"
+        "from cometbft_tpu.libs import trace\n"
+        "vals, commits = chip_smoke.make_commits(3, 8, 1, 'nojax')\n"
+        "bid, commit = commits[0]\n"
+        "with trace.capture():\n"
+        "    with trace.capture():\n"
+        "        pass\n"
+        "    vals.verify_commit(chip_smoke.CHAIN_ID, bid, commit.height, commit)\n"
+        "    trace.record('engine.queue_wait', 1.0, 2.0)\n"
+        "names = [s['name'] for s in trace.spans()]\n"
+        "assert names.count('validation.verify_commit') == 1 and 'batch.verify' in names\n"
+        "assert names[-1] == 'engine.queue_wait' and trace._annotation is None\n"
+        "chip_smoke.clear_verified_cache()\n"
+        "vals.verify_commit(chip_smoke.CHAIN_ID, bid, commit.height, commit)\n"
+        "trace.record('engine.queue_wait', 1.0, 2.0)\n"
+        "assert len(trace.spans()) == len(names)\n"
+        "assert trace.span('batch.verify') is trace.span('hybrid.call')\n"
+        "print('jax' in sys.modules)\n"
+    )
+    env = {k: v for k, v in os.environ.items() if not k.startswith("CMTPU_")}
+    env.update(CMTPU_BACKEND="cpu", JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == "False"
+
+
+def test_a_capture_beside_jax_writes_the_ring_and_makes_no_annotation():
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    with trace.capture():
+        with trace.span("hybrid.call", n=8) as call:
+            assert call._ann is None and trace.current() is call
+            call.backdate(call.t0 - 1.0)
+    assert trace.span("hybrid.call") is trace.span("batch.verify")  # closed: the no-op again
+    (one,) = trace.spans()
+    assert one["name"] == "hybrid.call" and one["t1"] - one["t0"] >= 1.0
+
+
+def test_perf_counter_is_one_clock_for_two_processes():
+    """What lets a node's spans and its sidecar's be merged by their times:
+    a child's reading, handed over a pipe, lies between two of the parent's."""
+    before = time.perf_counter()
+    out = subprocess.run([sys.executable, "-c", "import time; print(repr(time.perf_counter()))"],
+                         capture_output=True, text=True, timeout=60, check=True)
+    after = time.perf_counter()
+    assert before < float(out.stdout) < after
+
+
 # -- (b) on: one verify_commit ----------------------------------------------------
 
 
@@ -494,7 +546,7 @@ def _names_in_code():
 def _names_in_perf_md():
     with open(os.path.join(ROOT, "PERF.md")) as f:
         table = f.read().split("<!-- spans -->")[1].split("<!-- /spans -->")[0]
-    return set(re.findall(r"`((?:validation|blocksync|types|state|store|batch|engine|supervisor|hybrid|device)\.[a-z_]+)`", table))
+    return set(re.findall(r"`((?:validation|blocksync|types|state|store|batch|engine|supervisor|hybrid|device|grpc|sidecar)\.[a-z_]+)`", table))
 
 
 @pytest.mark.parametrize("name", trace.NAMES)
