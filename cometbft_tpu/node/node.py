@@ -546,6 +546,8 @@ class Node:
             ("bytes_sent", "Bytes of request frames written to the sidecar."),
             ("bytes_received", "Bytes of response frames read from the sidecar."),
             ("lanes_sent", "Signatures sent to the sidecar for verification."),
+            ("columns_fixed", "Columns sent to the sidecar at one stride."),
+            ("columns_ragged", "Columns sent to the sidecar with a lengths array."),
         ):
             reg.gauge_func("sidecar", key, text, sidecar_sample(key))
 

@@ -20,14 +20,30 @@ pipelined requests) carrying gRPC-style unary methods:
 Wire format: every frame is a 4-byte big-endian length + protobuf body.
   Request  { 1: id (uvarint), 2: method (string), 3: payload (bytes) }
   Response { 1: id (uvarint), 2: ok (bool), 3: error (string), 4: payload }
-  BatchVerifyReq  { 1..3: repeated pubs/msgs/sigs (bytes) }
+  BatchVerifyReq  { 1: pubs, 2: msgs, 3: sigs (Column each) }
+  Column          { 1: count (uvarint), 2: stride (uvarint),
+                    3: lengths (packed uvarint, as `repeated uint32`),
+                    4: data (bytes: the entries joined, in order) }
   BatchVerifyResp { 1: all_ok (bool), 2: bitmap (bytes, 1 byte per sig) }
   MerkleReq       { 1: repeated leaves (bytes) }
   MerkleResp      { 1: root (bytes) }
   WarmupReq       { 1: repeated buckets (uvarint) }
   PingResp        { 1: "pong", 2: mesh_width, 3: streaming, 4: chunk }
   ChunkReq        { 1: stream_id, 2: seq, 3: final (bool),
-                    4..6: repeated pubs/msgs/sigs (bytes) }
+                    4: pubs, 5: msgs, 6: sigs (Column each) }
+
+Columns (PR 31): the three columns of a batch cross the wire as three blobs,
+never a field a triple. Where every entry of a column has one length (keys
+at 32, signatures at 64) `stride` is that length and `lengths` is absent;
+else (a commit's sign bytes, whose timestamps encode to different sizes)
+`stride` is 0 and `lengths` holds `count` entries, two bytes each there.
+The encoder reads which from the column itself; an empty column, and one of
+empty entries, are stride 0. The decoder refuses, as the request's error
+response on a connection that survives: a stride with a lengths array,
+`count` x `stride` (or the lengths' sum) unequal to the blob's length to
+the byte, a lengths array of another count than `count` or with a length
+of over five bytes of varint, and three columns of different counts.
+Never a shorter, padded or reordered batch.
 
 Streaming (round 10): a large BatchVerify splits into mesh-width-aligned
 chunks, each sent as an ordinary framed request (its own id, so the
@@ -43,8 +59,7 @@ host route with the device idle. The FINAL chunk's response carries the
 whole stream's BatchVerifyResp; any chunk error fails the stream with an
 error response (never a partial bitmap). Capability-gated: servers
 advertise streaming in the Ping reply (field 3) and clients fall back to
-unary against old servers; old unary clients see a protocol identical to
-round 9's.
+unary against servers that do not.
 
 Running the device behind one process also serializes TPU access — a chip
 belongs to one process at a time, so N node processes on one host can only
@@ -62,7 +77,9 @@ Both ends trace themselves into `libs/trace.py`'s ring: the client a
 `grpc.call` a call (children `grpc.encode`, `grpc.wait`, `grpc.decode`),
 the server a `sidecar.request` a request (children `sidecar.decode`,
 `sidecar.encode`, and the chain's own spans); `req` is the id of the frame
-that carried the answer on both. Bytes and lanes are counted always
+that carried the answer on both, `ragged` on `grpc.encode` / `sidecar.decode`
+how many of the three columns went with a lengths array. Bytes, lanes and
+columns (`columns_fixed`, `columns_ragged`) are counted always
 (`GrpcBackend.counters()`, `SidecarServer.counters()`).
 """
 
@@ -87,6 +104,7 @@ from cometbft_tpu.sidecar.backend import (
 from cometbft_tpu.sidecar.engine import engine_of
 from cometbft_tpu.sidecar.scheduler import CoalescingScheduler, VerifyFuture
 from cometbft_tpu.wire import proto
+from cometbft_tpu.wire.columns import decode_columns, encode_columns
 
 DEFAULT_ADDR = "127.0.0.1:26670"
 DEFAULT_BUCKETS = (128, 1024, 10240)
@@ -179,6 +197,23 @@ def _encode_response(req_id: int, ok: bool, error: str, payload: bytes) -> bytes
         + proto.field_string(3, error)
         + proto.field_bytes(4, payload)
     )
+
+
+def _decode_response(body: bytes) -> bytes:
+    """A Response's payload; its error raised."""
+    fields = proto.decode_fields(body)
+    if not proto.get_bool(fields, 2):
+        raise RuntimeError(f"sidecar error: {proto.get_string(fields, 3)}")
+    return proto.get_bytes(fields, 4)
+
+
+def _decode_bitmap(payload: bytes, n: int) -> tuple[bool, list[bool]]:
+    """A BatchVerifyResp for `n` lanes: the whole bitmap or an error."""
+    fields = proto.decode_fields(payload)
+    bitmap = proto.get_bytes(fields, 2)
+    if len(bitmap) != n:
+        raise RuntimeError(f"sidecar answered {len(bitmap)} of {n} lanes")
+    return proto.get_bool(fields, 1), [bool(b) for b in bitmap]
 
 
 # -- server -------------------------------------------------------------------
@@ -386,10 +421,8 @@ class SidecarServer:
             # count, so client-side sizing (the coalescer's default merge
             # cap, chain pricing) sees the serving mesh, not the local
             # host's; field 3 advertises the chunked-streaming method and
-            # field 4 the server's preferred chunk size. Legacy clients
-            # that compared the raw body to b"pong" must upgrade with the
-            # server; new clients still accept a bare b"pong" from an old
-            # server (width defaults to 1, streaming to off).
+            # field 4 the server's preferred chunk size. A client still
+            # accepts a bare b"pong" (width 1, streaming off).
             width = 1
             mw = getattr(self.backend, "mesh_width", None)
             if mw is not None:
@@ -404,13 +437,9 @@ class SidecarServer:
                 + proto.field_varint(4, self._preferred_chunk())
             )
         if method == "BatchVerify":
-            with trace.span("sidecar.decode"):
-                fields = proto.decode_fields(payload)
-                pubs = proto.get_repeated_bytes(fields, 1)
-                msgs = proto.get_repeated_bytes(fields, 2)
-                sigs = proto.get_repeated_bytes(fields, 3)
-            if not (len(pubs) == len(msgs) == len(sigs)):
-                raise ValueError("pubs/msgs/sigs length mismatch")
+            with trace.span("sidecar.decode") as dec:
+                pubs, msgs, sigs, ragged = decode_columns(proto.decode_fields(payload), 1)
+                dec.set(ragged=ragged)
             sp.set(lanes=len(pubs))
             self._count(lanes_in=len(pubs))
             if not pubs:
@@ -450,16 +479,12 @@ class SidecarServer:
             sid = proto.get_uvarint(fields, 1)
             seq = proto.get_uvarint(fields, 2)
             final = proto.get_bool(fields, 3)
-            pubs = proto.get_repeated_bytes(fields, 4)
-            msgs = proto.get_repeated_bytes(fields, 5)
-            sigs = proto.get_repeated_bytes(fields, 6)
-            t1 = time.perf_counter()
             if seq == 0:
                 if sid in streams:
                     raise ValueError(f"stream {sid} already open")
                 if len(streams) >= 64:  # a leaking client must not hoard triples
                     raise ValueError("too many open streams on this connection")
-                # Only the decode says that a stream starts here: its span
+                # Only the envelope says that a stream starts here: its span
                 # opens now and takes the decode's start.
                 sp = trace.span("sidecar.request", method="BatchVerifyChunk").__enter__()
                 sp.backdate(t0)
@@ -467,15 +492,14 @@ class SidecarServer:
             st = streams.get(sid)
             if st is None:
                 raise ValueError(f"unknown stream {sid} (chunk seq {seq})")
-            trace.record("sidecar.decode", t0, t1, parent=st.span, seq=seq)
             st.bytes_in += n_in
             if seq != st.next_seq:
-                raise ValueError(
-                    f"stream {sid}: chunk seq {seq}, expected {st.next_seq}"
-                )
+                raise ValueError(f"stream {sid}: chunk seq {seq}, expected {st.next_seq}")
             st.next_seq += 1
-            if not (len(pubs) == len(msgs) == len(sigs)):
-                raise ValueError("pubs/msgs/sigs length mismatch")
+            # A column the decoder refuses fails the stream it belongs to.
+            pubs, msgs, sigs, ragged = decode_columns(fields, 4)
+            trace.record("sidecar.decode", t0, time.perf_counter(), parent=st.span,
+                         seq=seq, ragged=ragged)
             self._count(lanes_in=len(pubs))
             st.pubs += pubs
             st.msgs += msgs
@@ -619,6 +643,8 @@ class GrpcBackend(VerifyBackend):
             "bytes_sent": 0,      # frames written, their 4-byte lengths included
             "bytes_received": 0,  # response frames read by a waiter, likewise
             "lanes_sent": 0,      # triples written for verification
+            "columns_fixed": 0,   # columns encoded at one stride (three a call or a chunk)
+            "columns_ragged": 0,  # columns encoded with a lengths array
         }
 
     def _connect_locked(self) -> None:
@@ -756,13 +782,13 @@ class GrpcBackend(VerifyBackend):
 
     def _call(self, method: str, payload, lanes: int = 0, decode=None):
         """One unary call under its `grpc.call` span, two attempts. `payload`
-        is the request's bytes, or a function that encodes them (timed as
-        `grpc.encode`); `decode` turns the response's payload into the
-        answer (timed as `grpc.decode` with the envelope's)."""
+        is the request's bytes, or a function of the `grpc.encode` span
+        that encodes them under it; `decode` turns the response's payload
+        into the answer (timed as `grpc.decode` with the envelope's)."""
         with trace.span("grpc.call", method=method, lanes=lanes, chunks=1) as call:
             if callable(payload):
-                with trace.span("grpc.encode"):
-                    payload = payload()
+                with trace.span("grpc.encode") as enc:
+                    payload = payload(enc)
             sent = 0
             for attempt in (0, 1):
                 try:
@@ -777,10 +803,7 @@ class GrpcBackend(VerifyBackend):
                         raise
             call.set(req=req_id, bytes_out=sent, bytes_in=len(body) + _LEN.size)
             with trace.span("grpc.decode"):
-                fields = proto.decode_fields(body)
-                if not proto.get_bool(fields, 2):
-                    raise RuntimeError(f"sidecar error: {proto.get_string(fields, 3)}")
-                out = proto.get_bytes(fields, 4)
+                out = _decode_response(body)
                 return out if decode is None else decode(out)
 
     def ping(self) -> bool:
@@ -841,21 +864,17 @@ class GrpcBackend(VerifyBackend):
         with self._plock:
             self.counters_["unary_calls"] += 1
 
-        def encode() -> bytes:
-            return b"".join(
-                proto.field_bytes(1, p, emit_default=True) for p in pubs
-            ) + b"".join(
-                proto.field_bytes(2, m, emit_default=True) for m in msgs
-            ) + b"".join(
-                proto.field_bytes(3, s, emit_default=True) for s in sigs
-            )
+        return self._call("BatchVerify", lambda enc: self._encode_columns(enc, 1, pubs, msgs, sigs),
+                          lanes=n, decode=lambda out: _decode_bitmap(out, n))
 
-        def decode(out: bytes):
-            fields = proto.decode_fields(out)
-            bitmap = proto.get_bytes(fields, 2)
-            return proto.get_bool(fields, 1), [bool(b) for b in bitmap[:n]]
-
-        return self._call("BatchVerify", encode, lanes=n, decode=decode)
+    def _encode_columns(self, enc, first: int, pubs, msgs, sigs) -> bytes:
+        """`encode_columns` under the `grpc.encode` span `enc`, counted."""
+        payload, ragged = encode_columns(first, pubs, msgs, sigs)
+        enc.set(ragged=ragged)
+        with self._plock:
+            self.counters_["columns_fixed"] += 3 - ragged
+            self.counters_["columns_ragged"] += ragged
+        return payload
 
     def _batch_verify_streamed(self, pubs, msgs, sigs, chunk: int):
         """Chunked-streaming BatchVerify with the same two-attempt redial
@@ -877,11 +896,6 @@ class GrpcBackend(VerifyBackend):
                             raise
             finally:
                 call.set(**tally)
-
-    def _check_ack(self, body: bytes) -> None:
-        fields = proto.decode_fields(body)
-        if not proto.get_bool(fields, 2):
-            raise RuntimeError(f"sidecar error: {proto.get_string(fields, 3)}")
 
     @staticmethod
     def _stream_window() -> int:
@@ -918,20 +932,12 @@ class GrpcBackend(VerifyBackend):
 
         for seq in range(n_chunks):
             lo, hi = seq * chunk, min((seq + 1) * chunk, n)
-            with trace.span("grpc.encode", seq=seq):
+            with trace.span("grpc.encode", seq=seq) as enc:
                 payload = (
                     proto.field_varint(1, sid, emit_default=True)
                     + proto.field_varint(2, seq, emit_default=True)
                     + proto.field_bool(3, seq == n_chunks - 1)
-                    + b"".join(
-                        proto.field_bytes(4, p, emit_default=True) for p in pubs[lo:hi]
-                    )
-                    + b"".join(
-                        proto.field_bytes(5, m, emit_default=True) for m in msgs[lo:hi]
-                    )
-                    + b"".join(
-                        proto.field_bytes(6, s, emit_default=True) for s in sigs[lo:hi]
-                    )
+                    + self._encode_columns(enc, 4, pubs[lo:hi], msgs[lo:hi], sigs[lo:hi])
                 )
             # Windowed pipelining: at most `window` unacked chunks in
             # flight — the server is packing/dispatching chunk k while this
@@ -939,7 +945,7 @@ class GrpcBackend(VerifyBackend):
             # chunk k+window so a slow server applies backpressure instead
             # of buffering the whole batch in socket memory.
             if seq >= window:
-                self._check_ack(ack(seq - window))
+                _decode_response(ack(seq - window))
             slots.append(self._begin_call(
                 "BatchVerifyChunk", payload, pin_sock=pinned, lanes=hi - lo
             ))
@@ -951,19 +957,10 @@ class GrpcBackend(VerifyBackend):
             self.counters_["streamed_chunks"] += n_chunks
         with trace.span("grpc.wait"):
             for i in range(max(0, n_chunks - window), n_chunks - 1):
-                self._check_ack(ack(i))
+                _decode_response(ack(i))
             final = ack(n_chunks - 1)
         with trace.span("grpc.decode"):
-            fields = proto.decode_fields(final)
-            if not proto.get_bool(fields, 2):
-                raise RuntimeError(f"sidecar error: {proto.get_string(fields, 3)}")
-            out = proto.decode_fields(proto.get_bytes(fields, 4))
-            bitmap = proto.get_bytes(out, 2)
-            if len(bitmap) != n:
-                raise RuntimeError(
-                    f"sidecar stream answered {len(bitmap)} of {n} lanes"
-                )
-            result = proto.get_bool(out, 1), [bool(b) for b in bitmap]
+            result = _decode_bitmap(_decode_response(final), n)
         with self._plock:
             self.counters_["streamed_calls"] += 1
         return result
@@ -979,7 +976,7 @@ class GrpcBackend(VerifyBackend):
     def merkle_root(self, leaves):
         return self._call(
             "MerkleRoot",
-            lambda: b"".join(
+            lambda enc: b"".join(
                 proto.field_bytes(1, leaf, emit_default=True) for leaf in leaves
             ),
             decode=lambda out: proto.get_bytes(proto.decode_fields(out), 1),

@@ -505,27 +505,240 @@ def test_small_batches_stay_unary(monkeypatch, sidecar):
     assert c["unary_calls"] == 1 and c["streamed_calls"] == 0
 
 
-def test_legacy_unary_client_against_new_server(sidecar):
-    """A round-9 client knows nothing of BatchVerifyChunk: its unary
-    BatchVerify (now routed through the server-side scheduler) must still
-    verify correctly against the upgraded server."""
-    client, _ = sidecar
-    pubs, msgs, sigs = _signed_triples(24, tag=b"legacy", corrupt=(5,))
-    # The legacy wire call, byte-for-byte: one framed BatchVerify request.
+# -- the columnar payload (PR 31) ------------------------------------------------
+
+
+def _column(num, entries, *, count=None, stride=None, lengths=None, data=None):
+    """One Column field built from the schema in `service.py`'s docstring
+    with `proto`'s primitives alone, never by `encode_columns`; a keyword
+    overrides what the entries say, which is how a malformed one is made."""
     from cometbft_tpu.wire import proto
 
-    payload = b"".join(
-        proto.field_bytes(1, p, emit_default=True) for p in pubs
-    ) + b"".join(
-        proto.field_bytes(2, m, emit_default=True) for m in msgs
-    ) + b"".join(
-        proto.field_bytes(3, s, emit_default=True) for s in sigs
-    )
-    out = client._call("BatchVerify", payload)
+    sizes = {len(e) for e in entries}
+    if stride is None:
+        stride = sizes.pop() if len(sizes) == 1 else 0
+    if lengths is None and not stride:
+        lengths = [len(e) for e in entries]
+    body = proto.field_varint(1, len(entries) if count is None else count)
+    body += proto.field_varint(2, stride)
+    if lengths is not None:
+        if not isinstance(lengths, bytes):
+            lengths = b"".join(map(proto.encode_uvarint, lengths))
+        body += proto.field_bytes(3, lengths, emit_default=True)
+    body += proto.field_bytes(4, b"".join(entries) if data is None else data)
+    return proto.field_bytes(num, body, emit_default=True)
+
+
+def _payload(pubs, msgs, sigs, first=1, **bad):
+    """A BatchVerifyReq's three columns by hand; `bad` goes to the messages'."""
+    return _column(first, pubs) + _column(first + 1, msgs, **bad) + _column(first + 2, sigs)
+
+
+def _ragged_triples(n):
+    """Signed triples whose every column is ragged: lane 1 has a 31-byte
+    key, lane 2 an empty message (signed), lane 3 a 63-byte signature."""
+    pubs, msgs, sigs = _signed_triples(n, tag=b"ragged", corrupt=(0,))
+    pv = ed25519.gen_priv_key_from_secret(b"ragged")
+    msgs[2], sigs[2] = b"", pv.sign(b"")
+    pubs[1], sigs[3] = pubs[1][:31], sigs[3][:63]
+    return pubs, msgs, sigs
+
+
+COLUMN_CASES = {
+    # name: (pubs, msgs, sigs), columns that go with a lengths array
+    "empty": (([], [], []), 3),
+    "one-lane": (([b"p" * 32], [b"vote"], [b"s" * 64]), 0),
+    "all-messages-empty": (([b"p" * 32] * 3, [b""] * 3, [b"s" * 64] * 3), 1),
+    "one-message-empty": (([b"p" * 32] * 3, [b"a", b"", b"c"], [b"s" * 64] * 3), 1),
+    "31-byte-key-63-byte-signature": (
+        ([b"p" * 32, b"q" * 31], [b"mm", b"nn"], [b"s" * 63, b"t" * 64]), 2),
+    "10000-fixed": (
+        ([bytes([i % 251]) * 32 for i in range(10_000)],
+         [b"%06d" % i + b"v" * 134 for i in range(10_000)],
+         [bytes([i % 241]) * 64 for i in range(10_000)]), 0),
+    "10000-ragged-messages": (
+        ([b"p" * 32] * 10_000, [b"v" * (120 + i % 7) for i in range(10_000)],
+         [b"s" * 64] * 10_000), 1),
+}
+
+
+@pytest.mark.parametrize("name", COLUMN_CASES)
+@pytest.mark.parametrize("first", [1, 4], ids=["BatchVerifyReq", "ChunkReq"])
+def test_columns_round_trip_and_match_the_schema_built_by_hand(name, first):
+    from cometbft_tpu.wire import proto
+    from cometbft_tpu.wire.columns import decode_columns, encode_columns
+
+    (pubs, msgs, sigs), n_ragged = COLUMN_CASES[name]
+    payload, ragged = encode_columns(first, pubs, msgs, sigs)
+    assert ragged == n_ragged
+    got = decode_columns(proto.decode_fields(payload), first)
+    assert got == (pubs, msgs, sigs, n_ragged)
+    assert all(type(col) is list and all(type(e) is bytes for e in col) for col in got[:3])
+    # the hand-built payload decodes to the same batch, and a fixed column
+    # costs a header a column over its entries, not bytes a triple
+    assert decode_columns(proto.decode_fields(_payload(pubs, msgs, sigs, first)), first) == got
+    entries = sum(map(len, pubs)) + sum(map(len, msgs)) + sum(map(len, sigs))
+    assert len(payload) - entries <= 3 * 16 + 2 * len(pubs) * n_ragged
+
+
+def test_a_lengths_array_packs_and_unpacks_as_the_loop_over_uvarints_does():
+    """The array code against `proto`'s entry-by-entry codec, the reference."""
+    import random
+
+    import numpy as np
+
+    from cometbft_tpu.wire import proto
+    from cometbft_tpu.wire.columns import _pack_uvarints, _unpack_uvarints
+
+    rng = random.Random(31)
+    edges = [0, 1, 127, 128, 16_383, 16_384, 2**21 - 1, 2**21, 2**28 - 1, 2**28, 2**32 - 1, 2**35 - 1]
+    for n, pool in ((0, edges), (1, edges), (2, edges), (7, edges), (10_000, edges), (10_000, [138, 139])):
+        values = [rng.choice(pool + [rng.randrange(2**35)] * (pool is edges)) for _ in range(n)]
+        packed = b"".join(map(proto.encode_uvarint, values))
+        assert _pack_uvarints(np.array(values, np.int64)) == packed
+        assert _unpack_uvarints(packed, n).tolist() == values == proto.get_repeated_uvarint({3: [packed]}, 3)
+        for bad in (packed + b"\x81", packed + b"\x01", packed[:-1]):
+            if bad != packed[:0] or n:
+                with pytest.raises(ValueError, match=f"lengths for {n} entries"):
+                    _unpack_uvarints(bad, n)
+
+
+MALFORMED = {
+    # name: (overrides of the messages' column over 3 triples, the decoder's words)
+    "count-over": (dict(count=4), "4 x 2 is not its 6 bytes"),
+    "count-under": (dict(count=2), "2 x 2 is not its 6 bytes"),
+    "count-beyond-64-bits-of-bytes": (dict(count=(1 << 62) + 3), "is not its 6 bytes"),
+    "blob-shorter": (dict(data=b"aabbc"), "3 x 2 is not its 5 bytes"),
+    "blob-longer": (dict(data=b"aabbccd"), "3 x 2 is not its 7 bytes"),
+    "stride-with-lengths": (dict(lengths=[2, 2, 2]), "a stride and a lengths array"),
+    "lengths-sum-short": (dict(stride=0, lengths=[2, 2, 1]), "lengths do not sum to its 6 bytes"),
+    "lengths-sum-long": (dict(stride=0, lengths=[2, 2, 0xFFFFFFFF]), "lengths do not sum"),
+    "lengths-for-fewer": (dict(stride=0, lengths=[3, 3]), "2 lengths for 3 entries"),
+    "lengths-for-more": (dict(stride=0, lengths=[2, 2, 1, 1]), "4 lengths for 3 entries"),
+    "lengths-cut-short": (dict(stride=0, lengths=b"\x02\x02\x82"), "2 lengths for 3 entries"),
+    "length-of-six-bytes": (dict(stride=0, lengths=b"\x02\x02\x82\x80\x80\x80\x80\x00"),
+                            "a length of more than five bytes of varint"),
+    "counts-differ": (dict(count=2, data=b"aabb"), "pubs/msgs/sigs length mismatch"),
+}
+
+
+def _malformed_payload(name, first=1):
+    return _payload([b"p" * 32] * 3, [b"aa", b"bb", b"cc"], [b"s" * 64] * 3, first,
+                    **MALFORMED[name][0])
+
+
+@pytest.mark.parametrize("name", MALFORMED)
+def test_a_malformed_column_is_refused_by_the_decoder(name):
+    from cometbft_tpu.wire import proto
+    from cometbft_tpu.wire.columns import decode_columns
+
+    with pytest.raises(ValueError, match=MALFORMED[name][1]):
+        decode_columns(proto.decode_fields(_malformed_payload(name)), 1)
+
+
+@pytest.mark.parametrize("name", MALFORMED)
+def test_a_malformed_column_is_an_error_response_and_the_connection_serves_on(sidecar, name):
+    client, server = sidecar
+    assert client.ping()
+    sock = client._sock
+    with pytest.raises(RuntimeError, match="sidecar error: ValueError: .*" + MALFORMED[name][1]):
+        client._call("BatchVerify", _malformed_payload(name))
+    assert server.counters()["lanes_in"] == 0, "never a shorter, padded or reordered batch"
+    pubs, msgs, sigs = _signed_triples(5, tag=b"after", corrupt=(4,))
+    assert client.batch_verify(pubs, msgs, sigs) == (False, [True] * 4 + [False])
+    assert client._sock is sock, "the connection survived the refusal"
+    assert server.counters()["lanes_in"] == 5 and server.counters()["requests"] == 3
+
+
+def test_a_hand_built_unary_payload_is_served(sidecar):
+    """A client written from the schema alone (`_column`, no code of the
+    package's encoder) is answered lane for lane."""
+    from cometbft_tpu.wire import proto
+
+    client, _ = sidecar
+    pubs, msgs, sigs = _signed_triples(24, tag=b"by-hand", corrupt=(5,))
+    out = client._call("BatchVerify", _payload(pubs, msgs, sigs))
     fields = proto.decode_fields(out)
     bitmap = [bool(b) for b in proto.get_bytes(fields, 2)]
     assert not proto.get_bool(fields, 1)
     assert bitmap == [i != 5 for i in range(24)]
+
+
+@pytest.mark.parametrize("chunk", [64, 8], ids=["unary", "streamed"])
+def test_ragged_columns_are_answered_as_the_cpu_reference_answers_them(monkeypatch, sidecar, chunk):
+    """A 31-byte key, an empty message and a 63-byte signature ride as
+    ragged columns and come back false or true lane for lane, one frame or
+    five; the counters say how each column went."""
+    client, server = sidecar
+    monkeypatch.setenv("CMTPU_SIDECAR_CHUNK", str(chunk))
+    pubs, msgs, sigs = _ragged_triples(37)
+    ref = CpuBackend().batch_verify(pubs, msgs, sigs)
+    assert client.batch_verify(pubs, msgs, sigs) == ref
+    assert [i for i, b in enumerate(ref[1]) if not b] == [0, 1, 3]
+    c = client.counters()
+    frames = 1 if chunk == 64 else 5
+    assert (c["unary_calls"], c["streamed_chunks"]) == ((1, 0) if chunk == 64 else (0, 5))
+    assert c["columns_fixed"] + c["columns_ragged"] == 3 * frames
+    # the frame that holds lanes 1-3 has three ragged columns; of the later
+    # chunks only the second's messages differ in length ("ragged-8", "-9",
+    # "-10" ..), the rest go at one stride
+    assert c["columns_ragged"] == (3 if chunk == 64 else 3 + 1)
+    assert server.counters()["lanes_in"] == 37 == c["lanes_sent"]
+    # a batch of one stride a column: three fixed columns more
+    pubs, msgs, sigs = _signed_triples(6, tag=b"fixed!")
+    assert client.batch_verify(pubs, msgs, sigs) == (True, [True] * 6)
+    after = client.counters()
+    assert after["columns_fixed"] - c["columns_fixed"] == 3
+    assert after["columns_ragged"] == c["columns_ragged"]
+
+
+def test_the_column_counters_reach_metrics_beside_the_other_sidecar_gauges(sidecar):
+    from cometbft_tpu.libs.metrics import Registry
+    from cometbft_tpu.node.node import Node
+
+    client, _ = sidecar  # the fixture made it the process's backend
+    reg = Registry(namespace="cmt")
+    Node._register_backend_metrics(reg)
+    assert "cmt_sidecar_columns_fixed 0" in reg.render()
+    client.batch_verify(*_ragged_triples(6))
+    client.batch_verify(*_signed_triples(6, tag=b"fixed!"))
+    out = reg.render()
+    assert "cmt_sidecar_columns_fixed 3" in out and "cmt_sidecar_columns_ragged 3" in out
+    assert "cmt_sidecar_lanes_sent 12" in out
+
+
+def test_10000_lanes_cross_unary_and_streamed_in_the_order_sent(monkeypatch):
+    """The commit's size through both methods: the backend behind the
+    server sees the batch byte for byte, and the answer keeps its order."""
+
+    class Odd:
+        name = "odd"
+
+        def __init__(self):
+            self.calls = []
+
+        def batch_verify(self, pubs, msgs, sigs):
+            self.calls.append((pubs, msgs, sigs))
+            return False, [m[5] % 2 == 1 for m in msgs]
+
+    (pubs, msgs, sigs), _ = COLUMN_CASES["10000-fixed"]
+    server = SidecarServer("127.0.0.1:0", backend=Odd()).start()
+    client = GrpcBackend(server.bound_addr, timeout_s=30)
+    try:
+        assert client.ping() and client.chunk_size() == 1024
+        want = (False, [i % 2 == 1 for i in range(10_000)])
+        assert client.batch_verify(pubs, msgs, sigs) == want
+        monkeypatch.setenv("CMTPU_SIDECAR_CHUNK", str(1 << 20))
+        assert client.batch_verify(pubs, msgs, sigs) == want
+        assert server.backend.calls == [(pubs, msgs, sigs)] * 2
+        c = client.counters()
+        assert (c["streamed_chunks"], c["unary_calls"]) == (10, 1)
+        assert (c["columns_fixed"], c["columns_ragged"]) == (3 * 11, 0)
+        # 236 bytes a triple of payload, and under a byte a triple of everything else
+        assert 236 <= c["bytes_sent"] / c["lanes_sent"] < 236.1
+    finally:
+        client.close()
+        server.shutdown()
 
 
 def test_server_coalesces_across_connections(monkeypatch):

@@ -107,6 +107,10 @@ def test_a_node_without_jax_verifies_through_the_supervised_sidecar(chain_server
     assert grpc["streaming"] is True and grpc["remote_chunk"] == CHUNK
     assert grpc["streamed_calls"] >= 3 and grpc["unary_calls"] >= 2
     assert grpc["lanes_sent"] == 2 * validators + sum(sizes)
+    # three columns a frame that carried triples: a call, or a chunk of a stream
+    assert (grpc["columns_fixed"] + grpc["columns_ragged"]
+            == 3 * (grpc["unary_calls"] + grpc["streamed_chunks"]))
+    assert grpc["columns_fixed"] >= 2 * (grpc["unary_calls"] + grpc["streamed_chunks"]), "keys and signatures"
     after = server.counters()
     assert after["lanes_in"] - before["lanes_in"] == grpc["lanes_sent"]
     assert after["bytes_in"] - before["bytes_in"] == grpc["bytes_sent"]
@@ -151,34 +155,74 @@ def test_a_batch_reaches_the_backend_as_one_call_in_the_order_sent(n):
         assert (c["streamed_calls"], c["unary_calls"]) == ((1, 0) if n > 8 else (0, 1))
         assert c["streamed_chunks"] == (-(-n // 8) if n > 8 else 0)
         assert c["lanes_sent"] == n == server.counters()["lanes_in"]
+        assert (c["columns_fixed"], c["columns_ragged"]) == (3 * max(1, c["streamed_chunks"]), 0)
         assert server.counters()["requests"] == 2  # the Ping and the batch: a stream is one
     finally:
         client.close()
         server.shutdown()
 
 
+def _chunk(seq, final, columns=None, sid=1):
+    """A ChunkReq by hand: one triple at one stride unless `columns` is given."""
+    from cometbft_tpu.wire import proto
+    from tests.test_sidecar import _payload
+
+    if columns is None:
+        columns = _payload([b"p" * 32], [b"m"], [b"s" * 64], first=4)
+    return (proto.field_varint(1, sid, emit_default=True)
+            + proto.field_varint(2, seq, emit_default=True) + proto.field_bool(3, final) + columns)
+
+
 def test_a_failed_stream_is_an_error_and_is_counted():
     """Never a shorter bitmap: a chunk out of sequence tears the stream
     down, and the server counts it."""
-    from cometbft_tpu.wire import proto
-
     server = SidecarServer("127.0.0.1:0", backend=_Recording()).start()
     client = GrpcBackend(server.bound_addr, timeout_s=10)
     try:
-        def chunk(seq, final):
-            return (proto.field_varint(1, 1, emit_default=True)
-                    + proto.field_varint(2, seq, emit_default=True) + proto.field_bool(3, final)
-                    + proto.field_bytes(4, b"p" * 32) + proto.field_bytes(5, b"m")
-                    + proto.field_bytes(6, b"s" * 64))
-
-        assert client._call("BatchVerifyChunk", chunk(0, False)) == b""
+        assert client._call("BatchVerifyChunk", _chunk(0, False)) == b""
         with pytest.raises(RuntimeError, match="chunk seq 2, expected 1"):
-            client._call("BatchVerifyChunk", chunk(2, True))
+            client._call("BatchVerifyChunk", _chunk(2, True))
         with pytest.raises(RuntimeError, match="unknown stream 1"):
-            client._call("BatchVerifyChunk", chunk(1, True))
+            client._call("BatchVerifyChunk", _chunk(1, True))
         c = server.counters()
         assert c["streams_failed"] == 1 and c["lanes_in"] == 1 and c["requests"] == 2
         assert server.backend.calls == []
+    finally:
+        client.close()
+        server.shutdown()
+
+
+@pytest.mark.parametrize("at", [0, 1], ids=["first-chunk", "later-chunk"])
+@pytest.mark.parametrize("name", ["count-over", "blob-shorter", "blob-longer", "stride-with-lengths",
+                                  "lengths-sum-short", "lengths-for-fewer", "counts-differ"])
+def test_a_malformed_chunk_fails_its_stream_whole(name, at):
+    """A column the decoder refuses, in a stream's first chunk or a later
+    one: that chunk's error response, the stream gone with every lane it
+    held, `streams_failed` counted, nothing handed to the backend, and the
+    same connection then streams a batch to its whole bitmap."""
+    from tests.test_sidecar import MALFORMED, _malformed_payload
+
+    server = SidecarServer("127.0.0.1:0", backend=_Recording())
+    server._preferred_chunk = lambda: 8
+    server.start()
+    client = GrpcBackend(server.bound_addr, timeout_s=10)
+    try:
+        assert client.ping()
+        sock = client._sock
+        for seq in range(at):
+            assert client._call("BatchVerifyChunk", _chunk(seq, False, sid=9)) == b""
+        with pytest.raises(RuntimeError, match="sidecar error: ValueError: .*" + MALFORMED[name][1]):
+            client._call("BatchVerifyChunk", _chunk(at, False, _malformed_payload(name, first=4), sid=9))
+        with pytest.raises(RuntimeError, match="unknown stream 9"):
+            client._call("BatchVerifyChunk", _chunk(at + 1, True, sid=9))
+        c = server.counters()
+        assert c["streams_failed"] == 1 and c["lanes_in"] == at and c["requests"] == 3
+        assert server.backend.calls == []
+        pubs, msgs, sigs = [b"p" * 32] * 20, [b"m%d" % i for i in range(20)], [b"s" * 64] * 20
+        assert client.batch_verify(pubs, msgs, sigs) == (True, [True] * 20)
+        assert client._sock is sock and client.counters()["streamed_calls"] == 1
+        assert server.backend.calls == [(pubs, msgs, sigs)]
+        assert server.counters()["streams_failed"] == 1
     finally:
         client.close()
         server.shutdown()
@@ -246,6 +290,7 @@ def test_both_processes_record_one_request_under_a_capture_and_nothing_without(c
     assert [k["name"] for k in kids].count("grpc.encode") == 2  # one a chunk, never one a lane
     assert {k["name"] for k in kids} == {"grpc.encode", "grpc.wait", "grpc.decode"}
     assert all(call["t0"] <= k["t0"] and k["t1"] <= call["t1"] for k in kids)
+    encodes = [k for k in kids if k["name"] == "grpc.encode"]
     # the sidecar: sidecar.request > ... > hybrid.call, on the same clock
     req = _one(mine, "sidecar.request", req=call["attrs"]["req"], lanes=96)[0]
     assert req["attrs"]["method"] == "BatchVerifyChunk" and req["attrs"]["chunks"] == 2
@@ -255,6 +300,10 @@ def test_both_processes_record_one_request_under_a_capture_and_nothing_without(c
     under = [s for s in mine if s["root"] == req["id"]]
     names = [s["name"] for s in under]
     assert names.count("sidecar.decode") == 2 and names.count("sidecar.encode") == 1
+    # how many of a chunk's three columns went ragged: as written, so as read
+    decodes = sorted(_one(under, "sidecar.decode"), key=lambda s: s["attrs"]["seq"])
+    assert [d["attrs"]["ragged"] for d in decodes] == [e["attrs"]["ragged"] for e in encodes]
+    assert all(r in (0, 1) for r in (e["attrs"]["ragged"] for e in encodes)), "the sign bytes at most"
     for name in ("engine.queue_wait", "engine.dispatch", "supervisor.tier_call", "hybrid.call"):
         assert names.count(name) == 1, name
     hybrid = _one(under, "hybrid.call")[0]
