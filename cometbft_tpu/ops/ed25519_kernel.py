@@ -470,13 +470,23 @@ def clear_compiled_caches() -> None:
     jax.clear_caches()
 
 
+# Held while a batch is packed on the host: Python under the interpreter
+# lock, on the critical path of the dispatch it belongs to. A sidecar's
+# connection threads pass through it before they touch a frame
+# (sidecar/service.py `_answer`), so a stream that arrives while another
+# request's dispatch packs waits those few milliseconds instead of taking
+# them from the packer (PR 32: `device.pack` read 17-25 ms, not 6.5, with
+# three streams decoding beside it, and the amount moved from run to run).
+PACK_GATE = threading.Lock()
+
+
 def batch_verify_submit(pubs, msgs, sigs):
     """Pack on the calling thread, dispatch on the device-owner thread,
     return a collect() -> (ok, bitmap) closure. The hybrid backend runs its
     host MSM share between submit and collect; callers that want the
     blocking behavior just collect immediately (batch_verify below)."""
     n = len(pubs)
-    with trace.span("device.pack", lanes=n) as pack:
+    with trace.span("device.pack", lanes=n) as pack, PACK_GATE:
         operands, host_ok = pack_batch(pubs, msgs, sigs)
         key = _bucket_key(operands)
         pack.set(bucket=key[0])

@@ -29,6 +29,24 @@ run their device (vLLM/Orca continuous batching):
   bitmap slicing of the round-6 coalescer verbatim, and splits a failed
   merged dispatch into per-request retries so a poisoned request errors
   alone.
+* The merge cap is on the lanes a dispatch will RUN, not on the lanes its
+  requests offered (PR 32): queued requests that carry the same triples
+  (N nodes of one chain sent the same commit on N connections) cost the cap
+  their distinct lanes once. Sameness is read off a fingerprint of a
+  request's whole columns (`columns_fingerprint`), taken by the dispatcher
+  outside the lock and only when the queue offers more than the cap holds;
+  alone it sizes the dispatch and nothing else: in the pack a request is a
+  copy of an earlier one only if its columns compare equal entry for entry
+  (then it takes that one's lanes and the per-triple walk, which decides
+  every other shared lane, sees each distinct request once). So four
+  queued copies of one 10,000-signature commit are one 10,000-lane call on
+  a 16,384-lane chip, with no walk at all;
+  requests that overlap only in part are sized by what they offered (an
+  upper bound of their distinct lanes), two different 10,000-lane commits
+  still take two dispatches (a copy of what is already in costs nothing
+  and rides along, even past a request that did not fit; nothing that
+  adds lanes passes one), and a request is never joined to one already
+  in flight. No answer depends on who shared the dispatch.
 
 Callers tag their class either explicitly (`engine.submit(..., klass=...)`)
 or ambiently via `submission_class(...)` — a threadlocal the engine reads
@@ -38,9 +56,10 @@ blocksync windows). Untagged traffic is blocksync-class: the middle of the
 ladder, below votes, above opportunistic prewarm.
 
 Knobs: `CMTPU_ENGINE_HOLD_MS` (compat hold, default 0 = continuous),
-`CMTPU_ENGINE_MAX` (merge cap, default 16384 x mesh width, auto caps
-grow-only via refresh_cap), `CMTPU_ENGINE_STARVATION_MS` (promotion age,
-default 100), `CMTPU_ENGINE_DEADLINE_MS` (consensus admission deadline,
+`CMTPU_ENGINE_MAX` (merge cap on a dispatch's distinct lanes, default
+16384 x mesh width, auto caps grow-only via refresh_cap),
+`CMTPU_ENGINE_STARVATION_MS` (promotion age, default 100),
+`CMTPU_ENGINE_DEADLINE_MS` (consensus admission deadline,
 default `CMTPU_DEADLINE_MS` else 50), `CMTPU_ENGINE_RATE` /
 `CMTPU_ENGINE_OVERHEAD_MS` (fallback dispatch-wall model when no hybrid
 tier is present to read rates from).
@@ -127,6 +146,22 @@ def current_class() -> int:
     return CLASS_BLOCKSYNC if k is None else k
 
 
+def columns_fingerprint(pubs, msgs, sigs) -> int:
+    """What two requests that carry the same triples in the same order have
+    in common: a hash of their whole columns. It sizes a merged dispatch
+    (`_collect`) and, in the pack, names the earlier request a later one is
+    then compared with entry for entry; alone it decides no answer, so the
+    interpreter's keyed 64-bit hash is enough. It also leaves every entry's
+    hash cached for the pack's per-triple dedup."""
+    return hash((tuple(pubs), tuple(msgs), tuple(sigs)))
+
+
+def _same_columns(a: "_Request", b: "_Request") -> bool:
+    """The exact test the fingerprint only suggests: the same triples in the
+    same order, entry for entry."""
+    return a.pubs == b.pubs and a.msgs == b.msgs and a.sigs == b.sigs
+
+
 def engine_of(backend) -> "VerificationEngine | None":
     """The engine behind a backend, if one is active: the backend itself,
     or the one the CoalescingScheduler shim embeds. None for a bare chain
@@ -176,6 +211,7 @@ class VerifyFuture:
 class _Request:
     __slots__ = (
         "pubs", "msgs", "sigs", "future", "klass", "deadline", "t_start", "span",
+        "fingerprint",
     )
 
     def __init__(self, pubs, msgs, sigs, future, klass, deadline):
@@ -189,6 +225,8 @@ class _Request:
         # The submitter's open span: what the dispatcher thread does for
         # this request is traced as its child (libs/trace.py).
         self.span = trace.current()
+        # `columns_fingerprint` of the columns, once the dispatcher needed it.
+        self.fingerprint: int | None = None
 
 
 class VerificationEngine(VerifyBackend):
@@ -249,6 +287,7 @@ class VerificationEngine(VerifyBackend):
         self._class_wait: list[list[float]] = [[] for _ in range(_N_CLASSES)]
         self._class_wait_i = [0] * _N_CLASSES
         self._rate_cache: tuple[float, float] | None = None
+        self._fingerprint_ms = 0.0
         self.counters_ = {
             "requests": 0,
             "dispatches": 0,
@@ -409,60 +448,95 @@ class VerificationEngine(VerifyBackend):
         """Block until work exists; in compat-hold mode keep the window
         open for batchmates; then assemble the next dispatch: starvation
         promotions first (oldest first), then strict class priority, whole
-        requests only up to the deadline-aware cap (first always taken)."""
-        with self._cond:
-            while not self._have_work() and not self._closed:
-                self._cond.wait()
-            if not self._have_work():
-                return []
-            hold_s = self.hold_ms / 1000.0
-            first_t = min(q[0].future.t_submit for q in self._queues if q)
-            while hold_s > 0 and not self._closed:
-                if self._queued_sigs() >= self.max_sigs:
-                    break
-                remaining = first_t + hold_s - time.perf_counter()
-                if remaining <= 0:
-                    break
-                self._cond.wait(remaining)
-            now = time.perf_counter()
-            cap = self._deadline_cap(now)
-            # Starvation escape hatch: requests older than starvation_ms
-            # jump the class ladder (oldest first). Ages are monotone
-            # within a FIFO queue, so only each queue's stale prefix needs
-            # checking.
-            starv_s = self.starvation_ms / 1000.0
-            promoted: list[_Request] = []
-            if self.starvation_ms > 0:
-                for q in self._queues:
-                    for r in q:
-                        if now - r.future.t_submit >= starv_s:
-                            promoted.append(r)
-                        else:
-                            break
-                promoted.sort(key=lambda r: r.future.t_submit)
-            promoted_ids = {id(r) for r in promoted}
-            order = promoted + [
-                r
-                for klass in range(_N_CLASSES)
-                for r in self._queues[klass]
-                if id(r) not in promoted_ids
-            ]
-            batch: list[_Request] = []
-            total = 0
-            for req in order:
-                n = len(req.pubs)
-                if batch and total + n > cap:
-                    break
-                if id(req) in promoted_ids and any(
-                    self._queues[k] for k in range(req.klass)
-                ):
-                    # Promotion only counts when the escape hatch actually
-                    # bypassed fresher higher-class work.
-                    self.class_counters_[req.klass]["starvation_promotions"] += 1
-                self._queues[req.klass].remove(req)
-                total += n
-                batch.append(req)
-            return batch
+        requests only while the lanes the dispatch will run fit the
+        deadline-aware cap (first always taken). Requests that carry the
+        same columns cost the cap once; where the queue offers more than
+        the cap, the requests not yet fingerprinted are, outside the lock
+        (nothing per-lane runs under it), and the queue is read again."""
+        fingerprinted = False
+        self._fingerprint_ms = 0.0  # the dispatcher's own: what sizing this dispatch cost
+        while True:
+            with self._cond:
+                while not self._have_work() and not self._closed:
+                    self._cond.wait()
+                if not self._have_work():
+                    return []
+                hold_s = self.hold_ms / 1000.0
+                first_t = min(q[0].future.t_submit for q in self._queues if q)
+                while hold_s > 0 and not self._closed:
+                    if self._queued_sigs() >= self.max_sigs:
+                        break
+                    remaining = first_t + hold_s - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    self._cond.wait(remaining)
+                now = time.perf_counter()
+                cap = self._deadline_cap(now)
+                order, promoted_ids = self._drain_order(now)
+                blank = [r for r in order if r.fingerprint is None]
+                crowded = len(order) > 1 and self._queued_sigs() > cap
+                if fingerprinted or not (crowded and blank):
+                    return self._take(order, promoted_ids, cap)
+            t0 = time.perf_counter()
+            for req in blank:
+                req.fingerprint = columns_fingerprint(req.pubs, req.msgs, req.sigs)
+            self._fingerprint_ms = (time.perf_counter() - t0) * 1000.0
+            fingerprinted = True  # later arrivals count whole this time round
+
+    def _drain_order(self, now: float) -> tuple[list[_Request], set[int]]:
+        """The queued requests in the order a dispatch takes them, and the
+        ids of those the starvation escape hatch promoted: requests older
+        than starvation_ms jump the class ladder (oldest first). Ages are
+        monotone within a FIFO queue, so only each queue's stale prefix
+        needs checking."""
+        starv_s = self.starvation_ms / 1000.0
+        promoted: list[_Request] = []
+        if self.starvation_ms > 0:
+            for q in self._queues:
+                for r in q:
+                    if now - r.future.t_submit >= starv_s:
+                        promoted.append(r)
+                    else:
+                        break
+            promoted.sort(key=lambda r: r.future.t_submit)
+        promoted_ids = {id(r) for r in promoted}
+        order = promoted + [
+            r
+            for klass in range(_N_CLASSES)
+            for r in self._queues[klass]
+            if id(r) not in promoted_ids
+        ]
+        return order, promoted_ids
+
+    def _take(
+        self, order: list[_Request], promoted_ids: set[int], cap: int
+    ) -> list[_Request]:
+        """Unqueue the head of `order` that fits `cap`. A fingerprint seen
+        earlier in the batch costs nothing (its lanes are already in), so
+        such a request rides along even past one that did not fit; nothing
+        that adds lanes passes a request that did not fit."""
+        batch: list[_Request] = []
+        seen: set[int] = set()
+        total = 0
+        full = False
+        for req in order:
+            again = req.fingerprint is not None and req.fingerprint in seen
+            n = 0 if again else len(req.pubs)
+            if not again and (full or (batch and total + n > cap)):
+                full = True
+                continue
+            if id(req) in promoted_ids and any(
+                self._queues[k] for k in range(req.klass)
+            ):
+                # Promotion only counts when the escape hatch actually
+                # bypassed fresher higher-class work.
+                self.class_counters_[req.klass]["starvation_promotions"] += 1
+            self._queues[req.klass].remove(req)
+            total += n
+            if req.fingerprint is not None:
+                seen.add(req.fingerprint)
+            batch.append(req)
+        return batch
 
     def _loop(self) -> None:
         while True:
@@ -490,6 +564,8 @@ class VerificationEngine(VerifyBackend):
             lanes=sum(len(r.pubs) for r in batch),
             klass=CLASS_NAMES[batch[0].klass],
         ) as call:
+            if self._fingerprint_ms:
+                call.set(fingerprint_ms=round(self._fingerprint_ms, 3))
             self._dispatch_traced(batch, call)
 
     def _dispatch_traced(self, batch: list[_Request], call) -> None:
@@ -520,6 +596,7 @@ class VerificationEngine(VerifyBackend):
             # Nothing to slice or protect: serve the lone request directly
             # (errors propagate to its caller alone).
             req = batch[0]
+            call.set(unique=len(req.pubs))
             try:
                 req.future._set_result(
                     self.inner.batch_verify(req.pubs, req.msgs, req.sigs)
@@ -529,31 +606,48 @@ class VerificationEngine(VerifyBackend):
             return
         # Columnar pack with within-batch dedup: identical triples from
         # concurrent requests (N light clients walking the same descent)
-        # share one lane.
+        # share one lane. A request with the fingerprint AND the columns of
+        # one before it in the batch is its copy (the fingerprint finds the
+        # candidate, the comparison decides) and takes that one's lanes, so
+        # the per-triple walk sees each distinct request once, and copies
+        # of one request alone (N nodes of one chain) skip it.
         with trace.span("engine.merge", phase="pack"):
-            lane_of: dict[tuple, int] = {}
-            pubs: list[bytes] = []
-            msgs: list[bytes] = []
-            sigs: list[bytes] = []
-            lanes: list[list[int]] = []
+            kinds: list[_Request] = []  # the batch's distinct requests, in order
+            kind_of: list[int] = []     # each request's place among them
+            first_with: dict[int, int] = {}
             for req in batch:
-                req_lanes = []
-                for p, m, s in zip(req.pubs, req.msgs, req.sigs):
-                    key = (p, s, m)
-                    lane = lane_of.get(key)
-                    if lane is None:
-                        lane = len(pubs)
-                        lane_of[key] = lane
-                        pubs.append(p)
-                        msgs.append(m)
-                        sigs.append(s)
-                    req_lanes.append(lane)
-                lanes.append(req_lanes)
+                k = None
+                if req.fingerprint is not None:
+                    k = first_with.setdefault(req.fingerprint, len(kinds))
+                if k is None or k == len(kinds) or not _same_columns(req, kinds[k]):
+                    k = len(kinds)
+                    kinds.append(req)
+                kind_of.append(k)
+            if len(kinds) == 1:
+                pubs, msgs, sigs = kinds[0].pubs, kinds[0].msgs, kinds[0].sigs
+                lanes = [range(len(pubs))]
+            else:
+                lane_of: dict[tuple, int] = {}
+                pubs, msgs, sigs = [], [], []
+                lanes = []
+                for req in kinds:
+                    req_lanes = []
+                    for p, m, s in zip(req.pubs, req.msgs, req.sigs):
+                        key = (p, s, m)
+                        lane = lane_of.get(key)
+                        if lane is None:
+                            lane = len(pubs)
+                            lane_of[key] = lane
+                            pubs.append(p)
+                            msgs.append(m)
+                            sigs.append(s)
+                        req_lanes.append(lane)
+                    lanes.append(req_lanes)
             dedup = sum(len(r.pubs) for r in batch) - len(pubs)
             if dedup:
                 with self._cond:
                     self.counters_["dedup_sigs"] += dedup
-        call.set(dedup=dedup)
+        call.set(dedup=dedup, unique=len(pubs))
         try:
             _, bits = self.inner.batch_verify(pubs, msgs, sigs)
         except BaseException:
@@ -565,8 +659,8 @@ class VerificationEngine(VerifyBackend):
             self._fallback(batch)
             return
         with trace.span("engine.merge", phase="slice"):
-            for req, req_lanes in zip(batch, lanes):
-                req_bits = [bits[lane] for lane in req_lanes]
+            for req, k in zip(batch, kind_of):
+                req_bits = [bits[lane] for lane in lanes[k]]
                 req.future._set_result((all(req_bits), req_bits))
 
     def _fallback(self, batch: list[_Request]) -> None:

@@ -69,7 +69,8 @@ share it through a server like this. What `python -m cometbft_tpu.sidecar`
 supervised one every node runs in process (engine -> `ResilientBackend`
 (`hybrid` -> `cpu`)): one assembly, with its deadline, breakers and cpu
 anchor, and ONE engine, in which concurrent CONNECTIONS coalesce into
-single columnar dispatches with per-request bitmap slicing. A server
+single columnar dispatches with per-request bitmap slicing (the merge cap
+is on a dispatch's distinct lanes: `sidecar/engine.py`). A server
 handed a bare backend (a test's, a fanout shard worker's) puts its own
 CoalescingScheduler over the device lock in front of it, as before.
 
@@ -77,10 +78,14 @@ Both ends trace themselves into `libs/trace.py`'s ring: the client a
 `grpc.call` a call (children `grpc.encode`, `grpc.wait`, `grpc.decode`),
 the server a `sidecar.request` a request (children `sidecar.decode`,
 `sidecar.encode`, and the chain's own spans); `req` is the id of the frame
-that carried the answer on both, `ragged` on `grpc.encode` / `sidecar.decode`
+that carried the answer on both. Request ids are each connection's own, so
+the connection goes with them: `port` on a `grpc.call` is its socket's local
+port, `conn` on a `sidecar.request` the peer's port, the same number.
+`ragged` on `grpc.encode` / `sidecar.decode`
 how many of the three columns went with a lengths array. Bytes, lanes and
 columns (`columns_fixed`, `columns_ragged`) are counted always
-(`GrpcBackend.counters()`, `SidecarServer.counters()`).
+(`GrpcBackend.counters()`, `SidecarServer.counters()`), as are the server's
+connections (`connections_accepted`, `connections_open`).
 """
 
 from __future__ import annotations
@@ -237,6 +242,21 @@ class _ServerStream:
         self.bytes_out = 0
 
 
+def _after_host_pack() -> None:
+    """A connection's thread lets a host pack in progress finish before it
+    decodes its frame: both are Python under one interpreter lock, the pack
+    is on the critical path of the dispatch in flight, and the frame's
+    request waits for that dispatch anyway. Never imports jax: no device
+    tier loaded, no gate."""
+    import sys
+
+    ek = sys.modules.get("cometbft_tpu.ops.ed25519_kernel")
+    gate = getattr(ek, "PACK_GATE", None)
+    if gate is not None:
+        with gate:
+            pass
+
+
 def _device_tier(backend):
     """The tier under `backend` that holds the device, for the warm-up and
     for the lines that say what is served: the backend itself when it is
@@ -260,7 +280,15 @@ class SidecarServer:
     `get_backend()` assembles in this process — under `CMTPU_BACKEND=auto`
     the supervised chain with its one engine, in which concurrent
     connections (many node processes sharing one chip) merge into single
-    columnar dispatches with per-request bitmap slicing. A bare backend
+    columnar dispatches with per-request bitmap slicing. What merges is what
+    is queued together when the device frees and fits the cap (16,384 lanes
+    a chip) by its DISTINCT lanes: four nodes of one chain that sent the
+    same 10,000-signature commit are one 10,000-lane call, each answered
+    with its own whole bitmap in its own order; two different commits of
+    that size still take a dispatch each, and a request that arrives while
+    the same lanes are in flight waits for the next dispatch. While the
+    device tier packs a dispatch on the host, the other connections' threads
+    wait with their frames (`_after_host_pack`). A bare backend
     handed in gets this server's own CoalescingScheduler over the device
     lock in front of it (CMTPU_COALESCE=0 strips it). Socket handling is
     one thread per connection, so hosts can pipeline requests like the
@@ -291,6 +319,7 @@ class SidecarServer:
             "bytes_out": 0,       # frames written, likewise
             "lanes_in": 0,        # triples received for verification
             "streams_failed": 0,  # streams torn down by an error
+            "connections_accepted": 0,  # over the server's life
         }
         self._conns: set[socket.socket] = set()  # open connections (under _count_lock)
         self._serving = threading.Event()
@@ -300,9 +329,12 @@ class SidecarServer:
         class Handler(socketserver.BaseRequestHandler):
             def handle(self):
                 sock = self.request
-                conn = {"streams": {}}  # per-connection stream table
+                # per-connection: the stream table, and the peer's port,
+                # which tells its requests' spans from another connection's
+                conn = {"streams": {}, "port": self.client_address[1]}
                 with outer._count_lock:
                     outer._conns.add(sock)
+                    outer.counters_["connections_accepted"] += 1
                 try:
                     self._serve(sock, conn)
                 finally:
@@ -347,9 +379,10 @@ class SidecarServer:
                 self.counters_[key] += d
 
     def counters(self) -> dict:
-        """What crossed this server's wire since it started."""
+        """What crossed this server's wire since it started, and over how
+        many connections."""
         with self._count_lock:
-            return dict(self.counters_)
+            return {**self.counters_, "connections_open": len(self._conns)}
 
     def _answer(self, body: bytes, conn: dict) -> bytes:
         """One request frame to the body of its response frame. Faults are
@@ -358,6 +391,7 @@ class SidecarServer:
         chunk and closes with its answer."""
         n_in = len(body) + _LEN.size
         req_id = 0
+        _after_host_pack()
         try:
             fields = proto.decode_fields(body)
             req_id = proto.get_uvarint(fields, 1)
@@ -368,8 +402,9 @@ class SidecarServer:
             self._count(requests=1, bytes_in=n_in, bytes_out=len(resp) + _LEN.size)
             return resp
         if method == "BatchVerifyChunk":
-            return self._answer_chunk(req_id, payload, conn["streams"], n_in)
-        with trace.span("sidecar.request", method=method, req=req_id, bytes_in=n_in) as sp:
+            return self._answer_chunk(req_id, payload, conn, n_in)
+        with trace.span("sidecar.request", method=method, req=req_id, conn=conn["port"],
+                        bytes_in=n_in) as sp:
             try:
                 resp = _encode_response(req_id, True, "", self._dispatch(method, payload, conn, sp))
             except Exception as e:
@@ -464,13 +499,14 @@ class SidecarServer:
             return b""
         raise ValueError(f"unknown method {method!r}")
 
-    def _answer_chunk(self, req_id: int, payload: bytes, streams: dict, n_in: int) -> bytes:
+    def _answer_chunk(self, req_id: int, payload: bytes, conn: dict, n_in: int) -> bytes:
         """One chunk of a streamed BatchVerify (module docstring: ChunkReq)
         to the body of its response frame. A non-final chunk is decoded,
         kept, and acked at once. The final chunk submits the whole stream as
         ONE call, all its lanes in the order sent, and its response is the
         stream's BatchVerifyResp. Any failure tears the stream down and
         surfaces as this chunk's error response — never a partial bitmap."""
+        streams = conn["streams"]
         st = None
         final = False
         try:
@@ -486,7 +522,8 @@ class SidecarServer:
                     raise ValueError("too many open streams on this connection")
                 # Only the envelope says that a stream starts here: its span
                 # opens now and takes the decode's start.
-                sp = trace.span("sidecar.request", method="BatchVerifyChunk").__enter__()
+                sp = trace.span("sidecar.request", method="BatchVerifyChunk",
+                                conn=conn["port"]).__enter__()
                 sp.backdate(t0)
                 streams[sid] = _ServerStream(sp)
             st = streams.get(sid)
@@ -585,6 +622,15 @@ class SidecarServer:
 
 
 # -- client -------------------------------------------------------------------
+
+
+def _local_port(sock: socket.socket | None) -> int:
+    """This end's port of a connection: what the server's spans name it by
+    (`conn`); 0 for a socket already closed."""
+    try:
+        return sock.getsockname()[1]
+    except (AttributeError, OSError):
+        return 0
 
 
 class GrpcBackend(VerifyBackend):
@@ -801,7 +847,8 @@ class GrpcBackend(VerifyBackend):
                     self._drop_failed(e)
                     if attempt:
                         raise
-            call.set(req=req_id, bytes_out=sent, bytes_in=len(body) + _LEN.size)
+            call.set(req=req_id, port=_local_port(slot[2]), bytes_out=sent,
+                     bytes_in=len(body) + _LEN.size)
             with trace.span("grpc.decode"):
                 out = _decode_response(body)
                 return out if decode is None else decode(out)
@@ -914,7 +961,8 @@ class GrpcBackend(VerifyBackend):
     def _stream_once(self, pubs, msgs, sigs, chunk: int, tally: dict):
         """One attempt at a stream, on one connection. `tally` takes what
         the enclosing `grpc.call` span says of it: the bytes of its frames
-        both ways, its chunks, and `req`, the id of the final chunk's frame."""
+        both ways, its chunks, `req`, the id of the final chunk's frame, and
+        `port`, the connection's local port."""
         n = len(pubs)
         with self._plock:
             self._next_stream += 1
@@ -953,6 +1001,7 @@ class GrpcBackend(VerifyBackend):
             if pinned is None:
                 pinned = slots[0][0][2]
         tally["req"] = slots[-1][1]
+        tally["port"] = _local_port(pinned)
         with self._plock:
             self.counters_["streamed_chunks"] += n_chunks
         with trace.span("grpc.wait"):

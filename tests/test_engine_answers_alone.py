@@ -1,0 +1,245 @@
+"""Requests that share a dispatch (ISSUE 32): N overlapping requests through
+one engine give each request exactly the answer it would get alone.
+
+The yardstick is that sentence in code, `benchmarks/reference/answers_alone.py`
+(each lane by the scalar ZIP-215 reference; no engine, no dedup, no
+batching). The engine under test runs over the host tier (`CpuBackend`) with
+a small cap; the requests are signed by OpenSSL and queued together behind a
+wedged dispatch, so what merges is decided by the cap alone: a merged
+dispatch is sized by the lanes it will run, and requests that carry the same
+columns cost the cap their distinct lanes once. CPU only, small sizes."""
+
+from __future__ import annotations
+
+import os
+import random
+import sys
+import threading
+
+import pytest
+from cryptography.hazmat.primitives.asymmetric.ed25519 import Ed25519PrivateKey
+
+from cometbft_tpu.crypto import ed25519
+from cometbft_tpu.libs import trace
+from cometbft_tpu.sidecar.backend import CpuBackend, VerifyBackend
+from cometbft_tpu.sidecar.engine import VerificationEngine, columns_fingerprint
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+
+from reference.answers_alone import answers_alone  # noqa: E402
+
+pytestmark = pytest.mark.engine
+
+LANES = 20  # a request
+CAP = 32    # the engine's: two requests offer more than it holds
+
+
+@pytest.fixture(autouse=True)
+def clean_cache():
+    ed25519._verified.clear()
+    yield
+    ed25519._verified.clear()
+
+
+@pytest.fixture
+def profiler_ring():
+    trace.clear()
+    with trace.capture():
+        yield
+    trace.clear()
+
+
+def _signed(seed: int, n: int = LANES):
+    """n triples signed by OpenSSL, a key a lane, from `seed`."""
+    rng = random.Random(seed)
+    pubs, msgs, sigs = [], [], []
+    for i in range(n):
+        key = Ed25519PrivateKey.from_private_bytes(rng.randbytes(32))
+        msg = b"precommit/%d/%d/" % (seed, i) + rng.randbytes(90 + i % 7)
+        pubs.append(key.public_key().public_bytes_raw())
+        msgs.append(msg)
+        sigs.append(key.sign(msg))
+    return pubs, msgs, sigs
+
+
+def _fresh(req):
+    """The same columns as new objects, as each connection's decoder makes them."""
+    return tuple([bytes(bytearray(x)) for x in col] for col in req)
+
+
+def _flipped(req, seed: int, k: int = 3):
+    pubs, msgs, sigs = _fresh(req)
+    for i in random.Random(seed).sample(range(len(sigs)), k):
+        sigs[i] = sigs[i][:7] + bytes([sigs[i][7] ^ 0x10]) + sigs[i][8:]
+    return pubs, msgs, sigs
+
+
+def _reordered(req, seed: int):
+    order = list(range(len(req[0])))
+    random.Random(seed).shuffle(order)
+    return tuple([col[i] for i in order] for col in _fresh(req))
+
+
+def _cases():
+    a, b, c = (_signed(s) for s in (1, 2, 3))
+    half = LANES // 2
+    overlap = tuple(x[half:] + y[:half] for x, y in zip(a, b))  # a's tail, then b's head
+    small = [_signed(s, 8) for s in (5, 6, 7, 8)]
+    return {
+        # name: (requests, the lanes of each dispatch that carries them)
+        "identical-x4": ([_fresh(a) for _ in range(4)], [LANES]),
+        "identical-x3-and-a-flipped-copy": (
+            [_fresh(a), _flipped(a, 32), _fresh(a), _fresh(a)], [LANES, LANES]),
+        "disjoint": (small, [32]),
+        "partly-overlapping": ([tuple(col[:12] for col in _fresh(a)),
+                                tuple(col[6:18] for col in _fresh(a))], [18]),
+        "same-triples-another-order": ([_fresh(a), _reordered(a, 5)], [LANES, LANES]),
+        "offered-over-the-cap-distinct-under-it": (
+            [_fresh(a), _fresh(a), _fresh(a), tuple(col[:10] for col in _fresh(b))], [30]),
+        "distinct-over-the-cap": ([_fresh(a), _fresh(b), _fresh(c)], [LANES, LANES, LANES]),
+        # a copy of what is already in costs nothing, so it may pass a request that did not fit
+        "two-chains-interleaved": ([_fresh(a), _fresh(b), _fresh(a), _fresh(b)], [LANES, LANES]),
+        "an-overlap-is-sized-by-what-it-offered": ([_fresh(a), overlap], [LANES, LANES]),
+    }
+
+
+CASES = _cases()
+
+
+class _Gate(VerifyBackend):
+    """The host tier behind a gate: the first call (a blocker's) waits until
+    the test has queued every request, so they are collected together; every
+    call's lanes are recorded."""
+
+    name = "gate"
+
+    def __init__(self):
+        self.cpu = CpuBackend()
+        self.release = threading.Event()
+        self.entered = threading.Event()
+        self.calls: list[int] = []
+
+    def batch_verify(self, pubs, msgs, sigs):
+        if not self.entered.is_set():
+            self.entered.set()
+            assert self.release.wait(30)
+            return True, [True] * len(pubs)
+        self.calls.append(len(pubs))
+        return self.cpu.batch_verify(pubs, msgs, sigs)
+
+
+def _through_one_engine(requests, cap=CAP, gate=None):
+    """Every request's answer with all of them queued together, the lanes of
+    the dispatches that carried them, and the engine's counters."""
+    gate = gate or _Gate()
+    eng = VerificationEngine(gate, hold_ms=0.0, max_sigs=cap, starvation_ms=0.0)
+    try:
+        blocker = eng.submit([b"b" * 32], [b"blocker"], [b"s" * 64])
+        assert gate.entered.wait(10)
+        futs = [eng.submit(*r) for r in requests]
+        gate.release.set()
+        blocker.result(30)
+        return [f.result(60) for f in futs], gate.calls, eng.counters()
+    finally:
+        gate.release.set()
+        eng.close()
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_every_request_gets_the_answer_it_would_get_alone(name):
+    requests, want_calls = CASES[name]
+    answers, calls, counters = _through_one_engine(requests)
+    assert answers == answers_alone(requests)
+    assert calls == want_calls, "what merged is decided by the distinct lanes and the cap"
+    assert all(n <= CAP for n in calls)
+    assert counters["dedup_sigs"] == sum(len(r[0]) for r in requests) - sum(calls)
+    assert counters["dispatches"] == 1 + len(calls)  # the blocker's first
+
+
+def test_four_copies_over_the_cap_leave_collect_as_one_batch_and_one_call(profiler_ring):
+    """The deployment's case at a small size: 4 x 20 lanes offered against a
+    cap of 32, 20 distinct: one dispatch, its span saying so."""
+    requests, _ = CASES["identical-x4"]
+    answers, calls, counters = _through_one_engine(requests)
+    assert calls == [LANES] and answers == answers_alone(requests)
+    assert counters["dispatches"] == 2 and counters["coalesced_dispatches"] == 1  # the blocker's, then one
+    assert counters["batched_requests"] == 4 and counters["dedup_sigs"] == 3 * LANES
+    merged = [s for s in trace.spans() if s["name"] == "engine.dispatch" and s["attrs"]["requests"] > 1]
+    assert [(s["attrs"]["requests"], s["attrs"]["lanes"], s["attrs"]["unique"], s["attrs"]["dedup"])
+            for s in merged] == [(4, 4 * LANES, LANES, 3 * LANES)]
+    lone = [s for s in trace.spans() if s["name"] == "engine.dispatch" and s["attrs"]["requests"] == 1]
+    assert [s["attrs"]["unique"] for s in lone] == [1], "a lone request runs what it offered"
+    assert merged[0]["attrs"]["fingerprint_ms"] > 0 and "fingerprint_ms" not in lone[0]["attrs"]
+    phases = sorted(s["attrs"]["phase"] for s in trace.spans() if s["name"] == "engine.merge")
+    assert phases == ["pack", "slice"]
+
+
+def test_a_copy_is_one_only_if_its_columns_compare_equal(monkeypatch):
+    """The fingerprint finds the candidate, the comparison decides: with
+    every fingerprint made equal, requests that differ are still walked
+    triple by triple and every answer is still the one it would get alone;
+    true copies are compared once each and skip the walk."""
+    import cometbft_tpu.sidecar.engine as engine_mod
+
+    compared = []
+    real = engine_mod._same_columns
+    monkeypatch.setattr(engine_mod, "_same_columns", lambda a, b: compared.append(1) or real(a, b))
+    requests, _ = CASES["identical-x4"]
+    answers, calls, _ = _through_one_engine(requests)
+    assert calls == [LANES] and answers == answers_alone(requests) and len(compared) == 3
+    monkeypatch.setattr(engine_mod, "columns_fingerprint", lambda *cols: 7)
+    requests, _ = CASES["offered-over-the-cap-distinct-under-it"]
+    del compared[:]
+    answers, calls, counters = _through_one_engine(requests)
+    assert answers == answers_alone(requests)
+    assert calls == [30] and len(compared) == 3, "two copies, and one request that only hashes alike"
+    requests = [CASES["identical-x4"][0][0], _flipped(CASES["identical-x4"][0][0], 32)]
+    answers, calls, _ = _through_one_engine(requests, cap=64)  # they fit as offered: no fingerprint
+    assert answers == answers_alone(requests) and calls == [LANES + 3]
+    answers, calls, _ = _through_one_engine(requests)  # over the cap, and they hash alike
+    assert answers == answers_alone(requests) and calls == [LANES + 3], "sized wrong, answered right"
+
+
+def test_no_fingerprint_is_taken_where_the_queue_fits_the_cap(monkeypatch):
+    """A lone request, and requests that fit the cap as offered, pay nothing:
+    the fingerprint is taken only where the queue offers more than the cap."""
+    import cometbft_tpu.sidecar.engine as engine_mod
+
+    taken = []
+    real = engine_mod.columns_fingerprint
+    monkeypatch.setattr(engine_mod, "columns_fingerprint",
+                        lambda *cols: taken.append(len(cols[0])) or real(*cols))
+    small = [_signed(s, 8) for s in (5, 6)]
+    answers, calls, _ = _through_one_engine(small)
+    assert calls == [16] and taken == [] and answers == answers_alone(small)
+    requests, _ = CASES["identical-x4"]
+    _through_one_engine(requests)
+    assert taken == [LANES] * 4, "once a request, outside the lock"
+
+
+def test_a_failed_merged_dispatch_still_falls_back_to_each_request_alone():
+    """The guarantee that does not move: a merged dispatch that fails is
+    retried request by request, and each still gets its own answer."""
+    requests = [_fresh(CASES["identical-x4"][0][0]), _flipped(CASES["identical-x4"][0][0], 9),
+                _fresh(CASES["identical-x4"][0][0])]
+
+    class FailsMerged(_Gate):
+        def batch_verify(self, pubs, msgs, sigs):
+            if self.entered.is_set() and not self.calls:
+                self.calls.append(-len(pubs))
+                raise RuntimeError("the merged call fails")
+            return super().batch_verify(pubs, msgs, sigs)
+
+    answers, calls, counters = _through_one_engine(requests, cap=64, gate=FailsMerged())
+    assert answers == answers_alone(requests)
+    assert calls == [-(LANES + 3), LANES, LANES, LANES]
+    assert counters["fallback_splits"] == 1
+
+
+def test_the_fingerprint_is_of_the_whole_columns_in_their_order():
+    a = _signed(1)
+    assert columns_fingerprint(*a) == columns_fingerprint(*_fresh(a))
+    assert columns_fingerprint(*a) != columns_fingerprint(*_reordered(a, 5))
+    assert columns_fingerprint(*a) != columns_fingerprint(*_flipped(a, 32, k=1))
+    assert columns_fingerprint(*a) != columns_fingerprint(*(col[:-1] for col in a))

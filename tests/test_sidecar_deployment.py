@@ -12,6 +12,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import pytest
 
@@ -318,6 +319,111 @@ def test_both_processes_record_one_request_under_a_capture_and_nothing_without(c
     trace.clear()
     got = _node(server.bound_addr, validators=96, sizes=[CHUNK])
     assert got["spans"] == [] and trace.spans() == []
+
+
+# -- (d2) four nodes of one chain on four connections (ISSUE 32) -----------------------
+
+
+class _SlowCpu(be.CpuBackend):
+    """The host tier, slow enough that what arrives during a call queues."""
+
+    name = "slow-cpu"
+
+    def batch_verify(self, pubs, msgs, sigs):
+        time.sleep(0.4)
+        return super().batch_verify(pubs, msgs, sigs)
+
+
+def test_four_clients_sending_one_commit_get_the_answers_they_would_get_alone(monkeypatch):
+    """One real server, four real clients on four connections sending the
+    same 96-signature commit at once, one of them a copy with flipped
+    signatures, against a merge cap of 128 lanes (two requests offer more):
+    every bitmap equals `answers_alone`, the copies shared a dispatch, and
+    each side's spans name the connection."""
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    from reference.answers_alone import answers_alone
+
+    monkeypatch.setenv("CMTPU_COALESCE_MAX", "128")
+    vals, commits = chip_smoke.make_commits(32, 96, 1, "four-nodes")
+    _, commit = commits[0]
+
+    def columns(c):
+        return ([v.pub_key.bytes() for v in vals.validators],
+                [bytes(sb) for sb in c.vote_sign_bytes_all(chip_smoke.CHAIN_ID)],
+                [s.signature for s in c.signatures])
+
+    requests = [columns(commit), columns(chip_smoke.flip_signatures(commit, [5, 40])),
+                columns(commit), columns(commit)]
+    server = SidecarServer("127.0.0.1:0", backend=_SlowCpu())
+    server._preferred_chunk = lambda: CHUNK
+    server.start()
+    clients = [GrpcBackend(server.bound_addr, timeout_s=60) for _ in requests]
+    answers = [None] * len(requests)
+    start = threading.Barrier(len(requests))
+
+    def node(k):
+        start.wait(10)
+        answers[k] = clients[k].batch_verify(*requests[k])
+
+    trace.clear()
+    try:
+        assert all(c.ping() for c in clients)
+        before = server.scheduler_counters()
+        with trace.capture():
+            threads = [threading.Thread(target=node, args=(k,)) for k in range(len(requests))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+        spans = trace.spans()
+        assert answers == answers_alone(requests)
+        assert [ok for ok, _ in answers] == [True, False, True, True]
+        assert [j for j, b in enumerate(answers[1][1]) if not b] == [5, 40]
+        served, after = server.counters(), server.scheduler_counters()
+        assert served["lanes_in"] == sum(c.counters()["lanes_sent"] for c in clients) == 4 * 96
+        assert served["connections_accepted"] == 4 == served["connections_open"]
+        assert after["coalesced_dispatches"] > before["coalesced_dispatches"]
+        assert after["dedup_sigs"] - before["dedup_sigs"] >= 96, "two copies at least shared their lanes"
+        assert after["dispatches"] - before["dispatches"] < len(requests)
+        # the spans of the two sides are joined by connection and request id
+        calls = _one(spans, "grpc.call", method="BatchVerifyChunk")
+        asked = _one(spans, "sidecar.request", method="BatchVerifyChunk")
+        assert len(calls) == len(asked) == 4
+        ports = [c["attrs"]["port"] for c in calls]
+        assert len(set(ports)) == 4 and 0 not in ports
+        assert sorted((r["attrs"]["conn"], r["attrs"]["req"]) for r in asked) == sorted(
+            (c["attrs"]["port"], c["attrs"]["req"]) for c in calls)
+        assert len({c["attrs"]["req"] for c in calls}) == 1, "in lock-step the ids alone are equal"
+        dispatches = _one(spans, "engine.dispatch")
+        assert all(d["attrs"]["unique"] <= 128 for d in dispatches)
+        assert any(d["attrs"]["requests"] >= 2 and d["attrs"]["lanes"] > 128 for d in dispatches)
+    finally:
+        for c in clients:
+            c.close()
+        server.shutdown()
+    assert server.counters()["connections_accepted"] == 4
+
+
+def test_a_frame_waits_for_a_host_pack_in_progress():
+    """While the device tier packs a dispatch on the host (its `PACK_GATE`
+    held), a connection's thread does not decode: the frame is answered once
+    the pack is over, and at once where no pack runs."""
+    from cometbft_tpu.ops import ed25519_kernel as ek
+
+    server = SidecarServer("127.0.0.1:0", backend=_Recording()).start()
+    client = GrpcBackend(server.bound_addr, timeout_s=10)
+    answered = threading.Event()
+    try:
+        assert client.ping()
+        with ek.PACK_GATE:
+            t = threading.Thread(target=lambda: client.ping() and answered.set())
+            t.start()
+            assert not answered.wait(0.3), "answered while a pack was in progress"
+        assert answered.wait(10)
+        t.join(10)
+    finally:
+        client.close()
+        server.shutdown()
 
 
 # -- (e) one assembly -----------------------------------------------------------------

@@ -476,7 +476,8 @@ def test_two_requests_in_one_dispatch_leave_engine_merge(profiler):
     merges = [s for s in spans if s["name"] == "engine.merge"]
     assert sorted(s["attrs"]["phase"] for s in merges) == ["pack", "slice"]
     dispatch = next(s for s in spans if s["name"] == "engine.dispatch")
-    assert dispatch["attrs"] == {"requests": 2, "lanes": 4, "klass": "blocksync", "dedup": 2}
+    assert dispatch["attrs"] == {"requests": 2, "lanes": 4, "klass": "blocksync", "dedup": 2,
+                                 "unique": 2}
     assert dispatch["parent"] == mine.id and all(s["parent"] == dispatch["id"] for s in merges)
     waits = [s for s in spans if s["name"] == "engine.queue_wait"]
     assert len(waits) == 2 and all(s["parent"] == mine.id for s in waits)
