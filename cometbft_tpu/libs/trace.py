@@ -84,6 +84,7 @@ NAMES = (
     "batch.dispatch",
     "batch.cache_insert",
     "engine.queue_wait",
+    "engine.join",
     "engine.dispatch",
     "engine.merge",
     # supervisor

@@ -45,8 +45,21 @@ run their device (vLLM/Orca continuous batching):
   upper bound of their distinct lanes), two different 10,000-lane commits
   still take two dispatches (a copy of what is already in costs nothing
   and rides along, even past a request that did not fit; nothing that
-  adds lanes passes one), and a request is never joined to one already
-  in flight. No answer depends on who shared the dispatch.
+  adds lanes passes one).
+* A request that arrives while the same columns are IN FLIGHT takes that
+  dispatch's answer (PR 33, in-flight join): while the chain runs a
+  dispatch the engine publishes what it carries (its distinct requests and
+  each one's lanes in the dispatched columns); `submit()` holds a new
+  request against each in O(1) (lane count, first and last triple) and only
+  one that passes is compared entry for entry, outside the lock, on the
+  submitter's thread. Equal, and the dispatch still in flight: the request
+  is attached to it and answered from its bitmap when it returns, never
+  queued for a second run of the same lanes. Whatever differs in a byte, in
+  order or in length is queued as before; nothing is kept past the
+  dispatch. A dispatch that fails retries the requests that joined it with
+  its batch, each alone. So four nodes of one chain cost the chip one run a
+  height, whenever they arrive. No answer depends on who shared the
+  dispatch.
 
 Callers tag their class either explicitly (`engine.submit(..., klass=...)`)
 or ambiently via `submission_class(...)` — a threadlocal the engine reads
@@ -162,6 +175,38 @@ def _same_columns(a: "_Request", b: "_Request") -> bool:
     return a.pubs == b.pubs and a.msgs == b.msgs and a.sigs == b.sigs
 
 
+def _may_be_copy(a: "_Request", b: "_Request") -> bool:
+    """What a copy has for certain, in O(1): the lane count, the first and
+    the last triple. Decides no answer: it spares `_same_columns` where the
+    columns plainly differ."""
+    return len(a.pubs) == len(b.pubs) and all(
+        x[:1] == y[:1] and x[-1:] == y[-1:]
+        for x, y in ((a.sigs, b.sigs), (a.pubs, b.pubs), (a.msgs, b.msgs))
+    )
+
+
+class _InFlight:
+    """What the dispatcher publishes while the chain runs a dispatch: the
+    distinct requests it carries, each one's lanes in the dispatched
+    columns, and the requests that joined it since, each as (request, its
+    kind). `kinds` and `lanes` never change; `joined` only under `_cond`
+    and only while the record is the one published."""
+
+    __slots__ = ("kinds", "lanes", "joined")
+
+    def __init__(self, kinds, lanes):
+        self.kinds = kinds
+        self.lanes = lanes
+        self.joined: list[tuple[_Request, int]] = []
+
+    def kind_of_copy(self, req: "_Request") -> int | None:
+        """The place among `kinds` of the request `req` is a copy of."""
+        for k, kind in enumerate(self.kinds):
+            if _may_be_copy(req, kind) and _same_columns(req, kind):
+                return k
+        return None
+
+
 def engine_of(backend) -> "VerificationEngine | None":
     """The engine behind a backend, if one is active: the backend itself,
     or the one the CoalescingScheduler shim embeds. None for a bare chain
@@ -211,7 +256,7 @@ class VerifyFuture:
 class _Request:
     __slots__ = (
         "pubs", "msgs", "sigs", "future", "klass", "deadline", "t_start", "span",
-        "fingerprint",
+        "fingerprint", "compare_ms",
     )
 
     def __init__(self, pubs, msgs, sigs, future, klass, deadline):
@@ -227,6 +272,23 @@ class _Request:
         self.span = trace.current()
         # `columns_fingerprint` of the columns, once the dispatcher needed it.
         self.fingerprint: int | None = None
+        # What comparing it with the request in flight took, where it joined
+        # that dispatch (then it never stood in the queue).
+        self.compare_ms: float | None = None
+
+    def answer(self, result=None, error: BaseException | None = None) -> None:
+        """Resolves the future. A request that joined a dispatch in flight
+        first records its `engine.join`, submission to answer."""
+        if self.compare_ms is not None:
+            trace.record(
+                "engine.join", self.future.t_submit, time.perf_counter(),
+                parent=self.span, lanes=len(self.pubs),
+                compare_ms=round(self.compare_ms, 3),
+            )
+        if error is not None:
+            self.future._set_error(error)
+        else:
+            self.future._set_result(result)
 
 
 class VerificationEngine(VerifyBackend):
@@ -288,13 +350,16 @@ class VerificationEngine(VerifyBackend):
         self._class_wait_i = [0] * _N_CLASSES
         self._rate_cache: tuple[float, float] | None = None
         self._fingerprint_ms = 0.0
+        self._in_flight: _InFlight | None = None  # published while the chain runs
         self.counters_ = {
             "requests": 0,
             "dispatches": 0,
             "coalesced_dispatches": 0,  # dispatches carrying >1 request
             "batched_requests": 0,      # requests that shared a dispatch
             "coalesced_sigs": 0,        # sigs that rode a shared dispatch
-            "dedup_sigs": 0,            # lanes saved by within-batch dedup
+            "dedup_sigs": 0,            # lanes offered that no dispatch ran again
+            "joined_requests": 0,       # answered by a dispatch already in flight
+            "joined_sigs": 0,           # their lanes
             "fallback_splits": 0,       # coalesced dispatches split on error
         }
         self.class_counters_ = [
@@ -330,11 +395,24 @@ class VerificationEngine(VerifyBackend):
             else None
         )
         req = _Request(list(pubs), list(msgs), list(sigs), fut, klass, deadline)
+        # Read without the lock: a record that has ended, or one published
+        # a moment later, only means the request is queued as ever; the
+        # join itself is decided under the lock, on the record's identity.
+        flying = self._in_flight
+        kind = None
+        if flying is not None:
+            t0 = time.perf_counter()
+            kind = flying.kind_of_copy(req)
+            compare_ms = (time.perf_counter() - t0) * 1000.0
         with self._cond:
             if self._closed:
                 raise RuntimeError("engine is closed")
             self.counters_["requests"] += 1
             self.class_counters_[klass]["admitted"] += 1
+            if kind is not None and self._in_flight is flying:
+                req.compare_ms = compare_ms
+                flying.joined.append((req, kind))
+                return fut
             self._queues[klass].append(req)
             self._ensure_thread()
             self._cond.notify_all()
@@ -569,14 +647,8 @@ class VerificationEngine(VerifyBackend):
             self._dispatch_traced(batch, call)
 
     def _dispatch_traced(self, batch: list[_Request], call) -> None:
-        shared = len(batch) > 1
         with self._cond:
             self.counters_["dispatches"] += 1
-            for req in batch:
-                req.future.shared = shared
-                self.class_counters_[req.klass]["dispatched_sigs"] += len(
-                    req.pubs
-                )
             refresh = self._cap_auto and self.counters_["dispatches"] % 64 == 1
         if refresh:
             # Cheap cached-width read (no dial): pick up a remote pod's
@@ -585,35 +657,89 @@ class VerificationEngine(VerifyBackend):
                 self.refresh_cap()
             except Exception:
                 pass
+        if len(batch) > 1:
+            kinds, kind_of, (pubs, msgs, sigs), lanes = self._pack(batch)
+        else:
+            # Nothing to pack: the lone request's columns as they stand.
+            req = batch[0]
+            kinds, kind_of = [req], [0]
+            pubs, msgs, sigs = req.pubs, req.msgs, req.sigs
+            lanes = [range(len(pubs))]
+        call.set(unique=len(pubs))
+        # While the chain runs, a copy of a request this dispatch carries
+        # joins it (`submit`) and is answered with the batch below.
+        flying = _InFlight(kinds, lanes)
         with self._cond:
+            self._in_flight = flying
+        answer = bits = error = None
+        try:
+            answer = self.inner.batch_verify(pubs, msgs, sigs)
+            bits = answer[1]
+        except BaseException as e:
+            error = e
+        with self._cond:
+            self._in_flight = None  # nothing joins from here
+            # Those that did are the batch's now: sliced with it, retried
+            # with it, and `_loop`'s last resort finds them in it.
+            joined = len(flying.joined)
+            batch += [req for req, _ in flying.joined]
+            kind_of += [k for _, k in flying.joined]
+            shared = len(batch) > 1
+            offered = 0
+            for req in batch:
+                req.future.shared = shared
+                offered += len(req.pubs)
+                self.class_counters_[req.klass]["dispatched_sigs"] += len(
+                    req.pubs
+                )
+            dedup = offered - len(pubs)  # lanes offered that this dispatch ran once
             if shared:
                 self.counters_["coalesced_dispatches"] += 1
                 self.counters_["batched_requests"] += len(batch)
-                self.counters_["coalesced_sigs"] += sum(
-                    len(r.pubs) for r in batch
+                self.counters_["coalesced_sigs"] += offered
+                self.counters_["dedup_sigs"] += dedup
+                self.counters_["joined_requests"] += joined
+                self.counters_["joined_sigs"] += sum(
+                    len(req.pubs) for req, _ in flying.joined
                 )
         if not shared:
-            # Nothing to slice or protect: serve the lone request directly
-            # (errors propagate to its caller alone).
-            req = batch[0]
-            call.set(unique=len(req.pubs))
-            try:
-                req.future._set_result(
-                    self.inner.batch_verify(req.pubs, req.msgs, req.sigs)
-                )
-            except BaseException as e:
-                req.future._set_error(e)
+            # Nothing to slice or protect: the lone request gets the
+            # chain's answer as it is (errors propagate to its caller alone).
+            if error is not None:
+                batch[0].future._set_error(error)
+            else:
+                batch[0].future._set_result(answer)
             return
-        # Columnar pack with within-batch dedup: identical triples from
-        # concurrent requests (N light clients walking the same descent)
-        # share one lane. A request with the fingerprint AND the columns of
-        # one before it in the batch is its copy (the fingerprint finds the
-        # candidate, the comparison decides) and takes that one's lanes, so
-        # the per-triple walk sees each distinct request once, and copies
-        # of one request alone (N nodes of one chain) skip it.
+        call.set(dedup=dedup)
+        if joined:
+            call.set(requests=len(batch), lanes=offered, joined=joined)
+        if error is not None or len(bits) != len(pubs):
+            # A sick tier answering with the wrong shape is a failed
+            # dispatch, not something to mis-slice.
+            self._fallback(batch)
+            return
+        with trace.span("engine.merge", phase="slice"):
+            kind_bits: dict[int, list[bool]] = {}  # copies of a request share one walk
+            for req, k in zip(batch, kind_of):
+                if k not in kind_bits:
+                    kind_bits[k] = [bits[lane] for lane in lanes[k]]
+                req_bits = list(kind_bits[k])
+                req.answer((all(req_bits), req_bits))
+
+    def _pack(self, batch: list[_Request]):
+        """Columnar pack with within-batch dedup: identical triples from
+        concurrent requests (N light clients walking the same descent)
+        share one lane. A request with the fingerprint AND the columns of
+        one before it in the batch is its copy (the fingerprint finds the
+        candidate, the comparison decides) and takes that one's lanes, so
+        the per-triple walk sees each distinct request once, and copies
+        of one request alone (N nodes of one chain) skip it. Gives the
+        batch's distinct requests in order, each request's place among
+        them, the columns to dispatch and each distinct request's lanes in
+        them."""
         with trace.span("engine.merge", phase="pack"):
-            kinds: list[_Request] = []  # the batch's distinct requests, in order
-            kind_of: list[int] = []     # each request's place among them
+            kinds: list[_Request] = []
+            kind_of: list[int] = []
             first_with: dict[int, int] = {}
             for req in batch:
                 k = None
@@ -624,44 +750,25 @@ class VerificationEngine(VerifyBackend):
                     kinds.append(req)
                 kind_of.append(k)
             if len(kinds) == 1:
-                pubs, msgs, sigs = kinds[0].pubs, kinds[0].msgs, kinds[0].sigs
-                lanes = [range(len(pubs))]
-            else:
-                lane_of: dict[tuple, int] = {}
-                pubs, msgs, sigs = [], [], []
-                lanes = []
-                for req in kinds:
-                    req_lanes = []
-                    for p, m, s in zip(req.pubs, req.msgs, req.sigs):
-                        key = (p, s, m)
-                        lane = lane_of.get(key)
-                        if lane is None:
-                            lane = len(pubs)
-                            lane_of[key] = lane
-                            pubs.append(p)
-                            msgs.append(m)
-                            sigs.append(s)
-                        req_lanes.append(lane)
-                    lanes.append(req_lanes)
-            dedup = sum(len(r.pubs) for r in batch) - len(pubs)
-            if dedup:
-                with self._cond:
-                    self.counters_["dedup_sigs"] += dedup
-        call.set(dedup=dedup, unique=len(pubs))
-        try:
-            _, bits = self.inner.batch_verify(pubs, msgs, sigs)
-        except BaseException:
-            self._fallback(batch)
-            return
-        if len(bits) != len(pubs):
-            # A sick tier answering with the wrong shape is a failed
-            # dispatch, not something to mis-slice.
-            self._fallback(batch)
-            return
-        with trace.span("engine.merge", phase="slice"):
-            for req, k in zip(batch, kind_of):
-                req_bits = [bits[lane] for lane in lanes[k]]
-                req.future._set_result((all(req_bits), req_bits))
+                one = kinds[0]
+                return kinds, kind_of, (one.pubs, one.msgs, one.sigs), [range(len(one.pubs))]
+            lane_of: dict[tuple, int] = {}
+            pubs, msgs, sigs = [], [], []
+            lanes = []
+            for req in kinds:
+                req_lanes = []
+                for p, m, s in zip(req.pubs, req.msgs, req.sigs):
+                    key = (p, s, m)
+                    lane = lane_of.get(key)
+                    if lane is None:
+                        lane = len(pubs)
+                        lane_of[key] = lane
+                        pubs.append(p)
+                        msgs.append(m)
+                        sigs.append(s)
+                    req_lanes.append(lane)
+                lanes.append(req_lanes)
+            return kinds, kind_of, (pubs, msgs, sigs), lanes
 
     def _fallback(self, batch: list[_Request]) -> None:
         """The merged dispatch failed: retry each request alone so one
@@ -671,11 +778,9 @@ class VerificationEngine(VerifyBackend):
             self.counters_["fallback_splits"] += 1
         for req in batch:
             try:
-                req.future._set_result(
-                    self.inner.batch_verify(req.pubs, req.msgs, req.sigs)
-                )
+                req.answer(self.inner.batch_verify(req.pubs, req.msgs, req.sigs))
             except BaseException as e:
-                req.future._set_error(e)
+                req.answer(error=e)
 
     # -- observability -----------------------------------------------------
 

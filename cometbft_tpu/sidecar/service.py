@@ -285,8 +285,11 @@ class SidecarServer:
     a chip) by its DISTINCT lanes: four nodes of one chain that sent the
     same 10,000-signature commit are one 10,000-lane call, each answered
     with its own whole bitmap in its own order; two different commits of
-    that size still take a dispatch each, and a request that arrives while
-    the same lanes are in flight waits for the next dispatch. While the
+    that size still take a dispatch each. A request that arrives while the
+    same columns are in flight joins that dispatch and takes its answer
+    (compared entry for entry on this connection's thread, inside
+    `submit`), so the four nodes cost the chip one run a height whenever
+    they arrive. While the
     device tier packs a dispatch on the host, the other connections' threads
     wait with their frames (`_after_host_pack`). A bare backend
     handed in gets this server's own CoalescingScheduler over the device

@@ -7,7 +7,13 @@ batching). The engine under test runs over the host tier (`CpuBackend`) with
 a small cap; the requests are signed by OpenSSL and queued together behind a
 wedged dispatch, so what merges is decided by the cap alone: a merged
 dispatch is sized by the lanes it will run, and requests that carry the same
-columns cost the cap their distinct lanes once. CPU only, small sizes."""
+columns cost the cap their distinct lanes once.
+
+ISSUE 33 adds the in-flight join: a request submitted while a dispatch that
+carries the same columns is in flight is answered by that dispatch; what
+differs in a byte, in order or in length is queued as before. Here the
+dispatch in flight is held at a stop in the host tier (`_Stops`) while the
+later requests are submitted. CPU only, small sizes."""
 
 from __future__ import annotations
 
@@ -15,6 +21,7 @@ import os
 import random
 import sys
 import threading
+import time
 
 import pytest
 from cryptography.hazmat.primitives.asymmetric.ed25519 import Ed25519PrivateKey
@@ -243,3 +250,237 @@ def test_the_fingerprint_is_of_the_whole_columns_in_their_order():
     assert columns_fingerprint(*a) != columns_fingerprint(*_reordered(a, 5))
     assert columns_fingerprint(*a) != columns_fingerprint(*_flipped(a, 32, k=1))
     assert columns_fingerprint(*a) != columns_fingerprint(*(col[:-1] for col in a))
+
+
+# -- a request that arrives while the same columns are in flight (ISSUE 33) ---------
+
+
+class _Stops(VerifyBackend):
+    """The host tier with stops: a call whose number (from 0) is in `stops`
+    says that it is in flight and waits there until the test lets it go;
+    `spoil` then makes of call 0 a dispatch that fails. Every call's lanes
+    are recorded."""
+
+    name = "stops"
+
+    def __init__(self, stops=(0,), spoil=None, failing_calls=()):
+        self.cpu = CpuBackend()
+        self.in_flight = {i: threading.Event() for i in stops}
+        self.go = {i: threading.Event() for i in stops}
+        self.spoil = spoil
+        self.failing_calls = failing_calls
+        self.calls: list[int] = []
+
+    def batch_verify(self, pubs, msgs, sigs):
+        i = len(self.calls)
+        self.calls.append(len(pubs))
+        if i in self.go:
+            self.in_flight[i].set()
+            assert self.go[i].wait(30)
+        if i in self.failing_calls or (i == 0 and self.spoil == "raises"):
+            raise RuntimeError(f"call {i} fails")
+        ok, bits = self.cpu.batch_verify(pubs, msgs, sigs)
+        if i == 0 and self.spoil == "wrong-shape":
+            return ok, bits[:-1]
+        return ok, bits
+
+    def let_go(self):
+        for event in self.go.values():
+            event.set()
+
+
+def _engine(backend, cap=CAP):
+    return VerificationEngine(backend, hold_ms=0.0, max_sigs=cap, starvation_ms=0.0)
+
+
+def _while_in_flight(first, later, backend=None):
+    """`first` dispatched alone and held in flight while `later` are
+    submitted; then it is let go. Every answer (first's first; an error in
+    place of an answer that raised), the lanes of every call of the chain,
+    the engine's counters."""
+    backend = backend or _Stops()
+    eng = _engine(backend)
+    try:
+        futs = [eng.submit(*first)]
+        assert backend.in_flight[0].wait(10)
+        futs += [eng.submit(*r) for r in later]
+        backend.let_go()
+        answers = []
+        for f in futs:
+            try:
+                answers.append(f.result(60))
+            except RuntimeError as e:
+                answers.append(e)
+        return answers, backend.calls, eng.counters()
+    finally:
+        backend.let_go()
+        eng.close()
+
+
+def _flipped_at(req, lane: int):
+    pubs, msgs, sigs = _fresh(req)
+    sigs[lane] = sigs[lane][:7] + bytes([sigs[lane][7] ^ 0x10]) + sigs[lane][8:]
+    return pubs, msgs, sigs
+
+
+def _arrivals():
+    a, b = CASES["distinct-over-the-cap"][0][:2]
+    shorter = tuple(col[:-1] for col in _fresh(a))
+    return {
+        # name: (what is submitted while `a` is in flight, the lanes of the calls after a's, how
+        #        many of them are compared entry for entry: those alike in the first and last triple)
+        "three-copies": ([_fresh(a) for _ in range(3)], [], 3),
+        "a-copy-flipped-in-the-middle": ([_flipped_at(a, 7)], [LANES], 1),
+        "a-copy-flipped-at-its-first-lane": ([_flipped_at(a, 0)], [LANES], 0),
+        "a-copy-flipped-at-its-last-lane": ([_flipped_at(a, LANES - 1)], [LANES], 0),
+        "a-reordered-copy-with-the-ends-in-place": (
+            [tuple([col[0]] + col[1:-1][::-1] + [col[-1]] for col in _fresh(a))], [LANES], 1),
+        "a-reordered-copy": ([_reordered(a, 5)], [LANES], 0),
+        "a-copy-one-triple-shorter": ([shorter], [LANES - 1], 0),
+        "the-same-length-other-content": ([_fresh(b)], [LANES], 0),
+        "two-copies-about-a-flipped-one": ([_fresh(a), _flipped_at(a, 7), _fresh(a)], [LANES], 3),
+    }
+
+
+ARRIVALS = _arrivals()
+
+
+@pytest.mark.parametrize("name", sorted(ARRIVALS))
+def test_a_copy_of_the_request_in_flight_takes_its_answer_and_nothing_else_does(name, monkeypatch):
+    """Copies are answered by the dispatch in flight (the chain is not called
+    for them); whatever differs in a byte, in order or in length is queued
+    and run as before; `_same_columns` is never called where the O(1) check
+    fails; every answer is the one the request would get alone."""
+    import cometbft_tpu.sidecar.engine as engine_mod
+
+    compared = []
+    real = engine_mod._same_columns
+    monkeypatch.setattr(engine_mod, "_same_columns", lambda a, b: compared.append(1) or real(a, b))
+    first = _fresh(CASES["identical-x4"][0][0])
+    later, want_calls, want_compared = ARRIVALS[name]
+    answers, calls, counters = _while_in_flight(first, later)
+    assert answers == answers_alone([first] + later)
+    assert calls == [LANES] + want_calls and len(compared) == want_compared
+    joined = sum(r == first for r in later)
+    assert joined == len(later) - len(want_calls)
+    assert counters["requests"] == 1 + len(later)
+    assert counters["dispatches"] == len(calls)
+    assert (counters["joined_requests"], counters["joined_sigs"]) == (joined, joined * LANES)
+    assert counters["dedup_sigs"] == joined * LANES, "lanes offered that the chain did not run again"
+    assert counters["batched_requests"] == (1 + joined if joined else 0)
+    assert counters["coalesced_dispatches"] == (1 if joined else 0)
+    assert counters["fallback_splits"] == 0 and counters["queue_depth"] == 0
+
+
+def test_a_copy_joins_its_kind_of_a_merged_dispatch_in_flight():
+    """Two requests that overlap in part are in flight as one merged dispatch
+    of their 18 distinct lanes: a copy of either joins it and is given that
+    request's lanes, in its own order; a flipped copy is queued."""
+    a = CASES["identical-x4"][0][0]
+    x, y = (tuple(col[:12] for col in a), tuple(col[6:18] for col in a))
+    backend = _Stops(stops=(0, 1))
+    eng = _engine(backend, cap=64)
+    try:
+        blocker = eng.submit([b"b" * 32], [b"blocker"], [b"s" * 64])
+        assert backend.in_flight[0].wait(10)
+        requests = [_fresh(x), _fresh(y)]
+        futs = [eng.submit(*r) for r in requests]
+        backend.go[0].set()
+        assert backend.in_flight[1].wait(10)
+        requests += [_fresh(y), _flipped_at(y, 4), _fresh(x), _fresh(y)]
+        futs += [eng.submit(*r) for r in requests[2:]]
+        backend.let_go()
+        blocker.result(30)
+        assert [f.result(60) for f in futs] == answers_alone(requests)
+        assert [f.shared for f in futs] == [True] * 3 + [False] + [True] * 2
+    finally:
+        backend.let_go()
+        eng.close()
+    assert backend.calls == [1, 18, 12]
+    counters = eng.counters()
+    assert (counters["joined_requests"], counters["joined_sigs"]) == (3, 36)
+    assert counters["dedup_sigs"] == (24 - 18) + 36
+    assert counters["batched_requests"] == 5 and counters["coalesced_dispatches"] == 1
+
+
+@pytest.mark.parametrize("spoil, failing_calls", [
+    ("raises", ()), ("wrong-shape", ()), ("raises", (2,)),
+], ids=["the-dispatch-raises", "the-dispatch-answers-with-the-wrong-shape",
+        "the-dispatch-raises-and-so-does-one-retry"])
+def test_a_failed_dispatch_leaves_each_request_that_joined_it_its_own_answer(spoil, failing_calls):
+    """The requests that joined go through `_fallback` with the batch: each
+    is retried alone and gets its own answer or its own error; none inherits
+    another request's."""
+    a = CASES["identical-x4"][0][0]
+    first, later = _fresh(a), [_fresh(a), _fresh(a)]
+    answers, calls, counters = _while_in_flight(
+        first, later, backend=_Stops(spoil=spoil, failing_calls=failing_calls))
+    want = answers_alone([first] + later)
+    for i, (got, alone) in enumerate(zip(answers, want)):
+        if 1 + i in failing_calls:  # call 0 is the dispatch, call 1 + i the retry of request i
+            assert isinstance(got, RuntimeError) and str(got) == f"call {1 + i} fails"
+        else:
+            assert got == alone
+    assert calls == [LANES] * 4
+    assert counters["fallback_splits"] == 1 and counters["joined_requests"] == 2
+
+
+def test_a_lone_dispatch_that_nobody_joined_still_fails_to_its_caller_alone():
+    answers, calls, counters = _while_in_flight(
+        _fresh(CASES["identical-x4"][0][0]), [], backend=_Stops(spoil="raises"))
+    assert isinstance(answers[0], RuntimeError) and calls == [LANES]
+    assert counters["fallback_splits"] == 0
+
+
+def test_a_comparison_that_ends_after_the_dispatch_returned_is_queued_not_lost(monkeypatch):
+    """The join is decided under the lock, on the identity of what is in
+    flight: a submitter still comparing when the dispatch returns runs as a
+    dispatch of its own."""
+    import cometbft_tpu.sidecar.engine as engine_mod
+
+    a = CASES["identical-x4"][0][0]
+    backend = _Stops()
+    eng = _engine(backend)
+    real = engine_mod._same_columns
+    futs = []
+
+    def slow(x, y):
+        backend.let_go()
+        futs[0].result(30)  # the dispatch has returned and is answered
+        return real(x, y)
+
+    monkeypatch.setattr(engine_mod, "_same_columns", slow)
+    try:
+        futs.append(eng.submit(*_fresh(a)))
+        assert backend.in_flight[0].wait(10)
+        futs.append(eng.submit(*_fresh(a)))
+        assert [f.result(60) for f in futs] == answers_alone([a, a])
+    finally:
+        backend.let_go()
+        eng.close()
+    assert backend.calls == [LANES, LANES]
+    counters = eng.counters()
+    assert counters["joined_requests"] == 0 and counters["dispatches"] == 2 == counters["requests"]
+
+
+def test_what_the_ring_says_of_a_joined_dispatch(profiler_ring):
+    """The dispatch's span closes with what it ended up answering; a request
+    that joined has an `engine.join` and no `engine.queue_wait`; the late
+    slice is an `engine.merge` under the dispatch."""
+    a = CASES["identical-x4"][0][0]
+    t0 = time.perf_counter()
+    answers, calls, _ = _while_in_flight(_fresh(a), [_fresh(a) for _ in range(3)])
+    t1 = time.perf_counter()
+    assert calls == [LANES] and answers == answers_alone([a] * 4)
+    spans = trace.spans()
+    (dispatch,) = [s for s in spans if s["name"] == "engine.dispatch"]
+    attrs = dispatch["attrs"]
+    assert (attrs["requests"], attrs["lanes"], attrs["unique"], attrs["joined"], attrs["dedup"]) == (
+        4, 4 * LANES, LANES, 3, 3 * LANES)
+    merges = [s for s in spans if s["name"] == "engine.merge"]
+    assert [(s["attrs"]["phase"], s["parent"]) for s in merges] == [("slice", dispatch["id"])]
+    assert len([s for s in spans if s["name"] == "engine.queue_wait"]) == 1, "the first request's"
+    joins = [s for s in spans if s["name"] == "engine.join"]
+    assert [s["attrs"]["lanes"] for s in joins] == [LANES] * 3
+    assert all(s["attrs"]["compare_ms"] >= 0 and t0 <= s["t0"] <= s["t1"] <= t1 for s in joins)
+    assert all(dispatch["t0"] <= s["t0"] and s["t1"] <= dispatch["t1"] for s in joins)

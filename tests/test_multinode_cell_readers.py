@@ -5,12 +5,16 @@ two nodes whose calls overlap and whose request ids are equal each get their
 own `sidecar.request`; a request that rode another's dispatch is given that
 dispatch's spans; each reader finds its number and returns None where the
 program (the parent commit) or the run has nothing for it; the readers of
-`commit10k-sidecar` read the joined entries unchanged. No chip, no process."""
+`commit10k-sidecar` read the joined entries unchanged. ISSUE 33: a ring
+recorded by a real engine in which three requests joined the dispatch in
+flight reads as the benchmark's files (none edited) expect. No chip, no process."""
 
 from __future__ import annotations
 
 import json
 import os
+import threading
+import time
 import types
 
 import pytest
@@ -253,3 +257,127 @@ def test_the_cell_and_its_configuration_are_in_the_benchmark():
     for m in bench["end_to_end"]:
         if m["name"].startswith("commit_verify_"):
             assert m["workloads"][-1] == CELL
+
+
+# -- what the benchmark reads of a height answered by one dispatch (ISSUE 33) --------
+
+JOIN_LANES, JOIN_CAP, JOIN_NODES = 100, 128, 4
+
+
+@pytest.fixture
+def joined_height(monkeypatch):
+    """One height through a real engine under `trace.capture()`: node 0's
+    request is dispatched alone and held in flight while the other three
+    nodes' copies are submitted, each inside a `sidecar.request` span of its
+    own connection, as the server opens them. Gives (obs, the ring)."""
+    monkeypatch.syspath_prepend(BENCH)
+    import multinodelib
+
+    from cometbft_tpu.libs import trace
+    from cometbft_tpu.sidecar.backend import VerifyBackend
+    from cometbft_tpu.sidecar.engine import VerificationEngine
+
+    class Held(VerifyBackend):
+        name = "held"
+        in_flight, go = threading.Event(), threading.Event()
+
+        def batch_verify(self, pubs, msgs, sigs):
+            with trace.span("hybrid.call", n=len(pubs)):
+                self.in_flight.set()
+                assert self.go.wait(30)
+                return True, [True] * len(pubs)
+
+    columns = [bytes([i]) * 32 for i in range(JOIN_LANES)]
+    submitted = [threading.Event() for _ in range(JOIN_NODES)]
+
+    def connection(k: int):
+        with trace.span("sidecar.request", method="batch_verify", req=7, conn=40001 + k,
+                        lanes=JOIN_LANES):
+            fut = eng.submit(list(columns), list(columns), list(columns))
+            submitted[k].set()
+            assert fut.result(30) == (True, [True] * JOIN_LANES)
+
+    trace.clear()
+    eng = VerificationEngine(Held(), hold_ms=0.0, max_sigs=JOIN_CAP, starvation_ms=0.0)
+    before = eng.counters()
+    t_open = time.perf_counter()
+    try:
+        with trace.capture():
+            threads = [threading.Thread(target=connection, args=(k,)) for k in range(JOIN_NODES)]
+            threads[0].start()
+            assert Held.in_flight.wait(10)
+            for k in range(1, JOIN_NODES):
+                threads[k].start()
+                assert submitted[k].wait(10)
+            Held.go.set()
+            for t in threads:
+                t.join(30)
+        after = eng.counters()
+    finally:
+        Held.go.set()
+        eng.close()
+    ring = trace.spans()
+    # each node's side: an operation whose `grpc.call` holds its connection's request
+    nodes = []
+    for k in range(JOIN_NODES):
+        (r,) = [s for s in ring if s["name"] == "sidecar.request" and s["attrs"]["conn"] == 40001 + k]
+        t0, t1 = r["t0"] - 0.003, r["t1"] + 0.003
+        nodes.append([
+            _sp(1, "validation.verify_commit", t0, t1),
+            _sp(2, "batch.dispatch", t0 + 0.001, t1 - 0.001, parent=1, root=1),
+            _sp(3, "engine.queue_wait", t0 + 0.001, t0 + 0.0015, parent=2, root=1),
+            _sp(4, "grpc.call", t0 + 0.002, t1 - 0.002, parent=2, root=1, req=7, port=40001 + k,
+                lanes=JOIN_LANES),
+        ])
+    lanes_in = JOIN_NODES * JOIN_LANES
+    obs = types.SimpleNamespace(
+        window=(t_open - 1.0, time.perf_counter() + 1.0),  # the nodes' calls begin before the requests
+        samples={"nodes_spans": [{"spans": n, "dropped": 0} for n in nodes]},
+        counters_before={"engine": before, "server": {"lanes_in": 0}},
+        counters_after={"engine": after, "server": {"lanes_in": lanes_in}},
+    )
+    obs.samples["wire_ops"] = multinodelib.join(obs)
+    yield obs, ring
+    trace.clear()
+
+
+def test_the_ring_of_a_height_answered_by_one_dispatch(joined_height):
+    _, ring = joined_height
+    (dispatch,) = [s for s in ring if s["name"] == "engine.dispatch"]
+    attrs = dispatch["attrs"]
+    assert (attrs["requests"], attrs["lanes"], attrs["unique"], attrs["joined"]) == (
+        JOIN_NODES, JOIN_NODES * JOIN_LANES, JOIN_LANES, JOIN_NODES - 1)
+    merges = [s for s in ring if s["name"] == "engine.merge"]
+    assert [(s["attrs"]["phase"], s["parent"]) for s in merges] == [("slice", dispatch["id"])]
+    requests = {s["attrs"]["conn"]: s for s in ring if s["name"] == "sidecar.request"}
+    for conn, r in requests.items():
+        names = sorted(s["name"] for s in ring if s["root"] == r["id"] and s["id"] != r["id"])
+        if conn == 40001:  # the one dispatched: the chain's spans hang under its request
+            assert names == ["engine.dispatch", "engine.merge", "engine.queue_wait", "hybrid.call"]
+        else:
+            assert names == ["engine.join"], "it never stood in the queue"
+
+
+def test_the_cells_readers_read_a_height_answered_by_one_dispatch(joined_height):
+    import harness
+
+    obs, _ = joined_height
+    ops = obs.samples["wire_ops"]
+    assert [e["node_index"] for e in ops] == list(range(JOIN_NODES))
+    assert all(len(e["requests"]) == 1 for e in ops), "exactly one sidecar.request an operation"
+    assert [e["requests"][0]["attrs"]["conn"] for e in ops] == [40001 + k for k in range(JOIN_NODES)]
+    # a joined request is given no other request's dispatch: no `hybrid.call` of its own
+    assert [sum(s["name"] == "hybrid.call" for s in e["sidecar"]) for e in ops] == [1, 0, 0, 0]
+
+    def read(name):
+        path = os.path.join(BENCH, "layers", name + ".py")
+        return harness.load_by_path(path, "layer_" + name.replace(".", "_")).read(obs, None)
+
+    assert read("requests_per_dispatch.commit") == pytest.approx(4.0)
+    assert read("dedup_lane_share_pct.commit") == pytest.approx(75.0)
+    assert 0 < read("engine_merge_ms.commit") < 1000
+    assert read("wire_ms.commit") is not None, "read over the operation that owns a hybrid.call"
+    assert read("queue_wait_ms.commit") is not None
+    generator = harness.load_by_path(
+        os.path.join(BENCH, "generators", "commit_stream_4nodes.py"), "generator_commit_stream_4nodes")
+    assert generator._trace_problems(obs) == []
