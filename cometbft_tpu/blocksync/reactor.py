@@ -129,7 +129,7 @@ class BlocksyncReactor(Reactor):
     def start(self) -> None:
         self._running = True
         if self.block_sync_enabled:
-            threading.Thread(target=self._pool_routine, daemon=True).start()
+            threading.Thread(target=self._pool_routine, daemon=True, name="blocksync-pool").start()
 
     def stop(self) -> None:
         self._running = False
@@ -146,7 +146,7 @@ class BlocksyncReactor(Reactor):
         was_enabled = self.block_sync_enabled
         self.block_sync_enabled = True
         if self._running and not was_enabled:
-            threading.Thread(target=self._pool_routine, daemon=True).start()
+            threading.Thread(target=self._pool_routine, daemon=True, name="blocksync-pool").start()
 
     # -- peers ----------------------------------------------------------------
 
@@ -359,7 +359,7 @@ class BlocksyncReactor(Reactor):
                 done.set()
 
         self._pf_job = (done, times)
-        threading.Thread(target=run, daemon=True).start()
+        threading.Thread(target=run, daemon=True, name="blocksync-prefetch").start()
         return True
 
     def _pipeline_wait(self) -> None:

@@ -108,7 +108,9 @@ def jax_memory() -> str:
 def jax_trace(seconds: float, trace_dir: str) -> str:
     """A profiler session of `seconds`: it is also what turns the program's
     spans on (libs/trace.py), and the capture's spans are written as
-    `spans.json` beside the xplane, on `time.perf_counter()`."""
+    `spans.json` beside the xplane, on `time.perf_counter()`, with `threads`:
+    the CPU seconds each role's threads used over the session
+    (`trace.thread_cpu()` at its two edges)."""
     import jax
 
     from cometbft_tpu.libs import trace
@@ -117,12 +119,19 @@ def jax_trace(seconds: float, trace_dir: str) -> str:
     options.python_tracer_level = 0  # the Python tracer slows the host it observes
     t0 = time.perf_counter()
     jax.profiler.start_trace(trace_dir, profiler_options=options)
+    before = trace.thread_cpu()
     time.sleep(seconds)
+    after = trace.thread_cpu()
     jax.profiler.stop_trace()
     captured = [s for s in trace.spans() if s["t0"] >= t0]
+    # Over both edges' roles, so the differences still close on `process`. A
+    # role whose threads come and go (a prefetch job a height) reads only what
+    # its live ones held at the edges: its work is in `ended_or_native`.
+    threads = {role: after.get(role, 0.0) - before.get(role, 0.0)
+               for role in {**before, **after}}
     with open(os.path.join(trace_dir, "spans.json"), "w") as f:
         json.dump({"t0": t0, "t1": time.perf_counter(), "dropped": trace.dropped(),
-                   "spans": captured}, f)
+                   "spans": captured, "threads": threads}, f)
     return (
         f"trace written to {trace_dir} (open with TensorBoard/Perfetto); "
         f"{len(captured)} program spans in spans.json"

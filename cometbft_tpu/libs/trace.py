@@ -4,14 +4,32 @@
         ...
         sp.set(hits=hits)
 
-A span records `(id, parent, root, name, t0, t1, thread, attrs)` into one
-bounded in-memory ring on `time.perf_counter()` and, for the same interval,
-enters `jax.profiler.TraceAnnotation("seam:" + name)`, so it also lies in
-the profiler's xplane beside the device's operations. `parent` is the
-innermost open span of the thread (or the one handed over with `parent=`
-when the work crossed a thread); `root` is the id of the outermost one, so
-the spans of one request — one `verify_commit`, one synced height, one
-prefetch job — share it.
+A span records `(id, parent, root, name, t0, t1, cpu, pcpu, thread, attrs)`
+into one bounded in-memory ring on `time.perf_counter()` and, for the same
+interval, enters `jax.profiler.TraceAnnotation("seam:" + name)`, so it also
+lies in the profiler's xplane beside the device's operations. `parent` is
+the innermost open span of the thread (or the one handed over with
+`parent=` when the work crossed a thread); `root` is the id of the
+outermost one, so the spans of one request — one `verify_commit`, one
+synced height, one prefetch job — share it.
+
+`t1 - t0` says where the thread stood; `cpu` and `pcpu` say what ran. `cpu`
+is the seconds this thread was on a CPU inside the span
+(`time.thread_time()`), `pcpu` the seconds of CPU the whole process used
+meanwhile, all its threads (`time.process_time()`). `t1 - t0 - cpu` is the
+time the thread did not run: it waited for the interpreter lock, a queue,
+a socket or the device. `pcpu / (t1 - t0)` near 1 says the interpreter was
+saturated meanwhile (the wait was for the lock), near 0 that the process
+slept (a real wait); in between the two cannot be told apart, and native
+threads that hold no lock (XLA's, the host MSM's) count in `pcpu` too. The
+clocks are read inside one another (`t0`, `pcpu`, `cpu` ... `cpu`, `pcpu`,
+`t1`), so `cpu <= pcpu` and `cpu <= t1 - t0` up to the clocks' resolution.
+`cpu` is None where a span is closed on another thread than opened it, and
+in a `record()` whose caller gave none. On plain Linux the two clocks are
+exact and cost ~0.3 us a read. Under gVisor (the chip tool's machine,
+PERF.md section 6) they tick at 10 ms, cost 6 us a read and charge part of
+a lock's wait as running: a span's `cpu` is then a whole number of ticks
+and an upper bound; read sums over many spans, as a sampling profiler's.
 
 The switch is the profiler session, not a knob: a site first asks whether
 JAX has been imported at all and then whether a profiler session is on.
@@ -30,10 +48,17 @@ with neither a capture nor a profiler session every site is the no-op.
 process of a host, so the spans of a node and of the sidecar it calls
 merge by their times.
 
-A site sits at a layer boundary, once per call, dispatch, request or
-height — never inside a loop over lanes, signatures or messages. `NAMES`
-is every name the program emits (tests/test_trace.py holds the code and
-PERF.md's table to it). This module imports nothing of JAX.
+A site sits at a layer boundary, once per call, dispatch, request, height
+or received p2p message — never inside a loop over lanes, signatures or
+packets. `NAMES` is every name the program emits (tests/test_trace.py
+holds the code and PERF.md's table to it). This module imports nothing of
+JAX.
+
+The ring's `thread` is the thread's name, and every thread the hot paths
+start is named `<role>` or `<role>:<which>` (`p2p-recv:<peer>`,
+`blocksync-pool`, `sidecar-conn:<port>`, `verify-engine`, `cmtpu-dev`):
+`role()` is the part before the colon, and `thread_cpu()` the CPU each
+role's live threads have used, for `/metrics` and `/debug/jax/trace`.
 """
 
 from __future__ import annotations
@@ -107,6 +132,14 @@ NAMES = (
     "sidecar.request",
     "sidecar.decode",
     "sidecar.encode",
+    # p2p receive
+    "p2p.recv_msg",
+)
+
+# Every role the hot paths name a thread by (module text).
+ROLES = (
+    "p2p-recv", "p2p-send", "p2p-drain", "blocksync-pool", "blocksync-prefetch",
+    "verify-engine", "cmtpu-dev", "sidecar-conn", "sidecar-reader",
 )
 
 _ring: collections.deque = collections.deque(maxlen=RING)
@@ -187,7 +220,8 @@ _OFF = _Off()
 
 
 class _Span:
-    __slots__ = ("id", "parent", "root", "name", "t0", "attrs", "_ann")
+    __slots__ = ("id", "parent", "root", "name", "t0", "attrs", "_ann",
+                 "_tid", "_cpu0", "_pcpu0")
 
     def __init__(self, name: str, parent, attrs: dict, ann):
         self.id = next(_ids)
@@ -215,10 +249,15 @@ class _Span:
         stack.append(self)
         if self._ann is not None:
             self._ann.__enter__()
+        self._tid = threading.get_ident()
         self.t0 = time.perf_counter()
+        self._pcpu0 = time.process_time()
+        self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
+        cpu1 = time.thread_time()
+        pcpu1 = time.process_time()
         t1 = time.perf_counter()
         if self._ann is not None:
             self._ann.__exit__(*exc)
@@ -231,6 +270,8 @@ class _Span:
         _put({
             "id": self.id, "parent": None if parent is None else parent.id,
             "root": self.root, "name": self.name, "t0": self.t0, "t1": t1,
+            "cpu": cpu1 - self._cpu0 if threading.get_ident() == self._tid else None,
+            "pcpu": pcpu1 - self._pcpu0,
             "thread": threading.current_thread().name, "attrs": self.attrs,
         })
         return False
@@ -258,18 +299,77 @@ def current():
     return stack[-1] if stack else None
 
 
-def record(name: str, t0: float, t1: float, parent=None, **attrs) -> None:
+def on() -> bool:
+    """Whether a session of either kind is open. A site that has to read a
+    clock now for a `record()` later asks this first, so that with no
+    session it reads none."""
+    return bool(_captures) or _session() is not None
+
+
+def mark():
+    """`(perf_counter, process_time, thread_time)` now, the start of an
+    interval this thread will `record()`; None, and no clock read, with no
+    session."""
+    if not on():
+        return None
+    return time.perf_counter(), time.process_time(), time.thread_time()
+
+
+def since(mark) -> dict:
+    """`t0`, `t1`, `cpu` and `pcpu` of the interval from `mark()` to now,
+    as `record()` takes them. Same thread as the mark."""
+    cpu1 = time.thread_time()
+    pcpu1 = time.process_time()
+    t0, pcpu0, cpu0 = mark
+    return {"t0": t0, "t1": time.perf_counter(), "cpu": cpu1 - cpu0, "pcpu": pcpu1 - pcpu0}
+
+
+def record(name: str, t0: float, t1: float, parent=None, cpu=None, pcpu=None, **attrs) -> None:
     """Ring only, for an interval known after the fact or one that crosses
-    threads (the engine's queue wait). Times are `time.perf_counter()`."""
-    if not _captures and _session() is None:
+    threads (the engine's queue wait). Times are `time.perf_counter()`;
+    `cpu` / `pcpu` are the caller's own readings (`since()`), None where it
+    has none: an interval that crossed threads has no thread's CPU."""
+    if not on():
         return
     sid = next(_ids)
     _put({
         "id": sid, "parent": None if parent is None else parent.id,
         "root": sid if parent is None else parent.root, "name": name,
-        "t0": t0, "t1": t1, "thread": threading.current_thread().name,
-        "attrs": attrs,
+        "t0": t0, "t1": t1, "cpu": cpu, "pcpu": pcpu,
+        "thread": threading.current_thread().name, "attrs": attrs,
     })
+
+
+def role(thread_name: str) -> str:
+    """`<role>` of a thread named `<role>` or `<role>:<which>`."""
+    return thread_name.partition(":")[0]
+
+
+def thread_cpu() -> dict[str, float]:
+    """CPU seconds each role's LIVE threads have used since they started,
+    `process` (all threads, ended ones too) and `ended_or_native` = `process`
+    minus the live threads' sum, so the roles close on the process: threads
+    that have ended (a prefetch job a height) and native threads Python does
+    not list (XLA's, the host MSM's). A caller that wants an interval reads
+    it at both edges. Where the platform cannot read another thread's clock
+    (no `pthread_getcpuclockid`) it is `{"process": ...}` alone."""
+    clock_of = getattr(time, "pthread_getcpuclockid", None)
+    if clock_of is None:
+        return {"process": time.process_time()}
+    out: dict[str, float] = {}
+    live = 0.0
+    for t in threading.enumerate():
+        try:
+            used = time.clock_gettime(clock_of(t.ident))
+        except (OSError, TypeError):  # ended since it was listed, or not started yet
+            continue
+        who = role(t.name)
+        out[who] = out.get(who, 0.0) + used
+        live += used
+    process = time.process_time()  # read last: never under the sum of its parts
+    out["process"] = process
+    out["ended_or_native"] = process - live
+    return out
 
 
 def spans() -> list[dict]:

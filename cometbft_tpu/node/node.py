@@ -190,6 +190,7 @@ class Node:
                            lambda: self.block_store.base())
             self._register_backend_metrics(reg)
             self._register_engine_metrics(reg)
+            self._register_host_metrics(reg)
             self._register_recvq_metrics(reg)
             self._register_mesh_metrics(reg)
             self._register_fanout_metrics(reg)
@@ -405,7 +406,7 @@ class Node:
     @staticmethod
     def _register_backend_metrics(reg) -> None:
         """backend_trips / backend_retries / backend_deadline_exceeded /
-        backend_active_tier gauges plus the scheduler_* coalescer gauges,
+        backend_active_tier gauges plus the hybrid_* and sidecar_* ones,
         sampled lazily off the process-wide verification backend.  Sampling (not registering) checks for the
         supervisor so scraping never forces backend construction — under
         CMTPU_BACKEND=auto with an accelerator visible that would import
@@ -427,22 +428,6 @@ class Node:
 
             return fn
 
-        def sched_sample(key):
-            # Lazy like sample(): zeros until the coalescing scheduler
-            # exists (CMTPU_COALESCE=0 keeps them zero forever).
-            def fn():
-                b = backend_mod._backend
-                if getattr(b, "name", "") != "coalesce":
-                    return 0
-                c = b.counters()
-                if key == "coalesce_ratio_milli":
-                    return int(1000 * c["requests"] / max(1, c["dispatches"]))
-                if key == "queue_wait_p95_us":
-                    return int(c["queue_wait_p95_ms"] * 1000)
-                return c.get(key, 0)
-
-            return fn
-
         reg.gauge_func("backend", "trips",
                        "Verification-tier circuit-breaker trips.",
                        sample("trips"))
@@ -456,25 +441,6 @@ class Node:
                        "Degradation-chain index of the serving tier "
                        "(0 = primary).",
                        sample("active_tier"))
-        reg.gauge_func("scheduler", "requests",
-                       "Verification requests submitted to the coalescer.",
-                       sched_sample("requests"))
-        reg.gauge_func("scheduler", "dispatches",
-                       "Backend dispatches the coalescer issued.",
-                       sched_sample("dispatches"))
-        reg.gauge_func("scheduler", "batched_requests",
-                       "Requests that shared a coalesced dispatch.",
-                       sched_sample("batched_requests"))
-        reg.gauge_func("scheduler", "fallback_splits",
-                       "Coalesced dispatches split into per-request retries.",
-                       sched_sample("fallback_splits"))
-        reg.gauge_func("scheduler", "coalesce_ratio_milli",
-                       "Requests per dispatch x1000.",
-                       sched_sample("coalesce_ratio_milli"))
-        reg.gauge_func("scheduler", "queue_wait_p95_us",
-                       "95th-percentile coalescer queue wait, microseconds.",
-                       sched_sample("queue_wait_p95_us"))
-
         def hybrid_sample(key):
             # The hybrid tier's running planner counters, wherever the chain
             # holds it; zeros until the backend exists and has such a tier.
@@ -556,11 +522,11 @@ class Node:
         """engine_* gauges: the continuous-batching verification engine's
         per-class view (consensus/blocksync/ingress/light admission counts,
         dispatched signatures, p95 admission wait, starvation promotions)
-        plus its dispatch total. Lazy like the backend gauges — the sampler
-        peeks `backend_mod._backend` (never get_backend()) and unwraps the
-        CoalescingScheduler shim, so a scrape never constructs the chain;
-        the legacy scheduler_*/vote_batch_* gauges keep reading through
-        their existing registrations. Zeros under CMTPU_COALESCE=0."""
+        plus its totals (requests, dispatches, requests that shared one,
+        fallback splits, p95 queue wait). Lazy like the backend gauges — the
+        sampler peeks `backend_mod._backend` (never get_backend()) and
+        unwraps the CoalescingScheduler shim, so a scrape never constructs
+        the chain. Zeros under CMTPU_COALESCE=0."""
         from cometbft_tpu.sidecar import backend as backend_mod
 
         def _engine():
@@ -584,6 +550,18 @@ class Node:
             "engine", "dispatches",
             "Device dispatches the continuous-batching engine issued.",
             eng_sample(lambda e: e.counters_["dispatches"]),
+        )
+        for key, text in (
+            ("requests", "Verification requests submitted to the engine."),
+            ("batched_requests", "Requests that shared a dispatch with another."),
+            ("fallback_splits", "Merged dispatches split into per-request retries."),
+        ):
+            reg.gauge_func("engine", key, text,
+                           eng_sample(lambda e, k=key: e.counters_[k]))
+        reg.gauge_func(
+            "engine", "queue_wait_p95_us",
+            "95th-percentile wait in the engine's queue, all classes, microseconds.",
+            eng_sample(lambda e: int(e.counters()["queue_wait_p95_ms"] * 1000)),
         )
         from cometbft_tpu.sidecar.engine import CLASS_NAMES
 
@@ -619,6 +597,39 @@ class Node:
                     ]
                 ),
             )
+
+    @staticmethod
+    def _register_host_metrics(reg) -> None:
+        """host_thread_cpu_seconds_<role>: CPU seconds the live threads of
+        each role the hot paths name (`trace.ROLES`) have used, `other` for
+        live threads of any other name (MainThread, consensus, rpc), and
+        `process` and `ended_or_native` so that they close
+        (`trace.thread_cpu()`): under one interpreter lock, whose rate
+        grows is who holds it. A scrape reads clocks and constructs
+        nothing; its gauges share one reading."""
+        from cometbft_tpu.libs import trace
+
+        held = [0.0, {}]
+
+        def sample(pick):
+            def fn():
+                now = time.monotonic()
+                if now - held[0] > 0.1:
+                    held[:] = now, trace.thread_cpu()
+                return pick(held[1])
+            return fn
+
+        closing = ("process", "ended_or_native")
+        for key in trace.ROLES + closing:
+            reg.gauge_func(
+                "host", "thread_cpu_seconds_" + key.replace("-", "_"),
+                f"CPU seconds used: {key}.", sample(lambda c, k=key: c.get(k, 0.0)),
+            )
+        reg.gauge_func(
+            "host", "thread_cpu_seconds_other", "CPU seconds used: live threads of no named role.",
+            sample(lambda c: sum(v for k, v in c.items()
+                                 if k not in trace.ROLES and k not in closing)),
+        )
 
     def _register_recvq_metrics(self, reg) -> None:
         """recvq_* gauges: the prioritized p2p recv demux, aggregated across

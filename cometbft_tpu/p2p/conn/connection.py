@@ -15,7 +15,7 @@ import struct
 import threading
 import time
 
-from cometbft_tpu.libs import flowrate
+from cometbft_tpu.libs import flowrate, trace
 from cometbft_tpu.p2p.conn import recvq
 from cometbft_tpu.wire import proto as wire
 
@@ -66,6 +66,8 @@ class _Channel:
         self.sending: memoryview | None = None
         self.recently_sent = 0
         self.recving = bytearray()
+        self.recv_packets = 0  # of the message being received
+        self.recv_t0 = 0.0  # when its first packet was read
 
 
 class MConnection:
@@ -81,8 +83,11 @@ class MConnection:
         send_rate: int = DEFAULT_SEND_RATE,
         recv_rate: int = DEFAULT_RECV_RATE,
         clock=None,
+        name: str = "",
     ):
         self._conn = conn
+        # which connection this is (the peer's id), for its threads' names
+        self._which = f":{name}" if name else ""
         self.channels = {d.id: _Channel(d) for d in channel_descs}
         self.on_receive = on_receive
         self.on_error = on_error
@@ -96,7 +101,7 @@ class MConnection:
         self._send_signal = threading.Event()
         self._running = False
         self._pong_pending = False
-        self._last_msg_recv = time.monotonic()
+        self._last_msg_recv = time.perf_counter()  # the tracer's clock
         # Prioritized recv demux (CMTPU_RECVQ, default on): _recv_routine
         # frames + enqueues; the demux's drain thread delivers in priority
         # order.  Off = the historical inline delivery, verbatim.
@@ -107,14 +112,15 @@ class MConnection:
                 channels=self.channels,
                 clock=clock,
                 on_error=self._fatal,
+                name="p2p-drain" + self._which,
             )
 
     def start(self) -> None:
         self._running = True
         if self._recvq is not None:
             self._recvq.start()
-        threading.Thread(target=self._send_routine, daemon=True).start()
-        threading.Thread(target=self._recv_routine, daemon=True).start()
+        for role, routine in (("p2p-send", self._send_routine), ("p2p-recv", self._recv_routine)):
+            threading.Thread(target=routine, daemon=True, name=role + self._which).start()
 
     def stop(self) -> None:
         self._running = False
@@ -236,11 +242,19 @@ class MConnection:
         """Thin framer: decode packets, reassemble messages at EOF markers,
         then hand off.  With the demux on, completed messages are enqueued
         into the per-channel recv queues and the demux's drain thread calls
-        on_receive in priority order; off, delivery stays inline here."""
+        on_receive in priority order; off, delivery stays inline here.
+
+        Traced, a completed message is one `p2p.recv_msg` in the ring, never
+        a packet: from its first packet's arrival to its EOF packet, with
+        the CPU this thread (and the process) used since the message before
+        it completed here — decrypting, framing, reassembly, the limiter's
+        bookkeeping; with the demux off, that message's inline delivery too.
+        The first one of a session has no such mark and records no CPU."""
+        mark = None  # trace.mark() at the last message completed under a session
         while self._running:
             try:
                 pkt = self._read_packet()
-                self._last_msg_recv = time.monotonic()
+                self._last_msg_recv = now = time.perf_counter()
                 f = wire.decode_fields(pkt)
                 if 1 in f:  # ping
                     self._pong_pending = True
@@ -258,8 +272,20 @@ class MConnection:
                     ch.recving += data
                     if len(ch.recving) > ch.desc.recv_message_capacity:
                         raise ValueError("received message exceeds channel capacity")
+                    if not ch.recv_packets:
+                        ch.recv_t0 = now
+                    ch.recv_packets += 1
                     if eof:
                         msg, ch.recving = bytes(ch.recving), bytearray()
+                        packets, ch.recv_packets = ch.recv_packets, 0
+                        if trace.on():
+                            took = trace.since(mark) if mark else {"t1": time.perf_counter()}
+                            took["t0"] = ch.recv_t0  # the message's own start, not the mark's
+                            trace.record("p2p.recv_msg", chan=chan_id, bytes=len(msg),
+                                         packets=packets, **took)
+                            mark = trace.mark()
+                        else:
+                            mark = None
                         if self._recvq is not None:
                             self._recvq.push(chan_id, msg)
                         else:

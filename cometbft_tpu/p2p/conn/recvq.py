@@ -104,10 +104,12 @@ class RecvQueues:
         max_depth: int | None = None,
         starvation_ms: float | None = None,
         quanta: tuple[int, ...] | None = None,
+        name: str = "p2p-drain",
     ):
         from cometbft_tpu.simnet.clock import MonotonicClock
 
         self._deliver = deliver
+        self._name = name  # the drain thread's
         self._on_error = on_error
         self._clock = clock or MonotonicClock()
         self.max_depth = int(
@@ -148,7 +150,7 @@ class RecvQueues:
     # -- lifecycle ----------------------------------------------------------
 
     def start(self) -> None:
-        self._thread = threading.Thread(target=self._drain_loop, daemon=True)
+        self._thread = threading.Thread(target=self._drain_loop, daemon=True, name=self._name)
         self._thread.start()
 
     def stop(self) -> None:

@@ -59,6 +59,7 @@ class Peer:
             lambda ch, msg: on_receive(self, ch, msg),
             lambda err: on_error(self, err),
             clock=clock,
+            name=self.id[:10],
         )
 
     def start(self) -> None:

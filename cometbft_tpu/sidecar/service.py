@@ -335,6 +335,7 @@ class SidecarServer:
                 # per-connection: the stream table, and the peer's port,
                 # which tells its requests' spans from another connection's
                 conn = {"streams": {}, "port": self.client_address[1]}
+                threading.current_thread().name = f"sidecar-conn:{conn['port']}"
                 with outer._count_lock:
                     outer._conns.add(sock)
                     outer.counters_["connections_accepted"] += 1
@@ -513,7 +514,7 @@ class SidecarServer:
         st = None
         final = False
         try:
-            t0 = time.perf_counter()
+            began = trace.mark()  # None, and no clock read, with no session
             fields = proto.decode_fields(payload)
             sid = proto.get_uvarint(fields, 1)
             seq = proto.get_uvarint(fields, 2)
@@ -527,7 +528,8 @@ class SidecarServer:
                 # opens now and takes the decode's start.
                 sp = trace.span("sidecar.request", method="BatchVerifyChunk",
                                 conn=conn["port"]).__enter__()
-                sp.backdate(t0)
+                if began is not None:
+                    sp.backdate(began[0])
                 streams[sid] = _ServerStream(sp)
             st = streams.get(sid)
             if st is None:
@@ -538,8 +540,9 @@ class SidecarServer:
             st.next_seq += 1
             # A column the decoder refuses fails the stream it belongs to.
             pubs, msgs, sigs, ragged = decode_columns(fields, 4)
-            trace.record("sidecar.decode", t0, time.perf_counter(), parent=st.span,
-                         seq=seq, ragged=ragged)
+            if began is not None:
+                trace.record("sidecar.decode", parent=st.span, seq=seq, ragged=ragged,
+                             **trace.since(began))
             self._count(lanes_in=len(pubs))
             st.pubs += pubs
             st.msgs += msgs

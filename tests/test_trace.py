@@ -514,6 +514,184 @@ def test_a_span_handed_to_another_thread_keeps_the_root(profiler):
     assert run["parent"] == call.id and run["thread"] != threading.current_thread().name
 
 
+# -- (f2) what ran: thread and process CPU ----------------------------------------------
+
+
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def _readings(attempts, measure, good):
+    """Readings of `measure()` up to the first that `good` accepts: the
+    sandbox shares its cores, and a spin that the host took off its CPU
+    reads as a wait."""
+    seen = []
+    for _ in range(attempts):
+        seen.append(measure())
+        if good(seen[-1]):
+            break
+    return seen
+
+
+def _span_of(work):
+    trace.clear()
+    with trace.capture(), trace.span("hybrid.call"):
+        work()
+    (s,) = trace.spans()
+    return s["t1"] - s["t0"], s["cpu"], s["pcpu"]
+
+
+def _alone_20ms():
+    return _readings(8, lambda: _span_of(lambda: _spin(0.02)),
+                     lambda r: abs(r[1] - r[0]) <= 0.2 * r[0])
+
+
+def _host_is_quiet():
+    """Whether three lone spins in a row each ran for most of their wall:
+    where they do not, the host takes this process off its cores and no
+    claim about a wall clock can be held against the program."""
+    return all(c >= 0.95 * w for w, c, _ in (_span_of(lambda: _spin(0.02)) for _ in range(3)))
+
+
+def test_a_span_that_spins_alone_ran_for_about_its_wall():
+    seen = _alone_20ms()
+    assert all(c <= w + 1e-4 and p >= c - 1e-4 for w, c, p in seen)
+    wall, cpu, pcpu = seen[-1]
+    if abs(cpu - wall) > 0.2 * wall and not _host_is_quiet():
+        pytest.skip(f"the host is too busy to time a lone spin: {seen}")
+    assert abs(cpu - wall) <= 0.2 * wall, seen
+
+
+def test_a_span_beside_three_spinning_threads_stood_more_than_it_ran():
+    """One interpreter lock, four threads that want it: the span's thread
+    gets its share of the CPU the process uses, whatever the host gives the
+    process; and where the host is quiet, the wall is mostly the others'
+    work while `pcpu` says the lock was never idle."""
+    stop = threading.Event()
+
+    def spin_until_stopped():
+        while not stop.is_set():
+            pass
+
+    quiet = _host_is_quiet()
+    others = [threading.Thread(target=spin_until_stopped, name=f"p2p-recv:{i}") for i in range(3)]
+    for t in others:
+        t.start()
+    try:
+        seen = _readings(8, lambda: _span_of(lambda: _spin(0.15)),
+                         lambda r: r[1] < 0.6 * r[0] and r[2] >= 0.8 * r[0])
+    finally:
+        stop.set()
+        for t in others:
+            t.join(10)
+    assert not any(t.is_alive() for t in others)
+    for wall, cpu, pcpu in seen:
+        assert cpu < 0.6 * pcpu, seen  # a thread's clock, not the process's
+        assert cpu <= wall + 1e-4 and pcpu >= cpu - 1e-4
+    wall, cpu, pcpu = seen[-1]
+    if quiet and _host_is_quiet():  # before and after: else the walls are the host's
+        assert cpu < 0.6 * wall and pcpu >= 0.8 * wall, seen
+
+
+def test_a_span_that_sleeps_did_not_run():
+    wall, cpu, pcpu = _span_of(lambda: time.sleep(0.03))
+    assert wall >= 0.03 and cpu < 0.001 and pcpu >= cpu - 1e-4
+
+
+def test_off_no_cpu_clock_is_read(monkeypatch):
+    """With no session of either kind neither `span()`, `record()`, `mark()`
+    nor the receive path reaches a CPU clock (tests/test_p2p_recv_trace.py
+    holds `_recv_routine` to the same counters)."""
+    reads = []
+    monkeypatch.setattr(time, "thread_time", lambda: reads.append("thread") or 0.0)
+    monkeypatch.setattr(time, "process_time", lambda: reads.append("process") or 0.0)
+    assert not trace.on() and trace.mark() is None
+    with trace.span("hybrid.call") as sp:
+        assert sp is trace._OFF
+    trace.record("engine.queue_wait", 1.0, 2.0)
+    assert reads == [] and trace.spans() == []
+    with trace.capture():
+        with trace.span("hybrid.call"):
+            pass
+    assert sorted(reads) == ["process"] * 2 + ["thread"] * 2, "on: two of each a span"
+
+
+def test_a_span_closed_on_another_thread_has_no_cpu():
+    with trace.capture():
+        sp = trace.span("sidecar.request").__enter__()
+        t = threading.Thread(target=sp.__exit__, args=(None, None, None))
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+    (s,) = trace.spans()
+    assert s["cpu"] is None and s["pcpu"] >= 0.0 and s["thread"] == t.name
+
+
+def test_record_keeps_the_cpu_it_is_given_and_none_where_given_none():
+    with trace.capture():
+        trace.record("engine.queue_wait", 1.0, 2.0, klass="consensus")
+        trace.record("sidecar.decode", 1.0, 2.0, cpu=0.25, pcpu=0.75, seq=3)
+        began = trace.mark()
+        _spin(0.005)
+        trace.record("p2p.recv_msg", chan=64, **trace.since(began))
+    crossed, given, timed = trace.spans()
+    assert (crossed["cpu"], crossed["pcpu"], crossed["attrs"]) == (None, None, {"klass": "consensus"})
+    assert (given["cpu"], given["pcpu"], given["attrs"]) == (0.25, 0.75, {"seq": 3})
+    assert (given["t0"], given["t1"]) == (1.0, 2.0)
+    assert 0.0 < timed["cpu"] <= timed["pcpu"] + 1e-4
+    assert timed["cpu"] <= timed["t1"] - timed["t0"] + 1e-4 and timed["attrs"] == {"chan": 64}
+
+
+def test_thread_cpu_closes_on_the_process_and_groups_threads_by_role():
+    assert trace.role("p2p-recv:0a1b2c3d4e") == "p2p-recv" and trace.role("verify-engine") == "verify-engine"
+    stop = threading.Event()
+
+    def spin_until_stopped():
+        while not stop.is_set():
+            pass
+
+    recv = [threading.Thread(target=spin_until_stopped, name=f"p2p-recv:{peer}")
+            for peer in ("aa", "bb")]
+    for t in recv:
+        t.start()
+    try:
+        _spin(0.05)
+        got = trace.thread_cpu()
+    finally:
+        stop.set()
+        for t in recv:
+            t.join(10)
+    assert not any(t.is_alive() for t in recv)
+    assert "p2p-recv:aa" not in got and got["p2p-recv"] > 0.0, "two threads, one role"
+    assert got[trace.role(threading.current_thread().name)] > 0.0
+    roles = sum(v for k, v in got.items() if k not in ("process", "ended_or_native"))
+    assert got["process"] == pytest.approx(roles + got["ended_or_native"], abs=1e-9)
+    assert got["ended_or_native"] >= -1e-3
+    # ended threads leave their role and stay in the process
+    later = trace.thread_cpu()
+    assert "p2p-recv" not in later and later["ended_or_native"] >= got["p2p-recv"] - 1e-3
+
+
+@pytest.mark.parametrize("name", trace.ROLES)
+def test_every_role_is_a_name_some_thread_start_gives(name):
+    """`ROLES` is what `/metrics` lists: a role no site starts a thread under
+    would read 0 for ever, and one missing from it would hide in `other`."""
+    sites = []
+    for path in glob.glob(os.path.join(ROOT, "cometbft_tpu", "**", "*.py"), recursive=True):
+        if not path.endswith(os.path.join("libs", "trace.py")):
+            with open(path) as f:
+                sites += re.findall(r'name\s*=\s*f?"([a-z0-9-]+)[":]|\("([a-z0-9-]+)", self\._\w+_routine\)',
+                                    f.read())
+    assert name in {a or b for a, b in sites}
+
+
+def test_thread_cpu_without_the_platforms_clock_is_the_process_alone(monkeypatch):
+    monkeypatch.delattr(time, "pthread_getcpuclockid")
+    assert set(trace.thread_cpu()) == {"process"}
+
+
 # -- (g) kernel scopes ----------------------------------------------------------------
 
 
@@ -540,14 +718,14 @@ def _names_in_code():
     found = set()
     for path in glob.glob(os.path.join(ROOT, "cometbft_tpu", "**", "*.py"), recursive=True):
         with open(path) as f:
-            found.update(re.findall(r'trace\.(?:span|record)\(\s*"([a-z_.]+)"', f.read()))
+            found.update(re.findall(r'trace\.(?:span|record)\(\s*"([a-z0-9_.]+)"', f.read()))
     return found
 
 
 def _names_in_perf_md():
     with open(os.path.join(ROOT, "PERF.md")) as f:
         table = f.read().split("<!-- spans -->")[1].split("<!-- /spans -->")[0]
-    return set(re.findall(r"`((?:validation|blocksync|types|state|store|batch|engine|supervisor|hybrid|device|grpc|sidecar)\.[a-z_]+)`", table))
+    return set(re.findall(r"`((?:validation|blocksync|types|state|store|batch|engine|supervisor|hybrid|device|grpc|sidecar|p2p)\.[a-z_]+)`", table))
 
 
 @pytest.mark.parametrize("name", trace.NAMES)
@@ -583,6 +761,12 @@ def test_pprof_jax_trace_writes_the_captures_spans(tmp_path, auto_chain):
     assert "spans.json" in msg and got["dropped"] == 0
     assert {s["name"] for s in got["spans"]} >= {"validation.verify_commit", "batch.verify"}
     assert all(got["t0"] <= s["t0"] for s in got["spans"])
+    assert all(s["pcpu"] is not None for s in got["spans"] if s["name"] != "engine.queue_wait")
+    # the session's CPU by role, closing on the process
+    threads = got["threads"]
+    assert threads["process"] > 0 and threads["verify-engine"] >= 0
+    assert threads["process"] == pytest.approx(
+        sum(v for k, v in threads.items() if k != "process"), abs=1e-6)
 
 
 def test_the_counters_reach_metrics_through_lazy_gauges():
@@ -603,6 +787,7 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
 
     reg = Registry(namespace="cmt")
     Node._register_backend_metrics(reg)
+    Node._register_host_metrics(reg)
     reactor = BlocksyncReactor.__new__(BlocksyncReactor)  # counters() reads these alone
     vars(reactor).update(
         pipeline_overlap_ms=0.0, heights_applied=9, fetch_wait_ms=4.2,
@@ -628,3 +813,36 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
     from cometbft_tpu.types import validator_set
     built = validator_set.columns_counters()["built"]
     assert f"cmt_verify_columns_built {built}" in out and "cmt_verify_columns_reused " in out
+    # CPU by thread role: one reading a scrape, closing on the process
+    host = {m.group(1): float(m.group(2)) for m in
+            re.finditer(r"^cmt_host_thread_cpu_seconds_(\w+) (\S+)$", out, re.M)}
+    assert set(host) == {r.replace("-", "_") for r in trace.ROLES} | {
+        "other", "process", "ended_or_native"}
+    assert host["process"] > 0 and host["other"] > 0, "this thread is of no named role"
+    assert host["process"] == pytest.approx(sum(host.values()) - host["process"], abs=1e-5)
+    assert "scheduler_" not in out, "the coalescer's gauges went: engine_* has the numbers"
+
+
+def test_the_engines_totals_are_engine_gauges():
+    from cometbft_tpu.libs.metrics import Registry
+    from cometbft_tpu.node.node import Node
+
+    class Inner:
+        def batch_verify(self, pubs, msgs, sigs):
+            return True, [True] * len(pubs)
+
+    reg = Registry(namespace="cmt")
+    Node._register_engine_metrics(reg)
+    assert "cmt_engine_requests 0" in reg.render()  # no backend yet: nothing constructed
+    eng = engine_mod.VerificationEngine(Inner())
+    be.set_backend(eng)
+    try:
+        for _ in range(3):
+            assert eng.batch_verify([b"p"], [b"m"], [b"s"]) == (True, [True])
+        out = reg.render()
+    finally:
+        be.set_backend(None)
+        eng.close()
+    assert "cmt_engine_requests 3" in out and "cmt_engine_dispatches 3" in out
+    assert "cmt_engine_batched_requests 0" in out and "cmt_engine_fallback_splits 0" in out
+    assert re.search(r"^cmt_engine_queue_wait_p95_us \d+$", out, re.M)
