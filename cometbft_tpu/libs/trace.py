@@ -124,6 +124,7 @@ NAMES = (
     "device.run",
     "device.wait",
     "device.unpack",
+    "device.table_build",
     # sidecar wire: the node's side, then the server's
     "grpc.call",
     "grpc.encode",

@@ -460,6 +460,13 @@ class Node:
             ("share_changes", "Split calls whose device share differs from the one before."),
             ("plan_abs_err_ms", "Sum of |predicted - measured| call wall, ms."),
             ("wall_ms", "Sum of measured call wall where a prediction was made, ms."),
+            ("resident_lanes", "Lanes verified against a resident key column's window tables."),
+            ("resident_calls", "Dispatches that rode a resident key column's window tables."),
+            ("resident_builds", "Key columns whose window tables were built on the device."),
+            ("resident_build_ms", "Sum of the table builds' time on the device-owner thread, ms."),
+            ("resident_bytes", "Bytes of window tables resident on each chip."),
+            ("resident_evictions", "Resident key columns given up to the bytes bound."),
+            ("resident_first_sightings", "Key columns remembered at their first sighting."),
         ):
             reg.gauge_func("hybrid", key, text, hybrid_sample(key))
 

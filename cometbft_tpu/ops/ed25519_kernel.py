@@ -16,18 +16,47 @@ all. The kernel takes the RAW 32-byte encodings as little-endian uint32
 words plus the padded challenge blocks, and runs the WHOLE verification on
 device: SHA-512 (sha512_kernel), k = digest mod L + signed-window recode +
 point decoding (ops/unpack.py), the signed-4-bit-window double-scalar
-ladder (edwards.windowed_double_base_mult), and the identity test — one
-jit-compiled program per (batch, block-count) bucket pair. A batch with a
-message past the largest block bucket is hashed on the host instead and the
-device receives 64-byte digests (verify_core_hosthash): see pack_batch.
+multiplication, and the identity test — one jit-compiled program per
+(batch, block-count) bucket pair. A batch with a message past the largest
+block bucket is hashed on the host instead and the device receives 64-byte
+digests (verify_core_hosthash): see pack_batch.
+
+Two programs, chosen by what the call's key column is, nothing else:
+
+- `verify_core`, for keys the tier has not seen as a column before (and the
+  host-hash program, always): decompress A and R, build [0..8](-A) per
+  lane, the ladder of edwards.windowed_double_base_mult — 252 doublings +
+  128 additions a lane.
+- `verify_core_resident`, for a column (or a long prefix of one) whose window
+  tables are resident on the device: decompress R only, then
+  edwards.windowed_table_mult — 128 table additions a lane and no doubling,
+  about a third of the field multiplications. Same digits, same signed
+  recoding, same cofactored equation, the key's decoding verdict kept
+  beside its table: the bitmap is the other program's, lane for lane.
+
+The tables (`_ResidentColumns`): a column of at least CMTPU_HYBRID_MIN
+DISTINCT, well-formed keys is remembered at its first sighting (its joined
+bytes) and its tables — int32[64, 8, 4, 17, bucket], 139,264 bytes a key,
+1.43 GB for 10,240 lanes — are built by one device program queued BEHIND
+the dispatch of its second sighting, which rides `verify_core` like the
+first: no call waits for a build it did not need (a call that arrives while
+its OWN column is being built waits for the tables and rides them). From
+the build's landing on, calls whose joined key bytes equal the column's, or
+a prefix of them at least half its bucket, ride the resident program, which
+has one shape a column, the tables' own: fewer lanes are widened to it on
+the host. Resident bytes are bounded (RESIDENT_MAX_BYTES a chip, oldest
+column out); a table is derived state: lost, it is rebuilt.
 """
 
 from __future__ import annotations
 
+import atexit
+import collections
 import functools
 import hashlib
 import os
 import queue
+import sys
 import threading
 import time
 from concurrent.futures import Future
@@ -39,6 +68,7 @@ import jax.numpy as jnp
 
 from cometbft_tpu.libs import trace
 from cometbft_tpu.ops import edwards as ed
+from cometbft_tpu.ops import field25519 as fe
 from cometbft_tpu.ops import sha512_kernel as s5
 from cometbft_tpu.ops import unpack
 
@@ -163,15 +193,10 @@ def block_bucket_for(b: int) -> int:
     return int(2 ** np.ceil(np.log2(b)))
 
 
-def verify_core(a_words, r_words, s_words, msg_words, msg_nblocks):
-    """Pure jittable core: raw little-endian words in (A, R, S as
-    int32[8, N]) plus the padded SHA-512 challenge byte stream as native
-    uint32 words (uint32[N, B*32] — a FREE view of the host pack buffer —
-    and per-lane block counts int32[N]), bool[N] out. The whole verification
-    is on-device: block-layout transpose + byte swap, challenge hash,
-    k = digest mod L, digit recodes, point decoding, window ladder, identity
-    test. The A and R decompressions ride ONE width-2N pass (lane-stacked) —
-    same op count in half the program."""
+def _challenge_words(msg_words, msg_nblocks):
+    """k's 64 digest bytes as int32[16, N] words: the padded SHA-512 byte
+    stream (native uint32 words, uint32[N, B*32] — a FREE view of the host
+    pack buffer — and per-lane block counts int32[N]) hashed on the device."""
     n, bwords = msg_words.shape
     bmax = bwords // 32
     with jax.named_scope("sha512"):
@@ -180,16 +205,25 @@ def verify_core(a_words, r_words, s_words, msg_words, msg_nblocks):
         # ops instead of multi-MB host passes.
         x = msg_words.astype(jnp.uint32).reshape(n, bmax, 16, 2)
         blocks_be = s5.bswap32(jnp.transpose(x, (1, 3, 2, 0)))
-        k_words = s5.digest_to_le_words(
-            s5.hash_blocks_core(blocks_be, msg_nblocks)
-        )
+        return s5.digest_to_le_words(s5.hash_blocks_core(blocks_be, msg_nblocks))
+
+
+def verify_core(a_words, r_words, s_words, msg_words, msg_nblocks):
+    """Pure jittable core: raw little-endian words in (A, R, S as
+    int32[8, N]) plus the padded SHA-512 challenge byte stream, bool[N] out.
+    The whole verification is on-device: block-layout transpose + byte swap,
+    challenge hash, k = digest mod L, digit recodes, point decoding, window
+    ladder, identity test. The A and R decompressions ride ONE width-2N
+    pass (lane-stacked) — same op count in half the program."""
+    k_words = _challenge_words(msg_words, msg_nblocks)
     return _verify_from_words(a_words, r_words, s_words, k_words)
 
 
 def verify_core_hosthash(a_words, r_words, s_words, k_words):
     """The program for batches pack_batch hashed on the host (a message past
     the largest block bucket): the 64-byte challenge digests come in as
-    int32[16, N] little-endian words."""
+    int32[16, N] little-endian words. Always the ladder: resident tables
+    serve the device-hash program only."""
     return _verify_from_words(a_words, r_words, s_words, k_words)
 
 
@@ -216,6 +250,42 @@ def _verify_from_words(a_words, r_words, s_words, k_words):
         acc = ed.point_add(acc, ed.point_neg(r))
         acc = ed.point_double(ed.point_double(ed.point_double(acc)))
         return ok[:n] & ok[n:] & ed.point_is_identity(acc)
+
+
+def verify_core_resident(tables_a, ok_a, r_words, s_words, msg_words, msg_nblocks):
+    """verify_core for lanes whose keys' window tables are resident:
+    tables_a int32[64, 8, 4, 17, N] and ok_a bool[N] as build_key_tables
+    left them, lane for lane (a dispatch of fewer lanes is widened to the
+    tables' on the host: _widen). The keys themselves are not an operand:
+    the challenge stream holds their bytes and the tables hold their
+    points. Hash, unpack, decompress R only, 128 table additions a lane,
+    the same finish."""
+    k_words = _challenge_words(msg_words, msg_nblocks)
+    with jax.named_scope("unpack"):
+        y_r, sign_r = unpack.words_to_limbs255(r_words)
+        s_digits = unpack.scalar_words_to_digits(s_words)
+        k_digits = unpack.digest_words_to_digits(k_words)
+    with jax.named_scope("decompress"):
+        r, ok_r = ed.decompress(y_r, sign_r)
+    with jax.named_scope("ladder"):
+        acc = ed.windowed_table_mult(s_digits, k_digits, tables_a)
+    with jax.named_scope("finish"):  # verify_core's, to the letter
+        acc = ed.point_add(acc, ed.point_neg(r))
+        acc = ed.point_double(ed.point_double(ed.point_double(acc)))
+        return ok_a & ok_r & ed.point_is_identity(acc)
+
+
+def build_key_tables(a_words):
+    """The build program of a resident column: keys as int32[8, M] words ->
+    (window tables of -A, int32[64, 8, 4, 17, M]; the decoding verdict
+    bool[M], kept: a key that does not decode stays false under ZIP-215
+    exactly as in verify_core, whatever its lane's table holds)."""
+    with jax.named_scope("unpack"):
+        y_a, sign_a = unpack.words_to_limbs255(a_words)
+    with jax.named_scope("decompress"):
+        a, ok_a = ed.decompress(y_a, sign_a)
+    with jax.named_scope("table_build"):
+        return ed.build_window_tables(ed.point_neg(a)), ok_a
 
 
 @functools.lru_cache(maxsize=None)
@@ -434,21 +504,76 @@ def _sharded_verify():
     return n_dev, sharded.sharded_verify_fn(sharded.make_mesh(jax.local_devices()))
 
 
-def _route_for(operands):
+@functools.lru_cache(maxsize=2)
+def _resident_programs(sharded: bool):
+    """(build, verify) programs of resident columns: lane-sharded over the
+    mesh (the tables on their last axis, zero collectives, as
+    _sharded_verify) for a column whose bucket takes the mesh route, else
+    single-device."""
+    if not sharded:
+        return jax.jit(build_key_tables), jax.jit(verify_core_resident)
+    from cometbft_tpu.ops import sharded as sh
+
+    mesh = sh.make_mesh(jax.local_devices())
+    return sh.sharded_build_fn(mesh), sh.sharded_resident_fn(mesh)
+
+
+def _mesh_sharded(key) -> bool:
+    """Whether a (batch, block) bucket pair takes the lane-sharded mesh
+    program: several chips, at/above the sharding floor, the chips dividing
+    the bucket; never the host-hash program, whose shapes aren't sharded."""
+    if key[1] == 0:
+        return False
+    sh = _sharded_verify()
+    return sh is not None and key[0] >= mesh_floor() and key[0] % sh[0] == 0
+
+
+def _tables_serve(key, tables) -> bool:
+    """Whether a resident column's (tables_a, ok_a) serve a dispatch of this
+    bucket pair whose lanes are the column's first. The resident program
+    has ONE shape a column, the tables' own: a dispatch of fewer lanes is
+    widened to it (_widen), so the walk of a planner over its shares loads
+    no program a share. That pays while the share's own bucket is at least
+    half the tables' lanes (on the chip the tables' 10,240 lanes take
+    36.4 ms, the ladder 33.1 at 4,096 and 47.5 at 6,144); a smaller share
+    keeps the ladder, and so does the host-hash program."""
+    if tables is None or key[1] == 0:
+        return False
+    lanes = tables[1].shape[0]
+    return lanes // 2 <= key[0] <= lanes
+
+
+def _widen(operands, lanes: int):
+    """Packed (r_words, s_words, msg_words, msg_nblocks) with zero lanes
+    appended up to `lanes`: lanes the device evaluates and nobody reads."""
+    pad = lanes - operands[0].shape[1]
+    if pad == 0:
+        return operands
+    r_words, s_words, msg_words, msg_nblocks = operands
+    return (
+        np.pad(r_words, ((0, 0), (0, pad))),
+        np.pad(s_words, ((0, 0), (0, pad))),
+        np.pad(msg_words, ((0, pad), (0, 0))),
+        np.pad(msg_nblocks, (0, pad)),
+    )
+
+
+def _route_for(operands, tables=None):
     """(program, mesh-sharded?) the routing layer would run for these packed
     operands: the lane-sharded multi-chip program when this process owns
     several chips and the bucket is at/above the sharding floor (the
     mesh-aware ladder guarantees such buckets divide the device count),
-    else the single-device bucket program."""
+    else the single-device bucket program. Where `tables` serve the
+    dispatch (_tables_serve) the resident program of the tables' own
+    bucket, which takes the tables in the keys' place."""
     key = _bucket_key(operands)
-    if key[1] != 0:  # hosthash program shapes aren't mesh-sharded
-        sh = _sharded_verify()
-        if (
-            sh is not None
-            and key[0] >= mesh_floor()
-            and key[0] % sh[0] == 0
-        ):
-            return sh[1], True
+    if _tables_serve(key, tables):
+        lanes = tables[1].shape[0]
+        sharded = _mesh_sharded((lanes, key[1]))
+        resident = _resident_programs(sharded)[1]
+        return (lambda _a_words, *rest: resident(*tables, *_widen(rest, lanes))), sharded
+    if _mesh_sharded(key):
+        return _sharded_verify()[1], True
     return _compiled(*key), False
 
 
@@ -466,8 +591,226 @@ def clear_compiled_caches() -> None:
     (field25519._ACCEL) the jaxpr of the one before."""
     _compiled.cache_clear()
     _sharded_verify.cache_clear()
+    _resident_programs.cache_clear()
     mesh_width.cache_clear()
     jax.clear_caches()
+
+
+# -- resident key columns ------------------------------------------------------------
+
+# Bytes of window tables kept on each chip: a quarter of a v5e's 16 GiB,
+# two 10,240-lane columns with room to spare. Oldest column out.
+RESIDENT_MAX_BYTES = 4 << 30
+TABLE_BYTES_PER_LANE = ed.DIGITS * 8 * 4 * fe.LIMBS * 4  # 139,264
+# Columns seen but not (yet) built whose joined bytes are remembered.
+_COLUMNS_REMEMBERED = 8
+
+_SEEN, _WANTED, _BUILDING, _RESIDENT = range(4)
+
+
+class _Column:
+    """One remembered key column: its joined bytes, and once built its
+    (tables_a, ok_a) on the device."""
+
+    __slots__ = ("joined", "lanes", "state", "tables", "landed")
+
+    def __init__(self, joined: bytes, lanes: int):
+        self.joined = joined
+        self.lanes = lanes
+        self.state = _SEEN
+        self.tables = None
+        self.landed = threading.Event()  # set when a build ends, however
+
+
+# What one look-up found: `tables`, the column's (tables_a, ok_a) where the
+# call's keys are a resident column or a prefix of one, else None; `build`,
+# the column whose build the call's dispatch is to be followed by, else None.
+Sighting = collections.namedtuple("Sighting", "tables build")
+_NOTHING = Sighting(None, None)
+
+
+class _ResidentColumns:
+    """Which key columns the tier has seen, and the window tables of those
+    it has seen twice. Policy in the module docstring; all of it is decided
+    by the call's key bytes."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._by_first: dict[bytes, list[_Column]] = {}
+        self._lru: collections.OrderedDict = collections.OrderedDict()  # id -> column
+        self._closing = False  # the interpreter is exiting: start no build
+        self._counters = dict.fromkeys(
+            (
+                "resident_lanes", "resident_calls", "resident_builds",
+                "resident_build_ms", "resident_bytes", "resident_evictions",
+                "resident_first_sightings",
+            ),
+            0,
+        )
+
+    def counters(self) -> dict:
+        with self._lock:
+            out = dict(self._counters)
+        out["resident_build_ms"] = round(out["resident_build_ms"], 2)
+        return out
+
+    def count_call(self, lanes: int) -> None:
+        with self._lock:
+            self._counters["resident_calls"] += 1
+            self._counters["resident_lanes"] += lanes
+
+    def sight(self, pubs) -> Sighting:
+        """The one look-up of a call, made where the whole column is in
+        view (before any split): by (first key, length), confirmed by one
+        compare of the joined bytes; a column that is a prefix of a resident
+        one matches too. Counts the sighting of a whole column."""
+        n = len(pubs)
+        if n < _resident_min():
+            return _NOTHING
+        try:
+            joined = b"".join(pubs)
+            if len(joined) != 32 * n or max(map(len, pubs)) != 32:
+                return _NOTHING  # a malformed key: never resident
+        except TypeError:
+            return _NOTHING
+        with self._lock:
+            col = self._match(joined, n)
+            if col is None:
+                # Distinct keys only: a column that repeats a key is several
+                # heights of a smaller set (a blocksync prefetch window) and
+                # wants THAT set's tables indexed by lane, which is another
+                # mechanism.
+                keys = {joined[i : i + 32] for i in range(0, 32 * n, 32)}
+                if len(keys) == n and self._nbytes(n) <= RESIDENT_MAX_BYTES:
+                    self._remember(_Column(joined, n))
+                return _NOTHING
+            self._lru.move_to_end(id(col))
+            if col.state == _SEEN:
+                col.state = _WANTED  # the second sighting
+            if col.state == _WANTED:
+                return Sighting(None, col)
+        # A call that arrives while its own column's tables are being built
+        # would queue behind the build on the device-owner thread anyway:
+        # it waits here and rides them, instead of walking the planner to
+        # one more ladder bucket (a program load of seconds) for nothing.
+        col.landed.wait()
+        return Sighting(col.tables, None)
+
+    def _match(self, joined: bytes, n: int):
+        """The remembered column these n keys are (whichever has tables, if
+        two do), else a resident one they are a prefix of, else None."""
+        found = None
+        for c in self._by_first.get(joined[:32], ()):
+            if c.lanes == n and c.joined == joined:
+                found = c
+                if c.tables is not None:
+                    break
+            elif c.lanes > n and c.tables is not None and c.joined.startswith(joined):
+                return c
+        return found
+
+    @staticmethod
+    def _nbytes(lanes: int) -> int:
+        """Table bytes a column of `lanes` keys holds on each chip."""
+        return TABLE_BYTES_PER_LANE * bucket_for(lanes) // mesh_width()
+
+    def _remember(self, col: _Column) -> None:
+        self._counters["resident_first_sightings"] += 1
+        self._by_first.setdefault(col.joined[:32], []).append(col)
+        self._lru[id(col)] = col
+        waiting = [c for c in self._lru.values() if c.tables is None]
+        for old in waiting[: max(0, len(waiting) - _COLUMNS_REMEMBERED)]:
+            if old.state != _BUILDING:
+                self._forget(old)
+
+    def _forget(self, col: _Column) -> None:
+        del self._lru[id(col)]
+        cols = self._by_first[col.joined[:32]]
+        cols.remove(col)
+        if not cols:
+            del self._by_first[col.joined[:32]]
+        if col.tables is not None:
+            # a dispatch in flight keeps its own reference to the arrays
+            col.tables = None
+            self._counters["resident_bytes"] -= self._nbytes(col.lanes)
+
+    def queue_build(self, col: _Column) -> None:
+        """Once per column: build(col) goes on the device-owner thread's
+        queue, so behind the dispatch of the call that asks."""
+        with self._lock:
+            if col.state != _WANTED or self._closing:
+                return
+            col.state = _BUILDING
+        fut = _pool().submit(lambda: self.build(col))
+        # Nobody waits for a build, except the interpreter's exit: a daemon
+        # thread left inside the device runtime while Python finalizes
+        # aborts the process.
+        atexit.register(self._close, fut)
+
+    def _close(self, fut: Future) -> None:
+        self._closing = True
+        if fut.running():
+            try:  # bounded: a wedged device must not hold the exit for good
+                fut.exception(timeout=120)
+            except TimeoutError:
+                pass
+
+    def build(self, col: _Column) -> None:
+        """On the device-owner thread: the column's tables, by one device
+        program. A build that fails (the device's memory) forgets the
+        column; its calls ride the ladder as before."""
+        bucket = bucket_for(col.lanes)
+        nbytes = self._nbytes(col.lanes)
+        t0 = time.perf_counter()
+        try:
+            if self._closing:
+                return
+            with trace.span("device.table_build", lanes=bucket, bytes=nbytes):
+                a_enc = np.zeros((bucket, 32), np.uint8)
+                a_enc[: col.lanes] = np.frombuffer(col.joined, np.uint8).reshape(-1, 32)
+                build_fn = _resident_programs(_mesh_sharded((bucket, 1)))[0]
+                tables = jax.block_until_ready(build_fn(unpack.bytes_to_words(a_enc)))
+            with self._lock:
+                if id(col) not in self._lru:
+                    return  # forgotten meanwhile
+                col.tables, col.state = tuple(tables), _RESIDENT
+                self._counters["resident_builds"] += 1
+                self._counters["resident_build_ms"] += (time.perf_counter() - t0) * 1000
+                self._counters["resident_bytes"] += nbytes
+                for old in list(self._lru.values()):  # oldest first
+                    if self._counters["resident_bytes"] <= RESIDENT_MAX_BYTES:
+                        break
+                    if old is not col and old.tables is not None:
+                        self._forget(old)
+                        self._counters["resident_evictions"] += 1
+        except Exception as e:  # nobody reads a build's result: say it here
+            print(f"ed25519_kernel: table build of {col.lanes} keys failed: "
+                  f"{type(e).__name__}: {e}", file=sys.stderr, flush=True)
+            with self._lock:
+                if id(col) in self._lru:
+                    self._forget(col)
+        finally:
+            col.landed.set()
+
+    def clear(self) -> None:
+        """Forget every column (tests; tables of another lowering or mesh)."""
+        with self._lock:
+            for col in list(self._lru.values()):
+                self._forget(col)
+
+
+def _resident_min() -> int:
+    """Smallest key column worth tables: the hybrid tier's split threshold,
+    below which a batch never reaches the device."""
+    try:
+        return max(1, int(os.environ.get("CMTPU_HYBRID_MIN", "2048")))
+    except ValueError:
+        return 2048
+
+
+_columns = _ResidentColumns()
+sight_column = _columns.sight
+resident_counters = _columns.counters
 
 
 # Held while a batch is packed on the host: Python under the interpreter
@@ -480,29 +823,45 @@ def clear_compiled_caches() -> None:
 PACK_GATE = threading.Lock()
 
 
-def batch_verify_submit(pubs, msgs, sigs):
+def batch_verify_submit(pubs, msgs, sigs, sighting: Sighting | None = None):
     """Pack on the calling thread, dispatch on the device-owner thread,
     return a collect() -> (ok, bitmap) closure. The hybrid backend runs its
     host MSM share between submit and collect; callers that want the
-    blocking behavior just collect immediately (batch_verify below)."""
+    blocking behavior just collect immediately (batch_verify below).
+    `sighting` is sight_column's answer for the column these lanes are the
+    first of, from a caller that saw the whole column before it split it;
+    a caller with none (a bare device tier) has the look-up made here."""
     n = len(pubs)
+    if sighting is None:
+        sighting = sight_column(pubs) if n else _NOTHING
     with trace.span("device.pack", lanes=n) as pack, PACK_GATE:
         operands, host_ok = pack_batch(pubs, msgs, sigs)
         key = _bucket_key(operands)
-        pack.set(bucket=key[0])
-    fn, sharded = _route_for(operands)
+        tables = sighting.tables if _tables_serve(key, sighting.tables) else None
+        if tables is not None:  # the resident program runs the tables' own bucket
+            key = (tables[1].shape[0], key[1])
+        pack.set(bucket=key[0], resident=tables is not None)
+    fn, sharded = _route_for(operands, tables)
     if sharded:
         _mesh_count("sharded_dispatches")
         _mesh_count("padded_lanes", key[0] - n)
+    if tables is not None:
+        _columns.count_call(n)
     caller = trace.current()
 
     def run():  # on the device-owner thread, traced under the caller
         started = time.perf_counter()
-        with trace.span("device.run", parent=caller, bucket=key[0], sharded=sharded):
+        with trace.span(
+            "device.run", parent=caller, bucket=key[0], sharded=sharded,
+            resident=tables is not None,
+        ):
             dev_ok = np.asarray(fn(*operands))
         return dev_ok, (started, time.perf_counter())
 
     fut = _pool().submit(run)
+    if sighting.build is not None:
+        # BEHIND this call's dispatch: no call waits for a build it did not need.
+        _columns.queue_build(sighting.build)
 
     def collect() -> tuple[bool, list]:
         with trace.span("device.wait"):
@@ -511,9 +870,10 @@ def batch_verify_submit(pubs, msgs, sigs):
             results = [bool(host_ok[i] and dev_ok[i]) for i in range(n)]
         return all(results), results
 
-    # (batch bucket, block bucket) — the compiled-program identity, so
-    # callers can tell a first dispatch (XLA compile) from a steady one.
-    collect.program_key = key
+    # (batch bucket, block bucket, lanes of the resident tables or 0) — the
+    # compiled-program identity, so callers can tell a first dispatch (XLA
+    # compile) from a steady one, and the ladder's walls from the tables'.
+    collect.program_key = (*key, key[0] if tables is not None else 0)
     # (start, return) of the program on the device-owner thread's clock,
     # time.perf_counter(), once collect() has run: the device's wall is known
     # to the caller even when it came to collect() long after the device

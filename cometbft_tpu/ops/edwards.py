@@ -9,14 +9,22 @@ curve25519-voi backend branches per point class instead;
 crypto/ed25519/ed25519.go:27-29).
 
 Double-scalar multiplication [s]B + [k]A uses SIGNED 4-bit fixed windows
-(64 digits in [-8, 8]): 4 doublings + 2 precomputed-table additions per
-window instead of the 1 doubling + 1 addition per BIT of a Shamir ladder —
-252 doublings + 128 adds total vs 253 + 253. The per-lane table for A is
-built once per batch (4 doublings + 3 additions); the table for the fixed
-base B is a compile-time constant (the analog of curve25519-voi's fixed-base
-precomputation that the reference's single-verify path leans on). Negated
-digits cost one conditional precomp negation — on Edwards that is a
-coordinate swap, which is why signed windows halve the table size for free.
+(64 digits in [-8, 8]), in two forms. Negated digits cost one conditional
+precomp negation either way — on Edwards that is a coordinate swap, which is
+why signed windows halve the table size for free.
+
+- The ladder (`windowed_double_base_mult`), for keys seen for the first
+  time: 4 doublings + 2 precomputed-table additions per window — 252
+  doublings + 128 adds. The per-lane table [0..8]A is built once per batch;
+  the table for the fixed base B is a compile-time constant.
+- The table sum (`windowed_table_mult`), for a key column whose window
+  tables are resident on the device: sum_w T_A[w][k_w] + T_B[w][s_w], 128
+  additions a lane and NO doubling. T_A is `build_window_tables`' int32
+  [64, 8, 4, 17, N] (window, entry 1..8, coordinate of the precomputed
+  form, limb, lane) with entry (w, j) = 16^w * j * P: 139,264 bytes a key,
+  built on the device once per key column (64 doublings + 448 additions a
+  key). T_B is the same table of the base point, [64, 9, 4, 17, 1] with
+  Z == 1, a compile-time constant (`WINDOW_TABLE_B`).
 """
 
 from __future__ import annotations
@@ -224,11 +232,11 @@ WINDOW_BITS = 4
 DIGITS = 64  # ceil(253 / 4) windows cover scalars < L < 2^253 (+ carry room)
 
 
-def build_table_pre(p) -> jnp.ndarray:
-    """Per-lane window table [0..8]P in precomp form as ONE int32[9, 4, 17, N]
-    array (axis 1 = ymx/ypx/2dT/Z). Built by a rolled chain of additions so
-    the table costs a single compiled add_precomp body, not 7 inlined point
-    ops (compile-size control)."""
+def _window_entries(p):
+    """([0..8]P in precomp form as ONE int32[9, 4, 17, N] array (axis 1 =
+    ymx/ypx/2dT/Z), 8P in extended form). Built by a rolled chain of
+    additions so the table costs a single compiled add_precomp body, not 7
+    inlined point ops (compile-size control)."""
     n = p[0].shape[1]
     pp = to_precomp(p)
     tbl = jnp.zeros((9, 4, fe.LIMBS, n), jnp.int32)
@@ -241,53 +249,83 @@ def build_table_pre(p) -> jnp.ndarray:
         tbl = tbl.at[i].set(jnp.stack(to_precomp(nxt)))
         return tbl, nxt
 
-    tbl, _ = lax.fori_loop(2, 9, body, (tbl, p))
-    return tbl
+    return lax.fori_loop(2, 9, body, (tbl, p))
 
 
-def _host_table_b() -> np.ndarray:
-    """Constant table [0..8]B in precomp form: int32[9, 4, 17, 1], computed
-    with host integer math at import (the fixed-base precomputation — B is a
-    compile-time constant, so [s]B rides the same select/add path as [k]A
-    with a broadcastable table)."""
+def build_window_tables(p) -> jnp.ndarray:
+    """Per-lane fixed-window tables of P: int32[64, 8, 4, 17, N], entry
+    (w, j - 1) = 16^w * j * P in precomp form (j = 1..8; the identity a
+    zero digit selects is select_precomp_signed's own). Per window the eight
+    entries by the rolled addition of the ladder's table, then ONE doubling
+    of the eighth to the next window's base: 16^(w+1) P = 2 * (8 * 16^w P)."""
+    n = p[0].shape[1]
 
-    def add_int(P1, P2):
-        x1, y1 = P1
-        x2, y2 = P2
-        num = _D * x1 * x2 % _P * y1 % _P * y2 % _P
-        x3 = (x1 * y2 + x2 * y1) % _P * pow(1 + num, _P - 2, _P) % _P
-        y3 = (y1 * y2 + x1 * x2) % _P * pow(1 - num + _P, _P - 2, _P) % _P
-        return (x3, y3)
+    def body(w, carry):
+        tbl, base = carry
+        entries, eighth = _window_entries(base)
+        return tbl.at[w].set(entries[1:]), point_double(eighth)
 
-    rows = [
-        np.stack(
+    tbl = jnp.zeros((DIGITS, 8, 4, fe.LIMBS, n), jnp.int32)
+    return lax.fori_loop(0, DIGITS, body, (tbl, p))[0]
+
+
+def _host_window_table_b() -> np.ndarray:
+    """Constant window tables of the base point in precomp form with Z == 1:
+    int32[64, 9, 4, 17, 1], entry (w, j) = 16^w * j * B (j = 0 the identity),
+    computed with host integer math at import (the fixed-base
+    precomputation — B is a compile-time constant, so [s]B rides the same
+    select/add path as [k]A with a broadcastable table). Projective integer
+    additions, then ONE modular inversion for all 512 points."""
+
+    def add_int(p1, p2):  # add-2008-hwcd-3, complete: doubles too
+        x1, y1, z1, t1 = p1
+        x2, y2, z2, t2 = p2
+        a = (y1 - x1) * (y2 - x2) % _P
+        b = (y1 + x1) * (y2 + x2) % _P
+        c = t1 * fe.TWO_D_INT % _P * t2 % _P
+        d = 2 * z1 * z2 % _P
+        e, f, g, h = b - a, d - c, d + c, b + a
+        return (e * f % _P, g * h % _P, f * g % _P, e * h % _P)
+
+    pts = []
+    base = (_BX, _BY, 1, _BX * _BY % _P)
+    for _ in range(DIGITS):
+        cur = base
+        for _ in range(8):
+            pts.append(cur)
+            eighth, cur = cur, add_int(cur, base)
+        base = add_int(eighth, eighth)  # 16 * base
+    # Montgomery's trick: prefix products of Z, one inversion, walk back.
+    prefix = [1]
+    for p in pts:
+        prefix.append(prefix[-1] * p[2] % _P)
+    inv = pow(prefix[-1], _P - 2, _P)
+    identity_row = np.stack([fe.int_to_limbs(v) for v in (1, 1, 0, 1)])
+    rows = [None] * len(pts)
+    for i in range(len(pts) - 1, -1, -1):
+        zinv = inv * prefix[i] % _P
+        inv = inv * pts[i][2] % _P
+        x, y = pts[i][0] * zinv % _P, pts[i][1] * zinv % _P
+        rows[i] = np.stack(
             [
-                fe.int_to_limbs(1),
-                fe.int_to_limbs(1),
-                fe.int_to_limbs(0),
+                fe.int_to_limbs((y - x) % _P),
+                fe.int_to_limbs((y + x) % _P),
+                fe.int_to_limbs(x * y % _P * fe.TWO_D_INT % _P),
                 fe.int_to_limbs(1),
             ]
         )
-    ]
-    cur = (_BX, _BY)
-    for _ in range(8):
-        x, y = cur
-        rows.append(
-            np.stack(
-                [
-                    fe.int_to_limbs((y - x) % _P),
-                    fe.int_to_limbs((y + x) % _P),
-                    fe.int_to_limbs(x * y % _P * fe.TWO_D_INT % _P),
-                    fe.int_to_limbs(1),
-                ]
-            )
-        )
-        cur = add_int(cur, (_BX, _BY))
+    tbl = np.stack(rows).reshape(DIGITS, 8, 4, fe.LIMBS)
+    tbl = np.concatenate(
+        [np.broadcast_to(identity_row, (DIGITS, 1, 4, fe.LIMBS)), tbl], axis=1
+    )
     # numpy literal so the Pallas kernel can close over it (see const_fe)
-    return np.stack(rows)[:, :, :, None]  # [9, 4, 17, 1]
+    return np.ascontiguousarray(tbl)[..., None]  # [64, 9, 4, 17, 1]
 
 
-TABLE_B_PRE = _host_table_b()
+WINDOW_TABLE_B = _host_window_table_b()
+TABLE_B_PRE = WINDOW_TABLE_B[0]  # [0..8]B: the ladder's table
+
+_PRECOMP_IDENTITY = WINDOW_TABLE_B[0, 0]  # (1, 1, 0, 1) as [4, 17, 1]
 
 
 def select_precomp_signed(table: jnp.ndarray, digits: jnp.ndarray):
@@ -295,14 +333,23 @@ def select_precomp_signed(table: jnp.ndarray, digits: jnp.ndarray):
     point table[|d|], negated when d < 0. Binary-cascade selects over the
     stacked table (no gather: TPU per-lane gathers lower to far slower code
     than a 4-level vector select tree). table: [9, 4, 17, N] or [9, 4, 17, 1]
-    (constant B table, broadcast over lanes)."""
+    (entries 0..8; the constant B table, broadcast over lanes), or
+    [8, 4, 17, N] (entries 1..8 of a resident window table: |d| == 0 selects
+    the identity, which no table need hold)."""
     idx = jnp.abs(digits)
-    m = lambda bit: ((idx & bit) == bit)[None, None, None, :]
+    with_zero = table.shape[0] == 9
+    # position among the eight entries the cascade walks: 0..7 of the
+    # nine-entry table, 1..8 (as j - 1) of the eight-entry one
+    pos = idx if with_zero else idx - 1
+    m = lambda bit: ((pos & bit) == bit)[None, None, None, :]
     u = table[:8]
     s = jnp.where(m(1), u[1::2], u[0::2])          # [4,4,17,N], groups by bits 3..2
     s = jnp.where(m(2)[0], s[1::2], s[0::2])       # [2,4,17,N], groups by bit 3
     s = jnp.where(m(4)[0, 0], s[1], s[0])          # [4, 17, N]
-    s = jnp.where(m(8)[0, 0], table[8], s)         # |d| == 8
+    if with_zero:
+        s = jnp.where(m(8)[0, 0], table[8], s)         # |d| == 8
+    else:
+        s = jnp.where((idx == 0)[None, None, :], _PRECOMP_IDENTITY, s)
     pt = (s[0], s[1], s[2], s[3])
     return precomp_select(digits < 0, precomp_neg(pt), pt)
 
@@ -315,7 +362,7 @@ def windowed_double_base_mult(s_digits: jnp.ndarray, k_digits: jnp.ndarray, a_po
     for SPMD: per window, 4 accumulator doublings + one add from the
     per-lane [1..8]A table + one add from the constant [1..8]B table."""
     n = s_digits.shape[1]
-    table_a = build_table_pre(a_point)
+    table_a, _ = _window_entries(a_point)
 
     def body(w, acc):
         row = DIGITS - 1 - w
@@ -327,6 +374,23 @@ def windowed_double_base_mult(s_digits: jnp.ndarray, k_digits: jnp.ndarray, a_po
         return acc
 
     return lax.fori_loop(0, DIGITS, body, identity(n))
+
+
+def windowed_table_mult(s_digits: jnp.ndarray, k_digits: jnp.ndarray, tables_a):
+    """[s]B + [k]A for lanes whose window tables are resident: the sum over
+    the 64 windows of T_A[w][k_w] + T_B[w][s_w], two table additions a
+    window and no doubling. Same signed digits as the ladder, so the same
+    point. tables_a: build_window_tables' [64, 8, 4, 17, N], lane for lane."""
+    table_b = jnp.asarray(WINDOW_TABLE_B)
+
+    def body(w, acc):
+        ta = lax.dynamic_index_in_dim(tables_a, w, 0, keepdims=False)
+        tb = lax.dynamic_index_in_dim(table_b, w, 0, keepdims=False)
+        acc = add_precomp(acc, select_precomp_signed(ta, k_digits[w]))
+        # every entry of the constant B tables has Z == 1
+        return add_precomp_z1(acc, select_precomp_signed(tb, s_digits[w]))
+
+    return lax.fori_loop(0, DIGITS, body, identity(s_digits.shape[1]))
 
 
 def scalars_to_digits(scalars: np.ndarray) -> np.ndarray:

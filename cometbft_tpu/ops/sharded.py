@@ -49,6 +49,33 @@ def sharded_verify_fn(mesh: Mesh, axis: str = "sig"):
     )
 
 
+def sharded_build_fn(mesh: Mesh, axis: str = "sig"):
+    """The build program of a resident key column with the lane axis
+    sharded: keys int32[8, M] -> (window tables int32[64, 8, 4, 17, M]
+    sharded on their last axis, the decoding verdicts bool[M]). Every lane's
+    tables are built on the chip that will add against them."""
+    return jax.jit(
+        ek.build_key_tables,
+        in_shardings=NamedSharding(mesh, P(None, axis)),
+        out_shardings=(
+            NamedSharding(mesh, P(None, None, None, None, axis)),
+            NamedSharding(mesh, P(axis)),
+        ),
+    )
+
+
+def sharded_resident_fn(mesh: Mesh, axis: str = "sig"):
+    """sharded_verify_fn for a resident column: the tables and their
+    verdicts, as sharded_build_fn left them, in the keys' place. Zero
+    collectives, as there."""
+    specs = (P(None, None, None, None, axis), P(axis), *_verify_specs(axis)[1:])
+    return jax.jit(
+        ek.verify_core_resident,
+        in_shardings=tuple(NamedSharding(mesh, s) for s in specs),
+        out_shardings=NamedSharding(mesh, P(axis)),
+    )
+
+
 def sharded_verify_replicated_fn(mesh: Mesh, axis: str = "sig"):
     """Batch verify with the ok bitmap REPLICATED instead of batch-sharded:
     on a multi-HOST mesh, `sharded_verify_fn`'s sharded output leaves each

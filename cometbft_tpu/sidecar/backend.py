@@ -138,7 +138,11 @@ class TpuBackend(VerifyBackend):
         }
 
     def counters(self) -> dict:
-        return {**self.device_info(), "device_lanes": self.device_lanes}
+        return {
+            **self.device_info(),
+            "device_lanes": self.device_lanes,
+            **self._ed.resident_counters(),
+        }
 
     def batch_verify(self, pubs, msgs, sigs):
         self.device_lanes += len(pubs)
@@ -212,14 +216,19 @@ class HybridBackend(VerifyBackend):
         self._dev_overhead = float(os.environ.get("CMTPU_DEV_OVERHEAD_MS", "8"))
         self._min_split = int(os.environ.get("CMTPU_HYBRID_MIN", "2048"))
         self._rate_lock = threading.Lock()
-        # Compiled-program keys (batch bucket, block bucket, mesh width)
-        # that have already run once in this process: the first dispatch of
-        # a program can pay a multi-second XLA compile, which must not be
-        # charged to the steady-state rate model.
+        # Compiled-program keys (batch bucket, block bucket, lanes of the
+        # resident tables or 0, mesh width) that have already run once in
+        # this process: the first dispatch of a program can pay a
+        # multi-second XLA compile, which must not be charged to the
+        # steady-state rate model.
         self._warmed: set[tuple] = set()
-        # Measured device wall per (batch bucket, mesh width): pack to the
-        # device-owner thread's return, the median of the last three calls
-        # that ran the bucket (_dev_recent). The device's own time repeats
+        # Measured device wall per (batch bucket, mesh width, resident?):
+        # pack to the device-owner thread's return, the median of the last
+        # three calls that ran the bucket (_dev_recent). One bucket has two
+        # programs — the ladder, and the table sum over a resident key
+        # column (ops/ed25519_kernel) — whose walls differ threefold, so a
+        # call is priced with the walls of the kind its look-up found.
+        # The device's own time repeats
         # to a millisecond, and what disturbs a sample is one-sided and
         # rare — a host stall inside the pack, 116 ms once in 815 calls on
         # the chip's machine — but a bucket the planner has left is never
@@ -229,8 +238,8 @@ class HybridBackend(VerifyBackend):
         # every other; real walls win. Width in the key so a mesh-size
         # change (or a test flipping the virtual mesh) can't reuse stale
         # single-chip walls.
-        self._dev_wall: dict[tuple[int, int], float] = {}
-        self._dev_recent: dict[tuple[int, int], collections.deque] = {}
+        self._dev_wall: dict[tuple[int, int, bool], float] = {}
+        self._dev_recent: dict[tuple[int, int, bool], collections.deque] = {}
         # Share + stage walls of the most recent split call (observability;
         # chip_smoke.py and the benchmark's readers report them).
         self.last_share = 0
@@ -255,8 +264,12 @@ class HybridBackend(VerifyBackend):
         minimizing predicted max(device time, host time)."""
         return self._plan_cost(n)[0]
 
-    def _plan_cost(self, n: int) -> tuple[int, float]:
-        """_plan's share with the wall in ms the model predicts for it."""
+    def _plan_cost(self, n: int, resident_lanes: int = 0) -> tuple[int, float]:
+        """_plan's share with the wall in ms the model predicts for it.
+        `resident_lanes` > 0: the call's key column has its tables on the
+        device, that many lanes wide, so the resident program's walls price
+        it — at the tables' own bucket whatever the share, because that
+        program has one shape a column (ops/ed25519_kernel `_tables_serve`)."""
         from cometbft_tpu.ops import ed25519_kernel as ek
 
         # Snapshot under the lock: _update_rates inserts first-observation
@@ -267,7 +280,9 @@ class HybridBackend(VerifyBackend):
         n_dev = self._n_dev
         with self._rate_lock:
             walls = {
-                b: w for (b, nd), w in self._dev_wall.items() if nd == n_dev
+                b: w
+                for (b, nd, res), w in self._dev_wall.items()
+                if nd == n_dev and res == (resident_lanes > 0)
             }
         # Mesh pricing: lanes run data-parallel across the chips, so the
         # modeled throughput is per-chip rate x width over ONE shared
@@ -276,7 +291,7 @@ class HybridBackend(VerifyBackend):
         mesh_rate = self._dev_rate * n_dev
 
         def dev_ms(b):  # padded lanes compute like real ones
-            bucket = ek.bucket_for(b)
+            bucket = resident_lanes or ek.bucket_for(b)
             wall = walls.get(bucket)
             if wall is not None:
                 return wall
@@ -303,20 +318,30 @@ class HybridBackend(VerifyBackend):
         best_b, best_cost = 0, host_ms(n)
         for b in ladder:
             cost = max(dev_ms(b), host_ms(n - b))
-            if cost < best_cost:
+            # The resident program costs the device the same whatever the
+            # share: of equal walls, the larger share (less for the host).
+            if cost < best_cost or (resident_lanes and cost == best_cost):
                 best_b, best_cost = b, cost
         return best_b, best_cost
 
     def _planned_call(self, pubs, msgs, sigs, between=None):
         """One call the planner routes, under its span: _plan_cost, then
         _routed_call with the share and the wall predicted for it."""
+        from cometbft_tpu.ops import ed25519_kernel as ek
+
         n = len(pubs)
         with trace.span("hybrid.call", n=n) as call:
             with trace.span("hybrid.plan") as plan:
-                share, predicted_ms = self._plan_cost(n)
-                plan.set(share=share, predicted_ms=predicted_ms)
+                # The one look-up of the call's key column, here where the
+                # whole column is in view: the device share is a prefix.
+                sighting = ek.sight_column(pubs)
+                lanes = sighting.tables[1].shape[0] if sighting.tables is not None else 0
+                share, predicted_ms = self._plan_cost(n, lanes)
+                plan.set(share=share, predicted_ms=predicted_ms, resident=lanes > 0)
             call.set(share=share, route=_route_name(share, n))
-            return self._routed_call(pubs, msgs, sigs, share, between, predicted_ms)
+            return self._routed_call(
+                pubs, msgs, sigs, share, between, predicted_ms, sighting
+            )
 
     def _note_route(self, n: int, share: int) -> None:
         """Count where n lanes went; calls big enough to be planned also
@@ -351,7 +376,7 @@ class HybridBackend(VerifyBackend):
                 "wall_ms": round(self._wall_ms, 2),
             }
         return {
-            **self._tpu.device_info(),
+            **self._tpu.counters(),  # the device, and its resident key columns
             "native": self._native.status(),
             **out,
             "last_share": self.last_share,
@@ -401,13 +426,18 @@ class HybridBackend(VerifyBackend):
             self._note_route(n, n)
             return self._tpu.batch_verify(pubs, msgs, sigs)
 
-    def _routed_call(self, pubs, msgs, sigs, share, between=None, predicted_ms=None):
+    def _routed_call(
+        self, pubs, msgs, sigs, share, between=None, predicted_ms=None, sighting=None
+    ):
         """Execute one planned verification: all-host (share<=0), all-device
         (share>=n), or the concurrent split — the ONE copy of the
         plan->submit->host MSM->overlap->collect->rate-update protocol.
         `between` (optional) runs under the device wait (verify_and_root's
         merkle); `predicted_ms` is the wall the planner expected, booked
-        against the measured one. Returns ((ok, bitmap), between_result)."""
+        against the measured one; `sighting` is the planner's look-up of the
+        whole key column, which the device share is a prefix of (None: the
+        device tier looks the share up itself). Returns ((ok, bitmap),
+        between_result)."""
         from cometbft_tpu.ops import ed25519_kernel as ek
 
         n = len(pubs)
@@ -429,7 +459,9 @@ class HybridBackend(VerifyBackend):
         share = min(share, n)
         self.last_share = share
         t0 = time.perf_counter()
-        collect = ek.batch_verify_submit(pubs[:share], msgs[:share], sigs[:share])
+        collect = ek.batch_verify_submit(
+            pubs[:share], msgs[:share], sigs[:share], sighting
+        )
         t_disp = time.perf_counter()
         if share < n:
             with trace.span("hybrid.host_msm", lanes=n - share):
@@ -469,6 +501,7 @@ class HybridBackend(VerifyBackend):
         host_ms = (t_host - t_disp) * 1000
         call_ms = (t_dev - t0) * 1000
         dev_ms = (t_run[1] - t0) * 1000
+        resident = key[2] > 0
         warm_key = (*key, self._n_dev)
         first_use = warm_key not in self._warmed
         self._warmed.add(warm_key)
@@ -484,6 +517,7 @@ class HybridBackend(VerifyBackend):
             "dev_wall_ms": round(dev_ms, 2),
             "total_ms": round(call_ms, 2),
             "first_use": first_use,
+            "resident": resident,
         }
         with self._rate_lock:
             if n_host > 0:
@@ -504,7 +538,7 @@ class HybridBackend(VerifyBackend):
                     r = n_dev / (dev_ms - self._dev_overhead) / self._n_dev
                     r = min(max(r, 5.0), 5000.0)
                     self._dev_rate += alpha * (r - self._dev_rate)
-                wall_key = (key[0], self._n_dev)
+                wall_key = (key[0], self._n_dev, resident)
                 recent = self._dev_recent.setdefault(
                     wall_key, collections.deque(maxlen=3)
                 )

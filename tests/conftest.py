@@ -125,3 +125,18 @@ def pytest_configure(config):
         "same-chain export determinism); runs in tier-1 — `-m bundle` "
         "selects just this group",
     )
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _forget_resident_columns():
+    """The device tier remembers the key columns it has seen for the life
+    of the process (ops/ed25519_kernel `_columns`): a test's second call
+    over the keys of an earlier test would ride that test's tables. Every
+    test starts with none, where the kernel is loaded at all."""
+    ek = sys.modules.get("cometbft_tpu.ops.ed25519_kernel")
+    if ek is not None:
+        ek._columns.clear()
+    yield

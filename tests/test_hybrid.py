@@ -154,7 +154,7 @@ def test_all_device_call_feeds_the_buckets_wall(monkeypatch):
     assert hb.last_share == 48
     assert hb.last_timing["first_use"] and hb._dev_wall == {}
     hb.batch_verify(pubs, msgs, sigs)
-    wall = hb._dev_wall[(ek.bucket_for(48), hb._n_dev)]
+    wall = hb._dev_wall[(ek.bucket_for(48), hb._n_dev, False)]
     assert 0 < wall <= hb.last_timing["total_ms"]
     assert wall == pytest.approx(hb.last_timing["dev_wall_ms"], abs=0.01)
 
@@ -165,12 +165,12 @@ def test_small_batches_touch_no_wall(monkeypatch):
     learned on commit-sized calls alone: no wall, no rate, no split count."""
     hb = _hybrid(monkeypatch, min_split=64)
     with hb._rate_lock:
-        hb._dev_wall[(128, 1)] = 7.0
+        hb._dev_wall[(128, 1, False)] = 7.0
     rates = (hb._dev_rate, hb._host_rate)
     pubs, msgs, sigs = _batch(16)
     ok, bits = hb.batch_verify(pubs, msgs, sigs)
     assert ok and all(bits)
-    assert hb._dev_wall == {(128, 1): 7.0}
+    assert hb._dev_wall == {(128, 1, False): 7.0}
     assert (hb._dev_rate, hb._host_rate) == rates
     assert hb.counters()["split_calls"] == 0 and hb.last_timing == {}
 
@@ -180,6 +180,8 @@ def test_small_batches_touch_no_wall(monkeypatch):
 # The chip's affine costs in ms (PERF.md section 5, chip runs of PR 24): the
 # device program, the pack before it, the unpack inside collect(), the host MSM.
 DEV_MS = (9.0, 6.35e-3)
+# The resident program (ISSUE 35's count: ~0.35 of the ladder's per-lane part).
+RESIDENT_DEV_MS = (9.0, 2.2e-3)
 PACK_MS_PER_LANE = 0.8e-3
 UNPACK_MS = 1.3
 HOST_MS = (12.4, 20.7e-3)
@@ -189,16 +191,22 @@ class _SimulatedTiers:
     """Stands in for both tiers on a clock that only the tiers' own costs
     move: `submit` packs and starts the device program, `batch_verify` is
     the native MSM, collect() waits for the program if it still runs and
-    unpacks. Lanes are placeholders; every bitmap is all true."""
+    unpacks. Lanes are placeholders; every bitmap is all true. The key
+    column's look-up answers `resident_lanes` (0: no tables; else the lanes
+    of the tables every call's column is a prefix of)."""
 
     def __init__(self, monkeypatch, hb):
         from cometbft_tpu.ops import ed25519_kernel as ek
 
         self.now = 0.0
         self.stall_ms = 0.0  # a host stall inside the next pack, once
+        self.resident_lanes = 0
+        self.compile_ms = 0.0  # a program's first use, once per program key
+        self._seen_keys: set = set()
         self._ek = ek
         monkeypatch.setattr(be, "time", self)
         monkeypatch.setattr(ek, "batch_verify_submit", self.submit)
+        monkeypatch.setattr(ek, "sight_column", self.sight)
         hb._native = self
 
     def perf_counter(self):
@@ -212,20 +220,33 @@ class _SimulatedTiers:
     def status():
         return "simulated"
 
-    def submit(self, pubs, msgs, sigs):
+    def sight(self, pubs):
+        import numpy as np
+
+        tables = (None, np.zeros(self.resident_lanes, bool)) if self.resident_lanes else None
+        return self._ek.Sighting(tables, None)
+
+    def submit(self, pubs, msgs, sigs, sighting=None):
         n = len(pubs)
-        bucket = self._ek.bucket_for(n)
+        resident = sighting is not None and sighting.tables is not None
+        # the resident program has one shape a column: the tables' own bucket
+        bucket = len(sighting.tables[1]) if resident else self._ek.bucket_for(n)
+        key = (bucket, 2, bucket if resident else 0)
         self.now += (PACK_MS_PER_LANE * n + self.stall_ms) / 1000
         self.stall_ms = 0.0
         started = self.now
-        returned = started + (DEV_MS[0] + DEV_MS[1] * bucket) / 1000
+        fixed, per_lane = RESIDENT_DEV_MS if resident else DEV_MS
+        returned = started + (fixed + per_lane * bucket) / 1000
+        if key not in self._seen_keys:
+            self._seen_keys.add(key)
+            returned += self.compile_ms / 1000
 
         def collect():
             self.now = max(self.now, returned) + UNPACK_MS / 1000
             collect.run_times = (started, returned)
             return True, [True] * n
 
-        collect.program_key = (bucket, 2)
+        collect.program_key = key
         collect.run_times = None
         return collect
 
@@ -246,8 +267,8 @@ def _start_fresh(hb, lanes):
 
 def _start_poisoned(hb, lanes):
     # the stuck run of PR 24: the host's ~100 ms booked as the 6,144 wall
-    hb._warmed.add((6144, 2, 1))
-    hb._dev_wall[(6144, 1)] = 100.0
+    hb._warmed.add((6144, 2, 0, 1))
+    hb._dev_wall[(6144, 1, False)] = 100.0
     hb._host_rate = 42.0
 
 
@@ -295,11 +316,11 @@ def test_one_stalled_call_does_not_strand_the_planner(monkeypatch):
     for _ in range(12):
         hb.batch_verify(*lanes)
     assert hb.last_share == 8192
-    changes, wall = hb.counters()["share_changes"], hb._dev_wall[(8192, 1)]
+    changes, wall = hb.counters()["share_changes"], hb._dev_wall[(8192, 1, False)]
     sim.stall_ms = 150.0
     hb.batch_verify(*lanes)
     assert hb.last_timing["dev_wall_ms"] == pytest.approx(wall + 150.0, abs=0.1)
-    assert hb._dev_wall[(8192, 1)] == pytest.approx(wall, abs=0.1)
+    assert hb._dev_wall[(8192, 1, False)] == pytest.approx(wall, abs=0.1)
     for _ in range(20):
         hb.batch_verify(*lanes)
         assert hb.last_share == 8192
@@ -319,9 +340,95 @@ def test_device_wall_learned_when_the_host_is_late(monkeypatch):
     t = hb.last_timing
     assert t["dev_wait_ms"] == pytest.approx(UNPACK_MS, abs=0.01)  # collect() did not block
     assert t["host_msm_ms"] == pytest.approx(92.22, abs=0.1) and t["total_ms"] > 98
-    assert hb._dev_wall == {(6144, 1): pytest.approx(52.93, abs=0.1)}  # 4.92 pack + 48.01 run
+    assert hb._dev_wall == {(6144, 1, False): pytest.approx(52.93, abs=0.1)}  # 4.92 pack + 48.01 run
     assert t["dev_wall_ms"] == pytest.approx(52.93, abs=0.1)
     assert t["dev_run_ms"] == pytest.approx(48.01, abs=0.1)
+
+
+# -- two programs a bucket: the ladder's walls and the resident tables' ---------------
+
+
+def test_walls_are_booked_by_kind(monkeypatch):
+    """One bucket, two programs: a call over a resident column books the
+    resident wall of the bucket it ran (the tables' own, whatever the
+    share) and leaves the ladder's alone, and the planner prices each kind
+    of call with its own kind's walls."""
+    hb, sim = _simulated(monkeypatch)
+    lanes = ([None] * 10000,) * 3
+    hb._routed_call(*lanes, 10000)
+    hb._routed_call(*lanes, 10000)
+    ladder = hb._dev_wall[(10240, 1, False)]
+    assert ladder == pytest.approx(8.0 + 9.0 + 6.35e-3 * 10240, abs=0.1) and len(hb._dev_wall) == 1
+    sim.resident_lanes = 10240
+    sighting = sim.sight(lanes[0])
+    hb._routed_call(*lanes, 8192, sighting=sighting)  # the resident program's first use
+    assert hb.last_timing["resident"] and hb.last_timing["first_use"]
+    assert hb._dev_wall == {(10240, 1, False): ladder}
+    hb._routed_call(*lanes, 8192, sighting=sighting)
+    resident = 6.55 + 9.0 + 2.2e-3 * 10240  # an 8,192-lane share, widened to the tables' 10,240
+    assert hb._dev_wall == {
+        (10240, 1, False): ladder,
+        (10240, 1, True): pytest.approx(resident, abs=0.1),
+    }
+    # each kind of call is priced with its own kind's walls
+    assert hb._plan_cost(10000)[1] > 49  # the ladder: a split the host's 1,808 lanes pace, or worse
+    assert hb._plan_cost(10000, 10240) == (10000, pytest.approx(resident, abs=0.1))
+
+
+def test_resident_programs_first_use_is_left_out_of_the_model(monkeypatch):
+    """The resident program of a bucket the ladder has long warmed is a new
+    program: its first dispatch (an XLA compile, 30 s here) books no wall,
+    moves no rate and counts no planning error."""
+    hb, sim = _simulated(monkeypatch)
+    lanes = ([None] * 10000,) * 3
+    for _ in range(6):
+        hb.batch_verify(*lanes)
+    walls, rate = dict(hb._dev_wall), hb._dev_rate
+    err = hb.counters()["plan_abs_err_ms"]
+    sim.resident_lanes, sim.compile_ms = 10240, 30000.0
+    hb.batch_verify(*lanes)
+    assert hb.last_timing["resident"] and hb.last_timing["first_use"]
+    assert hb.last_timing["dev_wall_ms"] > 30000
+    assert hb._dev_wall == walls and hb._dev_rate == rate
+    assert hb.counters()["plan_abs_err_ms"] == err
+    hb.batch_verify(*lanes)
+    assert not hb.last_timing["first_use"]
+    assert (10240, 1, True) in hb._dev_wall
+
+
+def test_planner_with_a_resident_wall_goes_all_device(monkeypatch):
+    """10,000 lanes, the ladder's split at 8,192 learned (host 1,808 lanes
+    ~49 ms). Once the column is resident and its program's wall is booked,
+    all-device prices lower than any split the host share paces, and the
+    planner goes there and stays: no prior, no constant, the walls alone.
+    One resident program is loaded on the way, not one a share."""
+    hb, sim = _simulated(monkeypatch)
+    lanes = ([None] * 10000,) * 3
+    for _ in range(12):
+        hb.batch_verify(*lanes)
+    assert hb.last_share == 8192
+    assert hb.last_timing["host_msm_ms"] == pytest.approx(49.8, abs=0.5)
+    sim.resident_lanes, sim.compile_ms = 10240, 20000.0
+    seen = set(sim._seen_keys)
+    shares = []
+    for _ in range(6):
+        ok, bits = hb.batch_verify(*lanes)
+        assert ok and len(bits) == 10000
+        shares.append(hb.last_share)
+    assert (10240, 1, True) in hb._dev_wall
+    assert shares[2:] == [10000] * 4, shares  # first use, the wall's booking, then there
+    assert 0 not in shares
+    assert sim._seen_keys - seen == {(10240, 2, 10240)}
+    t0 = sim.now
+    for _ in range(20):
+        hb.batch_verify(*lanes)
+        assert hb.last_share == 10000 and hb.last_timing["n_host"] == 0
+    # pack 8.0 + run 9.0 + 2.2e-3 * 10,240 + unpack 1.3
+    assert (sim.now - t0) * 1000 / 20 == pytest.approx(40.8, abs=0.5)
+    # a column that is not resident is still priced, and split, as before
+    sim.resident_lanes = 0
+    hb.batch_verify(*lanes)
+    assert hb.last_share == 8192 and not hb.last_timing["resident"]
 
 
 def test_run_stamps_taken_with_tracing_off():
@@ -392,9 +499,9 @@ def test_plan_snapshots_dev_wall_under_rate_lock(monkeypatch):
         while not stop.is_set():
             k += 1
             with hb._rate_lock:
-                hb._dev_wall[(128 * (k % 64 + 1), 1)] = 1.0 + (k % 7)
+                hb._dev_wall[(128 * (k % 64 + 1), 1, False)] = 1.0 + (k % 7)
                 if k % 5 == 0:
-                    hb._dev_wall.pop((128 * ((k * 31) % 64 + 1), 1), None)
+                    hb._dev_wall.pop((128 * ((k * 31) % 64 + 1), 1, False), None)
 
     t = threading.Thread(target=writer, daemon=True)
     t.start()
@@ -430,7 +537,7 @@ def test_dev_walls_keyed_by_mesh_width(monkeypatch):
     a stale single-chip wall would make the planner starve the mesh."""
     hb = _hybrid(monkeypatch, dev_rate=100.0, host_rate=100.0)
     with hb._rate_lock:
-        hb._dev_wall[(8192, 1)] = 1e9  # poisoned single-chip observation
+        hb._dev_wall[(8192, 1, False)] = 1e9  # poisoned single-chip observation
     hb._n_dev = 8
     assert hb._plan(9216) == 8192  # the width-1 wall does not apply
     hb._n_dev = 1
@@ -445,10 +552,10 @@ def test_warm_keys_include_mesh_width(monkeypatch):
     hb = _hybrid(monkeypatch)
     ts = (0.0, 0.001, 0.002, 0.002, 0.050, (0.001, 0.049))
     hb._n_dev = 1
-    hb._update_rates((128, 2), 128, 0, *ts)
+    hb._update_rates((128, 2, 0), 128, 0, *ts)
     assert hb.last_timing["first_use"]
-    hb._update_rates((128, 2), 128, 0, *ts)
+    hb._update_rates((128, 2, 0), 128, 0, *ts)
     assert not hb.last_timing["first_use"]
     hb._n_dev = 8
-    hb._update_rates((128, 2), 128, 0, *ts)
+    hb._update_rates((128, 2, 0), 128, 0, *ts)
     assert hb.last_timing["first_use"], "width change must re-warm"

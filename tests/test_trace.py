@@ -201,6 +201,11 @@ def test_one_verify_commit_gives_every_span_under_one_root(auto_chain, profiler)
     _verify_one(2)
     jax.profiler.stop_trace()
     spans = trace.spans()
+    # The keys' second sighting: their tables are built BEHIND this call's
+    # dispatch, on the owner thread, inside nobody's operation.
+    builds = [s for s in spans if s["name"] == "device.table_build"]
+    assert all(b["parent"] is None and b["thread"] == "cmtpu-dev" for b in builds)
+    spans = [s for s in spans if s["name"] != "device.table_build"]
     names = {s["name"] for s in spans}
     assert COMMIT_SPANS <= names, sorted(COMMIT_SPANS - names)
     assert names <= set(trace.NAMES)
@@ -223,8 +228,10 @@ def test_one_verify_commit_gives_every_span_under_one_root(auto_chain, profiler)
     assert one["device.run"]["thread"] == "cmtpu-dev"
     assert "hybrid.call" in _ancestors(one["device.run"], by_id)
     run = one["device.run"]["attrs"]  # the conftest's 8 virtual devices shard the call
-    assert set(run) == {"bucket", "sharded"} and run["bucket"] >= one["hybrid.call"]["attrs"]["share"]
+    assert set(run) == {"bucket", "sharded", "resident"} and not run["resident"]
+    assert run["bucket"] >= one["hybrid.call"]["attrs"]["share"]
     plan = one["hybrid.plan"]["attrs"]
+    assert plan["resident"] is False
     assert plan["share"] == one["hybrid.call"]["attrs"]["share"] and plan["predicted_ms"] > 0
     call = one["hybrid.call"]["attrs"]
     assert call["route"] == "split" and 0 < call["share"] < N_VALS and call["n"] == N_VALS
@@ -396,9 +403,9 @@ def test_share_changes_over_a_forced_sequence_of_shares(monkeypatch):
     hb = be.HybridBackend()
     # t0, t_disp, t_host, t_wait, t_dev, t_run (the owner thread's start, return)
     ts = (0.0, 0.001, 0.010, 0.010, 0.020, (0.001, 0.019))
-    hb._update_rates((32, 2), 32, 16, *ts)  # a program's first use
+    hb._update_rates((32, 2, 0), 32, 16, *ts)  # a program's first use
     for share, predicted in ((32, 25.0), (32, 20.0), (8, 20.0), (32, None), (48, 20.0), (8, 20.0)):
-        hb._update_rates((share, 2), share, 48 - share, *ts, predicted)
+        hb._update_rates((share, 2, 0), share, 48 - share, *ts, predicted)
     c = hb.counters()
     assert c["split_calls"] == 6, "the all-device call (48 of 48) is no split"
     assert c["share_changes"] == 3  # 32 -> 8 -> 32 -> (48: not a split) -> 8
