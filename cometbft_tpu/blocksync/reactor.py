@@ -113,6 +113,12 @@ class BlocksyncReactor(Reactor):
         self.idle_sleeps = 0       # 10 ms sleeps of the pool routine
         self.redo_requests = 0     # blocks refused and asked for again
         self.block_bytes_received = 0  # encoded block responses, as they left the wire
+        # The prefetch worker's own (one job at a time): windows it handed
+        # to the batch seam, their lanes, and its time from the first look
+        # at the window to the seam's answer.
+        self.prefetch_windows = 0
+        self.prefetch_lanes = 0
+        self.prefetch_ms = 0.0
         # The fetch wait under way: (its span, perf_counter at its start,
         # idle_sleeps at its start), from the first peek that found no pair
         # to the next that finds one.
@@ -169,6 +175,9 @@ class BlocksyncReactor(Reactor):
             "redo_requests": self.redo_requests,
             "pipeline_overlap_ms": round(self.pipeline_overlap_ms, 3),
             "block_bytes_received": self.block_bytes_received,
+            "prefetch_windows": self.prefetch_windows,
+            "prefetch_lanes": self.prefetch_lanes,
+            "prefetch_ms": round(self.prefetch_ms, 3),
             **self.pool.counters(),
         }
 
@@ -256,9 +265,11 @@ class BlocksyncReactor(Reactor):
             self.clock.sleep(0.01)
 
     # Prefetch window: how many consecutive fetched blocks to batch-verify
-    # in ONE device dispatch. 32 blocks x 1k validators fills the 32768
-    # bucket; the verified-triple cache then makes both the trySync
-    # VerifyCommitLight AND ApplyBlock's full LastCommit check cache hits.
+    # in ONE device dispatch. A window of 32 blocks covers 31 commits (each
+    # block's commit rides in the next block's LastCommit), so at 1,024
+    # validators it is 31,744 lanes, which pad to the 32768 bucket; the
+    # verified-triple cache then makes both the trySync VerifyCommitLight
+    # AND ApplyBlock's full LastCommit check cache hits.
     PREFETCH_WINDOW = 32
     # Signature budget for one prefetch dispatch: stay within the largest
     # precompiled device bucket AND well under the verified-triple cache
@@ -305,28 +316,33 @@ class BlocksyncReactor(Reactor):
         # here are unvalidated peer input (oversized signatures etc. make
         # bv.add raise), and backend hiccups surface from bv.verify — the
         # per-block path re-verifies, attributes, and punishes as before.
+        t0 = time.perf_counter()
         try:
             with trace.span("blocksync.prefetch") as job:
-                bv = ed25519.BatchVerifier()
-                vh = vals.hash()
-                chain_id = self.state.chain_id
-                covered = 0
-                for j in range(len(window) - 1):
-                    blk, nxt = window[j], window[j + 1]
-                    commit = nxt.last_commit
-                    if (
-                        blk.header.validators_hash != vh
-                        or commit is None
-                        or commit.height != blk.header.height
-                        or len(commit.signatures) != len(vals.validators)
-                    ):
-                        break
-                    sbs = commit.vote_sign_bytes_all(chain_id)
-                    for idx, cs in enumerate(commit.signatures):
-                        if cs.is_absent():
-                            continue
-                        bv.add(vals.validators[idx].pub_key, sbs[idx], cs.signature)
-                    covered += 1
+                # The walk of the window into triples: sign bytes and one
+                # bv.add a lane, everything before the seam.
+                with trace.span("blocksync.prefetch_collect") as collect:
+                    bv = ed25519.BatchVerifier()
+                    vh = vals.hash()
+                    chain_id = self.state.chain_id
+                    covered = 0
+                    for j in range(len(window) - 1):
+                        blk, nxt = window[j], window[j + 1]
+                        commit = nxt.last_commit
+                        if (
+                            blk.header.validators_hash != vh
+                            or commit is None
+                            or commit.height != blk.header.height
+                            or len(commit.signatures) != len(vals.validators)
+                        ):
+                            break
+                        sbs = commit.vote_sign_bytes_all(chain_id)
+                        for idx, cs in enumerate(commit.signatures):
+                            if cs.is_absent():
+                                continue
+                            bv.add(vals.validators[idx].pub_key, sbs[idx], cs.signature)
+                        covered += 1
+                    collect.set(blocks=covered, lanes=len(bv))
                 job.set(blocks=covered, lanes=len(bv))
                 self._prefetched_to = self.pool.height + max(covered, 1)
                 if covered >= 2 and len(bv):
@@ -335,8 +351,12 @@ class BlocksyncReactor(Reactor):
                     # votes but outranks ingress and light prewarm.
                     with engine.submission_class(engine.CLASS_BLOCKSYNC):
                         bv.verify()  # populates the cache; bad sigs fall to per-block
+                    self.prefetch_windows += 1
+                    self.prefetch_lanes += len(bv)
         except Exception:
             self._prefetched_to = self.pool.height + 1
+        finally:
+            self.prefetch_ms += (time.perf_counter() - t0) * 1000.0
 
     # -- verify/apply pipeline ------------------------------------------------
 

@@ -91,6 +91,7 @@ NAMES = (
     "blocksync.pipeline_submit",
     "blocksync.apply",
     "blocksync.prefetch",
+    "blocksync.prefetch_collect",
     "blocksync.decode",
     "types.data_hash",
     "types.part_set_proofs",

@@ -467,6 +467,8 @@ class Node:
             ("resident_bytes", "Bytes of window tables resident on each chip."),
             ("resident_evictions", "Resident key columns given up to the bytes bound."),
             ("resident_first_sightings", "Key columns remembered at their first sighting."),
+            ("resident_repeat_sightings", "Key columns refused tables because a key repeats."),
+            ("resident_repeat_lanes", "Lanes of the key columns refused because a key repeats."),
         ):
             reg.gauge_func("hybrid", key, text, hybrid_sample(key))
 
@@ -906,6 +908,9 @@ class Node:
             ("requests_to_busiest_peer", "Block requests sent to the connected peer asked most."),
             ("peers_asked", "Connected peers the catch-up pool has sent a block request."),
             ("block_bytes_received", "Bytes of encoded block responses received."),
+            ("prefetch_windows", "Prefetch windows handed to the batch seam."),
+            ("prefetch_lanes", "Lanes of the prefetch windows handed to the batch seam."),
+            ("prefetch_ms", "Prefetch worker's time in its windows, collect and verify, ms."),
         ):
             reg.gauge_func("blocksync", key, text, bs(key))
 

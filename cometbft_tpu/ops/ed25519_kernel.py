@@ -624,8 +624,9 @@ class _Column:
 
 # What one look-up found: `tables`, the column's (tables_a, ok_a) where the
 # call's keys are a resident column or a prefix of one, else None; `build`,
-# the column whose build the call's dispatch is to be followed by, else None.
-Sighting = collections.namedtuple("Sighting", "tables build")
+# the column whose build the call's dispatch is to be followed by, else None;
+# `distinct`, how many keys a column refused for repeating one holds, else 0.
+Sighting = collections.namedtuple("Sighting", "tables build distinct", defaults=(0,))
 _NOTHING = Sighting(None, None)
 
 
@@ -644,6 +645,7 @@ class _ResidentColumns:
                 "resident_lanes", "resident_calls", "resident_builds",
                 "resident_build_ms", "resident_bytes", "resident_evictions",
                 "resident_first_sightings",
+                "resident_repeat_sightings", "resident_repeat_lanes",
             ),
             0,
         )
@@ -681,7 +683,13 @@ class _ResidentColumns:
                 # wants THAT set's tables indexed by lane, which is another
                 # mechanism.
                 keys = {joined[i : i + 32] for i in range(0, 32 * n, 32)}
-                if len(keys) == n and self._nbytes(n) <= RESIDENT_MAX_BYTES:
+                if len(keys) < n:
+                    # Counted, not served: how much of the tier's traffic
+                    # the smaller set's tables would carry.
+                    self._counters["resident_repeat_sightings"] += 1
+                    self._counters["resident_repeat_lanes"] += n
+                    return Sighting(None, None, len(keys))
+                if self._nbytes(n) <= RESIDENT_MAX_BYTES:
                     self._remember(_Column(joined, n))
                 return _NOTHING
             self._lru.move_to_end(id(col))

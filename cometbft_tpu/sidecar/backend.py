@@ -338,6 +338,8 @@ class HybridBackend(VerifyBackend):
                 lanes = sighting.tables[1].shape[0] if sighting.tables is not None else 0
                 share, predicted_ms = self._plan_cost(n, lanes)
                 plan.set(share=share, predicted_ms=predicted_ms, resident=lanes > 0)
+                if sighting.distinct:  # the column repeats a smaller set's keys
+                    plan.set(distinct=sighting.distinct)
             call.set(share=share, route=_route_name(share, n))
             return self._routed_call(
                 pubs, msgs, sigs, share, between, predicted_ms, sighting

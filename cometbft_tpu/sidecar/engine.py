@@ -45,7 +45,11 @@ run their device (vLLM/Orca continuous batching):
   upper bound of their distinct lanes), two different 10,000-lane commits
   still take two dispatches (a copy of what is already in costs nothing
   and rides along, even past a request that did not fit; nothing that
-  adds lanes passes one).
+  adds lanes passes one). The cap bounds what is MERGED, never a request: a
+  lone request that offers more than the cap (a blocksync prefetch window
+  of 31 x 1,024 = 31,744 lanes against 16,384 a chip) is the first of its
+  dispatch whatever its size, so it runs as one dispatch of its own columns
+  in their order and is answered whole; only a copy of it rides along.
 * A request that arrives while the same columns are IN FLIGHT takes that
   dispatch's answer (PR 33, in-flight join): while the chain runs a
   dispatch the engine publishes what it carries (its distinct requests and

@@ -252,6 +252,58 @@ def test_the_fingerprint_is_of_the_whole_columns_in_their_order():
     assert columns_fingerprint(*a) != columns_fingerprint(*(col[:-1] for col in a))
 
 
+# -- a lone request over the cap (ISSUE 36) ------------------------------------------
+
+BIG = 2 * CAP + 8  # a blocksync prefetch window against the cap: 31,744 lanes against 16,384
+
+
+class _Columns(_Gate):
+    """`_Gate` that also keeps the columns of every call after the blocker's."""
+
+    def __init__(self):
+        super().__init__()
+        self.columns: list[tuple] = []
+
+    def batch_verify(self, pubs, msgs, sigs):
+        if self.entered.is_set():
+            self.columns.append((list(pubs), list(msgs), list(sigs)))
+        return super().batch_verify(pubs, msgs, sigs)
+
+
+def _over_the_cap():
+    big, small = _signed(11, BIG), _signed(12, 8)
+    return {
+        # name: (requests, the lanes of each dispatch, which request each dispatch carries whole)
+        "alone": ([big], [BIG], [0]),
+        "with-flipped-lanes": ([_flipped(big, 36)], [BIG], [0]),
+        "behind-a-small-request": ([small, big], [8, BIG], [0, 1]),
+        "before-a-small-request": ([big, small], [BIG, 8], [0, 1]),
+        "and-a-copy-of-it": ([big, _fresh(big)], [BIG], [0]),
+        "and-a-flipped-copy": ([big, _flipped(big, 37)], [BIG, BIG], [0, 1]),
+    }
+
+
+OVER_THE_CAP = _over_the_cap()
+
+
+@pytest.mark.parametrize("name", sorted(OVER_THE_CAP))
+def test_a_lone_request_over_the_cap_is_one_dispatch_in_order_answered_whole(name):
+    """The cap bounds what is merged, never a request: one that alone offers
+    more than the cap runs as ONE dispatch of its own columns, in the order
+    it was queued, and gets the answer it would get alone; only a copy of it
+    rides along."""
+    requests, want_calls, carried = OVER_THE_CAP[name]
+    gate = _Columns()
+    answers, calls, counters = _through_one_engine(requests, gate=gate)
+    assert answers == answers_alone(requests)
+    assert all(len(bits) == len(r[0]) for (_, bits), r in zip(answers, requests)), "answered whole"
+    assert calls == want_calls
+    assert gate.columns == [tuple(list(col) for col in requests[i]) for i in carried], (
+        "each dispatch is one request's own columns, nothing cut, nothing reordered")
+    assert counters["dispatches"] == 1 + len(calls) and counters["max_sigs"] == CAP
+    assert counters["dedup_sigs"] == sum(len(r[0]) for r in requests) - sum(calls)
+
+
 # -- a request that arrives while the same columns are in flight (ISSUE 33) ---------
 
 

@@ -240,10 +240,10 @@ def test_the_cell_is_appended_to_the_metrics_it_took(name):
 def test_the_cell_and_its_configuration_are_in_the_benchmark():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        CELL, "valset-10000-sidecar-4nodes", "cold-commits-4nodes", 1)
-    config = bench["configs"][-1]
+    (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]  # later cells follow it
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "valset-10000-sidecar-4nodes", "cold-commits-4nodes", 1)
+    (config,) = [c for c in bench["configs"] if c["name"] == cell["config"]]
     with open(os.path.join(ROOT, config["file"])) as f:
         body = json.load(f)
     assert config["name"] == body["name"] == cell["config"] and config["reduced"] == body["reduced"] == []

@@ -238,10 +238,15 @@ def test_columns_that_are_never_resident(stub_programs, change):
         other[11] = other[11][:31]
     else:
         other = other + _keys(1, b"joined")
+    repeats = cols.counters()
     first = cols.sight(other)
-    assert first == ek.Sighting(None, None)
+    # a column refused for repeating a key says how many keys it holds, and is counted
+    repeated = change == "one key repeated"
+    assert first == ek.Sighting(None, None, LANES - 1 if repeated else 0)
     again = cols.sight(other)
     assert again.tables is None
+    grown = {k: cols.counters()[k] - repeats[k] for k in ("resident_repeat_sightings", "resident_repeat_lanes")}
+    assert grown == {"resident_repeat_sightings": 2 * repeated, "resident_repeat_lanes": 2 * LANES * repeated}
     # only a whole, distinct, well-formed column of its own is on its way to tables
     assert (again.build is not None) == (change in ("one key changed", "a longer column"))
     assert cols.counters()["resident_builds"] == builds

@@ -389,7 +389,8 @@ def test_blocksync_heights_are_roots_with_their_children(profiler):
     assert set(c) == {"heights_applied", "fetch_wait_ms", "verify_wait_ms",
                       "idle_sleeps", "redo_requests", "pipeline_overlap_ms",
                       "block_bytes_received", "requests_sent",
-                      "requests_to_busiest_peer", "peers_asked"}
+                      "requests_to_busiest_peer", "peers_asked",
+                      "prefetch_windows", "prefetch_lanes", "prefetch_ms"}
     assert c["requests_sent"] >= applied and live["peers_asked"] == 1
     assert live["requests_to_busiest_peer"] == live["requests_sent"] >= applied
     assert c["block_bytes_received"] >= sum(
@@ -788,7 +789,8 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
 
         def counters(self):
             hybrid = {"split_calls": 7, "share_changes": 3, "plan_abs_err_ms": 12.5,
-                      "wall_ms": 800.0}
+                      "wall_ms": 800.0, "resident_repeat_sightings": 3,
+                      "resident_repeat_lanes": 95232}
             return {"requests": 1, "dispatches": 1, "queue_wait_p95_ms": 0.0,
                     "inner": {"tiers": {"hybrid": {"backend": hybrid}}}}
 
@@ -799,6 +801,7 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
     vars(reactor).update(
         pipeline_overlap_ms=0.0, heights_applied=9, fetch_wait_ms=4.2,
         verify_wait_ms=0.0, idle_sleeps=2, redo_requests=1, block_bytes_received=311,
+        prefetch_windows=3, prefetch_lanes=95232, prefetch_ms=870.4,
         pool=types.SimpleNamespace(counters=lambda: {
             "requests_sent": 8, "requests_to_busiest_peer": 2, "peers_asked": 4}),
     )
@@ -815,6 +818,10 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
     assert "cmt_blocksync_requests_sent 8" in out and "cmt_blocksync_peers_asked 4" in out
     assert "cmt_blocksync_requests_to_busiest_peer 2" in out
     assert "cmt_blocksync_block_bytes_received 311" in out
+    assert "cmt_blocksync_prefetch_windows 3" in out and "cmt_blocksync_prefetch_lanes 95232" in out
+    assert "cmt_blocksync_prefetch_ms 870" in out
+    assert "cmt_hybrid_resident_repeat_sightings 3" in out
+    assert "cmt_hybrid_resident_repeat_lanes 95232" in out
     size = ed25519.verified_cache_counters()["size"]
     assert f"cmt_verify_cache_size {size}" in out and "cmt_verify_cache_hits " in out
     from cometbft_tpu.types import validator_set
