@@ -469,6 +469,8 @@ class Node:
             ("resident_first_sightings", "Key columns remembered at their first sighting."),
             ("resident_repeat_sightings", "Key columns refused tables because a key repeats."),
             ("resident_repeat_lanes", "Lanes of the key columns refused because a key repeats."),
+            ("pack_calls", "Dispatches the device tier packed on the host."),
+            ("pack_walk_calls", "Packed dispatches a malformed entry sent through the per-lane walk."),
         ):
             reg.gauge_func("hybrid", key, text, hybrid_sample(key))
 

@@ -321,127 +321,144 @@ def warmup(buckets=(128, 1024, 6144, 10240), merkle_leaves=(1024, 65536)) -> Non
 def _bucket_key(operands) -> tuple[int, int]:
     """(batch, block) bucket pair; bmax 0 selects the host-hash program
     (4 operands: the oversized-message fallback in pack_batch)."""
-    n = operands[0].shape[1]
+    # the keys' words are None where the pack saw resident tables serve
+    n = (operands[1] if operands[0] is None else operands[0]).shape[1]
     bmax = operands[3].shape[1] // 32 if len(operands) == 5 else 0
     return n, bmax
 
 
-def _host_checks(pubs, sigs):
-    """Shared host-side packing: shape checks, byte matrices, vectorized
-    s < L. Returns (a_enc, r_enc, s_le, pubs_c, sigs_c, shape_ok,
-    s_in_range) with nb = bucket_for(n) rows."""
+def _whole_column(col, stride: int):
+    """The column's entries joined, where every one is a bytes-like of
+    `stride` bytes, else None: the join and the set of lengths are two
+    C-level passes, whatever the column's length."""
+    try:
+        joined = b"".join(col)
+    except TypeError:
+        return None
+    whole = len(joined) == stride * len(col) and set(map(len, col)) <= {stride}
+    return joined if whole else None
+
+
+def _lane_words(words: np.ndarray, nb: int) -> np.ndarray:
+    """Little-endian uint32 words [n, k] -> int32[k, nb], lanes n..nb zero
+    (unpack.bytes_to_words of the rows zero-padded to nb): one allocation
+    and one transposing copy."""
+    out = np.zeros((words.shape[1], nb), np.uint32)
+    out[:, : words.shape[0]] = words.T
+    return out.view(np.int32)
+
+
+def _host_checks(pubs, sigs, keys=None):
+    """The host's checks of a batch, on whole columns: (key rows uint8[n, 32],
+    signature rows uint8[n, 64], shape_ok, s_in_range bool[n]). Where the
+    joins' lengths prove every key 32 and every signature 64 bytes,
+    shape_ok is None and the rows are views of the joined columns (`keys`,
+    a sighting's, stands for the key column's join). A batch with any other
+    entry takes the per-lane walk: shape_ok is a list of n verdicts and a
+    refused lane's rows are zero."""
     n = len(pubs)
-    nb = bucket_for(n)
-    zero_pub, zero_sig = b"\x00" * 32, b"\x00" * 64
-    shape_ok = [len(pubs[i]) == 32 and len(sigs[i]) == 64 for i in range(n)]
-    pubs_c = [pubs[i] if shape_ok[i] else zero_pub for i in range(n)]
-    sigs_c = [sigs[i] if shape_ok[i] else zero_sig for i in range(n)]
-
-    a_enc = np.zeros((nb, 32), np.uint8)
-    r_enc = np.zeros((nb, 32), np.uint8)
-    s_le = np.zeros((nb, 32), np.uint8)
+    sig_col = _whole_column(sigs, 64)
+    key_col = _whole_column(pubs, 32) if keys is None else keys[:n]
+    shape_ok = None
+    if sig_col is None or key_col is None:
+        zero_pub, zero_sig = b"\x00" * 32, b"\x00" * 64
+        shape_ok = [len(pubs[i]) == 32 and len(sigs[i]) == 64 for i in range(n)]
+        key_col = b"".join([pubs[i] if shape_ok[i] else zero_pub for i in range(n)])
+        sig_col = b"".join([sigs[i] if shape_ok[i] else zero_sig for i in range(n)])
+    key_rows = np.frombuffer(key_col, np.uint8).reshape(n, 32)
+    sig_rows = np.frombuffer(sig_col, np.uint8).reshape(n, 64)
+    # s < L, vectorized: compare the four little-endian uint64 words
+    # most-significant first.
+    s_words = sig_rows.view("<u8")[:, 4:]  # [n, 4]
+    l_words = np.frombuffer(L.to_bytes(32, "little"), dtype="<u8")
     s_in_range = np.zeros(n, bool)
-    if n:
-        a_enc[:n] = np.frombuffer(b"".join(pubs_c), np.uint8).reshape(n, 32)
-        sig_arr = np.frombuffer(b"".join(sigs_c), np.uint8).reshape(n, 64)
-        r_enc[:n] = sig_arr[:, :32]
-        s_le[:n] = sig_arr[:, 32:]
-        # s < L, vectorized: compare the four little-endian uint64 words
-        # most-significant first.
-        s_words = s_le[:n].view("<u8")  # [n, 4]
-        l_words = np.frombuffer(L.to_bytes(32, "little"), dtype="<u8")
-        decided = np.zeros(n, bool)
-        for w in (3, 2, 1, 0):
-            lt = ~decided & (s_words[:, w] < l_words[w])
-            gt = ~decided & (s_words[:, w] > l_words[w])
-            s_in_range |= lt
-            decided |= lt | gt
-        # s == L (all words equal) leaves decided False -> not in range.
-        s_le[:n][~s_in_range] = 0
-    return a_enc, r_enc, s_le, pubs_c, sigs_c, shape_ok, s_in_range
+    decided = np.zeros(n, bool)
+    for w in (3, 2, 1, 0):
+        lt = ~decided & (s_words[:, w] < l_words[w])
+        gt = ~decided & (s_words[:, w] > l_words[w])
+        s_in_range |= lt
+        decided |= lt | gt
+    # s == L (all words equal) leaves decided False -> not in range.
+    return key_rows, sig_rows, shape_ok, s_in_range
 
 
-def pack_batch(pubs, msgs, sigs):
+def pack_batch(pubs, msgs, sigs, sighting=None):
     """Host-side packing of one verification batch — no crypto: shape
     checks, the vectorized s < L check, raw-byte -> word views, and the
     challenge messages R || A || M padded into SHA-512 blocks (the hashing
     itself runs on device). Returns device operands plus the host-decided
     validity mask (shape errors, s >= L). Invalid entries are packed as
-    zeros — lanes the device evaluates but the mask vetoes."""
+    zeros — lanes the device evaluates but the mask vetoes. A fixed number
+    of passes and array assignments over whole columns, whatever n (only a
+    malformed entry sends the batch through _host_checks' per-lane walk).
+    With a `sighting` of the column these keys are the first of, its key
+    rows stand for the key column's join, and where its tables serve the
+    dispatch the keys' words, which the resident program does not take,
+    are None."""
+    return _pack(pubs, msgs, sigs, sighting or _NOTHING)[:2]
+
+
+def _pack(pubs, msgs, sigs, sighting):
+    """pack_batch, and whether the batch took the per-lane walk."""
     n = len(pubs)
     nb = bucket_for(n)
-    a_enc, r_enc, s_le, pubs_c, sigs_c, shape_ok, s_in_range = _host_checks(
-        pubs, sigs
-    )
+    key_rows, sig_rows, shape_ok, s_in_range = _host_checks(pubs, sigs, sighting.keys)
+    walk = shape_ok is not None
     host_ok = np.zeros(nb, bool)
-    if n:
+    if not walk:
+        host_ok[:n] = s_in_range
+        mlens = np.fromiter(map(len, msgs), np.int64, n)
+    else:
+        host_ok[:n] = np.asarray(shape_ok, bool) & s_in_range
+        # shape-invalid rows are forced to length 0: their message is not read
         mlens = np.fromiter(
             (len(msgs[i]) if shape_ok[i] else 0 for i in range(n)), np.int64, n
         )
-    else:
-        mlens = np.zeros(0, np.int64)
+    sig_words = sig_rows.view("<u4")
+    r_words = _lane_words(sig_words[:, :8], nb)
+    s_words = _lane_words(sig_words[:, 8:], nb)
+    if not s_in_range.all():
+        s_words[:, np.nonzero(~s_in_range)[0]] = 0
     # Oversized messages (past the largest block bucket) fall back to host
     # hashing: the hosthash program's shapes are independent of message
     # length, so an adversary feeding growing messages cannot force a fresh
     # XLA compile per size.
-    oversized = n > 0 and int(mlens.max()) + 64 > BLOCK_BUCKETS[-1] * 128 - 17
-    if oversized:
+    if n > 0 and int(mlens.max()) + 64 > BLOCK_BUCKETS[-1] * 128 - 17:
         k_le = np.zeros((nb, 64), np.uint8)
-        digest_rows = bytearray(64 * n)
-        sha512 = hashlib.sha512
-        for i in range(n):
-            if not shape_ok[i] or not s_in_range[i]:
-                continue
-            h = sha512(sigs_c[i][:32])
-            h.update(pubs_c[i])
+        for i in np.nonzero(host_ok)[0].tolist():
+            h = hashlib.sha512(sig_rows[i, :32])
+            h.update(key_rows[i])
             h.update(msgs[i])
-            digest_rows[64 * i : 64 * (i + 1)] = h.digest()
-            host_ok[i] = True
-        if n:
-            k_le[:n] = np.frombuffer(bytes(digest_rows), np.uint8).reshape(n, 64)
-        operands = (
-            unpack.bytes_to_words(a_enc),
-            unpack.bytes_to_words(r_enc),
-            unpack.bytes_to_words(s_le),
-            unpack.bytes_to_words(k_le),
-        )
-        return operands, host_ok
+            k_le[i] = np.frombuffer(h.digest(), np.uint8)
+        a_words = _lane_words(key_rows.view("<u4"), nb)
+        return (a_words, r_words, s_words, unpack.bytes_to_words(k_le)), host_ok, walk
 
-    host_ok[:n] = np.asarray(shape_ok) & s_in_range
-    # Challenge blocks R || A || M, padded, built vectorized: R and A bulk-
-    # copy from the already-built byte matrices; messages fill in one pass
-    # per DISTINCT length (a commit's sign-bytes have 1-3 layouts, so this
-    # is a couple of reshaped assignments, not an n-row python loop).
+    # Challenge blocks R || A || M, padded: R and A are block copies of the
+    # columns' rows; messages and the pad go in per DISTINCT length (a
+    # commit's sign-bytes have 1-3 layouts), each length one join, one
+    # reshaped assignment and two constant column writes.
     tot = mlens + 64
     nblocks = s5.blocks_for(tot)
     bmax = block_bucket_for(int(nblocks.max()) if n else 1)
     buf = np.zeros((nb, bmax * 128), np.uint8)
-    if n:
-        buf[:n, 0:32] = r_enc[:n]
-        buf[:n, 32:64] = a_enc[:n]
-        for ln in np.unique(mlens):
-            if ln == 0:
-                continue  # shape-invalid rows were forced to length 0
-            rows = np.nonzero(mlens == ln)[0]
-            joined = b"".join(msgs[i] for i in rows)
-            buf[rows, 64 : 64 + ln] = np.frombuffer(joined, np.uint8).reshape(
-                len(rows), ln
-            )
-        s5.write_padding(buf[:n], tot, nblocks)
-    # Native-LE word view (free — no copy, no transpose; the device does
-    # the block-layout shuffle and byte swap itself).
-    pb = buf.view("<u4")
+    rows_n = buf[:n]
+    rows_n[:, 0:32] = sig_rows[:, :32]
+    rows_n[:, 32:64] = key_rows
+    by_length = s5.rows_by_length(tot)
+    for tl, rows in by_length:
+        if tl == 64:
+            continue  # empty messages, and the shape-invalid rows forced to 0
+        of_length = msgs if isinstance(rows, slice) else map(msgs.__getitem__, rows.tolist())
+        rows_n[rows, 64:tl] = np.frombuffer(b"".join(of_length), np.uint8).reshape(-1, tl - 64)
+    s5.write_padding(rows_n, by_length)
+    # padded lanes hash zero blocks (nblocks 0 -> IV digest): vetoed by mask
     pnb = np.zeros(nb, np.int32)
     pnb[:n] = nblocks
-    # padded lanes hash zero blocks (nblocks 0 -> IV digest): vetoed by mask
-    operands = (
-        unpack.bytes_to_words(a_enc),
-        unpack.bytes_to_words(r_enc),
-        unpack.bytes_to_words(s_le),
-        pb,
-        pnb,
-    )
-    return operands, host_ok
+    resident = _tables_serve((nb, bmax), sighting.tables)
+    a_words = None if resident else _lane_words(key_rows.view("<u4"), nb)
+    # The stream goes as a native-LE word view (free — no copy, no transpose;
+    # the device does the block-layout shuffle and byte swap itself).
+    return (a_words, r_words, s_words, buf.view("<u4"), pnb), host_ok, walk
 
 
 _device_pool = None
@@ -625,8 +642,10 @@ class _Column:
 # What one look-up found: `tables`, the column's (tables_a, ok_a) where the
 # call's keys are a resident column or a prefix of one, else None; `build`,
 # the column whose build the call's dispatch is to be followed by, else None;
-# `distinct`, how many keys a column refused for repeating one holds, else 0.
-Sighting = collections.namedtuple("Sighting", "tables build distinct", defaults=(0,))
+# `distinct`, how many keys a column refused for repeating one holds, else 0;
+# `keys`, the column's well-formed keys as uint8[lanes, 32] rows (a view of
+# the look-up's own join, which the pack then does not make again), else None.
+Sighting = collections.namedtuple("Sighting", "tables build distinct keys", defaults=(0, None))
 _NOTHING = Sighting(None, None)
 
 
@@ -669,12 +688,10 @@ class _ResidentColumns:
         n = len(pubs)
         if n < _resident_min():
             return _NOTHING
-        try:
-            joined = b"".join(pubs)
-            if len(joined) != 32 * n or max(map(len, pubs)) != 32:
-                return _NOTHING  # a malformed key: never resident
-        except TypeError:
-            return _NOTHING
+        joined = _whole_column(pubs, 32)
+        if joined is None:
+            return _NOTHING  # a malformed key: never resident
+        rows = np.frombuffer(joined, np.uint8).reshape(n, 32)
         with self._lock:
             col = self._match(joined, n)
             if col is None:
@@ -688,21 +705,21 @@ class _ResidentColumns:
                     # the smaller set's tables would carry.
                     self._counters["resident_repeat_sightings"] += 1
                     self._counters["resident_repeat_lanes"] += n
-                    return Sighting(None, None, len(keys))
+                    return Sighting(None, None, len(keys), rows)
                 if self._nbytes(n) <= RESIDENT_MAX_BYTES:
                     self._remember(_Column(joined, n))
-                return _NOTHING
+                return Sighting(None, None, 0, rows)
             self._lru.move_to_end(id(col))
             if col.state == _SEEN:
                 col.state = _WANTED  # the second sighting
             if col.state == _WANTED:
-                return Sighting(None, col)
+                return Sighting(None, col, 0, rows)
         # A call that arrives while its own column's tables are being built
         # would queue behind the build on the device-owner thread anyway:
         # it waits here and rides them, instead of walking the planner to
         # one more ladder bucket (a program load of seconds) for nothing.
         col.landed.wait()
-        return Sighting(col.tables, None)
+        return Sighting(col.tables, None, 0, rows)
 
     def _match(self, joined: bytes, n: int):
         """The remembered column these n keys are (whichever has tables, if
@@ -829,6 +846,13 @@ resident_counters = _columns.counters
 # them from the packer (PR 32: `device.pack` read 17-25 ms, not 6.5, with
 # three streams decoding beside it, and the amount moved from run to run).
 PACK_GATE = threading.Lock()
+# Dispatches packed, and those of them a malformed entry sent through the
+# per-lane walk (_host_checks); counted under PACK_GATE.
+_pack_counters = {"pack_calls": 0, "pack_walk_calls": 0}
+
+
+def pack_counters() -> dict:
+    return dict(_pack_counters)
 
 
 def batch_verify_submit(pubs, msgs, sigs, sighting: Sighting | None = None):
@@ -843,12 +867,15 @@ def batch_verify_submit(pubs, msgs, sigs, sighting: Sighting | None = None):
     if sighting is None:
         sighting = sight_column(pubs) if n else _NOTHING
     with trace.span("device.pack", lanes=n) as pack, PACK_GATE:
-        operands, host_ok = pack_batch(pubs, msgs, sigs)
+        operands, host_ok, walk = _pack(pubs, msgs, sigs, sighting)
         key = _bucket_key(operands)
-        tables = sighting.tables if _tables_serve(key, sighting.tables) else None
+        # the pack left the keys' words out where the sighting's tables serve
+        tables = sighting.tables if operands[0] is None else None
         if tables is not None:  # the resident program runs the tables' own bucket
             key = (tables[1].shape[0], key[1])
-        pack.set(bucket=key[0], resident=tables is not None)
+        pack.set(bucket=key[0], resident=tables is not None, walk=walk)
+        _pack_counters["pack_calls"] += 1  # under PACK_GATE
+        _pack_counters["pack_walk_calls"] += walk
     fn, sharded = _route_for(operands, tables)
     if sharded:
         _mesh_count("sharded_dispatches")
@@ -875,8 +902,8 @@ def batch_verify_submit(pubs, msgs, sigs, sighting: Sighting | None = None):
         with trace.span("device.wait"):
             dev_ok, collect.run_times = fut.result()
         with trace.span("device.unpack"):
-            results = [bool(host_ok[i] and dev_ok[i]) for i in range(n)]
-        return all(results), results
+            ok = host_ok[:n] & dev_ok[:n]
+            return bool(ok.all()), ok.tolist()
 
     # (batch bucket, block bucket, lanes of the resident tables or 0) — the
     # compiled-program identity, so callers can tell a first dispatch (XLA

@@ -210,19 +210,33 @@ def blocks_for(lens: np.ndarray) -> np.ndarray:
     return ((lens + 17 + 127) // 128).astype(np.int32)
 
 
-def write_padding(buf: np.ndarray, lens: np.ndarray, nblocks: np.ndarray) -> None:
+def rows_by_length(lens: np.ndarray) -> list:
+    """[(length, rows)] over the distinct lengths, ascending: `rows` is
+    slice(None) where every row has the one length, else that length's row
+    indices. One stable sort and one split, so no pass a length and no
+    Python-level step a row."""
+    if len(lens) == 0:
+        return []
+    if lens.min() == lens.max():
+        return [(lens[0], slice(None))]
+    order = np.argsort(lens, kind="stable")
+    ordered = lens[order]
+    cuts = np.nonzero(np.diff(ordered))[0] + 1
+    return list(zip(ordered[np.r_[0, cuts]], np.split(order, cuts)))
+
+
+def write_padding(buf: np.ndarray, by_length: list) -> None:
     """Write the FIPS 180-4 pad into buf uint8[n, B*128] rows holding
-    messages of the given byte lengths: the 0x80 terminator plus the
-    128-bit big-endian bit length at each row's last-block end (messages
-    here are < 2^53 bits so the low 64 bits suffice). Shared by the generic
-    packer and the ed25519 challenge packer so the padding rules live once."""
-    n = buf.shape[0]
-    idx = np.arange(n)
-    buf[idx, lens] = 0x80
-    ends = nblocks.astype(np.int64) * 128
-    bl_bytes = (lens * 8).astype(">u8").view(np.uint8).reshape(n, 8)
-    for k in range(8):
-        buf[idx, ends - 8 + k] = bl_bytes[:, k]
+    messages whose byte lengths rows_by_length() grouped: for each length
+    the 0x80 terminator and the 128-bit big-endian bit length at the end of
+    its last block are constant columns, written into that length's rows
+    (messages here are < 2^53 bits so the low 64 bits suffice). Shared by
+    the generic packer and the ed25519 challenge packer so the padding
+    rules live once."""
+    for ln, rows in by_length:
+        end = int(blocks_for(ln)) * 128
+        buf[rows, ln] = 0x80
+        buf[rows, end - 8 : end] = np.frombuffer((int(ln) * 8).to_bytes(8, "big"), np.uint8)
 
 
 def pack_messages512(msgs: list[bytes]):
@@ -230,13 +244,13 @@ def pack_messages512(msgs: list[bytes]):
     (uint32[B, 2, 16, N], int32[N]). Vectorized where it counts: one
     big byte buffer, length-grouped padding writes."""
     n = len(msgs)
-    lens = np.fromiter((len(m) for m in msgs), np.int64, n)
+    lens = np.fromiter(map(len, msgs), np.int64, n)
     nblocks = blocks_for(lens)
     bmax = int(nblocks.max()) if n else 1
     buf = np.zeros((n, bmax * 128), np.uint8)
     for i, m in enumerate(msgs):
         buf[i, : lens[i]] = np.frombuffer(m, np.uint8)
-    write_padding(buf, lens, nblocks)
+    write_padding(buf, rows_by_length(lens))
     words = buf.view(">u4").reshape(n, bmax, 32).astype(np.uint32)
     # -> [B, 2(hi/lo), 16, N]: 64-bit word t is words[.., 2t](hi), 2t+1(lo)
     hi = words[:, :, 0::2]

@@ -142,6 +142,7 @@ class TpuBackend(VerifyBackend):
             **self.device_info(),
             "device_lanes": self.device_lanes,
             **self._ed.resident_counters(),
+            **self._ed.pack_counters(),
         }
 
     def batch_verify(self, pubs, msgs, sigs):

@@ -242,7 +242,7 @@ def test_columns_that_are_never_resident(stub_programs, change):
     first = cols.sight(other)
     # a column refused for repeating a key says how many keys it holds, and is counted
     repeated = change == "one key repeated"
-    assert first == ek.Sighting(None, None, LANES - 1 if repeated else 0)
+    assert first[:3] == (None, None, LANES - 1 if repeated else 0)
     again = cols.sight(other)
     assert again.tables is None
     grown = {k: cols.counters()[k] - repeats[k] for k in ("resident_repeat_sightings", "resident_repeat_lanes")}
@@ -279,7 +279,7 @@ def test_the_bytes_bound_evicts_the_oldest_column(stub_programs, monkeypatch):
     monkeypatch.setattr(ek, "RESIDENT_MAX_BYTES", per_column - 1)
     big = _keys(LANES, b"big")
     cols.sight(big)
-    assert cols.sight(big) == ek.Sighting(None, None)
+    assert cols.sight(big)[:3] == (None, None, 0)
 
 
 def test_concurrent_sightings_build_each_column_once(stub_programs):
