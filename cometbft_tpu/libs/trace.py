@@ -136,6 +136,14 @@ NAMES = (
     "sidecar.encode",
     # p2p receive
     "p2p.recv_msg",
+    # light client
+    "light.sync",
+    "light.fetch",
+    "light.verify",
+    "light.speculate",
+    "light.speculate_collect",
+    "light.detect",
+    "light.save",
 )
 
 # Every role the hot paths name a thread by (module text).

@@ -7,19 +7,27 @@ if fewer than 1/3 of the trusted validators persist (ErrNewValSetCantBeTrusted),
 it bisects: fetch the midpoint header, verify trusted→pivot, then
 pivot→target (light/client.go:706 verifySkipping). Every hop's commit is
 batch-verified on the device tier. Witness cross-checking (detector.py) runs
-after primary verification."""
+after primary verification.
+
+Spans (`libs/trace.py`): `light.sync` round `verify_light_block_at_height`,
+the root of a sync, with `light.fetch` (provider.fetch), `light.verify` (one
+a hop tried: `result` = ok / refused / invalid), `light.speculate` and its
+`light.speculate_collect`, `light.save` and `light.detect` under it.
+`counters()` says what the client's walks did."""
 
 from __future__ import annotations
 
 import secrets
 from dataclasses import dataclass
 
+from cometbft_tpu.libs import trace
 from cometbft_tpu.light import verifier
 from cometbft_tpu.sidecar import engine
 from cometbft_tpu.light.provider import (
     ErrLightBlockNotFound,
     ErrNoResponse,
     Provider,
+    fetch,
 )
 from cometbft_tpu.light.store import LightStore
 from cometbft_tpu.types import cmttime
@@ -97,6 +105,10 @@ class Client:
         self.logger = logger
         # Speculative-bisection counters (e2e observability).
         self.speculation = {"descents": 0, "prewarmed_sigs": 0}
+        # What the walks did (counters()): hops verified, hops refused for
+        # want of trust (the walk bisected), pivots fetched, and prewarms
+        # that raised (the hop checks then did the work themselves).
+        self._walk = {"hops": 0, "refusals": 0, "pivot_fetches": 0, "speculation_errors": 0}
         # Gateway-assisted sync counters: which path served each forward
         # verification, and what a rejected/unavailable gateway cost.
         self.gateway_stats = {
@@ -122,14 +134,14 @@ class Client:
                     f"trust option hash {opts.hash.hex()} at height {opts.height}"
                 )
             return
-        lb = self.primary.light_block(opts.height)
+        lb = fetch(self.primary, opts.height)
         if lb.hash() != opts.hash:
             raise ValueError(
                 f"primary's header hash {lb.hash().hex()} does not match trust "
                 f"option hash {opts.hash.hex()} at height {opts.height}"
             )
         lb.validate_basic(self.chain_id)
-        self.store.save_light_block(lb)
+        self._save([lb])
 
     # -- public API -----------------------------------------------------------
 
@@ -142,6 +154,12 @@ class Client:
             height = h
         return self.store.light_block(height)
 
+    def counters(self) -> dict:
+        """hops, refusals, pivot_fetches, speculation_errors and the
+        speculative prewarm's descents and prewarmed_sigs, over the client's
+        life."""
+        return {**self._walk, **self.speculation}
+
     def latest_trusted(self) -> LightBlock | None:
         h = self.store.last_light_block_height()
         return self.store.light_block(h) if h >= 0 else None
@@ -149,7 +167,7 @@ class Client:
     def update(self, now: Time | None = None) -> LightBlock | None:
         """client.go Update: verify the primary's latest header."""
         now = now or cmttime.now()
-        latest = self.primary.light_block(0)
+        latest = fetch(self.primary, 0)
         trusted = self.latest_trusted()
         if trusted is not None and latest.height <= trusted.height:
             return None
@@ -165,11 +183,18 @@ class Client:
         existing = self.store.light_block(height)
         if existing is not None:
             return existing
-        target = _latest if _latest is not None and _latest.height == height else (
-            self.primary.light_block(height)
-        )
-        target.validate_basic(self.chain_id)
-        self.verify_header(target, now)
+        walked = dict(self._walk)
+        with trace.span(
+            "light.sync", root=self.store.last_light_block_height(), target=height
+        ) as sync:
+            try:
+                target = _latest if _latest is not None and _latest.height == height else (
+                    fetch(self.primary, height)
+                )
+                target.validate_basic(self.chain_id)
+                self.verify_header(target, now)
+            finally:
+                sync.set(**{k: self._walk[k] - walked[k] for k in ("hops", "refusals")})
         return target
 
     def verify_header(self, new_lb: LightBlock, now: Time) -> None:
@@ -194,11 +219,10 @@ class Client:
                         trace = self._verify_with_gateway(trusted, new_lb, now)
                     else:
                         trace = self._verify_skipping(trusted, new_lb, now)
-            for lb in trace:
-                self.store.save_light_block(lb)
+            self._save(trace)
         elif new_lb.height < self.store.first_light_block_height():
             self._verify_backwards(new_lb)
-            self.store.save_light_block(new_lb)
+            self._save([new_lb])
         else:
             # Height within the trusted range but not stored: verify forward
             # from the closest lower trusted block.
@@ -206,10 +230,40 @@ class Client:
             if base is None:
                 raise RuntimeError(f"no trusted block below {new_lb.height}")
             trace = self._verify_skipping(base, new_lb, now)
-            for lb in trace:
-                self.store.save_light_block(lb)
+            self._save(trace)
         self._detect_divergence(new_lb, now)
         self.store.prune(self.pruning_size)
+
+    def _save(self, verified) -> None:
+        """Verified light blocks into the trusted store, under one span."""
+        with trace.span("light.save", blocks=len(verified)):
+            for lb in verified:
+                self.store.save_light_block(lb)
+
+    def _verify_hop(self, trusted: LightBlock, untrusted: LightBlock, now: Time) -> None:
+        """verifier.verify of one hop (adjacent or skipping) under its
+        `light.verify` span; counts the hop verified or refused."""
+        with trace.span("light.verify", **{"from": trusted.height, "to": untrusted.height}) as hop:
+            try:
+                verifier.verify(
+                    trusted.signed_header,
+                    trusted.validator_set,
+                    untrusted.signed_header,
+                    untrusted.validator_set,
+                    self.trusting_period_ns,
+                    now,
+                    self.max_clock_drift_ns,
+                    self.trust_level,
+                )
+            except verifier.ErrNewValSetCantBeTrusted:
+                hop.set(result="refused")
+                self._walk["refusals"] += 1
+                raise
+            except Exception:
+                hop.set(result="invalid")
+                raise
+            hop.set(result="ok")
+            self._walk["hops"] += 1
 
     # -- verification strategies ----------------------------------------------
 
@@ -218,16 +272,9 @@ class Client:
         trace = []
         current = trusted
         for h in range(trusted.height + 1, target.height + 1):
-            lb = target if h == target.height else self.primary.light_block(h)
+            lb = target if h == target.height else fetch(self.primary, h)
             lb.validate_basic(self.chain_id)
-            verifier.verify_adjacent(
-                current.signed_header,
-                lb.signed_header,
-                lb.validator_set,
-                self.trusting_period_ns,
-                now,
-                self.max_clock_drift_ns,
-            )
+            self._verify_hop(current, lb, now)  # adjacent: verifier.verify_adjacent
             current = lb
             trace.append(lb)
         return trace
@@ -249,16 +296,7 @@ class Client:
         while stack:
             candidate = stack[-1]
             try:
-                verifier.verify(
-                    current.signed_header,
-                    current.validator_set,
-                    candidate.signed_header,
-                    candidate.validator_set,
-                    self.trusting_period_ns,
-                    now,
-                    self.max_clock_drift_ns,
-                    self.trust_level,
-                )
+                self._verify_hop(current, candidate, now)
             except verifier.ErrNewValSetCantBeTrusted:
                 pivot = (current.height + candidate.height) // 2
                 if pivot in (current.height, candidate.height):
@@ -266,7 +304,8 @@ class Client:
                 fetches += 1
                 if fetches > DEFAULT_MAX_RETRY_ATTEMPTS * 4:
                     raise RuntimeError("bisection: too many pivot fetches")
-                lb = self.primary.light_block(pivot)
+                self._walk["pivot_fetches"] += 1
+                lb = fetch(self.primary, pivot)
                 lb.validate_basic(self.chain_id)
                 stack.append(lb)
                 self._speculate_descent(current, stack)
@@ -281,44 +320,52 @@ class Client:
         hop chain: (current -> stack[-1]), (stack[-1] -> stack[-2]), ...,
         (stack[1] -> stack[0]).  One BatchVerifier call carries every hop's
         union prefix — when the process backend is the coalescing scheduler
-        this also merges with other clients' concurrent descents.  Errors
-        are swallowed: speculation is an accelerator, never an arbiter (the
-        sequential checks in _verify_skipping re-derive every verdict)."""
-        try:
-            from cometbft_tpu.crypto import ed25519
-            from cometbft_tpu.types import validation
+        this also merges with other clients' concurrent descents.  An error
+        fails no sync: speculation is an accelerator, never an arbiter (the
+        sequential checks in _verify_skipping re-derive every verdict); it
+        is counted (`speculation_errors`) and named on the span."""
+        with trace.span("light.speculate", hops=len(stack)) as span:
+            try:
+                from cometbft_tpu.crypto import ed25519
+                from cometbft_tpu.types import validation
 
-            triples: list[tuple] = []
-            lower = current
-            for upper in reversed(stack):
-                adjacent = upper.height == lower.height + 1
-                triples.extend(
-                    validation.speculative_verify_triples(
-                        self.chain_id,
-                        lower.validator_set,
-                        upper.validator_set,
-                        upper.signed_header.commit,
-                        None if adjacent else self.trust_level,
+                with trace.span("light.speculate_collect"):
+                    triples: list[tuple] = []
+                    lower = current
+                    for upper in reversed(stack):
+                        adjacent = upper.height == lower.height + 1
+                        triples.extend(
+                            validation.speculative_verify_triples(
+                                self.chain_id,
+                                lower.validator_set,
+                                upper.validator_set,
+                                upper.signed_header.commit,
+                                None if adjacent else self.trust_level,
+                            )
+                        )
+                        lower = upper
+                    bv = ed25519.BatchVerifier()
+                    for pub, msg, sig in triples:
+                        try:
+                            bv.add(pub, msg, sig)
+                        except (TypeError, ValueError):
+                            continue  # non-ed25519 or malformed entry: engine's call
+                span.set(lanes=len(bv))
+                if len(bv):
+                    self.speculation["descents"] += 1
+                    self.speculation["prewarmed_sigs"] += len(bv)
+                    # Light-class engine admission: speculative descent is
+                    # opportunistic prewarm, lowest on the priority ladder.
+                    with engine.submission_class(engine.CLASS_LIGHT):
+                        bv.verify()  # cache-filters, dedups, populates _verified
+            except Exception as e:
+                self._walk["speculation_errors"] += 1
+                span.set(error=type(e).__name__)
+                if self.logger:
+                    self.logger.info(
+                        "speculative prewarm failed; the hop checks verify",
+                        module="light", err=repr(e),
                     )
-                )
-                lower = upper
-            if not triples:
-                return
-            bv = ed25519.BatchVerifier()
-            for pub, msg, sig in triples:
-                try:
-                    bv.add(pub, msg, sig)
-                except (TypeError, ValueError):
-                    continue  # non-ed25519 or malformed entry: engine's call
-            if len(bv):
-                self.speculation["descents"] += 1
-                self.speculation["prewarmed_sigs"] += len(bv)
-                # Light-class engine admission: speculative descent is
-                # opportunistic prewarm, lowest on the priority ladder.
-                with engine.submission_class(engine.CLASS_LIGHT):
-                    bv.verify()  # cache-filters, dedups, populates _verified
-        except Exception:
-            pass
 
     # -- checkpoint-bundle cold sync (light/bundle.py; static artifact) -------
 
@@ -504,7 +551,7 @@ class Client:
         first_h = self.store.first_light_block_height()
         current = self.store.light_block(first_h)
         for h in range(first_h - 1, target.height - 1, -1):
-            lb = target if h == target.height else self.primary.light_block(h)
+            lb = target if h == target.height else fetch(self.primary, h)
             lb.validate_basic(self.chain_id)
             verifier.verify_backwards(lb.header, current.header)
             current = lb
@@ -522,7 +569,8 @@ class Client:
                     "all witnesses removed; reset the light client"
                 )
             return
-        detect_divergence(self, new_lb, now)
+        with trace.span("light.detect", witnesses=len(self.witnesses)):
+            detect_divergence(self, new_lb, now)
 
     def remove_witness(self, witness: Provider) -> None:
         self.witnesses = [w for w in self.witnesses if w is not witness]

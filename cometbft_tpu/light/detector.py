@@ -10,7 +10,7 @@ both sides, then fails verification so the caller can react."""
 from __future__ import annotations
 
 from cometbft_tpu.light import verifier
-from cometbft_tpu.light.provider import ErrLightBlockNotFound, ErrNoResponse
+from cometbft_tpu.light.provider import ErrLightBlockNotFound, ErrNoResponse, fetch
 from cometbft_tpu.types.cmttime import Time
 from cometbft_tpu.types.evidence import LightClientAttackEvidence
 from cometbft_tpu.types.light_block import LightBlock
@@ -44,7 +44,7 @@ def detect_divergence(client, new_lb: LightBlock, now: Time) -> None:
     drop = []
     for i, witness in enumerate(list(client.witnesses)):
         try:
-            w_lb = witness.light_block(new_lb.height)
+            w_lb = fetch(witness, new_lb.height)
         except (ErrLightBlockNotFound, ErrNoResponse):
             # Unresponsive/behind witnesses are dropped (detector.go:92-100).
             drop.append(witness)

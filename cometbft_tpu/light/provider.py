@@ -6,6 +6,7 @@ node's /commit + /validators routes."""
 
 from __future__ import annotations
 
+from cometbft_tpu.libs import trace
 from cometbft_tpu.types.block import SignedHeader
 from cometbft_tpu.types.light_block import LightBlock
 from cometbft_tpu.types.validator_set import ValidatorSet
@@ -22,6 +23,10 @@ class ErrNoResponse(Exception):
 class Provider:
     """light/provider/provider.go Provider interface."""
 
+    # Bytes read from the provider's source so far, where it counts them
+    # (None: it does not); `fetch` puts a fetch's share on its span.
+    bytes_received: int | None = None
+
     def chain_id(self) -> str:
         raise NotImplementedError
 
@@ -31,6 +36,17 @@ class Provider:
 
     def report_evidence(self, ev) -> None:
         raise NotImplementedError
+
+
+def fetch(provider: Provider, height: int) -> LightBlock:
+    """`provider.light_block(height)` under a `light.fetch` span: every
+    fetch of the light client, the decode of what arrived included."""
+    with trace.span("light.fetch", height=height) as span:
+        before = getattr(provider, "bytes_received", None)
+        lb = provider.light_block(height)
+        if before is not None:
+            span.set(bytes=provider.bytes_received - before)
+    return lb
 
 
 class MockProvider(Provider):

@@ -145,6 +145,12 @@ def test_repeat_column_lane_share_reader(bench, before, after, want):
     assert got == (None if want is None else pytest.approx(want))
 
 
+def _later_cells(bench) -> set:
+    """The cells added after this one: appended to the lists it is on, or on lists of their own."""
+    names = [w["name"] for w in bench["workloads"]]
+    return set(names[names.index(CELL) + 1:])
+
+
 @pytest.fixture(scope="module")
 def benchmark_json():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
@@ -154,18 +160,21 @@ def benchmark_json():
 @pytest.mark.parametrize("name", sorted(NEW))
 def test_each_new_metric_is_in_the_benchmark_once_with_the_catch_up_cells(benchmark_json, name):
     unit, better, source, layer = NEW[name]
-    (entry,) = [m for m in benchmark_json["per_layer"] if m["name"] == name]
+    (entry,) = [dict(m) for m in benchmark_json["per_layer"] if m["name"] == name]
+    cells = entry.pop("workloads")
     assert entry == {"name": name, "unit": unit, "better": better, "source": source, "layer": layer,
-                     "moves": "catchup_heights_per_s", "workloads": CATCHUP}
+                     "moves": "catchup_heights_per_s"}
+    assert cells[:len(CATCHUP)] == CATCHUP and set(cells[len(CATCHUP):]) <= _later_cells(benchmark_json)
     assert os.path.isfile(os.path.join(BENCH, "layers", name + ".py"))
-    assert [m["name"] for m in benchmark_json["per_layer"][-4:]] == list(NEW), "appended, in this order"
+    names = [m["name"] for m in benchmark_json["per_layer"]]
+    at = names.index(next(iter(NEW)))
+    assert names[at:at + len(NEW)] == list(NEW), "appended, in this order"
 
 
 def test_the_cell_its_configuration_and_its_files(benchmark_json):
-    (cell,) = [w for w in benchmark_json["workloads"] if w["name"] == CELL]
-    assert benchmark_json["workloads"][-1] is cell and benchmark_json["configs"][-1]["name"] == "valset-1024"
+    (cell,) = [w for w in benchmark_json["workloads"] if w["name"] == CELL]  # later cells follow it
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("valset-1024", "replay-1000-blocks", 1)
-    entry = benchmark_json["configs"][-1]
+    (entry,) = [c for c in benchmark_json["configs"] if c["name"] == "valset-1024"]
     with open(os.path.join(ROOT, entry["file"])) as f:
         config = json.load(f)
     with open(os.path.join(BENCH, "traffic", cell["traffic"] + ".json")) as f:
@@ -189,11 +198,13 @@ def test_the_cell_is_on_the_lists_of_the_catch_up_metrics_it_can_report(benchmar
     """Appended to `catchup_heights_per_s` and to every accepted `.catchup`
     metric but the two that read a block's transactions; on no `.commit` list."""
     (e2e,) = [m for m in benchmark_json["end_to_end"] if m["name"] == "catchup_heights_per_s"]
-    assert e2e["workloads"] == CATCHUP
+    assert e2e["workloads"][:len(CATCHUP)] == CATCHUP
+    later = _later_cells(benchmark_json)
     for m in benchmark_json["per_layer"]:
-        if m["name"].endswith(".catchup"):
+        if m["name"].endswith(".catchup") and not set(m["workloads"]) <= later:
             assert (CELL in m["workloads"]) == (m["name"] not in NOT_TAKEN), m["name"]
             if CELL in m["workloads"]:
-                assert m["workloads"][-1] == CELL and m["moves"] == "catchup_heights_per_s"
+                assert set(m["workloads"][m["workloads"].index(CELL) + 1:]) <= later
+                assert m["moves"] == "catchup_heights_per_s"
         else:
             assert CELL not in m["workloads"], m["name"]
