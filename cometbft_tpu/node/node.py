@@ -935,6 +935,17 @@ class Node:
             reg.gauge_func("verify_columns", key, text,
                            counter("cometbft_tpu.types.validator_set",
                                    "columns_counters", key))
+        for key, text in (
+            ("heads_built", "Validator-set wire heads built, once a membership."),
+            ("heads_reused", "Validator-set encodes that found their set's heads built."),
+            ("roots_built", "Validator-set Merkle roots computed."),
+            ("roots_reused", "Validator-set hashes that found their set's root computed."),
+            ("commit_records_built", "Commits whose signature records were built, once a commit."),
+            ("commit_encodes_reused", "Commit encodes that found the commit's bytes kept."),
+        ):
+            reg.gauge_func("wire", key, text,
+                           counter("cometbft_tpu.types.validator_set",
+                                   "wire_counters", key))
 
     def _register_lightgw_metrics(self, reg) -> None:
         """Light-client gateway gauges. Strictly passive: they read the

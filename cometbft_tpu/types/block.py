@@ -10,6 +10,7 @@ wrapper-value encoding of primitive fields mirrors cdcEncode
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field as dfield
 
 from cometbft_tpu.crypto import merkle, tmhash
@@ -283,11 +284,7 @@ class CommitSig:
         return BlockID()
 
     def encode(self) -> bytes:
-        out = wire.field_varint(1, self.block_id_flag)
-        out += wire.field_bytes(2, self.validator_address)
-        out += wire.field_message(3, self.timestamp.encode(), emit_empty=True)
-        out += wire.field_bytes(4, self.signature)
-        return out
+        return commit_sig_records((self,))[1]
 
     @classmethod
     def decode(cls, data: bytes) -> "CommitSig":
@@ -331,6 +328,54 @@ class CommitSig:
                     raise ValueError("signature is too big")
 
 
+def commit_sig_records(sigs) -> list:
+    """Every CommitSig's wire record in one pass: [frame, body, frame,
+    body, ...], a body being the tendermint.types.CommitSig bytes (flag,
+    address, time, signature; the time always there) and a frame the tag of
+    Commit's field 4 and the body's length. Tags are constants, and a time's
+    seconds are encoded once a distinct value: a commit's signatures mostly
+    share them."""
+    uvarint, varint = wire.encode_uvarint, wire.encode_varint_signed
+    seconds: dict = {}
+    out = []
+    add = out.extend
+    for cs in sigs:
+        ts = cs.timestamp
+        sec = ts.seconds
+        t = seconds.get(sec)
+        if t is None:
+            t = seconds[sec] = b"\x08" + varint(sec) if sec else b""
+        if ts.nanos:
+            t += b"\x10" + varint(ts.nanos)
+        flag, addr, sig = cs.block_id_flag, cs.validator_address, cs.signature
+        body = b"".join((
+            b"\x08" + varint(flag) if flag else b"",
+            b"\x12" + uvarint(len(addr)) + addr if addr else b"",
+            b"\x1a", uvarint(len(t)), t,
+            b"\x22" + uvarint(len(sig)) + sig if sig else b"",
+        ))
+        add((b"\x22" + uvarint(len(body)), body))
+    return out
+
+
+# How often a commit's records were built (commit_sig_records, once a
+# commit) and how often Commit.encode found the commit's bytes kept,
+# always counted: a catch-up node encodes each commit three times (its
+# block's part set, the store's last commit and seen commit).
+_wire_counts = {"commit_records_built": 0, "commit_encodes_reused": 0}
+_wire_lock = threading.Lock()
+
+
+def commit_wire_counters() -> dict:
+    with _wire_lock:
+        return dict(_wire_counts)
+
+
+def _count(key: str) -> None:
+    with _wire_lock:
+        _wire_counts[key] += 1
+
+
 # types/signable.go MaxSignatureSize is 96, sized for compressed bn254 G2;
 # this rebuild's bn254 signatures are UNCOMPRESSED G2 (crypto/bn254.py
 # SIGNATURE_SIZE = 128), so per-vote bn254 commits need the extra room.
@@ -360,9 +405,27 @@ class Commit:
     _sb_cache: tuple | None = dfield(default=None, compare=False, repr=False)
     _sba_cache: tuple | None = dfield(default=None, compare=False, repr=False)
     _columns: tuple | None = dfield(default=None, compare=False, repr=False)
+    # The signatures' wire records and the commit's bytes, built by
+    # encoding this object and never taken from wire input (a peer's
+    # non-canonical field order must not survive as the encoding). Each is
+    # kept with what it was built from, so a commit given another list or
+    # field builds again; init=False: dataclasses.replace() carries none.
+    _records: tuple | None = dfield(default=None, init=False, compare=False, repr=False)
+    _enc: tuple | None = dfield(default=None, init=False, compare=False, repr=False)
 
     def size(self) -> int:
         return len(self.signatures)
+
+    def sig_records(self) -> list:
+        """commit_sig_records of this commit's signatures, memoized under
+        the contract of _hash: a commit does not change after construction.
+        Commit.hash() takes its leaves from them, encode() its records."""
+        sigs = self.signatures
+        memo = self._records
+        if memo is None or memo[0] is not sigs or len(memo[1]) != 2 * len(sigs):
+            memo = self._records = (sigs, commit_sig_records(sigs))
+            _count("commit_records_built")
+        return memo[1]
 
     def sig_columns(self) -> tuple:
         """(flags, seconds, nanos, signatures): the signatures' attributes
@@ -394,9 +457,7 @@ class Commit:
 
     def hash(self) -> bytes:
         if self._hash is None:
-            self._hash = merkle.hash_from_byte_slices(
-                [cs.encode() for cs in self.signatures]
-            )
+            self._hash = merkle.hash_from_byte_slices(self.sig_records()[1::2])
         return self._hash
 
     def vote_sign_bytes(self, chain_id: str, val_idx: int) -> bytes:
@@ -555,14 +616,25 @@ class Commit:
         return wire.encode_uvarint(len(out)) + out
 
     def encode(self) -> bytes:
-        out = wire.field_varint(1, self.height)
-        out += wire.field_varint(2, self.round)
-        out += wire.field_message(3, self.block_id.encode(), emit_empty=True)
-        for cs in self.signatures:
-            out += wire.field_message(4, cs.encode(), emit_empty=True)
+        """One join of the commit's fields and its records, kept: the part
+        set, the store's last commit and the seen commit share it."""
+        records = self.sig_records()
+        key = (self.height, self.round, self.block_id, self.agg_signature, self.agg_bitmap)
+        memo = self._enc
+        if memo is not None and memo[0] is records and memo[1] == key:
+            _count("commit_encodes_reused")
+            return memo[2]
+        parts = [
+            wire.field_varint(1, self.height),
+            wire.field_varint(2, self.round),
+            wire.field_message(3, self.block_id.encode(), emit_empty=True),
+        ]
+        parts += records
         if self.agg_signature:
-            out += wire.field_bytes(5, self.agg_signature)
-            out += wire.field_bytes(6, self.agg_bitmap)
+            parts.append(wire.field_bytes(5, self.agg_signature))
+            parts.append(wire.field_bytes(6, self.agg_bitmap))
+        out = b"".join(parts)
+        self._enc = (records, key, out)
         return out
 
     @classmethod

@@ -8,6 +8,17 @@ from cometbft_tpu.crypto import encoding as key_encoding
 from cometbft_tpu.wire import proto as wire
 
 
+def wire_head(address: bytes, pub_key, voting_power: int) -> bytes:
+    """Fields 1-3 of tendermint.types.Validator (address, key, power): the
+    part of a validator's wire form that proposer rotation leaves as it is.
+    Field 4, the priority, follows it (Validator.encode)."""
+    return (
+        wire.field_bytes(1, address)
+        + wire.field_message(2, key_encoding.pub_key_to_proto(pub_key), emit_empty=True)
+        + wire.field_varint(3, voting_power)
+    )
+
+
 @dataclass
 class Validator:
     """types/validator.go:17-35. Mutable: priority changes every round."""
@@ -62,13 +73,9 @@ class Validator:
 
     def encode(self) -> bytes:
         """tendermint.types.Validator wire form."""
-        out = wire.field_bytes(1, self.address)
-        out += wire.field_message(
-            2, key_encoding.pub_key_to_proto(self.pub_key), emit_empty=True
-        )
-        out += wire.field_varint(3, self.voting_power)
-        out += wire.field_varint(4, self.proposer_priority)
-        return out
+        return wire_head(
+            self.address, self.pub_key, self.voting_power
+        ) + wire.field_varint(4, self.proposer_priority)
 
     @classmethod
     def decode(cls, data: bytes) -> "Validator":

@@ -10,12 +10,13 @@ address ascending (ValidatorsByVotingPower, validator_set.go:755-764).
 
 from __future__ import annotations
 
+import operator
 import threading
 from typing import NamedTuple
 
 from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.crypto import merkle
-from cometbft_tpu.types.validator import Validator
+from cometbft_tpu.types.validator import Validator, wire_head
 
 INT64_MAX = 2**63 - 1
 INT64_MIN = -(2**63)
@@ -67,17 +68,28 @@ class SetColumns(NamedTuple):
     pub_bytes: tuple | None
 
 
-class _ColumnsMemo:
-    """Holds a set's SetColumns once some verification has asked for them.
-    A set and its copies share ONE holder (what it holds is immutable), so
-    the first of them a commit is verified against fills it for all: a
-    node's state copies its sets every height and verifies against the
-    copies. A membership or power change gives the changed set a new one."""
+class _SetMemo:
+    """What a set derives from its entries' addresses, keys and powers, in
+    order, and proposer rotation leaves alone: the SetColumns commit
+    verification reads, each validator's wire head (fields 1-3 of
+    tendermint.types.Validator) and the Merkle root. A set and its copies
+    share ONE holder (what it holds is immutable), so the first of them to
+    need a piece fills it for all: a node's state copies its sets every
+    height, then verifies against, hashes and stores the copies. A
+    membership or power change gives the changed set a new one.
 
-    __slots__ = ("columns",)
+    `entries` is the (address, pub_key, voting_power) of every validator
+    the heads and the root were built from; they serve a set only while its
+    entries are still those (a validator changed in place is encoded and
+    hashed afresh, never served stale)."""
+
+    __slots__ = ("columns", "entries", "heads", "root")
 
     def __init__(self):
         self.columns: SetColumns | None = None
+        self.entries: list | None = None
+        self.heads: list | None = None
+        self.root: bytes | None = None
 
 
 # How often ValidatorSet.columns() computed the columns and how often it
@@ -92,6 +104,31 @@ def columns_counters() -> dict:
         return dict(_columns_counts)
 
 
+# How often a set's wire heads and Merkle root were built and how often a
+# copy found them in the holder it shares, always counted: on a chain whose
+# set does not change, `heads_built` stands still after the first height.
+_wire_counts = {"heads_built": 0, "heads_reused": 0, "roots_built": 0, "roots_reused": 0}
+
+
+def wire_counters() -> dict:
+    """The sets' counts above, and the commits' (types.block): records
+    built once a commit, and encodes that found the commit's bytes kept."""
+    from cometbft_tpu.types import block
+
+    with _columns_lock:
+        out = dict(_wire_counts)
+    out.update(block.commit_wire_counters())
+    return out
+
+
+def _count(key: str) -> None:
+    with _columns_lock:
+        _wire_counts[key] += 1
+
+
+_ENTRY = operator.attrgetter("address", "pub_key", "voting_power")
+
+
 class ValidatorSet:
     """types/validator_set.go:51-97."""
 
@@ -103,13 +140,12 @@ class ValidatorSet:
         # signature's validator up by address, which is O(n^2) per commit as
         # a linear scan at 4k+ validators.  Invalidated on membership change.
         self._addr_index: dict[bytes, int] | None = None
-        # Merkle-root memo: the hash covers (pubkey, power) per validator in
-        # order, so it shares _addr_index's invalidation points (membership/
-        # power changes); proposer-priority rotation leaves it intact.
-        self._hash_memo: bytes | None = None
-        # The columns commit verification reads (columns()): same
-        # invalidation points again, and copies share the holder.
-        self._columns_memo = _ColumnsMemo()
+        # The columns, wire heads and Merkle root (_SetMemo): they cover
+        # (address, key, power) per validator in order, so they share
+        # _addr_index's invalidation points (membership/power changes);
+        # proposer-priority rotation leaves them intact, and copies share
+        # the holder.
+        self._memo = _SetMemo()
         if validators:
             err = self._update_with_change_set(
                 [v.copy() for v in validators], allow_deletes=False
@@ -153,13 +189,13 @@ class ValidatorSet:
         c.validators = [v.copy() for v in self.validators]
         c.proposer = self.proposer
         c._total_voting_power = self._total_voting_power
-        c._columns_memo = self._columns_memo
+        c._memo = self._memo
         return c
 
     def columns(self) -> tuple[SetColumns, bool]:
         """The set's columns, and whether they were there already (False:
         this call computed them, one walk of the set)."""
-        memo = self._columns_memo
+        memo = self._memo
         cols = memo.columns
         reused = cols is not None
         if not reused:
@@ -206,13 +242,29 @@ class ValidatorSet:
                 proposer = v.compare_proposer_priority(proposer) if proposer else v
         return proposer
 
+    def _shared(self) -> _SetMemo | None:
+        """The holder, when the heads and root it has (or will have) are
+        this set's: it was built from these entries, or from none yet and
+        now adopts them. None when an entry changed in place since."""
+        memo = self._memo
+        entries = list(map(_ENTRY, self.validators))
+        if memo.entries is None:
+            memo.entries = entries
+        elif memo.entries != entries:
+            return None
+        return memo
+
     def hash(self) -> bytes:
         """Merkle root over SimpleValidator leaves (validator_set.go:347)."""
-        if self._hash_memo is None:
-            self._hash_memo = merkle.hash_from_byte_slices(
-                [v.bytes() for v in self.validators]
-            )
-        return self._hash_memo
+        memo = self._shared()
+        if memo is not None and memo.root is not None:
+            _count("roots_reused")
+            return memo.root
+        root = merkle.hash_from_byte_slices([v.bytes() for v in self.validators])
+        if memo is not None:
+            memo.root = root
+        _count("roots_built")
+        return root
 
     def validate_basic(self) -> None:
         if self.is_nil_or_empty():
@@ -367,8 +419,7 @@ class ValidatorSet:
 
     def _membership_changed(self) -> None:
         self._addr_index = None
-        self._hash_memo = None
-        self._columns_memo = _ColumnsMemo()
+        self._memo = _SetMemo()
 
     def _apply_updates(self, updates: list[Validator]) -> None:
         existing = sorted(self.validators, key=lambda v: v.address)
@@ -414,16 +465,40 @@ class ValidatorSet:
 
     # -- wire ----------------------------------------------------------------
 
+    def _heads(self) -> list:
+        """Each validator's wire head, from the shared holder once a
+        membership (built on the first encode, then reused by every copy)."""
+        memo = self._shared()
+        if memo is None:
+            _count("heads_built")
+            return [wire_head(*e) for e in map(_ENTRY, self.validators)]
+        heads = memo.heads
+        if heads is None:
+            heads = memo.heads = [wire_head(*e) for e in memo.entries]
+            _count("heads_built")
+        else:
+            _count("heads_reused")
+        return heads
+
     def encode(self) -> bytes:
+        """tendermint.types.ValidatorSet: one record a validator (its head,
+        then field 4 where the priority is not 0), the proposer, the total
+        power; joined once."""
         from cometbft_tpu.wire import proto as wire
 
-        out = b""
-        for v in self.validators:
-            out += wire.field_message(1, v.encode(), emit_empty=True)
+        uvarint, varint = wire.encode_uvarint, wire.encode_varint_signed
+        parts = []
+        add = parts.extend
+        for head, v in zip(self._heads(), self.validators):
+            prio = v.proposer_priority
+            body = head + b"\x20" + varint(prio) if prio else head
+            add((b"\x0a", uvarint(len(body)), body))
         if self.proposer is not None:
-            out += wire.field_message(2, self.proposer.encode(), emit_empty=True)
-        out += wire.field_varint(3, self.total_voting_power() if self.validators else 0)
-        return out
+            parts.append(wire.field_message(2, self.proposer.encode(), emit_empty=True))
+        parts.append(
+            wire.field_varint(3, self.total_voting_power() if self.validators else 0)
+        )
+        return b"".join(parts)
 
     @classmethod
     def decode(cls, data: bytes) -> "ValidatorSet":

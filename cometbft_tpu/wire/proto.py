@@ -62,6 +62,15 @@ def encode_varint_signed(n: int) -> bytes:
     """proto `int64`: negative values use 10-byte two's complement varint."""
     if n < 0:
         n += 1 << 64
+        if n >= 1 << 63:
+            # Every int64 below zero is ten bytes: unrolled, since proposer
+            # priorities below zero fill a validator set's encoding.
+            return bytes((
+                n & 0x7F | 0x80, n >> 7 & 0x7F | 0x80, n >> 14 & 0x7F | 0x80,
+                n >> 21 & 0x7F | 0x80, n >> 28 & 0x7F | 0x80,
+                n >> 35 & 0x7F | 0x80, n >> 42 & 0x7F | 0x80,
+                n >> 49 & 0x7F | 0x80, n >> 56 & 0x7F | 0x80, n >> 63,
+            ))
     return encode_uvarint(n)
 
 
