@@ -14,6 +14,7 @@ import time
 
 from cometbft_tpu.consensus import messages as cmsg
 from cometbft_tpu.consensus.cstypes import (
+    STEP_COMMIT,
     STEP_NAMES,
     STEP_NEW_HEIGHT,
     STEP_PRECOMMIT_WAIT,
@@ -58,6 +59,14 @@ class PeerState:
                 self._sent_votes.clear()
                 self.proposal = False
                 self.proposal_pol_round = -1
+            elif self.step < STEP_COMMIT <= msg.step:
+                # The peer has just set up its part set for the committed
+                # block (state.go enterCommit, which the reference announces
+                # with NewValidBlock{IsCommit} to reset what we think it
+                # has). A catch-up part sent before then found no part set
+                # and was dropped, and its mark would keep it from being
+                # sent again: the peer would sit in Commit for good.
+                self._sent_parts = {k for k in self._sent_parts if k[1] != -1}
             self.height = msg.height
             self.round = msg.round
             self.step = msg.step

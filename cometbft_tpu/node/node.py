@@ -942,6 +942,10 @@ class Node:
             ("roots_reused", "Validator-set hashes that found their set's root computed."),
             ("commit_records_built", "Commits whose signature records were built, once a commit."),
             ("commit_encodes_reused", "Commit encodes that found the commit's bytes kept."),
+            ("sig_records_walked", "Commit signature records decoded by the canonical walk."),
+            ("sig_records_generic", "Commit signature records in another form, decoded field by field."),
+            ("validator_records_walked", "Validator records decoded by the canonical walk."),
+            ("validator_records_generic", "Validator records in another form or key type, decoded field by field."),
         ):
             reg.gauge_func("wire", key, text,
                            counter("cometbft_tpu.types.validator_set",

@@ -358,11 +358,67 @@ def commit_sig_records(sigs) -> list:
     return out
 
 
+def canonical_commit_sig(record: bytes) -> CommitSig:
+    """A CommitSig record read in one walk, taken only in the form
+    commit_sig_records writes: the flag (1 to 127), an address of 20 bytes
+    where there is one, the time always there (seconds, then nanos, each
+    where not 0), the signature where there is one; every varint minimal.
+    Raises ValueError (IndexError where the record ends early) on any other
+    form, which CommitSig.decode then reads."""
+    varint = wire.canonical_varint
+    flag = record[1]
+    if record[0] != 0x08 or not 0 < flag < 0x80:
+        raise ValueError("not a canonical flag")
+    address, p = b"", 2
+    if record[2] == 0x12:
+        if record[3] != 20:
+            raise ValueError("not a canonical address")
+        address, p = record[4:24], 24
+    tlen = record[p + 1]
+    if record[p] != 0x1A or tlen >= 0x80:
+        raise ValueError("not a canonical time")
+    p += 2
+    t_end = p + tlen
+    seconds = nanos = 0
+    if p < t_end and record[p] == 0x08:
+        seconds, p = varint(record, p + 1)
+    if p < t_end and record[p] == 0x10:
+        nanos, p = varint(record, p + 1)
+    if p != t_end:
+        raise ValueError("not a canonical time")
+    signature = b""
+    if p < len(record):
+        if record[p] != 0x22:
+            raise ValueError("not a canonical signature")
+        n, p = varint(record, p + 1)
+        signature, p = record[p : p + n], p + n
+        if p != len(record):
+            raise ValueError("not a canonical record")
+    return CommitSig(flag, address, Time(seconds, nanos), signature)
+
+
+def commit_sigs_from_records(records) -> list:
+    """The CommitSigs of a commit's field-4 records: each record in
+    commit_sig_records' form read by canonical_commit_sig, any other by
+    CommitSig.decode, which gives the same objects for the same bytes."""
+    sigs, generic = wire.decode_records(records, canonical_commit_sig, CommitSig.decode)
+    with _wire_lock:
+        _wire_counts["sig_records_walked"] += len(sigs) - generic
+        _wire_counts["sig_records_generic"] += generic
+    return sigs
+
+
 # How often a commit's records were built (commit_sig_records, once a
 # commit) and how often Commit.encode found the commit's bytes kept,
 # always counted: a catch-up node encodes each commit three times (its
-# block's part set, the store's last commit and seen commit).
-_wire_counts = {"commit_records_built": 0, "commit_encodes_reused": 0}
+# block's part set, the store's last commit and seen commit). And how many
+# signature records Commit.decode read in one walk (`walked`) and how many
+# were in another form and went to CommitSig.decode (`generic`): what this
+# program encodes always walks.
+_wire_counts = {
+    "commit_records_built": 0, "commit_encodes_reused": 0,
+    "sig_records_walked": 0, "sig_records_generic": 0,
+}
 _wire_lock = threading.Lock()
 
 
@@ -644,7 +700,7 @@ class Commit:
             height=wire.get_varint(f, 1),
             round=wire.get_varint(f, 2),
             block_id=BlockID.decode(wire.get_bytes(f, 3)),
-            signatures=[CommitSig.decode(b) for b in wire.get_repeated_bytes(f, 4)],
+            signatures=commit_sigs_from_records(wire.get_repeated_bytes(f, 4)),
             agg_signature=wire.get_bytes(f, 5),
             agg_bitmap=wire.get_bytes(f, 6),
         )

@@ -4,8 +4,13 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field as dfield
 
+from cometbft_tpu.crypto import ed25519
 from cometbft_tpu.crypto import encoding as key_encoding
 from cometbft_tpu.wire import proto as wire
+
+# Field 2 of a Validator record holding an Ed25519 key: PublicKey{ed25519 =
+# 1} of 32 bytes, 34 bytes in all.
+_ED25519_KEY_FIELD = b"\x12\x22\x0a\x20"
 
 
 def wire_head(address: bytes, pub_key, voting_power: int) -> bytes:
@@ -17,6 +22,27 @@ def wire_head(address: bytes, pub_key, voting_power: int) -> bytes:
         + wire.field_message(2, key_encoding.pub_key_to_proto(pub_key), emit_empty=True)
         + wire.field_varint(3, voting_power)
     )
+
+
+def canonical_validator(record: bytes) -> "Validator":
+    """A Validator record read in one walk, taken only in the form
+    Validator.encode writes with an Ed25519 key: a 20-byte address, the
+    key, the power and the priority each where not 0, every varint minimal.
+    Raises ValueError (IndexError where the record ends early) on any other
+    form or key type, which Validator.decode then reads."""
+    if record[0] != 0x0A or record[1] != 20 or record[22:26] != _ED25519_KEY_FIELD:
+        raise ValueError("not a canonical Ed25519 validator")
+    varint = wire.canonical_varint
+    end = len(record)
+    p = 58
+    power = priority = 0
+    if p < end and record[p] == 0x18:
+        power, p = varint(record, p + 1)
+    if p < end and record[p] == 0x20:
+        priority, p = varint(record, p + 1)
+    if p != end:
+        raise ValueError("not a canonical validator")
+    return Validator(record[2:22], ed25519.PubKey(record[26:58]), power, priority)
 
 
 @dataclass

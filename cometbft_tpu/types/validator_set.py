@@ -16,7 +16,7 @@ from typing import NamedTuple
 
 from cometbft_tpu.crypto import batch as crypto_batch
 from cometbft_tpu.crypto import merkle
-from cometbft_tpu.types.validator import Validator, wire_head
+from cometbft_tpu.types.validator import Validator, canonical_validator, wire_head
 
 INT64_MAX = 2**63 - 1
 INT64_MIN = -(2**63)
@@ -107,12 +107,20 @@ def columns_counters() -> dict:
 # How often a set's wire heads and Merkle root were built and how often a
 # copy found them in the holder it shares, always counted: on a chain whose
 # set does not change, `heads_built` stands still after the first height.
-_wire_counts = {"heads_built": 0, "heads_reused": 0, "roots_built": 0, "roots_reused": 0}
+# And how many validator records (the entries and the proposer)
+# ValidatorSet.decode read in one walk (`walked`) and how many were in
+# another form or held another key type and went to Validator.decode
+# (`generic`).
+_wire_counts = {
+    "heads_built": 0, "heads_reused": 0, "roots_built": 0, "roots_reused": 0,
+    "validator_records_walked": 0, "validator_records_generic": 0,
+}
 
 
 def wire_counters() -> dict:
     """The sets' counts above, and the commits' (types.block): records
-    built once a commit, and encodes that found the commit's bytes kept."""
+    built once a commit, encodes that found the commit's bytes kept, and
+    signature records decoded by the walk or the generic decoder."""
     from cometbft_tpu.types import block
 
     with _columns_lock:
@@ -127,6 +135,19 @@ def _count(key: str) -> None:
 
 
 _ENTRY = operator.attrgetter("address", "pub_key", "voting_power")
+
+
+def _decode_validators(records) -> list:
+    """Validator records as Validator.decode reads them: each in
+    Validator.encode's form with an Ed25519 key read by
+    canonical_validator, any other by Validator.decode."""
+    from cometbft_tpu.wire import proto as wire
+
+    vals, generic = wire.decode_records(records, canonical_validator, Validator.decode)
+    with _columns_lock:
+        _wire_counts["validator_records_walked"] += len(vals) - generic
+        _wire_counts["validator_records_generic"] += generic
+    return vals
 
 
 class ValidatorSet:
@@ -506,8 +527,8 @@ class ValidatorSet:
 
         f = wire.decode_fields(data)
         vs = cls()
-        vs.validators = [Validator.decode(b) for b in wire.get_repeated_bytes(f, 1)]
+        vs.validators = _decode_validators(wire.get_repeated_bytes(f, 1))
         if 2 in f:
-            vs.proposer = Validator.decode(wire.get_bytes(f, 2))
+            (vs.proposer,) = _decode_validators([wire.get_bytes(f, 2)])
         vs._total_voting_power = 0
         return vs

@@ -81,6 +81,38 @@ def decode_varint_signed(buf: bytes, pos: int = 0) -> tuple[int, int]:
     return v, pos
 
 
+def canonical_varint(buf: bytes, pos: int) -> tuple[int, int]:
+    """An int64 field's value at buf[pos] read only in the form the encoders
+    above write it: minimal, at most ten bytes, and not 0 (a zero field is
+    omitted). Returns the value as get_varint reads it (ten bytes below
+    zero) and the position after it. Raises ValueError on any other form,
+    IndexError where buf ends first: a caller that walks canonical records
+    hands such a record to the generic decoder instead."""
+    b = buf[pos]
+    if b < 0x80:
+        if not b:
+            raise ValueError("zero field written")
+        return b, pos + 1
+    v = b & 0x7F
+    shift = 7
+    while True:
+        pos += 1
+        b = buf[pos]
+        v |= (b & 0x7F) << shift
+        if b < 0x80:
+            break
+        shift += 7
+        if shift > 63:
+            raise ValueError("varint too long")
+    if not b:
+        raise ValueError("non-minimal varint")
+    if v >> 64:
+        raise ValueError("varint overflows 64 bits")
+    if v >> 63:
+        v -= 1 << 64
+    return v, pos + 1
+
+
 def encode_zigzag(n: int) -> bytes:
     """proto `sint64`."""
     return encode_uvarint((n << 1) ^ (n >> 63))
@@ -154,30 +186,45 @@ def length_delimited(encoded: bytes) -> bytes:
 def decode_fields(buf: bytes) -> dict[int, list]:
     fields: dict[int, list] = {}
     pos = 0
-    while pos < len(buf):
-        key, pos = decode_uvarint(buf, pos)
+    n = len(buf)
+    while pos < n:
+        # A key or a length below 0x80 is one byte, read in place: a
+        # commit's or a validator set's thousands of records are all so.
+        key = buf[pos]
+        if key < 0x80:
+            pos += 1
+        else:
+            key, pos = decode_uvarint(buf, pos)
         fnum, wt = key >> 3, key & 7
-        if wt == WT_VARINT:
-            val, pos = decode_uvarint(buf, pos)
-        elif wt == WT_FIXED64:
-            if pos + 8 > len(buf):
-                raise ValueError("truncated fixed64")
-            val = buf[pos : pos + 8]
-            pos += 8
-        elif wt == WT_LEN:
-            ln, pos = decode_uvarint(buf, pos)
-            if pos + ln > len(buf):
+        if wt == WT_LEN:
+            if pos < n and buf[pos] < 0x80:
+                ln = buf[pos]
+                pos += 1
+            else:
+                ln, pos = decode_uvarint(buf, pos)
+            if pos + ln > n:
                 raise ValueError("truncated length-delimited field")
             val = buf[pos : pos + ln]
             pos += ln
+        elif wt == WT_VARINT:
+            val, pos = decode_uvarint(buf, pos)
+        elif wt == WT_FIXED64:
+            if pos + 8 > n:
+                raise ValueError("truncated fixed64")
+            val = buf[pos : pos + 8]
+            pos += 8
         elif wt == WT_FIXED32:
-            if pos + 4 > len(buf):
+            if pos + 4 > n:
                 raise ValueError("truncated fixed32")
             val = buf[pos : pos + 4]
             pos += 4
         else:
             raise ValueError(f"unsupported wire type {wt}")
-        fields.setdefault(fnum, []).append(val)
+        vals = fields.get(fnum)
+        if vals is None:
+            fields[fnum] = [val]
+        else:
+            vals.append(val)
     return fields
 
 
@@ -250,6 +297,26 @@ def get_repeated_bytes(fields: dict, num: int) -> list[bytes]:
     if any(not isinstance(v, bytes) for v in vals):
         raise ValueError(f"field {num}: expected length-delimited, got varint")
     return list(vals)
+
+
+def decode_records(records, walk, decode) -> tuple[list, int]:
+    """Each record as `walk` reads it, where it is in the one form `walk`
+    takes (it raises ValueError, or IndexError, on any other), else as the
+    generic `decode` reads it. `walk` returns only what `decode` returns for
+    the same bytes, so the result is `decode`'s, exceptions included.
+    Returns the objects, in order, and how many went to `decode`."""
+    out = []
+    add = out.append
+    generic = 0
+    for r in records:
+        try:
+            add(walk(r))
+            continue
+        except (IndexError, ValueError):
+            pass
+        add(decode(r))
+        generic += 1
+    return out, generic
 
 
 def get_repeated_uvarint(fields: dict, num: int) -> list[int]:

@@ -13,6 +13,7 @@ from cometbft_tpu.abci.example.kvstore import KVStoreApplication
 from cometbft_tpu.config import test_config as make_test_config
 from cometbft_tpu.consensus import messages as cmsg
 from cometbft_tpu.consensus.cstypes import (
+    STEP_COMMIT,
     STEP_NEW_HEIGHT,
     STEP_PREVOTE,
     STEP_PREVOTE_WAIT,
@@ -256,6 +257,43 @@ def test_peer_behind_in_height_gets_parts_and_seen_commit(net):
         v for v in peer.votes() if v.height == 1 and v.type == PRECOMMIT_TYPE
     ]
     assert len(commit_votes) == 4
+
+
+def test_peer_entering_commit_gets_catchup_parts_again(net):
+    """A catch-up part that reaches a peer before it has set up a part set
+    for the height is dropped there. When the peer then enters Commit at
+    the same height and round, its parts are sent once more; a repeated
+    announcement of the same step sends nothing new."""
+    cs, reactor, pvs, state, executor = net
+    block = executor.create_proposal_block(
+        1, state, Commit(height=0, round=0, block_id=BlockID(), signatures=[]),
+        pvs[0].address(),
+    )
+    parts = block.make_part_set()
+    block_id = BlockID(block.hash(), parts.header())
+    vs = VoteSet(CHAIN_ID, 1, 0, PRECOMMIT_TYPE, state.validators)
+    for pv in pvs:
+        assert vs.add_vote(_signed_vote(state, pv, PRECOMMIT_TYPE, 1, 0, block_id))
+    cs.block_store.save_block(block, parts, vs.make_commit())
+    rs = cs.rs
+    rs.height = 2
+    rs.votes = HeightVoteSet(CHAIN_ID, 2, state.validators)
+
+    peer = FakePeer()
+    ps = PeerState(peer)
+    ps.apply_new_round_step(cmsg.NewRoundStepMessage(height=1, round=0, step=STEP_PROPOSE))
+    _gossip(reactor, ps)
+    assert len(peer.msgs(cmsg.BlockPartMessage)) == parts.total
+
+    def parts_after(step):
+        peer.sent.clear()
+        ps.apply_new_round_step(cmsg.NewRoundStepMessage(height=1, round=0, step=step))
+        _gossip(reactor, ps)
+        return {p.part.index for p in peer.msgs(cmsg.BlockPartMessage)}
+
+    assert parts_after(STEP_PREVOTE) == set()
+    assert parts_after(STEP_COMMIT) == set(range(parts.total))
+    assert parts_after(STEP_COMMIT) == set()
 
 
 # -- mark/unmark symmetry under backpressure -------------------------------

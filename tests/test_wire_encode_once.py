@@ -406,6 +406,8 @@ def test_the_counters_count_one_build_and_the_reuses_of_a_commit():
     assert set(after) == {
         "heads_built", "heads_reused", "roots_built", "roots_reused",
         "commit_records_built", "commit_encodes_reused",
+        "sig_records_walked", "sig_records_generic",
+        "validator_records_walked", "validator_records_generic",
     }
 
 
