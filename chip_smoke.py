@@ -18,8 +18,8 @@ the chip at any time:
   device   CMTPU_BACKEND=tpu, bare — nothing to fall back on
   sidecar  `python -m cometbft_tpu.sidecar` with its default device choice;
            the parent is the client (CMTPU_BACKEND=grpc)
-  node     CMTPU_BACKEND=auto: the full chain (scheduler -> engine ->
-           supervisor -> hybrid), then `cmd devnet --backend auto`
+  node     CMTPU_BACKEND=auto: the full chain (engine -> supervisor ->
+           hybrid), then `cmd devnet --backend auto`
 
 Any failing phase fails the run: no result line is printed and the exit
 code is non-zero. A child that was to use the chip and finds another

@@ -972,7 +972,7 @@ def batch_verify_signatures(pubs, msgs, sigs) -> tuple[bool, list]:
 # ---------------------------------------------------------------------------
 # Verification backends: the same VerifyBackend shape the ed25519 chain
 # speaks ((pubs, msgs, sigs) byte columns -> (ok, bitmap)), so the generic
-# CoalescingScheduler / ResilientBackend / ChaosBackend stack applies
+# VerificationEngine / ResilientBackend / ChaosBackend stack applies
 # unchanged. The bn254 chain is its OWN instance — the ed25519 singleton
 # cannot verify bn254 triples — with the same env knobs.
 
@@ -1074,7 +1074,7 @@ _backend_lock = None
 
 def get_bn254_backend():
     """Process singleton mirroring sidecar.backend.get_backend(): under
-    CMTPU_BACKEND=auto the supervised chain behind the coalescer; any other
+    CMTPU_BACKEND=auto the supervised chain behind the engine; any other
     choice serves the bare host multi-pairing engine (always CPU-capable,
     fails loudly — never a silent downgrade to per-sig verification)."""
     global _backend, _backend_lock
@@ -1089,14 +1089,10 @@ def get_bn254_backend():
             return _backend
         choice = os.environ.get("CMTPU_BACKEND", "auto").strip() or "auto"
         if choice == "auto":
-            from cometbft_tpu.sidecar.scheduler import CoalescingScheduler
+            from cometbft_tpu.sidecar.engine import VerificationEngine
             from cometbft_tpu.sidecar.supervisor import ResilientBackend
 
-            chain = ResilientBackend(build_bn254_chain())
-            if os.environ.get("CMTPU_COALESCE", "1") != "0":
-                _backend = CoalescingScheduler(chain)
-            else:
-                _backend = chain
+            _backend = VerificationEngine(ResilientBackend(build_bn254_chain()))
         else:
             _backend = Bn254HostBackend()
     return _backend

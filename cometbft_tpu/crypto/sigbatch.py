@@ -4,37 +4,36 @@
 at a time, but under gossip many admissions run concurrently — one per peer
 connection, across every in-process node in devnet. This module gives those
 scalar callers consensus-class admission into the continuous-batching
-verification engine (round 14, `sidecar/engine.py`): each caller submits
-its pending triples tagged CLASS_CONSENSUS and the engine merges everything
-queued — across vote sets, peers AND the other traffic classes — into the
-next device dispatch, draining votes ahead of bulk work under a deadline
-bound. Cache semantics are unchanged: pending triples are filtered against
-the verified-triple cache here and only VALID dispatched triples populate
-it afterward.
+verification engine (`sidecar/engine.py`): each caller submits its pending
+triples tagged CLASS_CONSENSUS and the engine merges everything queued —
+across vote sets, peers AND the other traffic classes — into the next
+device dispatch, draining votes ahead of bulk work under a deadline bound.
+Cache semantics: pending triples are filtered against the verified-triple
+cache here and only VALID dispatched triples populate it afterward.
 
-When no engine is active (`CMTPU_COALESCE=0`, or a bare backend installed
-by tests) the round-12 private window dispatcher runs instead:
-callers block on a shared window (`CMTPU_VOTE_BATCH_WINDOW_MS`, default
-2 ms from the first waiter) and a dispatcher merges everything queued into
-ONE `ed25519.BatchVerifier` call.
+The engine is the process backend's own (`engine_of(get_backend())`, what
+`CMTPU_BACKEND=auto` builds). A backend with no engine in front (an
+explicit `cpu`/`tpu`/`hybrid`/`grpc` choice, or one a test installs) gets
+one of this batcher's own over it, built on first use with the batcher's
+window (`CMTPU_VOTE_BATCH_WINDOW_MS`, default 2 ms from the first waiter)
+as its hold and `max_sigs` as its cap.
 
-Failure containment is identical on both paths: a bad signature is just a
-False lane (never poisons the window), and any dispatch-level error —
-including a result not arriving within the deadline-derived timeout —
-degrades each request independently to the scalar `verify_signature` path.
-Window 0 (the env off switch) keeps the inline scalar behavior exactly.
+A bad signature is just a False lane (never poisons the dispatch), and any
+dispatch-level error — including a result not arriving within the
+deadline-derived timeout — degrades that request alone to the scalar
+`verify_signature` path. Window 0 (the env off switch) keeps the inline
+scalar behavior exactly.
 """
 
 from __future__ import annotations
 
 import os
 import threading
-import time
 
 _DEFAULT_WINDOW_MS = 2.0
-# A caller never waits forever on the dispatcher: consensus liveness
-# outranks batching, so a wedged dispatch degrades to scalar verification.
-# Used verbatim only when no supervisor deadline is configured — see
+# A caller never waits forever on the engine: consensus liveness outranks
+# batching, so a wedged dispatch degrades to scalar verification. Used
+# verbatim only when no supervisor deadline is configured — see
 # _result_timeout_s().
 _RESULT_TIMEOUT_S = 30.0
 
@@ -60,40 +59,21 @@ def _result_timeout_s() -> float:
     return max(1.0, deadline_ms / 1000.0 * (retries + 1) * 3)
 
 
-class _Req:
-    __slots__ = ("pubs", "msgs", "sigs", "event", "bits")
-
-    def __init__(self, pubs, msgs, sigs):
-        self.pubs = pubs
-        self.msgs = msgs
-        self.sigs = sigs
-        self.event = threading.Event()
-        self.bits: list[bool] | None = None
-
-
 class SigBatcher:
-    """Window-from-first-waiter batcher over `ed25519.BatchVerifier`.
+    """Consensus-class admission of scalar checks into the verification
+    engine (module docstring)."""
 
-    `inline` (test hook) dispatches each request through the batch
-    verifier immediately with no window and no dispatcher thread — the
-    "one device dispatch per vote" arm of an A/B comparison.
-    """
-
-    def __init__(self, window_ms: float | None = None, max_sigs: int = 4096,
-                 inline: bool = False):
+    def __init__(self, window_ms: float | None = None, max_sigs: int = 4096):
         if window_ms is None:
             window_ms = float(
                 os.environ.get("CMTPU_VOTE_BATCH_WINDOW_MS", "") or _DEFAULT_WINDOW_MS
             )
         self.window_ms = window_ms
         self.max_sigs = max_sigs
-        self.inline = inline
         self.result_timeout_s = _result_timeout_s()
-        self._cond = threading.Condition()
-        self._queue: list[_Req] = []
-        self._thread: threading.Thread | None = None
-        self._closed = False
-        # Counters (read by the lazy node gauges; mutate under _cond).
+        self._lock = threading.Lock()
+        self._own = None  # the engine over a bare process backend, once needed
+        # Counters (read by the lazy node gauges; mutate under _lock).
         self.requests = 0
         self.batched = 0  # requests that rode a shared dispatch
         self.dispatches = 0
@@ -127,7 +107,7 @@ class SigBatcher:
                 or len(pk.bytes()) != _ed.PUB_KEY_SIZE
             ):
                 # Structurally impossible — reject without letting it poison
-                # a batch (BatchVerifier.add raises on bad sizes).
+                # a batch.
                 bits[i] = False
             elif (pk.bytes(), bytes(sigs[i]), bytes(msgs[i])) in _ed._verified:
                 # Gossip re-delivery and own-vote echo land here: free.
@@ -135,76 +115,49 @@ class SigBatcher:
                 cache_hits += 1
             else:
                 pend.append(i)
-        with self._cond:
+        with self._lock:
             self.requests += 1
             self.cache_hits += cache_hits
             self.scalar_direct += scalar
         if not pend:
             return bits  # type: ignore[return-value]
-        if self.window_ms <= 0 and not self.inline:
+        if self.window_ms <= 0:
             # Off switch: today's inline scalar path, verbatim.
             for i in pend:
                 bits[i] = bool(pub_keys[i].verify_signature(msgs[i], sigs[i]))
-            with self._cond:
+            with self._lock:
                 self.scalar_direct += len(pend)
             return bits  # type: ignore[return-value]
-        if not self.inline:
-            eng = self._engine()
-            if eng is not None:
-                # Continuous-batching path: no private window thread — the
-                # engine merges concurrent admissions (and the other
-                # traffic classes) itself, votes first.
-                pbits = self._engine_dispatch(eng, pub_keys, msgs, sigs, pend)
-                for j, i in enumerate(pend):
-                    bits[i] = pbits[j]
-                return bits  # type: ignore[return-value]
-        req = _Req(
-            [pub_keys[i] for i in pend],
-            [msgs[i] for i in pend],
-            [sigs[i] for i in pend],
-        )
-        if self.inline:
-            self._dispatch([req])
-        else:
-            with self._cond:
-                self._queue.append(req)
-                if self._thread is None or not self._thread.is_alive():
-                    self._thread = threading.Thread(
-                        target=self._run, name="sigbatch", daemon=True
-                    )
-                    self._thread.start()
-                self._cond.notify_all()
-            if not req.event.wait(self.result_timeout_s):
-                req.bits = [
-                    bool(pk.verify_signature(m, s))
-                    for pk, m, s in zip(req.pubs, req.msgs, req.sigs)
-                ]
+        pbits = self._engine_dispatch(pub_keys, msgs, sigs, pend)
         for j, i in enumerate(pend):
-            bits[i] = bool(req.bits[j])
+            bits[i] = pbits[j]
         return bits  # type: ignore[return-value]
 
     # -- engine path ----------------------------------------------------------
 
-    @staticmethod
-    def _engine():
-        """The active continuous-batching engine, or None when the backend
-        chain runs bare (`CMTPU_COALESCE=0`, or a test-installed backend) —
-        the legacy private-window dispatcher serves those."""
+    def _engine(self):
+        """The process backend's engine, else this batcher's own over the
+        backend (built again if the process backend was replaced)."""
         from cometbft_tpu.sidecar import backend as _be
-        from cometbft_tpu.sidecar import engine as _engine
+        from cometbft_tpu.sidecar.engine import VerificationEngine, engine_of
 
-        try:
-            return _engine.engine_of(_be.get_backend())
-        except Exception:
-            return None
+        backend = _be.get_backend()
+        eng = engine_of(backend)
+        if eng is not None:
+            return eng
+        with self._lock:
+            if self._own is None or self._own.inner is not backend:
+                self._own = VerificationEngine(
+                    backend, hold_ms=self.window_ms, max_sigs=self.max_sigs
+                )
+            return self._own
 
-    def _engine_dispatch(self, eng, pub_keys, msgs, sigs, pend) -> list[bool]:
-        """Submit the pending triples consensus-class and wait. Decision
-        path matches the legacy dispatcher bit for bit: only VALID
+    def _engine_dispatch(self, pub_keys, msgs, sigs, pend) -> list[bool]:
+        """Submit the pending triples consensus-class and wait. Only VALID
         dispatched triples populate the verified cache, and any failure —
-        engine error, chain exhaustion surfacing as an exception, or the
-        deadline-derived timeout — degrades THIS request alone to the
-        scalar anchor."""
+        no backend, engine error, chain exhaustion surfacing as an
+        exception, or the deadline-derived timeout — degrades THIS request
+        alone to the scalar anchor."""
         from cometbft_tpu.crypto import ed25519 as _ed
         from cometbft_tpu.sidecar.engine import CLASS_CONSENSUS
 
@@ -212,11 +165,11 @@ class SigBatcher:
         ms = [bytes(msgs[i]) for i in pend]
         ss = [bytes(sigs[i]) for i in pend]
         try:
-            fut = eng.submit(pubs, ms, ss, klass=CLASS_CONSENSUS)
+            fut = self._engine().submit(pubs, ms, ss, klass=CLASS_CONSENSUS)
             _, rbits = fut.result(self.result_timeout_s)
             rbits = [bool(b) for b in rbits]
         except Exception:
-            with self._cond:
+            with self._lock:
                 self.fallbacks += 1
             return [
                 bool(pub_keys[i].verify_signature(msgs[i], sigs[i]))
@@ -225,7 +178,7 @@ class SigBatcher:
         _ed._verified_put_many(
             [(p, s, m) for p, m, s, b in zip(pubs, ms, ss, rbits) if b]
         )
-        with self._cond:
+        with self._lock:
             self.dispatches += 1
             self.dispatched_sigs += len(pend)
             if fut.shared:
@@ -233,13 +186,8 @@ class SigBatcher:
             self.max_batch = max(self.max_batch, len(pend))
         return rbits
 
-    def close(self) -> None:
-        with self._cond:
-            self._closed = True
-            self._cond.notify_all()
-
     def counters(self) -> dict:
-        with self._cond:
+        with self._lock:
             return {
                 "requests": self.requests,
                 "batched": self.batched,
@@ -250,73 +198,6 @@ class SigBatcher:
                 "fallbacks": self.fallbacks,
                 "max_batch": self.max_batch,
             }
-
-    # -- dispatcher -----------------------------------------------------------
-
-    def _run(self) -> None:
-        while True:
-            with self._cond:
-                while not self._queue and not self._closed:
-                    self._cond.wait()
-                if self._closed and not self._queue:
-                    return
-            # Window from the FIRST waiter (scheduler/ingress idiom): the
-            # request that opened the window pays it once; everything that
-            # arrives inside rides free.
-            if self.window_ms > 0:
-                time.sleep(self.window_ms / 1000.0)
-            with self._cond:
-                batch: list[_Req] = []
-                total = 0
-                while self._queue:
-                    nxt = len(self._queue[0].pubs)
-                    if batch and total + nxt > self.max_sigs:
-                        break  # whole requests only; rest opens a new window
-                    total += nxt
-                    batch.append(self._queue.pop(0))
-            if batch:
-                self._dispatch(batch)
-
-    def _dispatch(self, reqs: list[_Req]) -> None:
-        from cometbft_tpu.crypto import ed25519 as _ed
-
-        total = sum(len(r.pubs) for r in reqs)
-        try:
-            bv = _ed.BatchVerifier()
-            for r in reqs:
-                for pk, m, s in zip(r.pubs, r.msgs, r.sigs):
-                    bv.add(pk, m, s)
-            # BatchVerifier.verify(): cache filter + dedup, scheduler →
-            # supervised chain, ZIP-215 scalar fallback on ChainExhausted.
-            _, bits = bv.verify()
-        except Exception:
-            # Per-request isolation: degrade each request to the scalar
-            # anchor independently — one hostile entry or a backend crash
-            # must never reject a whole window of valid votes.
-            with self._cond:
-                self.fallbacks += len(reqs)
-            for r in reqs:
-                try:
-                    r.bits = [
-                        bool(pk.verify_signature(m, s))
-                        for pk, m, s in zip(r.pubs, r.msgs, r.sigs)
-                    ]
-                except Exception:
-                    r.bits = [False] * len(r.pubs)
-                r.event.set()
-            return
-        with self._cond:
-            self.dispatches += 1
-            self.dispatched_sigs += total
-            if len(reqs) > 1:
-                self.batched += len(reqs)
-            self.max_batch = max(self.max_batch, total)
-        i = 0
-        for r in reqs:
-            n = len(r.pubs)
-            r.bits = [bool(b) for b in bits[i : i + n]]
-            i += n
-            r.event.set()
 
 
 # -- module singleton ---------------------------------------------------------

@@ -821,16 +821,17 @@ class E2ERunner:
         return {"height": lb.height, "hash": lb.hash().hex().upper()}
 
     def _coalesce_counters(self) -> dict | None:
-        """Runner-process scheduler counter snapshot (integer counts only).
+        """Runner-process engine counter snapshot (integer counts only).
 
-        None when verification isn't routed through the coalescing
-        scheduler — backend not yet built, or CMTPU_COALESCE=0."""
+        None when verification isn't routed through an engine — backend
+        not yet built, or an explicit single tier."""
         from cometbft_tpu.sidecar import backend as backend_mod
+        from cometbft_tpu.sidecar.engine import engine_of
 
-        b = backend_mod._backend
-        if b is None or getattr(b, "name", "") != "coalesce":
+        eng = engine_of(backend_mod._backend)
+        if eng is None:
             return None
-        return {k: v for k, v in b.counters().items() if isinstance(v, int)}
+        return {k: v for k, v in eng.counters().items() if isinstance(v, int)}
 
     def _gateway_stats(self, url: str) -> dict | None:
         """The node's light_gateway_stats counters, or None when the
@@ -855,7 +856,7 @@ class E2ERunner:
         descent plan is fetched once and re-verified locally by everyone)
         and the node-side gateway counter deltas ride the report.  Every
         member must converge on the same hash; the returned dict carries
-        the swarm result plus the scheduler counter deltas attributable to
+        the swarm result plus the engine counter deltas attributable to
         the swarm."""
         from cometbft_tpu.libs.db import MemDB
         from cometbft_tpu.light.client import Client, TrustOptions

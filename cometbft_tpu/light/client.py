@@ -319,7 +319,7 @@ class Client:
         """Prewarm the verified-triple cache for the descent's optimistic
         hop chain: (current -> stack[-1]), (stack[-1] -> stack[-2]), ...,
         (stack[1] -> stack[0]).  One BatchVerifier call carries every hop's
-        union prefix — when the process backend is the coalescing scheduler
+        union prefix — when the process backend is the verification engine
         this also merges with other clients' concurrent descents.  An error
         fails no sync: speculation is an accelerator, never an arbiter (the
         sequential checks in _verify_skipping re-derive every verdict); it

@@ -13,8 +13,8 @@ sharing layers turn N identical bisections into ~1x the work:
   light/client.py's descent); every client's mandatory local re-verify of
   the same hops then hits `crypto/ed25519._verified` instead of the
   device.
-- **Coalescing scheduler underneath.** The gateway's own verification
-  dispatches go through the process backend — the CoalescingScheduler →
+- **Verification engine underneath.** The gateway's own verification
+  dispatches go through the process backend — the VerificationEngine →
   ResilientBackend chain under CMTPU_BACKEND=auto — so plan computations
   for *different* keys merge into columnar dispatches with everything
   else in flight.
@@ -295,7 +295,7 @@ class LightGateway:
         """Union-prefix prewarm of the descent's remaining hop commits in
         one BatchVerifier dispatch (identical to the client's
         _speculate_descent) — this is where concurrent sessions' work
-        merges in the coalescing scheduler."""
+        merges in the verification engine."""
         try:
             from cometbft_tpu.crypto import ed25519
             from cometbft_tpu.types import validation

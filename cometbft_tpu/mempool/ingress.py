@@ -16,7 +16,7 @@ Pipeline stages::
     dispatcher thread (micro-batch window)
       -> WFQ drain of lanes
       -> ed25519.BatchVerifier over envelope sigs — one dispatch through
-         the CoalescingScheduler -> ResilientBackend chain; the
+         the VerificationEngine -> ResilientBackend chain; the
          verified-triple LRU makes gossip re-admission free and the
          chain-exhausted fallback scalar-verifies, so a wedged device
          tier degrades admission but never drops valid txs
@@ -286,9 +286,9 @@ class IngressPipeline:
             if self._stop.is_set():
                 return
             self._wake.clear()
-            # Micro-batch window measured from the first waiter, mirroring
-            # the CoalescingScheduler: trade window_ms of latency for one
-            # fused preverify dispatch across concurrent senders.
+            # Micro-batch window measured from the first waiter, like the
+            # engine's hold that follows it: trade window_ms of latency for
+            # one fused preverify dispatch across concurrent senders.
             if self.window_ms > 0:
                 time.sleep(self.window_ms / 1000.0)
             while True:

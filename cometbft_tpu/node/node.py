@@ -412,12 +412,14 @@ class Node:
         CMTPU_BACKEND=auto with an accelerator visible that would import
         jax at node boot instead of first verification."""
         from cometbft_tpu.sidecar import backend as backend_mod
+        from cometbft_tpu.sidecar.engine import engine_of
 
         def sample(key):
             def fn():
                 b = backend_mod._backend  # no get_backend(): never constructs
-                if getattr(b, "name", "") == "coalesce":
-                    b = b.inner  # supervisor gauges read the wrapped chain
+                eng = engine_of(b)
+                if eng is not None:
+                    b = eng.inner  # supervisor gauges read the chain behind the engine
                 counters = getattr(b, "counters", None)
                 if counters is None:
                     return 0
@@ -480,8 +482,9 @@ class Node:
             # possibly chaos-wrapped). Never dials or constructs.
             def fn():
                 b = backend_mod._backend
-                if getattr(b, "name", "") == "coalesce":
-                    b = b.inner
+                eng = engine_of(b)
+                if eng is not None:
+                    b = eng.inner
                 g = None
                 if getattr(b, "name", "") == "grpc":
                     g = b
@@ -535,9 +538,9 @@ class Node:
         dispatched signatures, p95 admission wait, starvation promotions)
         plus its totals (requests, dispatches, requests that shared one,
         fallback splits, p95 queue wait). Lazy like the backend gauges — the
-        sampler peeks `backend_mod._backend` (never get_backend()) and
-        unwraps the CoalescingScheduler shim, so a scrape never constructs
-        the chain. Zeros under CMTPU_COALESCE=0."""
+        sampler peeks `backend_mod._backend` (never get_backend()), so a
+        scrape never constructs the chain. Zeros while the process backend
+        has no engine in front (not yet built, or an explicit single tier)."""
         from cometbft_tpu.sidecar import backend as backend_mod
 
         def _engine():

@@ -98,7 +98,7 @@ def mesh_width() -> int:
     `sig` mesh of ops/sharded). First call may initialize the JAX
     backend and raises whatever that raises: a device tier that cannot
     count its chips has not started. Callers that must never initialize
-    it (node metric scrapes, the coalescer's default cap) read
+    it (node metric scrapes, the engine's default cap) read
     known_mesh_width() instead."""
     global _probed_width
     n = max(1, jax.local_device_count())

@@ -224,7 +224,7 @@ class MultihostShardBackend:
     joins the collective step itself — every process runs
     `multihost_verify` on the same batch in the same order, which is what
     the collectives require. The lock serializes broadcasts so the frame
-    order IS the collective order even if the server coalescer ever grows
+    order IS the collective order even if the server's engine ever grows
     a second dispatcher. A dead follower surfaces as a socket error or a
     wedged collective; either way the fanout tier times the shard out and
     redistributes its slice — exactly the failure contract fanout shards

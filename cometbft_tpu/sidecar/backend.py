@@ -574,10 +574,10 @@ class HybridBackend(VerifyBackend):
 class LockedBackend(VerifyBackend):
     """Serializes every device call of a wrapped backend behind one lock.
 
-    The sidecar server wires this under its CoalescingScheduler: the
-    scheduler's single dispatcher merges requests from many CONNECTIONS
+    A sidecar server handed a bare backend wires this under its own engine:
+    the engine's single dispatcher merges requests from many CONNECTIONS
     into one columnar dispatch, and this wrapper keeps the one-chip /
-    one-XLA-stream discipline for the calls that bypass the scheduler —
+    one-XLA-stream discipline for the calls that bypass the engine —
     merkle roots, warmup — without the handler threads holding the lock
     across a whole verification."""
 
@@ -654,21 +654,15 @@ def _make_backend() -> VerifyBackend:
     if choice == "auto":
         # auto ships the supervised degradation chain (grpc|tpu -> hybrid
         # -> cpu with deadlines + circuit breakers, sidecar/supervisor.py):
-        # a wedged tier costs one CMTPU_DEADLINE_MS, never liveness.
+        # a wedged tier costs one CMTPU_DEADLINE_MS, never liveness. In
+        # front of it the engine merges concurrent callers' requests into
+        # single dispatches (sidecar/engine.py).
         # Explicit single-tier choices stay bare — forcing `tpu` or `grpc`
         # means "fail loudly", not "silently verify somewhere else".
+        from cometbft_tpu.sidecar.engine import VerificationEngine
         from cometbft_tpu.sidecar.supervisor import build_resilient
 
-        chain = build_resilient()
-        if os.environ.get("CMTPU_COALESCE", "1") != "0":
-            # Outermost tier: coalesce concurrent callers' requests into
-            # single dispatches (sidecar/scheduler.py). CMTPU_COALESCE=0
-            # strips the layer for A/B and for callers that need the bare
-            # supervised chain.
-            from cometbft_tpu.sidecar.scheduler import CoalescingScheduler
-
-            return CoalescingScheduler(chain)
-        return chain
+        return VerificationEngine(build_resilient())
     return device_backend(choice)
 
 

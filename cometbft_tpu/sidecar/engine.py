@@ -1,22 +1,24 @@
-"""Continuous-batching verification engine (round 14).
+"""Continuous-batching verification engine: the one admission queue.
 
-Until this round FOUR independent micro-batch windows fed the device tier —
-the coalescer (sidecar/scheduler.py), vote admission (crypto/sigbatch.py),
-ingress preverify (mempool/ingress.py) and the gateway prewarm
-(light/gateway.py) — each with its own window knob, queue, dispatcher
-thread and fallback path, each holding work the others could ride with.
-This module is the one engine they all feed, run the way inference servers
-run their device (vLLM/Orca continuous batching):
+Every verification the process sends to its device tier passes through one
+engine: vote admission (crypto/sigbatch.py), blocksync windows, ingress
+preverify (mempool/ingress.py), the light gateway's prewarm
+(light/gateway.py) and, in a sidecar, the connections of many node
+processes. `get_backend()` builds it over the supervised chain under
+`CMTPU_BACKEND=auto` (`VerificationEngine(build_resilient())`),
+`get_bn254_backend()` over the BLS chain, a `SidecarServer` over the device
+lock of a bare backend it is handed, and a `SigBatcher` over a bare process
+backend. The engine runs its device the way inference servers do
+(vLLM/Orca continuous batching):
 
-* There is no window-then-dispatch. The dispatcher sizes the next dispatch
-  from whatever is queued THE MOMENT the device frees up — a burst's first
-  request pays only the in-flight dispatch, never a fixed window. (A
-  compat hold, `hold_ms`, reproduces the old window-from-first-waiter
-  behavior for the CoalescingScheduler shim and its tests; the engine
-  default is 0.)
+* A dispatch is sized from whatever is queued when the device frees up,
+  after a hold (`hold_ms`, default `HOLD_MS`) measured from the first
+  waiter: the request that opens a burst pays the hold once and everything
+  that arrives inside it rides along. The hold ends early once the queue
+  fills the cap; 0 dispatches at once.
 * Requests carry a PRIORITY CLASS — consensus votes > blocksync > ingress
   preverify > light clients — drained strict-priority with a starvation
-  escape hatch: any request older than `CMTPU_ENGINE_STARVATION_MS` is
+  escape hatch: any request older than `STARVATION_MS` is
   promoted ahead of fresher higher-class work, so a consensus flood can
   delay a light client but never park it forever.
 * Dispatch sizing is DEADLINE-AWARE: a queued consensus request caps how
@@ -25,8 +27,8 @@ run their device (vLLM/Orca continuous batching):
   bulk work never drags a vote past its admission deadline.
 * Fallback/crosscheck/degradation remain ONE story: the engine dispatches
   through whatever chain it wraps (normally `build_resilient()`'s
-  supervisor), keeps the columnar pack + within-batch dedup + per-request
-  bitmap slicing of the round-6 coalescer verbatim, and splits a failed
+  supervisor), packs columns with within-batch dedup and per-request
+  bitmap slicing, and splits a failed
   merged dispatch into per-request retries so a poisoned request errors
   alone.
 * The merge cap is on the lanes a dispatch will RUN, not on the lanes its
@@ -72,14 +74,11 @@ backend chain without any API change (ingress preverify, gateway prewarm,
 blocksync windows). Untagged traffic is blocksync-class: the middle of the
 ladder, below votes, above opportunistic prewarm.
 
-Knobs: `CMTPU_ENGINE_HOLD_MS` (compat hold, default 0 = continuous),
-`CMTPU_ENGINE_MAX` (merge cap on a dispatch's distinct lanes, default
-16384 x mesh width, auto caps grow-only via refresh_cap),
-`CMTPU_ENGINE_STARVATION_MS` (promotion age, default 100),
-`CMTPU_ENGINE_DEADLINE_MS` (consensus admission deadline,
-default `CMTPU_DEADLINE_MS` else 50), `CMTPU_ENGINE_RATE` /
-`CMTPU_ENGINE_OVERHEAD_MS` (fallback dispatch-wall model when no hybrid
-tier is present to read rates from).
+The one knob is `CMTPU_COALESCE_MAX`: it pins the merge cap on a
+dispatch's distinct lanes, which is otherwise 16,384 x the chain's mesh
+width and grows (never shrinks) with it through `refresh_cap`. The
+consensus admission deadline is the supervisor's `CMTPU_DEADLINE_MS`, else
+50 ms.
 """
 
 from __future__ import annotations
@@ -103,6 +102,13 @@ CLASS_NAMES = ("consensus", "blocksync", "ingress", "light")
 _N_CLASSES = len(CLASS_NAMES)
 
 _WAIT_SAMPLES = 512  # admission-wait ring buffer (p50/p95 source)
+
+HOLD_MS = 2.0  # how long a queue stays open from its first waiter
+STARVATION_MS = 100.0  # age at which queued work jumps the class ladder
+# The dispatch-wall model where no hybrid tier in the chain has rates to read:
+# sigs/ms and fixed ms a dispatch.
+FALLBACK_RATE = 100.0
+FALLBACK_OVERHEAD_MS = 8.0
 
 
 def _env_float(name: str, default: float) -> float:
@@ -212,13 +218,13 @@ class _InFlight:
 
 
 def engine_of(backend) -> "VerificationEngine | None":
-    """The engine behind a backend, if one is active: the backend itself,
-    or the one the CoalescingScheduler shim embeds. None for a bare chain
-    (`CMTPU_COALESCE=0`) or a test-installed backend — callers keep their
-    legacy private-dispatcher paths in that case."""
-    if isinstance(backend, VerificationEngine):
-        return backend
-    eng = getattr(backend, "engine", None)
+    """The engine that takes `backend`'s submissions: the backend itself, or
+    the engine behind a pass-through wrapper that forwards attribute reads
+    (the benchmark's traced runs hand the sidecar such a wrapper over the
+    process engine, and the server must submit to that engine, not build a
+    second one). A forwarded `submit` is the engine's bound method, and its
+    `__self__` is that engine. None for a backend with no engine in front."""
+    eng = getattr(getattr(backend, "submit", None), "__self__", None)
     return eng if isinstance(eng, VerificationEngine) else None
 
 
@@ -303,47 +309,27 @@ class VerificationEngine(VerifyBackend):
     def __init__(
         self,
         inner: VerifyBackend,
-        hold_ms: float | None = None,
+        hold_ms: float = HOLD_MS,
         max_sigs: int | None = None,
-        starvation_ms: float | None = None,
-        deadline_ms: float | None = None,
+        starvation_ms: float = STARVATION_MS,
     ):
         self.inner = inner
-        # Compat hold: the round-6 window-from-first-waiter, kept for the
-        # CoalescingScheduler shim. 0 = true continuous batching.
-        self.hold_ms = (
-            _env_float("CMTPU_ENGINE_HOLD_MS", 0.0)
-            if hold_ms is None
-            else hold_ms
-        )
-        self.starvation_ms = (
-            _env_float("CMTPU_ENGINE_STARVATION_MS", 100.0)
-            if starvation_ms is None
-            else starvation_ms
-        )
+        self.hold_ms = hold_ms
+        self.starvation_ms = starvation_ms
         # Consensus admission deadline: a queued vote must be RESOLVED
         # within this budget, so it caps merged-dispatch growth. Derived
         # from the supervisor's per-call deadline when one is configured.
-        if deadline_ms is None:
-            deadline_ms = _env_float(
-                "CMTPU_ENGINE_DEADLINE_MS",
-                _env_float("CMTPU_DEADLINE_MS", 0.0) or 50.0,
-            )
-        self.consensus_deadline_ms = deadline_ms
-        self._cap_auto = False
-        if max_sigs is not None:
-            self.max_sigs = max_sigs
-        elif os.environ.get("CMTPU_ENGINE_MAX", ""):
-            self.max_sigs = int(_env_float("CMTPU_ENGINE_MAX", 16384))
-        else:
-            # Pod-width default: one merged dispatch can fill every chip
-            # (16384 lanes each). An explicit env or arg always wins. The
-            # auto cap re-reads the chain's width periodically
-            # (refresh_cap) because the width a grpc tier serves is only
-            # learned from the sidecar's Ping capability reply AFTER the
-            # first connect.
-            self._cap_auto = True
-            self.max_sigs = 16384 * max(1, _mesh_width_for_cap())
+        self.consensus_deadline_ms = _env_float("CMTPU_DEADLINE_MS", 0.0) or 50.0
+        if max_sigs is None and os.environ.get("CMTPU_COALESCE_MAX", ""):
+            max_sigs = int(_env_float("CMTPU_COALESCE_MAX", 16384))
+        # Unpinned, the cap is pod-wide (one merged dispatch can fill every
+        # chip, 16384 lanes each) and re-read from the chain's width now and
+        # then (refresh_cap): the width a grpc tier serves is only learned
+        # from the sidecar's Ping capability reply AFTER the first connect.
+        self._cap_auto = max_sigs is None
+        self.max_sigs = (
+            16384 * max(1, _mesh_width_for_cap()) if max_sigs is None else max_sigs
+        )
         self._queues: list[list[_Request]] = [[] for _ in range(_N_CLASSES)]
         self._cond = threading.Condition()
         self._closed = False
@@ -468,12 +454,11 @@ class VerificationEngine(VerifyBackend):
         """(sigs/ms, fixed overhead ms) for one dispatch through the chain,
         read from the hybrid planner's EMA-calibrated rates when a hybrid
         tier is present (duck-typed walk — chain shapes vary by backend
-        knob), else the env/default model."""
+        knob), else the fallback model."""
         cached = self._rate_cache
         if cached is not None:
             return cached
-        rate = _env_float("CMTPU_ENGINE_RATE", 100.0)
-        overhead = _env_float("CMTPU_ENGINE_OVERHEAD_MS", 8.0)
+        rate, overhead = FALLBACK_RATE, FALLBACK_OVERHEAD_MS
         stack = [self.inner]
         seen: set[int] = set()
         while stack:
@@ -527,8 +512,7 @@ class VerificationEngine(VerifyBackend):
         return any(self._queues)
 
     def _collect(self) -> list[_Request]:
-        """Block until work exists; in compat-hold mode keep the window
-        open for batchmates; then assemble the next dispatch: starvation
+        """Block until work exists; keep the hold open for batchmates; then assemble the next dispatch: starvation
         promotions first (oldest first), then strict class priority, whole
         requests only while the lanes the dispatch will run fit the
         deadline-aware cap (first always taken). Requests that carry the

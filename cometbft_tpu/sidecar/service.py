@@ -71,8 +71,8 @@ supervised one every node runs in process (engine -> `ResilientBackend`
 anchor, and ONE engine, in which concurrent CONNECTIONS coalesce into
 single columnar dispatches with per-request bitmap slicing (the merge cap
 is on a dispatch's distinct lanes: `sidecar/engine.py`). A server
-handed a bare backend (a test's, a fanout shard worker's) puts its own
-CoalescingScheduler over the device lock in front of it, as before.
+handed a bare backend (a test's, a fanout shard worker's) puts an engine
+of its own over the device lock in front of it.
 
 Both ends trace themselves into `libs/trace.py`'s ring: the client a
 `grpc.call` a call (children `grpc.encode`, `grpc.wait`, `grpc.decode`),
@@ -106,8 +106,7 @@ from cometbft_tpu.sidecar.backend import (
     VerifyBackend,
     get_backend,
 )
-from cometbft_tpu.sidecar.engine import engine_of
-from cometbft_tpu.sidecar.scheduler import CoalescingScheduler, VerifyFuture
+from cometbft_tpu.sidecar.engine import VerificationEngine, engine_of
 from cometbft_tpu.wire import proto
 from cometbft_tpu.wire.columns import decode_columns, encode_columns
 
@@ -291,12 +290,11 @@ class SidecarServer:
     `submit`), so the four nodes cost the chip one run a height whenever
     they arrive. While the
     device tier packs a dispatch on the host, the other connections' threads
-    wait with their frames (`_after_host_pack`). A bare backend
-    handed in gets this server's own CoalescingScheduler over the device
-    lock in front of it (CMTPU_COALESCE=0 strips it). Socket handling is
-    one thread per connection, so hosts can pipeline requests like the
-    reference's socket ABCI client; MerkleRoot and Warmup are serialized
-    by the device lock either way."""
+    wait with their frames (`_after_host_pack`). A bare backend handed in
+    gets this server's own engine over the device lock in front of it
+    (`engine`). Socket handling is one thread per connection, so hosts can
+    pipeline requests like the reference's socket ABCI client; MerkleRoot
+    and Warmup are serialized by the device lock either way."""
 
     def __init__(self, addr: str = DEFAULT_ADDR, backend: VerifyBackend | None = None):
         self.addr = addr
@@ -307,14 +305,11 @@ class SidecarServer:
         self.backend = backend
         self._device_lock = threading.Lock()
         # Where verifications are submitted: the engine the backend already
-        # carries, never a second one in front of it; else this server's own
-        # scheduler (None: stripped, calls go inline under the device lock).
-        self._sched: CoalescingScheduler | None = None
-        self._front = engine_of(backend)
-        if self._front is None and os.environ.get("CMTPU_COALESCE", "1") != "0":
-            self._front = self._sched = CoalescingScheduler(
-                LockedBackend(self.backend, self._device_lock)
-            )
+        # carries, never a second one in front of it; else this server's own.
+        self.engine = engine_of(backend)
+        self._owns_engine = self.engine is None
+        if self._owns_engine:
+            self.engine = VerificationEngine(LockedBackend(backend, self._device_lock))
         self._count_lock = threading.Lock()  # the counters below
         self.counters_ = {
             "requests": 0,        # answered: a stream is one
@@ -417,20 +412,6 @@ class SidecarServer:
         self._count(requests=1, bytes_in=n_in, bytes_out=len(resp) + _LEN.size)
         return resp
 
-    def _submit(self, pubs, msgs, sigs) -> VerifyFuture:
-        """One request into the verification path: async through the engine
-        the backend carries or this server's own scheduler (cross-connection
-        coalescing either way), an immediately-resolved future otherwise."""
-        if self._front is not None:
-            return self._front.submit(pubs, msgs, sigs)
-        fut = VerifyFuture(len(pubs))
-        try:
-            with self._device_lock:
-                fut._set_result(self.backend.batch_verify(pubs, msgs, sigs))
-        except BaseException as e:
-            fut._set_error(e)
-        return fut
-
     def _preferred_chunk(self) -> int:
         """Streamed-chunk size advertised in the Ping reply: the kernel's
         bucket-aligned choice when the device tier is loaded (zero padding,
@@ -446,18 +427,12 @@ class SidecarServer:
                 pass
         return DEFAULT_STREAM_CHUNK
 
-    def scheduler_counters(self) -> dict:
-        """The counters of whatever coalesces this server's connections:
-        the backend's own engine, or the server-side scheduler (empty when
-        stripped)."""
-        return self._front.counters() if self._front is not None else {}
-
     def _dispatch(self, method: str, payload: bytes, conn: dict | None = None,
                   sp=trace._OFF) -> bytes:
         if method == "Ping":
             # Capability reply: PingResp { 1: "pong", 2: mesh_width,
             # 3: streaming, 4: chunk }. The width is the REMOTE pod's chip
-            # count, so client-side sizing (the coalescer's default merge
+            # count, so client-side sizing (the engine's default merge
             # cap, chain pricing) sees the serving mesh, not the local
             # host's; field 3 advertises the chunked-streaming method and
             # field 4 the server's preferred chunk size. A client still
@@ -482,12 +457,12 @@ class SidecarServer:
             sp.set(lanes=len(pubs))
             self._count(lanes_in=len(pubs))
             if not pubs:
-                # The scheduler short-circuits empty submissions with its
+                # The engine short-circuits empty submissions with its
                 # own sentinel; keep the backend's empty-batch answer.
                 with self._device_lock:
                     ok, bitmap = self.backend.batch_verify(pubs, msgs, sigs)
             else:
-                ok, bitmap = self._submit(pubs, msgs, sigs).result()
+                ok, bitmap = self.engine.submit(pubs, msgs, sigs).result()
             with trace.span("sidecar.encode"):
                 return _encode_bitmap(ok, bitmap)
         if method == "MerkleRoot":
@@ -550,7 +525,7 @@ class SidecarServer:
             out = b""
             if final:
                 n = len(st.pubs)
-                ok, bits = self._submit(st.pubs, st.msgs, st.sigs).result() if n else (True, [])
+                ok, bits = self.engine.submit(st.pubs, st.msgs, st.sigs).result() if n else (True, [])
                 if len(bits) != n:
                     raise ValueError(f"stream {sid}: answered {len(bits)} of {n} lanes")
                 with trace.span("sidecar.encode"):
@@ -612,7 +587,7 @@ class SidecarServer:
     def shutdown(self):
         """Stops listening and closes every open connection: a client then
         sees the server gone, not a listener that went quiet. The backend is
-        the caller's to close; the server's own scheduler goes with it."""
+        the caller's to close; the server's own engine goes with it."""
         if self._serving.is_set():
             self._server.shutdown()
         self._server.server_close()
@@ -623,8 +598,8 @@ class SidecarServer:
                 sock.shutdown(socket.SHUT_RDWR)
             except OSError:
                 pass
-        if self._sched is not None:
-            self._sched.close()
+        if self._owns_engine:
+            self.engine.close()
 
 
 # -- client -------------------------------------------------------------------

@@ -445,8 +445,8 @@ class ResilientBackend(VerifyBackend):
         local chips (hybrid/tpu tiers), a remote pod's (the grpc tier's
         Ping capability reply), or a whole fleet's (the fanout tier reports
         the SUM of its shards' widths, because shards verify concurrently
-        while chain tiers are alternatives). The coalescer and engine size
-        their merge caps from this.
+        while chain tiers are alternatives). The engine sizes its merge cap
+        from this.
 
         A tier whose breaker is open inside its cooldown is SKIPPED without
         touching its backend — a tripped grpc tier must not be dialed just

@@ -3,8 +3,9 @@ priority drain with a starvation escape hatch, deadline-aware dispatch
 sizing off the hybrid rate model, seeded mixed-load starvation-freedom
 (an ingress flood never delays a consensus triple past its deadline bound,
 a poisoned ingress request never fails a consensus caller), class tagging
-through the threadlocal, the SigBatcher engine path, the deadline-derived
-result timeout, and the CoalescingScheduler shim's grow-only refresh_cap.
+through the threadlocal, the SigBatcher engine path (the process engine,
+or its own over a bare backend), the deadline-derived result timeout, and
+the grow-only refresh_cap.
 Seeded/deterministic, CPU-only."""
 
 import random
@@ -26,7 +27,6 @@ from cometbft_tpu.sidecar.engine import (
     engine_of,
     submission_class,
 )
-from cometbft_tpu.sidecar.scheduler import CoalescingScheduler
 
 pytestmark = pytest.mark.engine
 
@@ -349,8 +349,8 @@ def test_sigbatch_rides_engine_consensus_class(monkeypatch):
     consensus-class straight to the engine (no private window thread),
     keeps bit-identical verdicts, populates the verified cache for valid
     triples only, and reports sharing through the engine future."""
-    sched = CoalescingScheduler(CpuBackend(), window_ms=2)
-    old_backend = backend_mod.set_backend(sched)
+    eng = VerificationEngine(CpuBackend(), hold_ms=2)
+    old_backend = backend_mod.set_backend(eng)
     old_batcher = sigbatch.set_batcher(None)
     try:
         b = sigbatch.SigBatcher(window_ms=2)
@@ -363,33 +363,61 @@ def test_sigbatch_rides_engine_consensus_class(monkeypatch):
         assert bits == [True, True, False, True]
         c = b.counters()
         assert c["dispatches"] == 1 and c["dispatched_sigs"] == 4
-        eng = engine_of(backend_mod._backend)
-        assert eng is not None
+        assert engine_of(backend_mod._backend) is eng
         assert eng.counters()["classes"]["consensus"]["admitted"] == 1
         # Valid triples (and only those) are now cache hits.
         assert (pubs[0].bytes(), sigs[0], msgs[0]) in ed25519._verified
         assert (pubs[2].bytes(), sigs[2], msgs[2]) not in ed25519._verified
-        # No private dispatcher thread was started on the engine path.
-        assert b._thread is None
+        # The process engine served it: the batcher built none of its own.
+        assert b._own is None
     finally:
         sigbatch.set_batcher(old_batcher)
         backend_mod.set_backend(old_backend)
-        sched.close()
+        eng.close()
 
 
 def test_sigbatch_legacy_path_serves_bare_backends():
-    """A bare (engine-less) backend keeps the round-12 private window
-    dispatcher: no engine to ride, same verdicts."""
-    old_backend = backend_mod.set_backend(CpuBackend())
+    """A bare (engine-less) backend is served through the batcher's own
+    engine over it: its window as the hold, its cap, the same verdicts, and
+    concurrent admissions merged into shared dispatches."""
+    cpu = CpuBackend()
+    calls = []
+    orig = cpu.batch_verify
+    cpu.batch_verify = lambda p, m, s: calls.append(len(p)) or orig(p, m, s)
+    old_backend = backend_mod.set_backend(cpu)
     old_batcher = sigbatch.set_batcher(None)
     try:
         assert engine_of(backend_mod._backend) is None
-        b = sigbatch.SigBatcher(window_ms=2)
-        pv = ed25519.gen_priv_key_from_secret(b"legacy-sb")
-        msg = b"legacy-vote"
-        assert b.verify_many([pv.pub_key()], [msg], [pv.sign(msg)]) == [True]
-        assert b.counters()["dispatches"] == 1
-        assert b._thread is not None, "legacy path must use its dispatcher"
+        b = sigbatch.SigBatcher(window_ms=200)
+        pvs = [ed25519.gen_priv_key_from_secret(b"legacy-sb-%d" % i) for i in range(4)]
+        msgs = [b"legacy-vote-%d" % i for i in range(4)]
+        sigs = [pv.sign(m) for pv, m in zip(pvs, msgs)]
+        sigs[3] = b"\x07" * 64  # bad signature: a False lane, not an error
+        start = threading.Barrier(4)
+        got = [None] * 4
+
+        def admit(i):
+            start.wait()
+            got[i] = b.verify_one(pvs[i].pub_key(), msgs[i], sigs[i])
+
+        threads = [threading.Thread(target=admit, args=(i,)) for i in range(4)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(10.0)
+        assert not any(t.is_alive() for t in threads)
+        assert got == [True, True, True, False]
+        own = b._own
+        assert own is not None and own.inner is cpu
+        assert (own.hold_ms, own.max_sigs) == (200, b.max_sigs)
+        assert calls == [4], "four admissions, one merged dispatch"
+        c = b.counters()
+        assert c["dispatches"] == 4 and c["batched"] == 4 and c["fallbacks"] == 0
+        assert own.counters()["coalesced_dispatches"] == 1
+        # A second admission reuses the batcher's engine.
+        msg = b"legacy-vote-late"
+        assert b.verify_one(pvs[0].pub_key(), msg, pvs[0].sign(msg)) is True
+        assert b._own is own
     finally:
         sigbatch.set_batcher(old_batcher)
         backend_mod.set_backend(old_backend)
@@ -414,7 +442,7 @@ def test_sigbatch_result_timeout_derived_from_deadline(monkeypatch):
     assert sigbatch.SigBatcher(window_ms=2).result_timeout_s == 1.0
 
 
-# -- satellite: shim refresh_cap compat ---------------------------------------
+# -- grow-only refresh_cap -----------------------------------------------------
 
 
 class _WidthStub(VerifyBackend):
@@ -434,39 +462,36 @@ class _WidthStub(VerifyBackend):
         return self.width
 
 
-def test_shim_refresh_cap_grows_never_shrinks(monkeypatch):
-    """The CoalescingScheduler shim must not hold a stale cap copy: a
-    Ping-advertised wider remote mesh grows the ENGINE cap and the shim
-    view follows; a narrower reading never shrinks it; pinned caps
-    (arg/env) never move."""
+def test_refresh_cap_grows_never_shrinks(monkeypatch):
+    """A Ping-advertised wider remote mesh grows the auto cap; a narrower
+    reading never shrinks it; caps pinned by argument or by
+    `CMTPU_COALESCE_MAX` never move."""
     monkeypatch.delenv("CMTPU_COALESCE_MAX", raising=False)
-    monkeypatch.delenv("CMTPU_ENGINE_MAX", raising=False)
     stub = _WidthStub(1)
-    sched = CoalescingScheduler(stub, window_ms=0)
+    eng = VerificationEngine(stub, hold_ms=0)
     try:
-        initial = sched.max_sigs
+        initial = eng.max_sigs
         assert initial % 16384 == 0
         stub.width = (initial // 16384) * 4  # the remote pod is wider
-        assert sched.refresh_cap() == 16384 * stub.width
-        assert sched.max_sigs == 16384 * stub.width, "stale shim cap"
-        assert sched.engine.max_sigs == sched.max_sigs
-        assert sched.counters()["max_sigs"] == sched.max_sigs
+        assert eng.refresh_cap() == 16384 * stub.width
+        assert eng.max_sigs == 16384 * stub.width
+        assert eng.counters()["max_sigs"] == eng.max_sigs
         stub.width = 1  # narrower later reading must not shrink
-        grown = sched.max_sigs
-        assert sched.refresh_cap() == grown and sched.max_sigs == grown
+        grown = eng.max_sigs
+        assert eng.refresh_cap() == grown and eng.max_sigs == grown
     finally:
-        sched.close()
+        eng.close()
 
-    pinned = CoalescingScheduler(_WidthStub(8), window_ms=0, max_sigs=99)
+    pinned = VerificationEngine(_WidthStub(8), hold_ms=0, max_sigs=99)
     try:
         assert pinned.refresh_cap() == 99 and pinned.max_sigs == 99
     finally:
         pinned.close()
 
     monkeypatch.setenv("CMTPU_COALESCE_MAX", "4096")
-    env_pinned = CoalescingScheduler(_WidthStub(8), window_ms=0)
+    env_pinned = VerificationEngine(_WidthStub(8), hold_ms=0)
     try:
-        assert env_pinned.refresh_cap() == 4096
+        assert env_pinned.refresh_cap() == 4096 and env_pinned.max_sigs == 4096
     finally:
         env_pinned.close()
 

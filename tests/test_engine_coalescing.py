@@ -1,4 +1,4 @@
-"""Coalescing verification scheduler (sidecar/scheduler.py): concurrent
+"""The verification engine's coalescing (sidecar/engine.py): concurrent
 requests merge into single dispatches with correct per-request bitmap
 slicing, a failed coalesced dispatch falls back to per-request retries
 (no cross-request poisoning), and chaos faults in one request's lane never
@@ -14,7 +14,7 @@ from cometbft_tpu.crypto import ed25519
 from cometbft_tpu.sidecar import backend as backend_mod
 from cometbft_tpu.sidecar.backend import CpuBackend, VerifyBackend
 from cometbft_tpu.sidecar.chaos import ChaosBackend
-from cometbft_tpu.sidecar.scheduler import CoalescingScheduler
+from cometbft_tpu.sidecar.engine import HOLD_MS, VerificationEngine
 from cometbft_tpu.sidecar.supervisor import ResilientBackend
 
 pytestmark = pytest.mark.chaos
@@ -59,40 +59,40 @@ class _GateBackend(VerifyBackend):
 
 
 def test_single_request_passes_through():
-    sched = CoalescingScheduler(CpuBackend(), window_ms=0)
+    eng = VerificationEngine(CpuBackend(), hold_ms=0)
     try:
         pubs, msgs, sigs = _signed(4)
-        ok, bits = sched.batch_verify(pubs, msgs, sigs)
+        ok, bits = eng.batch_verify(pubs, msgs, sigs)
         assert ok and bits == [True] * 4
-        c = sched.counters()
+        c = eng.counters()
         assert c["requests"] == 1 and c["dispatches"] == 1
         assert c["coalesced_dispatches"] == 0
     finally:
-        sched.close()
+        eng.close()
 
 
 def test_empty_request_resolves_immediately():
-    sched = CoalescingScheduler(CpuBackend(), window_ms=0)
+    eng = VerificationEngine(CpuBackend(), hold_ms=0)
     try:
-        assert sched.batch_verify([], [], []) == (False, [])
+        assert eng.batch_verify([], [], []) == (False, [])
     finally:
-        sched.close()
+        eng.close()
 
 
 def test_concurrent_requests_coalesce_with_correct_slicing():
     """Requests queued behind an in-flight dispatch merge into ONE backend
     call, and each caller gets exactly its own bitmap back."""
     gate = _GateBackend()
-    sched = CoalescingScheduler(gate, window_ms=0)
+    eng = VerificationEngine(gate, hold_ms=0)
     try:
         p0, m0, s0 = _signed(2, tag=b"first")
-        fut0 = sched.submit(p0, m0, s0)
+        fut0 = eng.submit(p0, m0, s0)
         while not gate.calls:  # dispatcher now wedged inside call #1
             time.sleep(0.001)
         batches = [_signed(3, tag=b"req-%d" % i) for i in range(3)]
         # poison one lane of request 1 only
         batches[1][2][1] = b"\x01" * 64
-        futs = [sched.submit(p, m, s) for p, m, s in batches]
+        futs = [eng.submit(p, m, s) for p, m, s in batches]
         gate.release.set()
         ok0, bits0 = fut0.result(10.0)
         assert ok0 and bits0 == [True, True]
@@ -101,34 +101,34 @@ def test_concurrent_requests_coalesce_with_correct_slicing():
         assert results[1] == (False, [True, False, True])
         assert results[2] == (True, [True, True, True])
         assert gate.calls == [2, 9], "queued requests must share one dispatch"
-        c = sched.counters()
+        c = eng.counters()
         assert c["coalesced_dispatches"] == 1
         assert c["batched_requests"] == 3
         assert c["fallback_splits"] == 0
         assert c["coalesce_ratio"] == 2.0
     finally:
         gate.release.set()
-        sched.close()
+        eng.close()
 
 
 def test_identical_triples_share_lanes():
     gate = _GateBackend()
-    sched = CoalescingScheduler(gate, window_ms=0)
+    eng = VerificationEngine(gate, hold_ms=0)
     try:
-        fut0 = sched.submit(*_signed(1, tag=b"warm"))
+        fut0 = eng.submit(*_signed(1, tag=b"warm"))
         while not gate.calls:
             time.sleep(0.001)
         shared = _signed(4, tag=b"dup")
-        futs = [sched.submit(*shared) for _ in range(3)]
+        futs = [eng.submit(*shared) for _ in range(3)]
         gate.release.set()
         assert fut0.result(10.0)[0]
         for f in futs:
             assert f.result(10.0) == (True, [True] * 4)
         assert gate.calls == [1, 4], "3x4 identical triples -> 4 lanes"
-        assert sched.counters()["dedup_sigs"] == 8
+        assert eng.counters()["dedup_sigs"] == 8
     finally:
         gate.release.set()
-        sched.close()
+        eng.close()
 
 
 def test_window_accumulates_burst_into_one_dispatch():
@@ -136,14 +136,14 @@ def test_window_accumulates_burst_into_one_dispatch():
     calls = []
     orig = cpu.batch_verify
     cpu.batch_verify = lambda p, m, s: calls.append(len(p)) or orig(p, m, s)
-    sched = CoalescingScheduler(cpu, window_ms=200)
+    eng = VerificationEngine(cpu, hold_ms=200)
     try:
         batches = [_signed(2, tag=b"burst-%d" % i) for i in range(4)]
         start = threading.Barrier(4)
 
         def go(b):
             start.wait()
-            return sched.batch_verify(*b)
+            return eng.batch_verify(*b)
 
         threads = []
         results = [None] * 4
@@ -158,17 +158,17 @@ def test_window_accumulates_burst_into_one_dispatch():
         assert all(r == (True, [True, True]) for r in results)
         assert len(calls) == 1 and calls[0] == 8
     finally:
-        sched.close()
+        eng.close()
 
 
 def test_max_sigs_caps_a_dispatch_without_splitting_requests():
     gate = _GateBackend()
-    sched = CoalescingScheduler(gate, window_ms=0, max_sigs=5)
+    eng = VerificationEngine(gate, hold_ms=0, max_sigs=5)
     try:
-        fut0 = sched.submit(*_signed(1, tag=b"head"))
+        fut0 = eng.submit(*_signed(1, tag=b"head"))
         while not gate.calls:
             time.sleep(0.001)
-        futs = [sched.submit(*_signed(3, tag=b"cap-%d" % i)) for i in range(3)]
+        futs = [eng.submit(*_signed(3, tag=b"cap-%d" % i)) for i in range(3)]
         gate.release.set()
         assert fut0.result(10.0)[0]
         for f in futs:
@@ -180,24 +180,24 @@ def test_max_sigs_caps_a_dispatch_without_splitting_requests():
         assert sum(gate.calls) == 10
     finally:
         gate.release.set()
-        sched.close()
+        eng.close()
 
 
 def test_oversized_single_request_is_not_split():
-    sched = CoalescingScheduler(CpuBackend(), window_ms=0, max_sigs=2)
+    eng = VerificationEngine(CpuBackend(), hold_ms=0, max_sigs=2)
     try:
         pubs, msgs, sigs = _signed(6, tag=b"big")
-        ok, bits = sched.batch_verify(pubs, msgs, sigs)
+        ok, bits = eng.batch_verify(pubs, msgs, sigs)
         assert ok and bits == [True] * 6
     finally:
-        sched.close()
+        eng.close()
 
 
 def test_submit_after_close_raises():
-    sched = CoalescingScheduler(CpuBackend(), window_ms=0)
-    sched.close()
+    eng = VerificationEngine(CpuBackend(), hold_ms=0)
+    eng.close()
     with pytest.raises(RuntimeError):
-        sched.submit(*_signed(1, tag=b"late"))
+        eng.submit(*_signed(1, tag=b"late"))
 
 
 # -- chaos: failed coalesced dispatches -----------------------------------
@@ -234,7 +234,7 @@ def test_failed_coalesced_dispatch_falls_back_per_request(wedge_ms):
     own correct bitmap via per-request retries; the caller with the bad
     signature is the only one who sees a False lane."""
     flaky = _FlakyBackend(limit=3, wedge_ms=wedge_ms)
-    sched = CoalescingScheduler(flaky, window_ms=200)
+    eng = VerificationEngine(flaky, hold_ms=200)
     try:
         batches = [_signed(3, tag=b"fb-%d" % i) for i in range(3)]
         batches[2][2][0] = b"\x02" * 64  # poison request 2, lane 0
@@ -243,7 +243,7 @@ def test_failed_coalesced_dispatch_falls_back_per_request(wedge_ms):
 
         def go(i):
             start.wait()
-            results[i] = sched.batch_verify(*batches[i])
+            results[i] = eng.batch_verify(*batches[i])
 
         threads = [
             threading.Thread(target=go, args=(i,)) for i in range(3)
@@ -255,13 +255,13 @@ def test_failed_coalesced_dispatch_falls_back_per_request(wedge_ms):
         assert results[0] == (True, [True] * 3)
         assert results[1] == (True, [True] * 3)
         assert results[2] == (False, [False, True, True])
-        c = sched.counters()
+        c = eng.counters()
         assert c["fallback_splits"] == 1
         assert c["coalesced_dispatches"] == 1
         # one failed merged call (9 lanes) + 3 per-request retries
         assert flaky.calls[0] == 9 and sorted(flaky.calls[1:]) == [3, 3, 3]
     finally:
-        sched.close()
+        eng.close()
 
 
 def test_poisoned_request_error_does_not_fail_batchmates():
@@ -282,7 +282,7 @@ def test_poisoned_request_error_does_not_fail_batchmates():
         def merkle_root(self, leaves):
             return self._cpu.merkle_root(leaves)
 
-    sched = CoalescingScheduler(_Vetoing(), window_ms=200)
+    eng = VerificationEngine(_Vetoing(), hold_ms=200)
     try:
         good = _signed(2, tag=b"ok")
         poisoned = _signed(2, tag=b"poison")
@@ -293,7 +293,7 @@ def test_poisoned_request_error_does_not_fail_batchmates():
         def go(name, batch):
             start.wait()
             try:
-                out[name] = sched.batch_verify(*batch)
+                out[name] = eng.batch_verify(*batch)
             except Exception as e:
                 out[name] = e
 
@@ -308,15 +308,15 @@ def test_poisoned_request_error_does_not_fail_batchmates():
         assert out["good"] == (True, [True, True])
         assert isinstance(out["poisoned"], ConnectionError)
     finally:
-        sched.close()
+        eng.close()
 
 
 def test_chaos_error_faults_fall_back_per_request():
-    """CMTPU_FAULTS-style seeded chaos under the scheduler: injected errors
+    """CMTPU_FAULTS-style seeded chaos under the engine: injected errors
     on merged dispatches degrade to per-request retries, verdicts stay
     honest."""
     chaos = ChaosBackend(CpuBackend(), "error:0.5", seed=7)
-    sched = CoalescingScheduler(chaos, window_ms=150)
+    eng = VerificationEngine(chaos, hold_ms=150)
     try:
         for round_i in range(4):
             batches = [
@@ -329,7 +329,7 @@ def test_chaos_error_faults_fall_back_per_request():
             def go(i):
                 start.wait()
                 try:
-                    results[i] = sched.batch_verify(*batches[i])
+                    results[i] = eng.batch_verify(*batches[i])
                 except ConnectionError:
                     results[i] = "error"
 
@@ -348,12 +348,12 @@ def test_chaos_error_faults_fall_back_per_request():
             if results[2] != "error":
                 assert results[2] == (True, [True, True])
     finally:
-        sched.close()
+        eng.close()
 
 
 def test_flip_fault_cannot_cross_request_boundaries():
     """A flip-corrupted tier under the SUPERVISED chain, under the
-    scheduler: the cpu cross-check catches the false-accept, and the one
+    engine: the cpu cross-check catches the false-accept, and the one
     request carrying an invalid signature is the only one whose bitmap
     shows it — a flip in its lane never flips a batchmate."""
     flipping = ChaosBackend(CpuBackend(), "flip:1.0", seed=3)
@@ -363,7 +363,7 @@ def test_flip_fault_cannot_cross_request_boundaries():
         deadline_ms=0,
         crosscheck="full",
     )
-    sched = CoalescingScheduler(chain, window_ms=200)
+    eng = VerificationEngine(chain, hold_ms=200)
     try:
         batches = [_signed(2, tag=b"flip-%d" % i) for i in range(3)]
         batches[0][2][0] = b"\x04" * 64  # only request 0 is invalid
@@ -372,7 +372,7 @@ def test_flip_fault_cannot_cross_request_boundaries():
 
         def go(i):
             start.wait()
-            results[i] = sched.batch_verify(*batches[i])
+            results[i] = eng.batch_verify(*batches[i])
 
         threads = [threading.Thread(target=go, args=(i,)) for i in range(3)]
         for t in threads:
@@ -383,7 +383,7 @@ def test_flip_fault_cannot_cross_request_boundaries():
         assert results[1] == (True, [True, True]), "batchmate must not flip"
         assert results[2] == (True, [True, True]), "batchmate must not flip"
     finally:
-        sched.close()
+        eng.close()
 
 
 # -- pod-scale coalescing ------------------------------------------------
@@ -431,23 +431,23 @@ def test_default_cap_scales_with_mesh_width(monkeypatch):
     """The default dispatch cap is 16384 x mesh width (one merged dispatch
     can fill every chip); an explicit env or ctor arg always wins."""
     monkeypatch.delenv("CMTPU_COALESCE_MAX", raising=False)
-    sched = CoalescingScheduler(CpuBackend(), window_ms=0)
+    eng = VerificationEngine(CpuBackend(), hold_ms=0)
     try:
-        assert sched.max_sigs == 16384 * 8  # the 8-device conftest mesh
-        assert sched.counters()["max_sigs"] == 131072
+        assert eng.max_sigs == 16384 * 8  # the 8-device conftest mesh
+        assert eng.counters()["max_sigs"] == 131072
     finally:
-        sched.close()
+        eng.close()
     monkeypatch.setenv("CMTPU_COALESCE_MAX", "4096")
-    sched = CoalescingScheduler(CpuBackend(), window_ms=0)
+    eng = VerificationEngine(CpuBackend(), hold_ms=0)
     try:
-        assert sched.max_sigs == 4096
+        assert eng.max_sigs == 4096
     finally:
-        sched.close()
-    sched = CoalescingScheduler(CpuBackend(), window_ms=0, max_sigs=5)
+        eng.close()
+    eng = VerificationEngine(CpuBackend(), hold_ms=0, max_sigs=5)
     try:
-        assert sched.max_sigs == 5
+        assert eng.max_sigs == 5
     finally:
-        sched.close()
+        eng.close()
 
 
 @pytest.mark.mesh
@@ -456,14 +456,14 @@ def test_pod_scale_merged_dispatch_with_per_caller_slicing():
     merge into ONE columnar dispatch under the pod-width default cap, and
     the single poisoned lane must come back to its own caller only."""
     gate = _MarkerGate()
-    sched = CoalescingScheduler(gate, window_ms=0)
+    eng = VerificationEngine(gate, hold_ms=0)
     try:
-        assert sched.max_sigs >= 8 * 4096
-        head = sched.submit(*_synthetic(1, b"head"))
+        assert eng.max_sigs >= 8 * 4096
+        head = eng.submit(*_synthetic(1, b"head"))
         while not gate.calls:  # dispatcher wedged inside call #1
             time.sleep(0.001)
         futs = [
-            sched.submit(
+            eng.submit(
                 *_synthetic(4096, b"req-%d" % i,
                             poison={100} if i == 3 else ())
             )
@@ -480,30 +480,36 @@ def test_pod_scale_merged_dispatch_with_per_caller_slicing():
             else:
                 assert ok and all(bits)
         assert gate.calls == [1, 32768], "pod batch must be ONE dispatch"
-        c = sched.counters()
+        c = eng.counters()
         assert c["coalesced_dispatches"] == 1
         assert c["batched_requests"] == 8
         assert c["fallback_splits"] == 0
     finally:
         gate.release.set()
-        sched.close()
+        eng.close()
 
 
-def test_auto_backend_composition_strips_with_knob(monkeypatch):
+def test_auto_backend_composes_one_engine(monkeypatch):
+    """`auto` and `get_bn254_backend` each put one engine, holding `HOLD_MS`,
+    in front of the supervised chain."""
+    from cometbft_tpu.crypto import bn254
+    from cometbft_tpu.sidecar.engine import engine_of
+
     monkeypatch.setenv("CMTPU_BACKEND", "auto")
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.delenv("CMTPU_FAULTS", raising=False)
     old = backend_mod._backend
+    old_bn = bn254._backend
     try:
-        monkeypatch.setenv("CMTPU_COALESCE", "0")
         backend_mod.set_backend(None)
-        bare = backend_mod.get_backend()
-        assert isinstance(bare, ResilientBackend)
-        monkeypatch.delenv("CMTPU_COALESCE")
-        backend_mod.set_backend(None)
-        sched = backend_mod.get_backend()
-        assert isinstance(sched, CoalescingScheduler)
-        assert isinstance(sched.inner, ResilientBackend)
-        sched.close()
+        bn254._backend = None
+        for eng in (backend_mod.get_backend(), bn254.get_bn254_backend()):
+            assert isinstance(eng, VerificationEngine)
+            assert engine_of(eng) is eng
+            assert eng.hold_ms == HOLD_MS == 2.0
+            assert isinstance(eng.inner, ResilientBackend)
+            assert engine_of(eng.inner) is None, "one engine, not two"
+            eng.close()
     finally:
         backend_mod.set_backend(old)
+        bn254._backend = old_bn

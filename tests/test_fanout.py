@@ -338,7 +338,7 @@ def test_engine_cap_and_rate_model_scale_through_fanout(monkeypatch):
     from cometbft_tpu.sidecar.engine import VerificationEngine
     from cometbft_tpu.sidecar.supervisor import ResilientBackend
 
-    monkeypatch.delenv("CMTPU_ENGINE_MAX", raising=False)
+    monkeypatch.delenv("CMTPU_COALESCE_MAX", raising=False)
     monkeypatch.setenv("CMTPU_DEV_RATE", "10.0")
     monkeypatch.setenv("CMTPU_DEV_OVERHEAD_MS", "3.0")
     a, b = _StubShard(1), _StubShard(1)
@@ -347,7 +347,7 @@ def test_engine_cap_and_rate_model_scale_through_fanout(monkeypatch):
     sup = ResilientBackend(
         [("fanout", fan), ("cpu", CpuBackend())], crosscheck="off"
     )
-    eng = VerificationEngine(sup)
+    eng = VerificationEngine(sup, hold_ms=0)
     try:
         cap0 = eng.refresh_cap()
         assert cap0 >= 16384 * 2

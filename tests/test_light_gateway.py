@@ -20,7 +20,7 @@ from cometbft_tpu.light.gateway import GatewayError, LightGateway
 from cometbft_tpu.light.mmr import MMR, verify_inclusion
 from cometbft_tpu.sidecar import backend as _be
 from cometbft_tpu.sidecar.backend import CpuBackend
-from cometbft_tpu.sidecar.scheduler import CoalescingScheduler
+from cometbft_tpu.sidecar.engine import VerificationEngine
 from cometbft_tpu.types.cmttime import Time
 
 from tests.test_light import (
@@ -394,7 +394,7 @@ def test_gateway_concurrent_swarm_shares_plan():
 
 
 def test_gateway_concurrent_distinct_targets_coalesce():
-    """N clients with DISTINCT targets on a shared CoalescingScheduler:
+    """N clients with DISTINCT targets on a shared VerificationEngine:
     each plan computation dispatches its own verification work, and the
     concurrent dispatches must merge into batched columnar calls (the
     coalesce ratio the whole design leans on)."""
@@ -403,8 +403,8 @@ def test_gateway_concurrent_distinct_targets_coalesce():
     targets = [40 - 2 * i for i in range(n_clients)]  # 40, 38, ... 30
 
     saved = _be._backend
-    sched = CoalescingScheduler(CpuBackend(), window_ms=60)
-    _be.set_backend(sched)
+    eng = VerificationEngine(CpuBackend(), hold_ms=60)
+    _be.set_backend(eng)
     _ed._verified.clear()
     try:
         gw = _gateway(chain)
@@ -436,12 +436,12 @@ def test_gateway_concurrent_distinct_targets_coalesce():
             assert r[2]["fallbacks"] == 0
 
         assert gw.stats()["plan_misses"] == n_clients  # all distinct keys
-        c = sched.counters()
+        c = eng.counters()
         assert c["batched_requests"] > 0, c
         assert c["requests"] / max(1, c["dispatches"]) > 1.0, c
     finally:
         _be.set_backend(saved)
-        sched.close()
+        eng.close()
         _ed._verified.clear()
 
 

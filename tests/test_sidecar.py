@@ -10,6 +10,7 @@ import pytest
 
 from cometbft_tpu.sidecar import backend as backend_mod
 from cometbft_tpu.sidecar.backend import CpuBackend
+from cometbft_tpu.sidecar.engine import VerificationEngine
 from cometbft_tpu.sidecar.service import GrpcBackend, SidecarServer
 from cometbft_tpu.crypto import ed25519
 from cometbft_tpu.crypto.merkle import hash_from_byte_slices
@@ -376,26 +377,22 @@ def test_supervisor_mesh_width_is_widest_tier():
 def test_coalescer_auto_cap_refreshes_from_width(monkeypatch):
     # The auto merge cap must follow the chain's width as a grpc tier
     # learns its pod's size from Ping — and never shrink back.
-    from cometbft_tpu.sidecar.scheduler import CoalescingScheduler
-
     monkeypatch.delenv("CMTPU_COALESCE_MAX", raising=False)
     inner = _WidthStubBackend(1)
-    sched = CoalescingScheduler(inner)
-    initial = sched.max_sigs
+    eng = VerificationEngine(inner)
+    initial = eng.max_sigs
     assert initial % 16384 == 0
     inner.width = (initial // 16384) * 2  # the remote pod is wider
-    assert sched.refresh_cap() == 16384 * inner.width
+    assert eng.refresh_cap() == 16384 * inner.width
     inner.width = 1  # a narrower reading later must not shrink the cap
-    assert sched.refresh_cap() == sched.max_sigs
-    sched.close()
+    assert eng.refresh_cap() == eng.max_sigs
+    eng.close()
 
 
 def test_coalescer_pinned_cap_never_moves():
-    from cometbft_tpu.sidecar.scheduler import CoalescingScheduler
-
-    sched = CoalescingScheduler(_WidthStubBackend(8), max_sigs=99)
-    assert sched.refresh_cap() == 99 and sched.max_sigs == 99
-    sched.close()
+    eng = VerificationEngine(_WidthStubBackend(8), max_sigs=99)
+    assert eng.refresh_cap() == 99 and eng.max_sigs == 99
+    eng.close()
 
 
 # -- round 10: frame guard + chunked streaming -------------------------------
@@ -742,11 +739,12 @@ def test_10000_lanes_cross_unary_and_streamed_in_the_order_sent(monkeypatch):
 
 
 def test_server_coalesces_across_connections(monkeypatch):
-    """Tentpole part 3: concurrent CONNECTIONS merge into one device
-    dispatch via the server-side scheduler, bitmaps sliced per request."""
-    monkeypatch.setenv("CMTPU_COALESCE_WINDOW_MS", "75")
+    """Concurrent CONNECTIONS merge into one device dispatch through the
+    engine the server submits to, bitmaps sliced per request."""
+    eng = VerificationEngine(CpuBackend(), hold_ms=75)
     addr = f"127.0.0.1:{_free_port()}"
-    server = SidecarServer(addr, backend=CpuBackend()).start()
+    server = SidecarServer(addr, backend=eng).start()
+    assert server.engine is eng
     clients = [GrpcBackend(addr, timeout_s=10) for _ in range(3)]
     try:
         pubs, msgs, sigs = _signed_triples(6, tag=b"merge", corrupt=(2,))
@@ -766,7 +764,7 @@ def test_server_coalesces_across_connections(monkeypatch):
         assert not errors, errors
         expected = [i != 2 for i in range(6)]
         assert results == [(False, expected)] * 3
-        c = server.scheduler_counters()
+        c = server.engine.counters()
         assert c["requests"] == 3
         assert c["coalesced_dispatches"] >= 1
         assert c["batched_requests"] >= 2
@@ -776,6 +774,7 @@ def test_server_coalesces_across_connections(monkeypatch):
         for cl in clients:
             cl.close()
         server.shutdown()
+        eng.close()
 
 
 class _KillMidStreamServer:

@@ -18,10 +18,11 @@ import pytest
 
 import chip_smoke
 from cometbft_tpu import native
+from cometbft_tpu.crypto import ed25519
 from cometbft_tpu.libs import trace
 from cometbft_tpu.sidecar import backend as be
 from cometbft_tpu.sidecar import service
-from cometbft_tpu.sidecar.engine import engine_of
+from cometbft_tpu.sidecar.engine import VerificationEngine, engine_of
 from cometbft_tpu.sidecar.service import GrpcBackend, SidecarServer
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -315,6 +316,13 @@ def test_both_processes_record_one_request_under_a_capture_and_nothing_without(c
     ureq = _one(mine, "sidecar.request", req=unary["attrs"]["req"], lanes=CHUNK)[0]
     assert ureq["attrs"]["bytes_in"] == unary["attrs"]["bytes_out"]
     assert [s["name"] for s in mine if s["parent"] == ureq["id"]].count("sidecar.decode") == 1
+    # A key column seen twice under the capture queued its table build on the
+    # device-owner thread: the build's span opened under the capture and is
+    # written when the build ends. That thread runs its queue in order, so
+    # once a no-op queued now has run, every such span is in the ring.
+    from cometbft_tpu.ops import ed25519_kernel as ek
+
+    ek._pool().submit(lambda: None).result(300)
     # with neither a capture nor a profiler session: nothing, on either side
     trace.clear()
     got = _node(server.bound_addr, validators=96, sizes=[CHUNK])
@@ -368,7 +376,7 @@ def test_four_clients_sending_one_commit_get_the_answers_they_would_get_alone(mo
     trace.clear()
     try:
         assert all(c.ping() for c in clients)
-        before = server.scheduler_counters()
+        before = server.engine.counters()
         with trace.capture():
             threads = [threading.Thread(target=node, args=(k,)) for k in range(len(requests))]
             for t in threads:
@@ -379,7 +387,7 @@ def test_four_clients_sending_one_commit_get_the_answers_they_would_get_alone(mo
         assert answers == answers_alone(requests)
         assert [ok for ok, _ in answers] == [True, False, True, True]
         assert [j for j, b in enumerate(answers[1][1]) if not b] == [5, 40]
-        served, after = server.counters(), server.scheduler_counters()
+        served, after = server.counters(), server.engine.counters()
         assert served["lanes_in"] == sum(c.counters()["lanes_sent"] for c in clients) == 4 * 96
         assert served["connections_accepted"] == 4 == served["connections_open"]
         assert after["coalesced_dispatches"] > before["coalesced_dispatches"]
@@ -438,19 +446,59 @@ def test_a_server_with_no_backend_given_serves_get_backends_chain_and_holds_one_
         assert again.backend is be.get_backend() is backend
     finally:
         again.shutdown()
-    assert server._sched is None and server._front is engine_of(backend)
-    assert server.scheduler_counters()["requests"] == backend.counters()["requests"]
+    assert server.engine is engine_of(backend) is backend
+    assert server.engine.counters()["requests"] == backend.counters()["requests"]
     # the warm-up and the lines that say what is served find the device tier under the chain
     hybrid = backend.inner.tiers[0].backend
     assert service._device_tier(backend) is hybrid
     assert server.device_counters()["platform"] == "cpu"
-    # a bare backend keeps the server's own scheduler, as before
+    # a bare backend gets an engine of the server's own, over the device lock
     bare = SidecarServer("127.0.0.1:0", backend=be.CpuBackend())
     try:
-        assert bare._front is bare._sched is not None
+        assert isinstance(bare.engine, VerificationEngine) and bare.engine is not backend
+        assert isinstance(bare.engine.inner, be.LockedBackend)
         assert bare.device_counters() == {} and bare.warmup() is False
     finally:
         bare.shutdown()
+
+
+class _Forwarding:
+    """A pass-through that forwards every attribute read, as the benchmark's
+    traced runs wrap the process backend before they hand it to a sidecar."""
+
+    def __init__(self, inner):
+        self._inner = inner
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+
+def test_a_server_handed_a_forwarding_wrapper_submits_to_the_engine_behind_it(monkeypatch):
+    monkeypatch.setenv("CMTPU_BACKEND", "auto")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.delenv("CMTPU_SIDECAR_ADDR", raising=False)
+    monkeypatch.delenv("CMTPU_FAULTS", raising=False)
+    old = be._backend
+    be.set_backend(None)
+    engine = be.get_backend()
+    server = SidecarServer("127.0.0.1:0", backend=_Forwarding(engine)).start()
+    client = GrpcBackend(server.bound_addr, timeout_s=30)
+    try:
+        # the front is the process engine itself: no second one, no third hold
+        assert server.engine is engine and not server._owns_engine
+        pvs = [ed25519.gen_priv_key_from_secret(b"fwd-%d" % i) for i in range(5)]
+        msgs = [b"fwd-msg-%d" % i for i in range(5)]
+        sigs = [pv.sign(m) for pv, m in zip(pvs, msgs)]
+        sigs[3] = b"\x07" * 64
+        pubs = [pv.pub_key().bytes() for pv in pvs]
+        before = engine.counters()["requests"]
+        assert client.batch_verify(pubs, msgs, sigs) == (False, [True, True, True, False, True])
+        assert engine.counters()["requests"] == before + 1
+    finally:
+        client.close()
+        server.shutdown()
+        engine.close()
+        be.set_backend(old)
 
 
 def test_a_sidecar_refuses_to_serve_the_grpc_backend():
@@ -489,7 +537,7 @@ def test_the_command_takes_its_address_out_of_the_environment(monkeypatch, capsy
     assert chain == ["cpu"], "JAX_PLATFORMS=cpu: the supervised chain of a host with no accelerator"
     assert "CMTPU_SIDECAR_ADDR" not in os.environ
     lines = capsys.readouterr().out.splitlines()
-    assert lines[0].startswith("sidecar: serving on 127.0.0.1:") and "backend=coalesce" in lines[0]
+    assert lines[0].startswith("sidecar: serving on 127.0.0.1:") and "backend=engine" in lines[0]
     assert lines[1] == "sidecar: backend {}"
     served = json.loads(next(ln for ln in lines if ln.startswith("sidecar: stopping, server "))[26:])
     assert served["requests"] == 1 and served["bytes_in"] > 0 and served["bytes_out"] > 0

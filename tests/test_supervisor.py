@@ -345,11 +345,11 @@ def test_auto_backend_is_supervised(monkeypatch):
     backend_mod.set_backend(None)
     try:
         b = backend_mod.get_backend()
-        # auto composes scheduler -> supervisor; the supervised chain is
-        # the scheduler's inner tier (CMTPU_COALESCE=0 strips the front).
-        from cometbft_tpu.sidecar.scheduler import CoalescingScheduler
+        # auto composes engine -> supervisor; the supervised chain is the
+        # engine's inner tier.
+        from cometbft_tpu.sidecar.engine import VerificationEngine
 
-        assert isinstance(b, CoalescingScheduler)
+        assert isinstance(b, VerificationEngine)
         assert isinstance(b.inner, ResilientBackend)
         pubs, msgs, sigs = _signed(3, tag=b"auto")
         ok, bits = b.batch_verify(pubs, msgs, sigs)

@@ -458,7 +458,7 @@ def test_engine_counters_keep_the_wait_percentile():
         def batch_verify(self, pubs, msgs, sigs):
             return True, [True] * len(pubs)
 
-    eng = engine_mod.VerificationEngine(Inner())
+    eng = engine_mod.VerificationEngine(Inner(), hold_ms=0)
     try:
         assert eng.batch_verify([b"p"], [b"m"], [b"s"]) == (True, [True])
         assert "queue_wait_p95_ms" in eng.counters()
@@ -785,7 +785,7 @@ def test_the_counters_reach_metrics_through_lazy_gauges():
     from cometbft_tpu.node.node import Node
 
     class Chain:  # the shape counters() has under CMTPU_BACKEND=auto
-        name = "coalesce"
+        name = "engine"
 
         def counters(self):
             hybrid = {"split_calls": 7, "share_changes": 3, "plan_abs_err_ms": 12.5,
@@ -851,7 +851,7 @@ def test_the_engines_totals_are_engine_gauges(monkeypatch):
     reg = Registry(namespace="cmt")
     Node._register_engine_metrics(reg)
     assert "cmt_engine_requests 0" in reg.render()  # no backend yet: nothing constructed
-    eng = engine_mod.VerificationEngine(Inner())
+    eng = engine_mod.VerificationEngine(Inner(), hold_ms=0)
     be.set_backend(eng)
     try:
         for _ in range(3):
